@@ -62,7 +62,7 @@ def hh_base_corpus(n_synth: int = 480, seed: int = 0):
 
 
 # Policy/base sizes for the hh chain. "tiny" is the round-4 byte-level
-# recipe; the BPE sizes answer VERDICT r4 item 5 (move off char-level): the
+# recipe; the BPE sizes move the chain off char-level: the
 # tokenizer is a from-scratch byte-level BPE trained on the hh corpus
 # (trlx_tpu/pipeline/bpe.py), "small" is what one CPU core converges inside a
 # round, "125m" is gpt2-124M-shaped (12x768) for the TPU-queue variant.

@@ -149,7 +149,7 @@ def train_ranking_rm(out_dir: str, steps: int, seed: int = 0,
 
     ``tokenizer_path`` must match the policy's tokenizer family (a bpe://
     tokenizer for the BPE hh sizes): the RM has to read exactly the strings
-    the policy emits (VERDICT r4 item 5)."""
+    the policy emits."""
     from flax import serialization
 
     from examples.summarize_rlhf.reward_model import train_reward_model

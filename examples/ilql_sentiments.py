@@ -6,8 +6,7 @@ eval sentiment hovers near 0 — the corpus is 50/50 positive/negative, so a
 well-fit LM generates balanced text (mean 0 is the LM optimum), and the
 advantage-shaped decode can only tilt toward positive WORDS once the base is
 fluent enough to emit them, which a 4-layer byte model barely reaches. The
-learning dynamics themselves are verified on randomwalks
-(PARITY_r3.json: ILQL 0.0 -> 0.83); with a real pretrained checkpoint
+learning dynamics themselves are verified on randomwalks; with a real pretrained checkpoint
 (reference: gpt2 + its tokenizer) this script runs the real task unchanged."""
 
 import sys

@@ -37,10 +37,9 @@ echo "seeded scheduler_race correctly rejected"
 
 echo "== tests"
 if [[ "${1:-}" == "--slow" ]]; then
-    # full suite; records the round's TESTS artifact (pass/fail counts,
-    # duration, slowest 10) so the suite status is committed evidence —
-    # including failures, so the report must be written even when pytest fails
-    ROUND_TESTS="${TESTS_ARTIFACT:-TESTS_r04.json}"
+    # full suite; writes a TESTS report (pass/fail counts, duration, slowest
+    # 10) — including failures, so it must be written even when pytest fails
+    ROUND_TESTS="${TESTS_ARTIFACT:-TESTS_report.json}"
     rc=0
     python -m pytest tests/ -q --junit-xml=/tmp/trlx_junit.xml || rc=$?
     python scripts/test_report.py /tmp/trlx_junit.xml "$ROUND_TESTS"
@@ -88,7 +87,7 @@ echo "== graftcheck-ir budget gate (python -m trlx_tpu.analysis.ir)"
 # deviates from graftcheck-ir-budget.json, or a new IR001-IR004 finding
 # appears. An INTENDED profile change is committed by regenerating the budget:
 #   python -m trlx_tpu.analysis.ir --write-budget   # then commit the diff
-# (TRLX_COMPILE_CACHE makes repeat runs cheap.)
+# (JAX_COMPILATION_CACHE_DIR makes repeat runs cheap.)
 timeout -k 10 900 python -m trlx_tpu.analysis.ir
 
 echo "== analysis-rt tests (CPU)"

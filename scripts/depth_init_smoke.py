@@ -1,22 +1,21 @@
 """Depth-48 init smoke: does 1/sqrt(2L) residual-projection init remove the
-first-step loss spikes PARITY_r4 recorded?
+first-step loss spikes a gpt2-xl-shaped run showed?
 
-Round-4 observed: the gpt2-xl-shaped (48 x 1600) random-init SFT stage spiked
+Observed: the gpt2-xl-shaped (48 x 1600) random-init SFT stage spiked
 3.3 -> 7-13 in its first steps at lr 1e-4 (clip+warmup active) while the
-24-layer model trained cleanly, and attributed it to "scale dynamics". VERDICT
-r4 named the actual suspect: every projection initialized at a flat 0.02,
-where HF GPT-2 (and therefore the reference via from_pretrained,
+24-layer model trained cleanly. The suspect: every projection initialized at
+a flat 0.02, where HF GPT-2 (and therefore the reference via from_pretrained,
 modeling_base.py:124-161) scales residual-out projections by 1/sqrt(2*L).
 transformer.py now applies that scaling by default (depth_scaled_init).
 
 This runs the EXACT failing recipe a few steps with the fix on vs off and
-records both loss curves. Round-5 outcome (DEPTH_INIT_r5.json): NEGATIVE —
+records both loss curves. Outcome on the CPU: NEGATIVE —
 with verified-correct scaled init the spike persists (3.31 -> 9.86 over 8
 steps; flat control 3.28 -> 5.01), so the instability is early-Adam scale
 dynamics, not initialization; the init change stays for HF random-init parity.
 ~60 min per variant on one CPU core (1.47B, f32, single device).
 
-Usage: python scripts/depth_init_smoke.py [--out DEPTH_INIT_r5.json] [--steps 8]
+Usage: python scripts/depth_init_smoke.py [--out DEPTH_INIT.json] [--steps 8]
 """
 
 import json
@@ -95,14 +94,14 @@ def run_variant(scaled: bool, steps: int):
 
 
 def main():
-    out_path = os.path.join(REPO, "DEPTH_INIT_r5.json")
+    out_path = os.path.join(REPO, "DEPTH_INIT.json")
     if "--out" in sys.argv:
         out_path = sys.argv[sys.argv.index("--out") + 1]
     steps = int(sys.argv[sys.argv.index("--steps") + 1]) if "--steps" in sys.argv else 8
 
     result = {
         "task": "48x1600 (1.47B) random-init SFT, lr 1e-4, clip+warmup — the "
-                "PARITY_r4 spike recipe — with depth-scaled residual init on vs off",
+                "first-step spike recipe — with depth-scaled residual init on vs off",
         "reference": "HF GPT-2 _init_weights 1/sqrt(2*n_layer), inherited by the "
                      "reference via from_pretrained (modeling_base.py:124-161)",
         "steps": steps,

@@ -12,8 +12,7 @@ numbers are reproducible):
   measured steady-state train step 927s at B=16,T=10 (2026-07-30, this box)
   -> a 120-SFT + 25-PPO convergence run would take ~2 days of wall clock.
 
-The TPU variant of the leg stays in scripts/tpu_queue.json (the chip turns
-these steps around in seconds — bench.py's xl_train_tok_s leg measures it).
+On the chip these steps are bench.py's xl_train leg (not measured yet).
 
 Usage: PYTHONPATH=/root/repo JAX_PLATFORMS=cpu python scripts/xl_microbench.py
            [--layers 48] [--hidden 1600] [--batch 16] [--seq 10]

@@ -331,50 +331,62 @@ def test_committed_budget_covers_every_small_entrypoint():
 # -------------------------------------------------- persistent compile cache
 
 
-def test_resolve_cache_dir_precedence(monkeypatch):
+def _cache_config(train_dir=None, mesh_dir=None):
     from types import SimpleNamespace
 
     from trlx_tpu.data.configs import MeshConfig, TrainConfig
-    from trlx_tpu.utils.compilation_cache import resolve_cache_dir
 
-    monkeypatch.delenv("TRLX_COMPILE_CACHE", raising=False)
-    assert TrainConfig().compilation_cache_dir is None  # knob exists, off by default
-    config = SimpleNamespace(
-        train=TrainConfig(compilation_cache_dir="/train-dir"),
-        mesh=MeshConfig(compilation_cache_dir="/mesh-dir"),
+    return SimpleNamespace(
+        train=TrainConfig(compilation_cache_dir=train_dir),
+        mesh=MeshConfig(compilation_cache_dir=mesh_dir),
     )
+
+
+def test_resolve_cache_dir_precedence(monkeypatch):
+    from trlx_tpu.data.configs import TrainConfig
+    from trlx_tpu.utils.compilation_cache import JAX_ENV_VAR, resolve_cache_dir
+
+    monkeypatch.delenv(JAX_ENV_VAR, raising=False)
+    assert TrainConfig().compilation_cache_dir is None  # knob exists, off by default
+    config = _cache_config("/train-dir", "/mesh-dir")
     assert resolve_cache_dir(config, cache_dir="/explicit") == "/explicit"
     assert resolve_cache_dir(config) == "/train-dir"
-    config.train.compilation_cache_dir = None
-    assert resolve_cache_dir(config) == "/mesh-dir"
-    config.mesh.compilation_cache_dir = None
-    assert resolve_cache_dir(config) is None
-    monkeypatch.setenv("TRLX_COMPILE_CACHE", "/env-dir")
-    assert resolve_cache_dir(config) == "/env-dir"
-    assert resolve_cache_dir(None) == "/env-dir"
+    assert resolve_cache_dir(_cache_config(mesh_dir="/mesh-dir")) == "/mesh-dir"
+    # nothing configured, CPU backend: the cache stays off
+    assert jax.default_backend() == "cpu"
+    assert resolve_cache_dir(_cache_config()) is None
+    assert resolve_cache_dir(None) is None
 
 
-def test_cpu_guard_declines_cache_for_executing_callers(tmp_path, monkeypatch):
-    # executing a cache-deserialized donated executable corrupts the heap on
-    # the CPU backend (jaxlib 0.4.36) — callers that will run what they
-    # compile (the trainer) must get None here, not a configured cache
-    import logging as pylogging
-
+def test_jax_env_var_set_means_no_directory_set_in_code(monkeypatch):
+    # whoever runs the program placed the cache: with the variable set, no
+    # path of ours may name another directory, whatever the config says
     from trlx_tpu.utils import compilation_cache as cc
 
-    monkeypatch.delenv(cc.FORCE_ENV_VAR, raising=False)
-    assert jax.default_backend() == "cpu"
-    messages = []
-    handler = pylogging.Handler()
-    handler.emit = lambda r: messages.append(r.getMessage())
-    base_logger = cc.logger.logger  # unwrap the MultiProcessAdapter
-    base_logger.addHandler(handler)
-    try:
-        assert cc.configure_compilation_cache(cache_dir=str(tmp_path / "c")) is None
-    finally:
-        base_logger.removeHandler(handler)
-    assert any("corrupts the heap" in m for m in messages)
-    assert not (tmp_path / "c").exists()  # declined before any mkdir
+    monkeypatch.setenv(cc.JAX_ENV_VAR, "/placed/from/outside")
+    updates = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update", lambda key, value: (updates.append(key), real_update(key, value))
+    )
+    config = _cache_config("/train-dir", "/mesh-dir")
+    assert cc.resolve_cache_dir(config, cache_dir="/explicit") is None
+    for backend in ("cpu", "tpu"):
+        monkeypatch.setattr(jax, "default_backend", lambda backend=backend: backend)
+        cc.configure_compilation_cache(cache_dir="/explicit", config=config)
+    assert "jax_compilation_cache_dir" not in updates
+
+
+def test_unset_on_tpu_resolves_one_fixed_path_inside_the_checkout(monkeypatch):
+    from trlx_tpu.utils import compilation_cache as cc
+
+    monkeypatch.delenv(cc.JAX_ENV_VAR, raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    first, second = cc.resolve_cache_dir(_cache_config()), cc.resolve_cache_dir(None)
+    assert first == second == os.path.join(REPO_ROOT, ".jax_cache")
+    # listed in .gitignore, so a checkout never carries a cache along
+    with open(os.path.join(REPO_ROOT, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
 
 
 def test_second_lower_hits_persistent_cache(tmp_path):
@@ -386,14 +398,12 @@ def test_second_lower_hits_persistent_cache(tmp_path):
         """
         import logging, os, sys
         os.environ["JAX_PLATFORMS"] = "cpu"
+        os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)  # the argument places it here
         cache_dir = sys.argv[1]
 
         from trlx_tpu.utils.compilation_cache import configure_compilation_cache
-        # compile_only: this process never executes what it compiles, which
-        # exempts it from the CPU cache guard (module docstring)
         assert configure_compilation_cache(
-            cache_dir=cache_dir, min_compile_time_secs=0.0,
-            compile_only=True) == cache_dir
+            cache_dir=cache_dir, min_compile_time_secs=0.0) == cache_dir
 
         records = []
         handler = logging.Handler()

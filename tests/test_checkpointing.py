@@ -193,7 +193,7 @@ def test_trainer_runs_from_native_checkpoint(native_dir, tmp_path):
 
 def test_convert_missing_weights_raises(tmp_path):
     """A preset name (no local weights) must NOT silently produce a random-init
-    'native checkpoint' (ADVICE r2): raising is the default, --allow-random the
+    'native checkpoint': raising is the default, --allow-random the
     explicit opt-in."""
     from trlx_tpu import checkpointing
 

@@ -1,0 +1,122 @@
+"""Compile the main path's Pallas kernels for a described (not attached) TPU
+v5e chip, at the published widths of the models they serve.
+
+Interpret mode, which every other kernel test uses, checks numerics and never
+the chip compiler's rules (block shapes against the dtype's tile, VMEM, what
+Mosaic can lower). The TPU compiler is installed with jax and compiles for a
+topology that is only described, so these cases guard every later PR at no
+chip time. Nothing runs: a pass here is a compile, not a chip run.
+
+This is the only file that describes a topology, and it does so inside a
+fixture: only one process may load the TPU library, so a call made while any
+module is imported would break the other xdist workers' collection.
+"""
+
+import functools
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from trlx_tpu.models.presets import PRESETS
+from trlx_tpu.ops import attention
+from trlx_tpu.ops.paged_attention import (
+    paged_attention_pallas,
+    paged_pool_layout,
+    paged_verify_attention_pallas,
+)
+
+BLOCK_SIZE = 16  # ServingConfig.block_size default
+SLOTS, NUM_BLOCKS, MAX_BLOCKS = 32, 1024, 8
+FLASH_B, FLASH_T = 8, 512
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache but
+    cannot be read back without the chip; keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _flash(grad):
+    c = PRESETS["gpt2"]
+    shape = (FLASH_B, c.num_heads, FLASH_T, c.dim_per_head)
+
+    def fwd(q, k, v, kv_valid):
+        return attention.flash_attention(q, k, v, kv_valid, True, None, 128, 128, False)
+
+    def loss(q, k, v, kv_valid):
+        return fwd(q, k, v, kv_valid).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
+    return fn, [(shape, jnp.bfloat16)] * 3 + [((FLASH_B, FLASH_T), jnp.int32)]
+
+
+def _paged(preset, quant, q_len):
+    c = PRESETS[preset]
+    layout = paged_pool_layout(
+        NUM_BLOCKS, BLOCK_SIZE, c.kv_heads, c.dim_per_head, jnp.bfloat16, quant
+    )
+    q_shape = (SLOTS, c.num_heads, c.dim_per_head)
+    kernel = paged_attention_pallas
+    if q_len:
+        q_shape = (SLOTS, q_len) + q_shape[1:]
+        kernel = paged_verify_attention_pallas
+    shapes = [
+        (q_shape, jnp.bfloat16), layout["k"], layout["v"],
+        ((SLOTS, MAX_BLOCKS), jnp.int32), ((SLOTS,), jnp.int32),
+    ]
+    if not quant:
+        return kernel, shapes
+
+    def quantized(q, k, v, tables, lens, k_scale, v_scale):
+        return kernel(q, k, v, tables, lens, k_scale=k_scale, v_scale=v_scale)
+
+    return quantized, shapes + [layout["k_scale"], layout["v_scale"]]
+
+
+CASES = {
+    "flash_fwd-gpt2": functools.partial(_flash, grad=False),
+    "flash_grad_pallas_bwd-gpt2": functools.partial(_flash, grad=True),
+}
+for _preset in ("gpt2", "gpt_bigcode"):  # Hkv=12 rep=1 D=64; Hkv=1 rep=16 D=128
+    for _pool, _quant in (("bf16", False), ("int8", True)):
+        CASES[f"paged_decode-{_pool}-{_preset}"] = functools.partial(
+            _paged, _preset, _quant, 0
+        )
+        CASES[f"paged_verify_q4-{_pool}-{_preset}"] = functools.partial(
+            _paged, _preset, _quant, 4
+        )
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(case, one_chip, no_persistent_cache, monkeypatch):
+    monkeypatch.setattr(attention, "BACKWARD_IMPL", "pallas")  # read at trace time
+    fn, shapes = CASES[case]()
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()  # raises what the chip's compiler would
+    assert "tpu_custom_call" in compiled.as_text()

@@ -1,0 +1,86 @@
+"""``chip_smoke.py`` rehearsed on the CPU: its kernel, PPO and sharded phase
+functions at tiny widths (Pallas interpreted), and its refusal to report
+anything from a device that is not a TPU. The chip run itself is the driver's;
+a pass here says the script's paths, arguments and assertions hold together."""
+
+import os
+import sys
+
+import pytest
+
+import jax
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO_ROOT)
+
+import chip_smoke  # noqa: E402
+
+TINY = chip_smoke.Sizes(
+    # vocab above the byte tokenizer's 259, as gpt2's 50257 is: ids it cannot
+    # decode must be survivable
+    model_overrides=dict(
+        vocab_size=300, hidden_size=32, num_layers=2, num_heads=2,
+        intermediate_size=64, max_position_embeddings=64,
+    ),
+    compute_dtype="float32",
+    prompt_len=8, new_tokens=8, batch=4, steps=3,
+    flash_batch=2, flash_len=128,
+    slots=4, num_blocks=40, block_size=4, max_blocks=4,
+    interpret=True,
+)
+
+
+def test_kernels_phase_at_tiny_widths(capsys):
+    chip_smoke.phase_kernels(TINY)
+    lines = [l for l in capsys.readouterr().out.splitlines() if l.startswith("[kernels]")]
+    assert len(lines) == 8  # flash out/dq/dk/dv + paged {bf16,int8} x {decode,verify}
+    assert all("max|diff|=" in l for l in lines)
+
+
+@pytest.mark.parametrize("serving", [False, True], ids=["one_shot", "serving"])
+def test_ppo_phase_at_tiny_widths(tmp_path, capsys, serving):
+    """Through ``trlx_tpu.train()`` on a one-device mesh, as on the chip."""
+    one_device = jax.devices()[:1]
+    with _only_devices(one_device):
+        trainer = chip_smoke.phase_ppo(TINY, str(tmp_path), serving=serving)
+    assert trainer.iter_count == TINY.steps
+    assert (trainer._serving_client is not None) == serving
+    out = capsys.readouterr().out
+    assert "0 after it (CompileWatcher)" in out
+    assert f"step {TINY.steps}/{TINY.steps}:" in out
+    if serving:
+        assert "resolved paged impl: xla" in out  # the CPU's own path
+
+
+def test_sharded_phase_on_four_virtual_devices(tmp_path, capsys):
+    with _only_devices(jax.devices()[:4]):
+        chip_smoke.phase_sharded(TINY, str(tmp_path))
+    out = capsys.readouterr().out
+    assert out.count("  device ") == 4
+    assert "first optimizer step health/grad_norm" in out
+
+
+def test_main_refuses_a_cpu_device(capsys):
+    assert jax.devices()[0].platform == "cpu"
+    assert chip_smoke.main([]) != 0
+    captured = capsys.readouterr()
+    assert '"ok"' not in captured.out and "[" not in captured.out  # no phase ran
+    assert "not a TPU" in captured.err
+
+
+class _only_devices:
+    """The test session has 8 virtual CPU devices; the chip has 1 (or 4). Show
+    the code under test that many: every mesh it builds from ``jax.devices()``
+    is then the mesh it would build there."""
+
+    def __init__(self, devices):
+        self.devices = list(devices)
+
+    def __enter__(self):
+        self._patch = pytest.MonkeyPatch()
+        self._patch.setattr(jax, "devices", lambda *a, **k: self.devices)
+        self._patch.setattr(jax, "device_count", lambda *a, **k: len(self.devices))
+        return self
+
+    def __exit__(self, *exc):
+        self._patch.undo()

@@ -162,7 +162,7 @@ def test_fused_top_k_top_p_matches_sequential():
     the tokens the sequential top-k -> top-p composition keeps. The two paths
     normalize softmax over different element counts (k vs V), so a token whose
     cumulative mass lands within float eps of p may legitimately flip — accept
-    mismatches only at such boundary tokens (ADVICE r4)."""
+    mismatches only at such boundary tokens."""
     from trlx_tpu.ops.sampling import apply_top_k_top_p
 
     rng = np.random.default_rng(0)

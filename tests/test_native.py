@@ -52,3 +52,33 @@ def test_find_stop_positions_matches_numpy(lib):
     seqs2 = np.array([[9, 9, 1, 2, 9, 9]], np.int32)
     assert native.find_stop_positions(seqs2, [[1, 2]])[0] == 2
     assert native.find_stop_positions(seqs2, [[7]])[0] == 6
+
+
+def test_stale_library_is_rebuilt(lib, tmp_path, monkeypatch):
+    """The built file is git-ignored and travels with copies of the tree, so
+    it is keyed to a hash of its source: a binary of any other source is not
+    loaded, and is dropped when the right one is built."""
+    import os
+    import shutil
+
+    shutil.copy(native._SRC_PATH, tmp_path / "data_plane.cpp")
+    monkeypatch.setattr(native, "_HERE", str(tmp_path))
+    monkeypatch.setattr(native, "_SRC_PATH", str(tmp_path / "data_plane.cpp"))
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_tried", False)
+    stale = [tmp_path / "libdata_plane.so", tmp_path / "libdata_plane.0123456789abcdef.so"]
+    for path in stale:
+        path.write_bytes(b"not a shared object: loading this would fail")
+
+    assert native.get_lib() is not None  # built from the source beside it
+    built = native.so_path()
+    assert os.path.exists(built) and not any(path.exists() for path in stale)
+
+    # the source moves on: the old binary no longer answers to it
+    with open(native._SRC_PATH, "a") as f:
+        f.write("\n// edited\n")
+    assert native.so_path() != built
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_tried", False)
+    assert native.get_lib() is not None
+    assert os.path.exists(native.so_path()) and not os.path.exists(built)

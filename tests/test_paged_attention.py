@@ -16,6 +16,7 @@ from trlx_tpu.ops.paged_attention import (
     paged_attention_pallas,
     paged_attention_xla,
     paged_decode_attention,
+    paged_pool_layout,
     paged_verify_attention,
     paged_verify_attention_pallas,
     paged_verify_attention_xla,
@@ -29,14 +30,20 @@ B, HKV, REP, D = 3, 2, 2, 8
 NB, BS, MB = 10, 4, 4  # 10 blocks of 4 tokens, up to 16 tokens per slot
 
 
+def _dense_tokens(pool, tables):
+    """Head-major pool [NB, Hkv, BS, ...] through [B, MB] tables -> f64
+    [B, MB*BS, Hkv, ...]: each slot's tokens in order, one row per token."""
+    g = np.asarray(pool, np.float64)[np.asarray(tables)]  # [B, MB, Hkv, BS, ...]
+    g = np.swapaxes(g, 2, 3)
+    return g.reshape((g.shape[0], MB * BS) + g.shape[3:])
+
+
 def _dense_reference(q, k_pool, v_pool, tables, lens, k_scale=None, v_scale=None):
     """Gather into dense [B, S, Hkv, D] f64 arrays and do plain softmax attention."""
     q = np.asarray(q, np.float64)
-    kd = np.asarray(k_pool, np.float64)[np.asarray(tables)].reshape(B, MB * BS, HKV, D)
-    vd = np.asarray(v_pool, np.float64)[np.asarray(tables)].reshape(B, MB * BS, HKV, D)
+    kd, vd = _dense_tokens(k_pool, tables), _dense_tokens(v_pool, tables)
     if k_scale is not None:
-        ks = np.asarray(k_scale, np.float64)[np.asarray(tables)].reshape(B, MB * BS, HKV)
-        vs = np.asarray(v_scale, np.float64)[np.asarray(tables)].reshape(B, MB * BS, HKV)
+        ks, vs = _dense_tokens(k_scale, tables), _dense_tokens(v_scale, tables)
     out = np.zeros((B, HKV * REP, D))
     for b in range(B):
         for h in range(HKV * REP):
@@ -57,24 +64,20 @@ def _make_pools(rng, quant):
     """Pools + a block table with a PREFIX-SHARED block (slots 0 and 1 both
     map their first block to physical block 1) and a mid-batch-replaced slot
     (slot 2 got fresh blocks from a later admission wave, short context)."""
-    kf = rng.standard_normal((NB, BS, HKV, D)).astype(np.float32)
-    vf = rng.standard_normal((NB, BS, HKV, D)).astype(np.float32)
+    kf = rng.standard_normal((NB, HKV, BS, D)).astype(np.float32)
+    vf = rng.standard_normal((NB, HKV, BS, D)).astype(np.float32)
     tables = np.array(
         [[1, 2, 3, 0], [1, 4, 0, 0], [7, 8, 0, 0]], np.int32
     )
     lens = np.array([11, 6, 2], np.int32)
     if not quant:
         return jnp.asarray(kf), jnp.asarray(vf), None, None, tables, lens, kf, vf
-    kq, ks = quantize_kv_rows(jnp.asarray(kf).reshape(NB * BS, HKV, D))
-    vq, vs = quantize_kv_rows(jnp.asarray(vf).reshape(NB * BS, HKV, D))
-    k_pool = kq.reshape(NB, BS, HKV, D)
-    v_pool = vq.reshape(NB, BS, HKV, D)
-    k_scale = ks[..., 0].reshape(NB, BS, HKV)
-    v_scale = vs[..., 0].reshape(NB, BS, HKV)
+    # quantize_kv_rows scales each [D] row on its own, whatever leads it
+    k_pool, ks = quantize_kv_rows(jnp.asarray(kf))
+    v_pool, vs = quantize_kv_rows(jnp.asarray(vf))
     # the dense reference consumes raw int8 + scales the same way
-    kd = np.asarray(kq).reshape(NB, BS, HKV, D)
-    vd = np.asarray(vq).reshape(NB, BS, HKV, D)
-    return k_pool, v_pool, k_scale, v_scale, tables, lens, kd, vd
+    return (k_pool, v_pool, ks[..., 0], vs[..., 0], tables, lens,
+            np.asarray(k_pool), np.asarray(v_pool))
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8kv"])
@@ -144,16 +147,19 @@ def test_dispatch_impls():
         paged_decode_attention(q, k_pool, v_pool, jnp.asarray(tables), jnp.asarray(lens), impl="mosaic")
 
 
+def _zero_pools(quant):
+    """One layer's empty pools in the engine's own layout."""
+    return {
+        key: jnp.zeros(shape, dtype)
+        for key, (shape, dtype) in paged_pool_layout(
+            NB, BS, HKV, D, jnp.float32, quant
+        ).items()
+    }
+
+
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8kv"])
 def test_write_paged_kv_lands_at_context_len(quant):
-    layout = {"k": jnp.zeros((NB, BS, HKV, D), jnp.float32), "v": jnp.zeros((NB, BS, HKV, D), jnp.float32)}
-    if quant:
-        layout = {
-            "k": jnp.zeros((NB, BS, HKV, D), jnp.int8),
-            "v": jnp.zeros((NB, BS, HKV, D), jnp.int8),
-            "k_scale": jnp.zeros((NB, BS, HKV), jnp.float32),
-            "v_scale": jnp.zeros((NB, BS, HKV), jnp.float32),
-        }
+    layout = _zero_pools(quant)
     tables = jnp.asarray(np.array([[1, 2, 3, 0], [4, 5, 0, 0], [6, 0, 0, 0]], np.int32))
     lens = jnp.asarray(np.array([5, 0, 3], np.int32))
     cache = {**layout, "block_tables": tables, "context_lens": lens}
@@ -166,7 +172,7 @@ def test_write_paged_kv_lands_at_context_len(quant):
     # slot 0: len 5 -> block tables[0][1]=2, offset 1; slot 1: len 0 -> block 4
     # offset 0; slot 2: len 3 -> block 6 offset 3
     for b, (blk, off) in enumerate([(2, 1), (4, 0), (6, 3)]):
-        np.testing.assert_allclose(k[blk, off], np.asarray(k_new)[b], rtol=0.02, atol=0.02)
+        np.testing.assert_allclose(k[blk, :, off], np.asarray(k_new)[b], rtol=0.02, atol=0.02)
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8kv"])
@@ -219,10 +225,10 @@ def test_paged_decode_matches_contiguous_greedy(quant):
                 row = rows[:, t]  # [Hkv, D]
                 if quant:
                     qrow, s = quantize_kv_rows(jnp.asarray(row)[None])
-                    pool[blk, off] = np.asarray(qrow[0])
-                    scale[blk, off] = np.asarray(s[0, :, 0])
+                    pool[blk, :, off] = np.asarray(qrow[0])
+                    scale[blk, :, off] = np.asarray(s[0, :, 0])
                 else:
-                    pool[blk, off] = row
+                    pool[blk, :, off] = row
             pcache[key][li] = jnp.asarray(pool)
             if quant:
                 pcache[key + "_scale"][li] = jnp.asarray(scale)
@@ -248,11 +254,9 @@ def _dense_verify_reference(q, k_pool, v_pool, tables, lens, k_scale=None,
     head): query j sees positions < lens[b] + j + 1."""
     B, Q, H, D = q.shape
     qf = np.asarray(q, np.float64)
-    kd = np.asarray(k_pool, np.float64)[np.asarray(tables)].reshape(B, MB * BS, HKV, D)
-    vd = np.asarray(v_pool, np.float64)[np.asarray(tables)].reshape(B, MB * BS, HKV, D)
+    kd, vd = _dense_tokens(k_pool, tables), _dense_tokens(v_pool, tables)
     if k_scale is not None:
-        ks = np.asarray(k_scale, np.float64)[np.asarray(tables)].reshape(B, MB * BS, HKV)
-        vs = np.asarray(v_scale, np.float64)[np.asarray(tables)].reshape(B, MB * BS, HKV)
+        ks, vs = _dense_tokens(k_scale, tables), _dense_tokens(v_scale, tables)
     out = np.zeros((B, Q, H, D))
     for b in range(B):
         for j in range(Q):
@@ -340,17 +344,7 @@ def test_write_paged_kv_multi_equals_sequential_single_writes(quant):
     including the per-row quantization (rows quantize independently in both
     paths)."""
     Q = 3
-    layout = {
-        "k": jnp.zeros((NB, BS, HKV, D), jnp.float32),
-        "v": jnp.zeros((NB, BS, HKV, D), jnp.float32),
-    }
-    if quant:
-        layout = {
-            "k": jnp.zeros((NB, BS, HKV, D), jnp.int8),
-            "v": jnp.zeros((NB, BS, HKV, D), jnp.int8),
-            "k_scale": jnp.zeros((NB, BS, HKV), jnp.float32),
-            "v_scale": jnp.zeros((NB, BS, HKV), jnp.float32),
-        }
+    layout = _zero_pools(quant)
     tables = jnp.asarray(np.array([[1, 2, 3, 0], [4, 5, 0, 0], [6, 9, 0, 0]], np.int32))
     lens = np.array([3, 0, 6], np.int32)  # slot 0 straddles a block boundary
     rng = np.random.default_rng(8)
@@ -372,10 +366,7 @@ def test_write_paged_kv_multi_equals_sequential_single_writes(quant):
 def test_write_paged_kv_multi_drops_positions_past_the_table():
     """Positions >= max_blocks*block_size must be dropped outright (not wrap,
     not corrupt the null block beyond what padding already does)."""
-    layout = {
-        "k": jnp.zeros((NB, BS, HKV, D), jnp.float32),
-        "v": jnp.zeros((NB, BS, HKV, D), jnp.float32),
-    }
+    layout = _zero_pools(quant=False)
     tables = jnp.asarray(np.array([[1, 0, 0, 0]] * B, np.int32))
     lens = jnp.asarray(np.array([MB * BS - 1, MB * BS - 1, MB * BS - 1], np.int32))
     k_new = jnp.ones((B, 2, HKV, D), jnp.float32)  # position 0 in-range, 1 past
@@ -384,7 +375,7 @@ def test_write_paged_kv_multi_drops_positions_past_the_table():
     )
     k = np.asarray(out["k"])
     assert k.sum() > 0  # the in-range position landed...
-    written = np.argwhere(np.abs(k).sum(axis=(2, 3)) > 0)
+    written = np.argwhere(np.abs(k).sum(axis=(1, 3)) > 0)  # (block, offset)
     assert {tuple(w) for w in written} <= {(0, BS - 1)}  # ...only at table reach
 
 

@@ -152,8 +152,7 @@ def test_pipelined_cached_decode_matches_on_mesh(setup):
     (data=2 x pipe=2 x model=2): the sequential layer scan streams every
     stage's param shards (transformer.py `_apply_stacked` cache branch), and
     its prefill + per-token logits must match the single-stage listed model.
-    VERDICT r4 weak-item 4: this path was trusted single-device, untested
-    multi-device."""
+    This path was once trusted single-device, untested multi-device."""
     ids, mask, m_list, p_list, _, _, p_stack = setup
     m_pp = TransformerLM(CFG.replace(pipeline_stages=2, pipeline_microbatches=2))
     mesh = make_mesh(data=2, fsdp=1, model=2, pipe=2)

@@ -51,7 +51,7 @@ def test_tokenize_dialogue_left_truncation():
 
 
 def test_tokenize_dialogue_right_truncation_saturated_empty_prompt():
-    """The one truncation edge round 3 left unpinned (VERDICT r3 weak #6): on
+    """A truncation edge worth pinning: on
     the RIGHT-truncation side, a fully-truncated leading prompt (only possible
     via an empty prompt string) triggers the bos re-insertion, and when the
     surviving content already saturates max_length the algorithm must trim one
@@ -200,8 +200,8 @@ def test_bpe_tokenizer_roundtrip_and_compression(tmp_path):
     """From-scratch byte-level BPE (trlx_tpu/pipeline/bpe.py): merges learned
     on a corpus must (a) roundtrip exactly on arbitrary text, (b) compress
     corpus words into multi-byte tokens, (c) persist through save/load and the
-    bpe:// tokenizer scheme (VERDICT r4 item 5: move the hh chain off
-    char-level tokenization)."""
+    bpe:// tokenizer scheme (what moves the hh chain off char-level
+    tokenization)."""
     from trlx_tpu.data.configs import TokenizerConfig
     from trlx_tpu.pipeline.bpe import BPETokenizer, train_bpe, train_and_save
     from trlx_tpu.pipeline.tokenization import load_tokenizer

@@ -181,31 +181,45 @@ def test_lint_catches_violations(tmp_path):
     assert "E999" in proc.stdout
 
 
-@pytest.mark.slow
-def test_bench_child_emits_driver_schema():
-    """bench.py is the driver's interface: the child must print exactly one JSON
-    line with the metric keys the driver records, on whatever platform jax
-    provides (CPU here)."""
-    import json
+def test_bench_refuses_to_measure_a_cpu():
+    """bench.py measures a TPU or nothing: on a CPU device it exits non-zero
+    and prints no result line (no child, no fallback, no cached capture)."""
     import subprocess
     import sys
 
     env = dict(os.environ, PYTHONPATH=REPO_ROOT, JAX_PLATFORMS="cpu")
     proc = subprocess.run(
-        [sys.executable, os.path.join(REPO_ROOT, "bench.py"), "--child"],
-        capture_output=True, text=True, env=env, cwd=REPO_ROOT, timeout=620,
+        [sys.executable, os.path.join(REPO_ROOT, "bench.py")],
+        capture_output=True, text=True, env=env, cwd=REPO_ROOT, timeout=300,
     )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    json_lines = [l for l in proc.stdout.splitlines() if l.startswith("{")]
-    assert len(json_lines) == 1, proc.stdout[-2000:]
-    result = json.loads(json_lines[0])
-    # the perf extras are best-effort in bench.py; surface their recorded error
-    assert "gpt2_perf_error" not in result, result
-    for key in ("metric", "value", "unit", "vs_baseline", "platform",
-                "gpt2_rollout_new_tok_s", "gpt2_train_mfu", "gpt2_rollout_bw_bound_tok_s"):
-        assert key in result, (key, result)
-    assert result["metric"] == "ppo_rollout_update_samples_per_sec_per_chip"
-    assert result["value"] > 0
+    assert proc.returncode != 0
+    assert proc.stdout.strip() == "", proc.stdout[-2000:]
+    assert "measures a TPU" in proc.stderr
+
+
+def test_bench_unknown_device_kind_raises():
+    """A device that is not in the peaks table is an error, not v5e."""
+    import bench
+
+    assert bench._peak_flops("TPU v5 lite") == 197e12
+    assert bench._peak_bw("TPU v5 lite") == 819e9
+    for peak in (bench._peak_flops, bench._peak_bw):
+        with pytest.raises(ValueError, match="no published peak"):
+            peak("TPU v9 mystery")
+        with pytest.raises(ValueError, match="no published peak"):
+            peak("cpu")
+
+
+def test_get_git_tag_without_git(monkeypatch, tmp_path):
+    """The chip machine's copy is no repository and may have no ``git``: the
+    trainer's run name must survive both."""
+    from trlx_tpu.utils import get_git_tag
+
+    monkeypatch.setenv("PATH", str(tmp_path))  # no git anywhere on it
+    assert get_git_tag() == ("unknown", "unknown")
+    monkeypatch.undo()
+    monkeypatch.chdir(tmp_path)  # git, but not a repository
+    assert get_git_tag() == ("unknown", "unknown")
 
 
 def test_rouge_scores_known_values():
@@ -242,8 +256,7 @@ def test_rouge_scores_known_values():
 
 def test_summarize_metric_fn_computes():
     """The summarize_rlhf eval metric_fn (live ROUGE + RM score) must produce
-    per-sample metric lists shaped for the trainer's evaluate() (VERDICT r4
-    item 4: the ROUGE evaluation path the repo lacked)."""
+    per-sample metric lists shaped for the trainer's evaluate()."""
     from examples.summarize_rlhf.rouge_eval import evaluate_summaries, make_metric_fn
 
     gold = {"doc a TL;DR:": "storm market", "doc b TL;DR:": "goal"}

@@ -20,8 +20,8 @@ Exit status: 1 on any new IR001–IR004 finding or any IR005/IR006 budget
 deviation, else 0 — the contract the ``analysis-ir`` section of
 ``scripts/ci.sh`` gates on. Runs devicelessly: ``__main__`` forces a virtual
 CPU platform (``TRLX_IR_DEVICES``, default 8) before jax is imported, and the
-persistent compilation cache (``TRLX_COMPILE_CACHE``) makes repeat runs
-cheap.
+persistent compilation cache (``JAX_COMPILATION_CACHE_DIR``) makes repeat
+runs cheap.
 """
 
 import argparse
@@ -54,12 +54,10 @@ def main(argv=None) -> int:
     args = parser.parse_args(argv)
 
     # repeat audits (and the trainer itself) share one on-disk compile cache;
-    # must run before the first compile of the process to take effect. The
-    # audit only inspects compiled artifacts — it never executes them — so it
-    # is exempt from the CPU cache guard.
+    # must run before the first compile of the process to take effect
     from trlx_tpu.utils.compilation_cache import configure_compilation_cache
 
-    configure_compilation_cache(compile_only=True)
+    configure_compilation_cache()
 
     entries = load_all()
     if args.list_entrypoints:

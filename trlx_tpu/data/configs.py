@@ -213,9 +213,9 @@ class MeshConfig:
     pipe: int = 1
     model: int = 1
     pipeline_microbatches: int = 4
-    # Persistent XLA compilation cache directory (also settable via the
-    # TRLX_COMPILE_CACHE env var). First TPU compiles are 20-40s; subsequent
-    # runs with the same shapes restore from here in milliseconds.
+    # Persistent XLA compilation cache directory; yields to
+    # $JAX_COMPILATION_CACHE_DIR and to train.compilation_cache_dir
+    # (trlx_tpu/utils/compilation_cache.py).
     compilation_cache_dir: Optional[str] = None
     remat: str = "none"
     param_dtype: str = "float32"
@@ -910,13 +910,12 @@ class TrainConfig:
     tags: List[str] = field(default_factory=list)
 
     seed: int = 1000
-    # Persistent XLA compilation cache directory. Takes precedence over the
-    # older mesh.compilation_cache_dir knob and the TRLX_COMPILE_CACHE env var
-    # (resolution: trlx_tpu/utils/compilation_cache.py). Must be applied
-    # before the process's FIRST compile — the trainer does this before it
-    # even creates its PRNGKey. Ignored (with a warning) on the CPU backend:
-    # jaxlib 0.4.36 corrupts the heap when executing cache-deserialized
-    # donated executables there; TPU/GPU are unaffected.
+    # Persistent XLA compilation cache directory. Yields to
+    # $JAX_COMPILATION_CACHE_DIR, takes precedence over the older
+    # mesh.compilation_cache_dir knob; unset everywhere, a TPU run caches under
+    # <checkout>/.jax_cache (resolution: trlx_tpu/utils/compilation_cache.py).
+    # Must be applied before the process's FIRST compile — the trainer does
+    # this before it even creates its PRNGKey.
     compilation_cache_dir: Optional[str] = None
     resume_from_checkpoint: Optional[str] = None
     reward_only_on_last: bool = False

@@ -131,9 +131,8 @@ class PPOConfig(MethodConfig):
     # chunk_size). Decode is bandwidth-bound on the weights — every step streams
     # all parameters regardless of batch — so the decode batch wants to be as
     # wide as memory allows, independently of the reward/scoring chunk. The
-    # batch-width effect is recorded per round by bench.py's
-    # gpt2_rollout_new_tok_s (B=256) vs gpt2_rollout_new_tok_s_b32 keys
-    # (BENCH_r0N.json / .bench_tpu_cache.json; docs/evidence.md).
+    # batch-width effect is what bench.py's gpt2_rollout_new_tok_s (B=256) vs
+    # gpt2_rollout_new_tok_s_b32 keys record.
     decode_batch_size: Optional[int] = None
 
     def kl_controller(self):
@@ -272,7 +271,7 @@ def _build_train_step(spec: str, mesh, method) -> EntryArtifacts:
     from trlx_tpu.models.presets import PRESETS
     from trlx_tpu.parallel.mesh import BATCH_AXES
     from trlx_tpu.parallel.sharding import make_param_shardings, make_state_shardings
-    from trlx_tpu.utils.modeling import logprobs_of_labels
+    from trlx_tpu.utils.modeling import next_token_logprobs
 
     dims = {"small": dict(hidden=64, layers=2, heads=4, vocab=256, B=8, P=24, R=8)}[spec]
     model_config = PRESETS["gpt2"].replace(
@@ -327,7 +326,7 @@ def _build_train_step(spec: str, mesh, method) -> EntryArtifacts:
             logits = jax.lax.with_sharding_constraint(
                 logits, NamedSharding(mesh, PartitionSpec())
             )
-        logprobs = logprobs_of_labels(logits[:, :-1], seq[:, 1:])
+        logprobs = next_token_logprobs(logits, seq)
         start = mb.query_tensors.shape[1] - 1
         logprobs = logprobs[:, start:start + R]
         values_pred = values_pred[:, start:start + R].astype(jnp.float32)
@@ -375,13 +374,13 @@ def _ppo_audit_loss_fn(module, method, mesh, R: int):
     """The audit-shape PPO loss shared by the overlap entrypoints: same
     construction as ``build_ppo_train_step``'s, minus the seeds (the overlap
     seed lives in ``parallel/fsdp.py``'s step builder, not the loss)."""
-    from trlx_tpu.utils.modeling import logprobs_of_labels
+    from trlx_tpu.utils.modeling import next_token_logprobs
 
     def loss_fn(params, mb):
         seq = jnp.concatenate([mb.query_tensors, mb.response_tensors], axis=1)
         mask = jnp.concatenate([mb.attention_mask, mb.response_mask], axis=1)
         logits, values_pred, _, _ = module.apply({"params": params}, seq, mask)
-        logprobs = logprobs_of_labels(logits[:, :-1], seq[:, 1:])
+        logprobs = next_token_logprobs(logits, seq)
         start = mb.query_tensors.shape[1] - 1
         logprobs = logprobs[:, start:start + R]
         values_pred = values_pred[:, start:start + R].astype(jnp.float32)

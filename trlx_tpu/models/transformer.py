@@ -85,7 +85,7 @@ class TransformerConfig:
     # Scale the residual-out projections (o_proj/down_proj) by 1/sqrt(2*L):
     # each residual stream sums 2L projection outputs, so flat-std init grows
     # the stream variance linearly with depth — the depth-48 first-step loss
-    # spikes PARITY_r4 recorded (3.3 -> 7-13 under clip+warmup) while depth-24
+    # spikes a gpt2-xl-shaped run showed (3.3 -> 7-13 under clip+warmup) while depth-24
     # trained cleanly. HF GPT-2 applies exactly this scaling in _init_weights
     # ("Scale initializations of select weights... by 1/sqrt(2*n_layer)"), and
     # the reference inherits it through from_pretrained/from_config
@@ -1084,7 +1084,7 @@ class TransformerLM(nn.Module):
         batch_size: int, dtype=None,
     ) -> KVCache:
         """Block-pool cache for the serving engine (see ops/paged_attention.py):
-        per-layer k/v pools ``[num_blocks, block_size, Hkv, D]`` (int8 + f32
+        per-layer k/v pools ``[num_blocks, Hkv, block_size, D]`` (int8 + f32
         row scales under ``kv_cache_quant``) plus shared ``block_tables``
         ``[B, max_blocks_per_seq]`` and ``context_lens`` ``[B]``. Block 0 is
         the allocator's reserved null block; fresh tables point at it."""

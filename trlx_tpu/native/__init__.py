@@ -6,6 +6,8 @@ environments without a toolchain everything silently uses the numpy fallbacks.
 """
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import sys
@@ -14,24 +16,42 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
-_SO_PATH = os.path.join(_HERE, "libdata_plane.so")
+_SRC_PATH = os.path.join(_HERE, "data_plane.cpp")
 _lib = None
 _tried = False
 
 
+def so_path() -> str:
+    """The built library's path, keyed to a hash of its source: the file is
+    git-ignored and copies of the tree carry it along, so a binary must never
+    outlive the ``data_plane.cpp`` it was built from."""
+    with open(_SRC_PATH, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_HERE, f"libdata_plane.{digest}.so")
+
+
 def build(verbose: bool = False) -> Optional[str]:
-    """Compile data_plane.cpp -> libdata_plane.so. Returns the path or None."""
-    src = os.path.join(_HERE, "data_plane.cpp")
-    cmd = ["g++", "-O3", "-shared", "-fPIC", "-o", _SO_PATH, src]
+    """Compile data_plane.cpp -> :func:`so_path`, dropping binaries of any
+    other source. Returns the path or None."""
+    path = so_path()
+    tmp = f"{path}.{os.getpid()}.tmp"  # concurrent builders each rename a whole file
+    cmd = ["g++", "-O3", "-shared", "-fPIC", "-o", tmp, _SRC_PATH]
     try:
         res = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
         if res.returncode != 0:
             if verbose:
                 print(res.stderr, file=sys.stderr)
             return None
-        return _SO_PATH
+        os.replace(tmp, path)
     except (OSError, subprocess.TimeoutExpired):
         return None
+    for stale in glob.glob(os.path.join(_HERE, "libdata_plane*.so")):
+        if stale != path:
+            try:
+                os.remove(stale)
+            except OSError:
+                pass
+    return path
 
 
 def get_lib():
@@ -40,11 +60,12 @@ def get_lib():
     if _lib is not None or _tried:
         return _lib
     _tried = True
-    if not os.path.exists(_SO_PATH):
+    path = so_path()
+    if not os.path.exists(path):
         if build() is None:
             return None
     try:
-        lib = ctypes.CDLL(_SO_PATH)
+        lib = ctypes.CDLL(path)
         lib.pad_collate_i32.argtypes = [
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
             ctypes.c_int64, ctypes.c_int64, ctypes.c_int32, ctypes.c_int,

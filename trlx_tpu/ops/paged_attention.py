@@ -27,9 +27,11 @@ Two implementations with one contract:
 
 Layouts (per layer):
 
-- ``k_pool`` / ``v_pool``: ``[num_blocks, block_size, Hkv, D]`` in the cache
-  dtype, or int8 under quantization,
-- ``k_scale`` / ``v_scale``: ``[num_blocks, block_size, Hkv]`` f32 per-row
+- ``k_pool`` / ``v_pool``: ``[num_blocks, Hkv, block_size, D]`` in the cache
+  dtype, or int8 under quantization — head-major, so one head of one block
+  is a whole ``[block_size, D]`` tile: the block shape the chip's compiler
+  accepts for the kernel at any ``block_size`` and dtype,
+- ``k_scale`` / ``v_scale``: ``[num_blocks, Hkv, block_size]`` f32 per-row
   scales (quantized layout only; scheme: :func:`quantize_kv_rows`),
 - ``block_tables``: ``[B, max_blocks]`` int32 physical block ids,
 - ``context_lens``: ``[B]`` int32 — valid tokens per slot INCLUDING the token
@@ -55,10 +57,12 @@ from trlx_tpu.analysis.ir.entrypoints import EntryArtifacts, register_entrypoint
 NEG_INF = -1e30  # kernel-internal mask value (f32 exact, like ops/attention.py)
 
 
-def _group_query_heads(q: jnp.ndarray, kv_heads: int) -> jnp.ndarray:
-    """[B, H, D] -> [B, Hkv, rep, D] so query head h maps to kv head h // rep."""
-    B, H, D = q.shape
-    return q.reshape(B, kv_heads, H // kv_heads, D)
+def _gather_blocks(pool: jnp.ndarray, block_tables: jnp.ndarray) -> jnp.ndarray:
+    """Each slot's blocks laid end to end: ``[NB, Hkv, BS, ...]`` through
+    ``[B, MB]`` tables -> ``[B, Hkv, MB*BS, ...]``. Tables are always in range
+    (unused entries point at the null block 0)."""
+    g = jnp.moveaxis(jnp.take(pool, block_tables, axis=0), 2, 1)  # [B, Hkv, MB, BS, ...]
+    return g.reshape(g.shape[:2] + (g.shape[2] * g.shape[3],) + g.shape[4:])
 
 
 def paged_attention_xla(
@@ -67,46 +71,14 @@ def paged_attention_xla(
     v_pool: jnp.ndarray,
     block_tables: jnp.ndarray,
     context_lens: jnp.ndarray,
-    *,
-    k_scale: Optional[jnp.ndarray] = None,
-    v_scale: Optional[jnp.ndarray] = None,
-    scale: Optional[float] = None,
+    **kwargs,
 ) -> jnp.ndarray:
-    """Reference path: gather each slot's blocks, mask, softmax in f32.
-
-    q ``[B, H, D]`` (one decode token per slot); returns ``[B, H, D]`` in
-    ``q.dtype``. Scales (when given) fold into scores/probs exactly as the
-    Pallas kernel and the dense int8 decode path do.
-    """
-    B, H, D = q.shape
-    NB, BS, Hkv, _ = k_pool.shape
-    MB = block_tables.shape[1]
-    S = MB * BS
-    if scale is None:
-        scale = 1.0 / math.sqrt(D)
-
-    # [B, MB, BS, Hkv, D] -> [B, S, Hkv, D]; tables always in range (null block 0)
-    kh = jnp.take(k_pool, block_tables, axis=0).reshape(B, S, Hkv, D)
-    vh = jnp.take(v_pool, block_tables, axis=0).reshape(B, S, Hkv, D)
-    qg = _group_query_heads(q, Hkv)
-
-    scores = jnp.einsum(
-        "bkrd,bskd->bkrs", qg, kh, preferred_element_type=jnp.float32
-    ) * scale
-    if k_scale is not None:
-        ks = jnp.take(k_scale, block_tables, axis=0).reshape(B, S, Hkv)
-        scores = scores * ks.transpose(0, 2, 1)[:, :, None, :]
-    valid = jnp.arange(S)[None, :] < context_lens[:, None]  # [B, S]
-    scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1)
-    if v_scale is not None:
-        vs = jnp.take(v_scale, block_tables, axis=0).reshape(B, S, Hkv)
-        probs = probs * vs.transpose(0, 2, 1)[:, :, None, :]
-    out = jnp.einsum(
-        "bkrs,bskd->bkrd", probs, vh.astype(jnp.float32),
-        preferred_element_type=jnp.float32,
-    )
-    return out.reshape(B, H, D).astype(q.dtype)
+    """Reference path for one decode token per slot: q ``[B, H, D]``,
+    ``context_lens`` INCLUDING this step's token; returns ``[B, H, D]`` in
+    ``q.dtype``. The ``Q == 1`` case of :func:`paged_verify_attention_xla`."""
+    return paged_verify_attention_xla(
+        q[:, None], k_pool, v_pool, block_tables, context_lens - 1, **kwargs
+    )[:, 0]
 
 
 def paged_verify_attention_xla(
@@ -120,31 +92,31 @@ def paged_verify_attention_xla(
     v_scale: Optional[jnp.ndarray] = None,
     scale: Optional[float] = None,
 ) -> jnp.ndarray:
-    """Speculative-verify / chunked-prefill widening of the reference path.
+    """Reference path: gather each slot's blocks, mask, softmax in f32.
+    Scales (when given) fold into scores/probs exactly as the Pallas kernel
+    and the dense int8 decode path do.
 
-    q ``[B, Q, H, D]`` — Q tokens appended per slot in one step, token ``j``
-    sitting at position ``context_lens + j`` (``context_lens`` here = tokens
-    present BEFORE this step's append, unlike the decode entry which gets the
-    post-write count). Query ``j`` attends causally: positions
-    ``< context_lens + j + 1``. Returns ``[B, Q, H, D]``.
+    q ``[B, Q, H, D]`` — Q tokens appended per slot in one step (decode,
+    speculative verify, chunked prefill), token ``j`` sitting at position
+    ``context_lens + j`` (``context_lens`` here = tokens present BEFORE this
+    step's append, unlike the decode entry which gets the post-write count).
+    Query ``j`` attends causally: positions ``< context_lens + j + 1``.
+    Returns ``[B, Q, H, D]``.
 
-    Q folds into the grouped-head row axis so the contraction is the same
-    ``bkrd,bskd->bkrs`` einsum as the single-token path — masked scores sit at
-    :data:`NEG_INF`, whose softmax probability underflows to exact 0, so
-    stale/garbage KV past a slot's frontier contributes exactly nothing and
-    ``Q == 1`` with ``context_lens = lens`` reproduces the decode step's
-    output bit-for-bit.
+    Q folds into the grouped-head row axis (query head h maps to kv head
+    ``h // rep``) — masked scores sit at :data:`NEG_INF`, whose softmax
+    probability underflows to exact 0, so stale/garbage KV past a slot's
+    frontier contributes exactly nothing.
     """
     B, Q, H, D = q.shape
-    NB, BS, Hkv, _ = k_pool.shape
-    MB = block_tables.shape[1]
-    S = MB * BS
+    Hkv = k_pool.shape[1]
+    S = block_tables.shape[1] * k_pool.shape[2]
     rep = H // Hkv
     if scale is None:
         scale = 1.0 / math.sqrt(D)
 
-    kh = jnp.take(k_pool, block_tables, axis=0).reshape(B, S, Hkv, D)
-    vh = jnp.take(v_pool, block_tables, axis=0).reshape(B, S, Hkv, D)
+    kh = _gather_blocks(k_pool, block_tables)  # [B, Hkv, S, D]
+    vh = _gather_blocks(v_pool, block_tables)
     # [B, Q, H, D] -> [B, Hkv, Q*rep, D]; row r <-> (q_idx = r // rep, rep = r % rep)
     qg = (
         q.reshape(B, Q, Hkv, rep, D)
@@ -153,11 +125,10 @@ def paged_verify_attention_xla(
     )
 
     scores = jnp.einsum(
-        "bkrd,bskd->bkrs", qg, kh, preferred_element_type=jnp.float32
+        "bkrd,bksd->bkrs", qg, kh, preferred_element_type=jnp.float32
     ) * scale
     if k_scale is not None:
-        ks = jnp.take(k_scale, block_tables, axis=0).reshape(B, S, Hkv)
-        scores = scores * ks.transpose(0, 2, 1)[:, :, None, :]
+        scores = scores * _gather_blocks(k_scale, block_tables)[:, :, None, :]
     q_idx = jnp.arange(Q * rep, dtype=jnp.int32) // rep  # [Q*rep]
     valid = (
         jnp.arange(S, dtype=jnp.int32)[None, None, :]
@@ -166,10 +137,9 @@ def paged_verify_attention_xla(
     scores = jnp.where(valid[:, None, :, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     if v_scale is not None:
-        vs = jnp.take(v_scale, block_tables, axis=0).reshape(B, S, Hkv)
-        probs = probs * vs.transpose(0, 2, 1)[:, :, None, :]
+        probs = probs * _gather_blocks(v_scale, block_tables)[:, :, None, :]
     out = jnp.einsum(
-        "bkrs,bskd->bkrd", probs, vh.astype(jnp.float32),
+        "bkrs,bksd->bkrd", probs, vh.astype(jnp.float32),
         preferred_element_type=jnp.float32,
     )
     out = (
@@ -182,90 +152,30 @@ def paged_verify_attention_xla(
 
 def _paged_kernel(
     tables_ref,  # scalar prefetch: [B, MB] int32
-    lens_ref,  # scalar prefetch: [B] int32
-    q_ref,  # [1, 1, rep, D]
-    k_ref,  # [1, BS, 1, D]
-    v_ref,
-    ks_ref,  # [1, BS, 1] f32 or None (bound via partial when quantized)
-    vs_ref,
-    o_ref,  # [1, 1, rep, D]
-    m_scratch,  # [rep, 1] f32
-    l_scratch,  # [rep, 1] f32
-    acc_scratch,  # [rep, D] f32
-    *,
-    block_size: int,
-    num_blocks_per_seq: int,
-    scale: float,
-):
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        m_scratch[...] = jnp.full_like(m_scratch, NEG_INF)
-        l_scratch[...] = jnp.zeros_like(l_scratch)
-        acc_scratch[...] = jnp.zeros_like(acc_scratch)
-
-    q = q_ref[0, 0].astype(jnp.float32)  # [rep, D]
-    k = k_ref[0, :, 0, :].astype(jnp.float32)  # [BS, D]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # [rep, BS]
-    if ks_ref is not None:
-        s = s * ks_ref[0, :, 0][None, :]
-
-    # token index of each row in this block; valid rows only
-    token_idx = j * block_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block_size), 1
-    )
-    s = jnp.where(token_idx < lens_ref[b], s, NEG_INF)
-
-    m_prev = m_scratch[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    # fully-masked blocks keep m == NEG_INF; exp(s - m) would be exp(0) there
-    p = jnp.where(m_new > NEG_INF / 2, jnp.exp(s - m_new), 0.0)  # [rep, BS]
-    alpha = jnp.exp(m_prev - m_new)
-    l_scratch[...] = alpha * l_scratch[...] + jnp.sum(p, axis=1, keepdims=True)
-    if vs_ref is not None:
-        p = p * vs_ref[0, :, 0][None, :]
-    v = v_ref[0, :, 0, :].astype(jnp.float32)  # [BS, D]
-    acc_scratch[...] = acc_scratch[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    m_scratch[...] = m_new
-
-    @pl.when(j == num_blocks_per_seq - 1)
-    def _finalize():
-        l = l_scratch[...]
-        safe_l = jnp.where(l == 0.0, 1.0, l)  # lens >= 1, but never NaN anyway
-        o_ref[0, 0, ...] = (acc_scratch[...] / safe_l).astype(o_ref.dtype)
-
-
-def _paged_verify_kernel(
-    tables_ref,  # scalar prefetch: [B, MB] int32
     lens_ref,  # scalar prefetch: [B] int32 (tokens present BEFORE the append)
-    q_ref,  # [1, 1, Q*rep, D]
-    k_ref,  # [1, BS, 1, D]
+    q_ref,  # [rows, D], rows = Q*rep
+    k_ref,  # [BS, D]: one head of one physical block
     v_ref,
-    ks_ref,  # [1, BS, 1] f32 or None (bound via partial when quantized)
+    ks_ref,  # [Hkv, BS] f32 or None (bound via partial when quantized)
     vs_ref,
-    o_ref,  # [1, 1, Q*rep, D]
-    m_scratch,  # [Q*rep, 1] f32
-    l_scratch,  # [Q*rep, 1] f32
-    acc_scratch,  # [Q*rep, D] f32
+    o_ref,  # [rows, D]
+    m_scratch,  # [rows, 1] f32
+    l_scratch,  # [rows, 1] f32
+    acc_scratch,  # [rows, D] f32
     *,
     block_size: int,
     num_blocks_per_seq: int,
     scale: float,
     rep: int,
 ):
-    """Verify variant of :func:`_paged_kernel`: the Q query positions fold
-    into the row axis (row r is query ``r // rep``, query-head ``r % rep``) so
-    the per-block flash update is unchanged — only the mask limit becomes
-    per-row: query j sees tokens ``< lens + j + 1``. Kept separate from the
-    decode kernel so the ``spec_k == 0`` hot path stays byte-identical.
+    """One (slot, kv head) walks its block table along the innermost grid
+    axis with the flash-attention running max/sum. The Q query positions fold
+    into the row axis (row r is query ``r // rep``, query-head ``r % rep``), so
+    only the mask limit is per-row: query j sees tokens ``< lens + j + 1``.
+    Single-token decode is the ``Q == 1`` case.
     """
     b = pl.program_id(0)
+    h = pl.program_id(1)
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -274,13 +184,13 @@ def _paged_verify_kernel(
         l_scratch[...] = jnp.zeros_like(l_scratch)
         acc_scratch[...] = jnp.zeros_like(acc_scratch)
 
-    q = q_ref[0, 0].astype(jnp.float32)  # [Q*rep, D]
-    k = k_ref[0, :, 0, :].astype(jnp.float32)  # [BS, D]
+    q = q_ref[...].astype(jnp.float32)  # [rows, D]
+    k = k_ref[...].astype(jnp.float32)  # [BS, D]
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # [Q*rep, BS]
+    ) * scale  # [rows, BS]
     if ks_ref is not None:
-        s = s * ks_ref[0, :, 0][None, :]
+        s = s * ks_ref[pl.ds(h, 1), :]
 
     rows = q.shape[0]
     token_idx = j * block_size + jax.lax.broadcasted_iota(
@@ -291,12 +201,13 @@ def _paged_verify_kernel(
 
     m_prev = m_scratch[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    p = jnp.where(m_new > NEG_INF / 2, jnp.exp(s - m_new), 0.0)  # [Q*rep, BS]
+    # fully-masked blocks keep m == NEG_INF; exp(s - m) would be exp(0) there
+    p = jnp.where(m_new > NEG_INF / 2, jnp.exp(s - m_new), 0.0)  # [rows, BS]
     alpha = jnp.exp(m_prev - m_new)
     l_scratch[...] = alpha * l_scratch[...] + jnp.sum(p, axis=1, keepdims=True)
     if vs_ref is not None:
-        p = p * vs_ref[0, :, 0][None, :]
-    v = v_ref[0, :, 0, :].astype(jnp.float32)  # [BS, D]
+        p = p * vs_ref[pl.ds(h, 1), :]
+    v = v_ref[...].astype(jnp.float32)  # [BS, D]
     acc_scratch[...] = acc_scratch[...] * alpha + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
@@ -305,8 +216,8 @@ def _paged_verify_kernel(
     @pl.when(j == num_blocks_per_seq - 1)
     def _finalize():
         l = l_scratch[...]
-        safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0, ...] = (acc_scratch[...] / safe_l).astype(o_ref.dtype)
+        safe_l = jnp.where(l == 0.0, 1.0, l)  # lens >= 1, but never NaN anyway
+        o_ref[...] = (acc_scratch[...] / safe_l).astype(o_ref.dtype)
 
 
 def _drop_scale_refs(kernel):
@@ -321,7 +232,7 @@ def _drop_scale_refs(kernel):
     return wrapped
 
 
-def paged_attention_pallas(
+def paged_verify_attention_pallas(
     q: jnp.ndarray,
     k_pool: jnp.ndarray,
     v_pool: jnp.ndarray,
@@ -336,72 +247,16 @@ def paged_attention_pallas(
     """Fused kernel: grid ``(B, Hkv, max_blocks)``, block table scalar-prefetched
     so each step's BlockSpec index map selects the physical block to DMA —
     the gather never materializes ``[B, S, Hkv, D]`` in HBM, and int8 blocks
-    dequantize in-register via score/prob scale folding.
-    """
-    B, H, D = q.shape
-    NB, BS, Hkv, _ = k_pool.shape
-    MB = block_tables.shape[1]
-    rep = H // Hkv
-    if scale is None:
-        scale = 1.0 / math.sqrt(D)
-    quant = k_scale is not None
+    dequantize in-register via score/prob scale folding. q ``[B, Q, H, D]``;
+    ``context_lens`` = tokens present BEFORE the append.
 
-    qg = _group_query_heads(q, Hkv)  # [B, Hkv, rep, D]
-    kernel = functools.partial(
-        _paged_kernel, block_size=BS, num_blocks_per_seq=MB, scale=scale
-    )
-    if not quant:
-        kernel = _drop_scale_refs(kernel)
-
-    # index maps receive (*grid, *scalar_prefetch_refs)
-    q_spec = pl.BlockSpec((1, 1, rep, D), lambda b, h, j, t, n: (b, h, 0, 0))
-    kv_spec = pl.BlockSpec((1, BS, 1, D), lambda b, h, j, t, n: (t[b, j], 0, h, 0))
-    in_specs = [q_spec, kv_spec, kv_spec]
-    inputs = [qg, k_pool, v_pool]
-    if quant:
-        sc_spec = pl.BlockSpec((1, BS, 1), lambda b, h, j, t, n: (t[b, j], 0, h))
-        in_specs += [sc_spec, sc_spec]
-        inputs += [k_scale, v_scale]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, Hkv, MB),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, rep, D), lambda b, h, j, t, n: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((rep, 1), jnp.float32),
-            pltpu.VMEM((rep, 1), jnp.float32),
-            pltpu.VMEM((rep, D), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, rep, D), q.dtype),
-        interpret=interpret,
-    )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32), *inputs)
-    return out.reshape(B, H, D)
-
-
-def paged_verify_attention_pallas(
-    q: jnp.ndarray,
-    k_pool: jnp.ndarray,
-    v_pool: jnp.ndarray,
-    block_tables: jnp.ndarray,
-    context_lens: jnp.ndarray,
-    *,
-    k_scale: Optional[jnp.ndarray] = None,
-    v_scale: Optional[jnp.ndarray] = None,
-    scale: Optional[float] = None,
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """Fused verify kernel: same ``(B, Hkv, max_blocks)`` grid and scalar-
-    prefetched block walk as :func:`paged_attention_pallas`, with the Q query
-    positions folded into the row axis of each grid cell (``[Q*rep, D]``
-    tiles). ``context_lens`` = tokens present BEFORE the append.
+    Every block's two minor dimensions equal the operand's own (``[BS, D]`` of
+    the head-major pool, ``[Hkv, BS]`` of a scale pool, ``[rows, D]`` of the
+    grouped queries): the one block shape the TPU lowering takes at any
+    ``block_size``, head count and dtype.
     """
     B, Q, H, D = q.shape
-    NB, BS, Hkv, _ = k_pool.shape
+    NB, Hkv, BS, _ = k_pool.shape
     MB = block_tables.shape[1]
     rep = H // Hkv
     rows = Q * rep
@@ -415,18 +270,22 @@ def paged_verify_attention_pallas(
         .reshape(B, Hkv, rows, D)
     )
     kernel = functools.partial(
-        _paged_verify_kernel,
+        _paged_kernel,
         block_size=BS, num_blocks_per_seq=MB, scale=scale, rep=rep,
     )
     if not quant:
         kernel = _drop_scale_refs(kernel)
 
-    q_spec = pl.BlockSpec((1, 1, rows, D), lambda b, h, j, t, n: (b, h, 0, 0))
-    kv_spec = pl.BlockSpec((1, BS, 1, D), lambda b, h, j, t, n: (t[b, j], 0, h, 0))
+    # index maps receive (*grid, *scalar_prefetch_refs)
+    q_spec = pl.BlockSpec((None, None, rows, D), lambda b, h, j, t, n: (b, h, 0, 0))
+    kv_spec = pl.BlockSpec(
+        (None, None, BS, D), lambda b, h, j, t, n: (t[b, j], h, 0, 0)
+    )
     in_specs = [q_spec, kv_spec, kv_spec]
     inputs = [qg, k_pool, v_pool]
     if quant:
-        sc_spec = pl.BlockSpec((1, BS, 1), lambda b, h, j, t, n: (t[b, j], 0, h))
+        # all heads' scales of the block; the kernel picks row h
+        sc_spec = pl.BlockSpec((None, Hkv, BS), lambda b, h, j, t, n: (t[b, j], 0, 0))
         in_specs += [sc_spec, sc_spec]
         inputs += [k_scale, v_scale]
 
@@ -434,7 +293,7 @@ def paged_verify_attention_pallas(
         num_scalar_prefetch=2,
         grid=(B, Hkv, MB),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, rows, D), lambda b, h, j, t, n: (b, h, 0, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((rows, 1), jnp.float32),
             pltpu.VMEM((rows, 1), jnp.float32),
@@ -454,19 +313,23 @@ def paged_verify_attention_pallas(
     )
 
 
-def paged_decode_attention(
+def paged_attention_pallas(
     q: jnp.ndarray,
     k_pool: jnp.ndarray,
     v_pool: jnp.ndarray,
     block_tables: jnp.ndarray,
     context_lens: jnp.ndarray,
-    *,
-    k_scale: Optional[jnp.ndarray] = None,
-    v_scale: Optional[jnp.ndarray] = None,
-    scale: Optional[float] = None,
-    impl: str = "auto",
+    **kwargs,
 ) -> jnp.ndarray:
-    """Dispatch: ``impl`` in {"auto", "pallas", "xla"}.
+    """Single-token decode through the fused kernel: q ``[B, H, D]``,
+    ``context_lens`` INCLUDING this step's token — the ``Q == 1`` verify."""
+    return paged_verify_attention_pallas(
+        q[:, None], k_pool, v_pool, block_tables, context_lens - 1, **kwargs
+    )[:, 0]
+
+
+def resolve_paged_impl(impl: str = "auto") -> str:
+    """``impl`` in {"auto", "pallas", "xla"} -> the path that will run.
 
     "auto" picks the kernel on a single-device TPU backend and the XLA
     gather path everywhere else — a Mosaic kernel cannot be auto-partitioned
@@ -475,19 +338,11 @@ def paged_decode_attention(
     ``interpret=True`` in tests to prove parity).
     """
     if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" and jax.device_count() == 1 else "xla"
-    if impl == "pallas":
-        return paged_attention_pallas(
-            q, k_pool, v_pool, block_tables, context_lens,
-            k_scale=k_scale, v_scale=v_scale, scale=scale,
-            interpret=jax.default_backend() == "cpu",
-        )
-    if impl == "xla":
-        return paged_attention_xla(
-            q, k_pool, v_pool, block_tables, context_lens,
-            k_scale=k_scale, v_scale=v_scale, scale=scale,
-        )
-    raise ValueError(f"unknown paged attention impl {impl!r}")
+        single_tpu = jax.default_backend() == "tpu" and jax.device_count() == 1
+        return "pallas" if single_tpu else "xla"
+    if impl not in ("pallas", "xla"):
+        raise ValueError(f"unknown paged attention impl {impl!r}")
+    return impl
 
 
 def paged_verify_attention(
@@ -497,123 +352,107 @@ def paged_verify_attention(
     block_tables: jnp.ndarray,
     context_lens: jnp.ndarray,
     *,
-    k_scale: Optional[jnp.ndarray] = None,
-    v_scale: Optional[jnp.ndarray] = None,
-    scale: Optional[float] = None,
     impl: str = "auto",
+    **kwargs,
 ) -> jnp.ndarray:
-    """Multi-token dispatch, same ``impl`` policy as
-    :func:`paged_decode_attention`. q is ``[B, Q, H, D]``; ``context_lens``
-    counts tokens present BEFORE the Q-token append.
-    """
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" and jax.device_count() == 1 else "xla"
-    if impl == "pallas":
+    """Multi-token dispatch on :func:`resolve_paged_impl`. q is
+    ``[B, Q, H, D]``; ``context_lens`` counts tokens present BEFORE the
+    Q-token append; ``kwargs`` are the scale pools and the softmax scale."""
+    if resolve_paged_impl(impl) == "pallas":
         return paged_verify_attention_pallas(
             q, k_pool, v_pool, block_tables, context_lens,
-            k_scale=k_scale, v_scale=v_scale, scale=scale,
-            interpret=jax.default_backend() == "cpu",
+            interpret=jax.default_backend() == "cpu", **kwargs,
         )
-    if impl == "xla":
-        return paged_verify_attention_xla(
-            q, k_pool, v_pool, block_tables, context_lens,
-            k_scale=k_scale, v_scale=v_scale, scale=scale,
-        )
-    raise ValueError(f"unknown paged attention impl {impl!r}")
+    return paged_verify_attention_xla(
+        q, k_pool, v_pool, block_tables, context_lens, **kwargs
+    )
 
 
-def write_paged_kv(
-    cache: dict, k_new: jnp.ndarray, v_new: jnp.ndarray
-) -> dict:
-    """Write one token's K/V per slot into the block pool.
+def paged_decode_attention(
+    q: jnp.ndarray,
+    k_pool: jnp.ndarray,
+    v_pool: jnp.ndarray,
+    block_tables: jnp.ndarray,
+    context_lens: jnp.ndarray,
+    **kwargs,
+) -> jnp.ndarray:
+    """Single-token dispatch: q ``[B, H, D]``, ``context_lens`` INCLUDING this
+    step's token — the ``Q == 1`` case of :func:`paged_verify_attention`."""
+    return paged_verify_attention(
+        q[:, None], k_pool, v_pool, block_tables, context_lens - 1, **kwargs
+    )[:, 0]
 
-    ``cache`` is one layer's paged cache: pools plus the shared
-    ``block_tables`` / ``context_lens`` (lens here = tokens already present,
-    i.e. the write position of the incoming token). ``k_new``/``v_new`` are
-    ``[B, Hkv, D]``. Quantizes when the layer carries scale pools (same
-    per-row scheme as the contiguous cache: ``quantize_kv_rows``).
 
-    Distinct live slots always write distinct physical slots (the allocator
-    never lets a write frontier sit in a shared block); idle slots all write
-    the reserved null block 0, whose contents are never read as valid.
-    """
-    from trlx_tpu.models.transformer import quantize_kv_rows
+def paged_slots(
+    block_tables: jnp.ndarray, pos: jnp.ndarray, num_blocks: int, block_size: int
+):
+    """Where token positions ``pos`` ``[B, Q]`` live in the pools, through
+    ``block_tables`` ``[B, MB]``: ``(block, offset)``, each ``[B, Q]``. A
+    position outside the table's reach (``< 0`` or ``>= MB * block_size``)
+    gets the out-of-range block ``num_blocks``, which
+    :func:`scatter_paged_rows` drops."""
+    reach = block_tables.shape[1] * block_size
+    pos_c = jnp.clip(pos, 0, reach - 1)
+    block = jnp.take_along_axis(block_tables, pos_c // block_size, axis=1)
+    return jnp.where((pos >= 0) & (pos < reach), block, num_blocks), pos_c % block_size
 
-    k_pool = cache["k"]
-    NB, BS, Hkv, D = k_pool.shape
-    lens = cache["context_lens"]
-    bt = cache["block_tables"]
-    block = jnp.take_along_axis(bt, (lens // BS)[:, None], axis=1)[:, 0]
-    slot = block * BS + lens % BS  # [B] flat row in the (NB*BS) pool
 
-    def scatter(pool, rows):
-        flat = pool.reshape(NB * BS, *pool.shape[2:])
-        return flat.at[slot].set(rows.astype(pool.dtype)).reshape(pool.shape)
-
-    out = dict(cache)
-    if "k_scale" in cache:
-        kq, ks = quantize_kv_rows(k_new)
-        vq, vs = quantize_kv_rows(v_new)
-        out["k"] = scatter(cache["k"], kq)
-        out["v"] = scatter(cache["v"], vq)
-        out["k_scale"] = scatter(cache["k_scale"], ks[..., 0])
-        out["v_scale"] = scatter(cache["v_scale"], vs[..., 0])
-    else:
-        out["k"] = scatter(cache["k"], k_new)
-        out["v"] = scatter(cache["v"], v_new)
-    return out
+def scatter_paged_rows(
+    pool: jnp.ndarray, block: jnp.ndarray, offset: jnp.ndarray, rows: jnp.ndarray
+) -> jnp.ndarray:
+    """Store ``rows`` ``[..., Hkv, *tail]`` at ``pool[block, :, offset]`` for
+    index arrays ``block``/``offset`` ``[...]`` (from :func:`paged_slots`) over
+    a head-major pool ``[NB, Hkv, BS, *tail]``."""
+    return pool.at[block, :, offset].set(rows.astype(pool.dtype), mode="drop")
 
 
 def write_paged_kv_multi(
     cache: dict, k_new: jnp.ndarray, v_new: jnp.ndarray
 ) -> dict:
-    """Write Q tokens' K/V per slot: ``k_new``/``v_new`` ``[B, Q, Hkv, D]``,
-    token ``j`` landing at position ``context_lens + j`` through the slot's
-    block table (lens = tokens already present, as in :func:`write_paged_kv`).
+    """Write Q tokens' K/V per slot into the block pool: ``k_new``/``v_new``
+    ``[B, Q, Hkv, D]``, token ``j`` landing at position ``context_lens + j``
+    through the slot's block table.
 
+    ``cache`` is one layer's paged cache: pools plus the shared
+    ``block_tables`` / ``context_lens`` (lens here = tokens already present,
+    i.e. the write position of the first incoming token). Quantizes when the
+    layer carries scale pools (same per-row scheme as the contiguous cache:
+    ``quantize_kv_rows`` per ``[Hkv, D]`` row, so the speculative path stays
+    bit-identical to non-speculative greedy decode).
+
+    Distinct live slots always write distinct physical slots (the allocator
+    never lets a write frontier sit in a shared block); idle slots all write
+    the reserved null block 0, whose contents are never read as valid.
     Positions past the table's reach (``>= max_blocks * block_size``) are
     dropped outright and positions whose table entry is the padding 0 land in
-    the reserved null block — the engine only ever *validates* positions it
-    reserved real blocks for, and any position is rewritten before the
-    attention mask can expose it, so overflow writes are harmless garbage.
-    Quantization matches the single-token path row-for-row
-    (:func:`quantize_kv_rows` per ``[Hkv, D]`` row), which is what keeps the
-    speculative path bit-identical to non-speculative greedy decode.
+    the null block — the engine only ever *validates* positions it reserved
+    real blocks for, and any position is rewritten before the attention mask
+    can expose it, so overflow writes are harmless garbage.
     """
     from trlx_tpu.models.transformer import quantize_kv_rows
 
-    k_pool = cache["k"]
-    NB, BS, Hkv, D = k_pool.shape
+    NB, Hkv, BS, D = cache["k"].shape
     B, Q = k_new.shape[:2]
     lens = cache["context_lens"]
-    bt = cache["block_tables"]
-    MB = bt.shape[1]
     pos = lens[:, None] + jnp.arange(Q, dtype=lens.dtype)[None, :]  # [B, Q]
-    pos_c = jnp.clip(pos, 0, MB * BS - 1)
-    blk = jnp.take_along_axis(bt, pos_c // BS, axis=1)  # [B, Q]
-    # out-of-table positions get an out-of-range flat index; mode="drop" below
-    flat = jnp.where(pos < MB * BS, blk * BS + pos_c % BS, NB * BS).reshape(-1)
-
-    def scatter(pool, rows):
-        vals = rows.reshape(B * Q, *rows.shape[2:]).astype(pool.dtype)
-        return (
-            pool.reshape(NB * BS, *pool.shape[2:])
-            .at[flat].set(vals, mode="drop")
-            .reshape(pool.shape)
-        )
+    block, offset = paged_slots(cache["block_tables"], pos, NB, BS)
 
     out = dict(cache)
+    new = {"k": k_new, "v": v_new}
     if "k_scale" in cache:
-        kq, ks = quantize_kv_rows(k_new.reshape(B * Q, Hkv, D))
-        vq, vs = quantize_kv_rows(v_new.reshape(B * Q, Hkv, D))
-        out["k"] = scatter(cache["k"], kq.reshape(B, Q, Hkv, D))
-        out["v"] = scatter(cache["v"], vq.reshape(B, Q, Hkv, D))
-        out["k_scale"] = scatter(cache["k_scale"], ks[..., 0].reshape(B, Q, Hkv))
-        out["v_scale"] = scatter(cache["v_scale"], vs[..., 0].reshape(B, Q, Hkv))
-    else:
-        out["k"] = scatter(cache["k"], k_new)
-        out["v"] = scatter(cache["v"], v_new)
+        for key, rows in (("k", k_new), ("v", v_new)):
+            quantized, row_scale = quantize_kv_rows(rows.reshape(B * Q, Hkv, D))
+            new[key] = quantized.reshape(B, Q, Hkv, D)
+            new[key + "_scale"] = row_scale[..., 0].reshape(B, Q, Hkv)
+    for key, rows in new.items():
+        out[key] = scatter_paged_rows(cache[key], block, offset, rows)
     return out
+
+
+def write_paged_kv(cache: dict, k_new: jnp.ndarray, v_new: jnp.ndarray) -> dict:
+    """Write one token's K/V per slot (``[B, Hkv, D]``) at ``context_lens``:
+    the ``Q == 1`` case of :func:`write_paged_kv_multi`."""
+    return write_paged_kv_multi(cache, k_new[:, None], v_new[:, None])
 
 
 def paged_pool_layout(
@@ -622,7 +461,7 @@ def paged_pool_layout(
 ) -> dict:
     """Per-layer pool buffers as ``{key: (shape, dtype)}`` (mirror of the
     contiguous ``kv_cache_layout``)."""
-    shape = (num_blocks, block_size, kv_heads, dim_per_head)
+    shape = (num_blocks, kv_heads, block_size, dim_per_head)
     if quant:
         return {
             "k": (shape, jnp.int8), "v": (shape, jnp.int8),

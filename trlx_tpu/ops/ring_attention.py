@@ -28,7 +28,6 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from trlx_tpu.parallel.mesh import MODEL_AXIS
@@ -183,9 +182,9 @@ def _ring_fwd_sharded(q, k, v, kv_valid, mesh, axis_name, causal, scale, batch_a
     fn = functools.partial(
         _ring_fwd_local, axis_name=axis_name, n=n, causal=causal, scale=scale
     )
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec, vspec),
-        out_specs=(spec, rowspec), check_rep=False,
+        out_specs=(spec, rowspec), check_vma=False,
     )(q, k, v, kv_valid)
 
 
@@ -202,10 +201,10 @@ def _ring_core_bwd(mesh, axis_name, causal, scale, batch_axes, res, g):
     fn = functools.partial(
         _ring_bwd_local, axis_name=axis_name, n=n, causal=causal, scale=scale
     )
-    dq, dk, dv = shard_map(
+    dq, dk, dv = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(spec, spec, spec, vspec, spec, rowspec, spec),
-        out_specs=(spec, spec, spec), check_rep=False,
+        out_specs=(spec, spec, spec), check_vma=False,
     )(q, k, v, kv_valid, out, lse, g)
     return dq, dk, dv, None
 

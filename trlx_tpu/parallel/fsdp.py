@@ -55,7 +55,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from trlx_tpu.parallel.mesh import BATCH_AXES, DATA_AXIS, FSDP_AXIS, MODEL_AXIS, PIPE_AXIS
@@ -212,10 +211,10 @@ def make_sharded_opt_init(tx, specs: OverlapSpecs, mesh: Mesh) -> Callable:
     """``init(params) -> opt_state`` with ZeRO-sharded state: ``tx.init`` runs
     on each device's parameter shard, so moments (and int8 moment blocks) are
     born shard-local — no full-size state ever exists, on any device."""
-    body = shard_map(
+    body = jax.shard_map(
         tx.init, mesh=mesh,
         in_specs=(specs.param_specs,), out_specs=specs.state_specs,
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(body)
 
@@ -378,11 +377,11 @@ def make_overlapped_grad_accum_step(
         batch_specs = jax.tree.map(
             lambda x: PartitionSpec(BATCH_AXES, *([None] * (x.ndim - 1))), batch
         )
-        mapped = shard_map(
+        mapped = jax.shard_map(
             body, mesh=mesh,
             in_specs=(specs.param_specs, specs.state_specs, batch_specs),
             out_specs=(specs.param_specs, specs.state_specs, PartitionSpec()),
-            check_rep=False,
+            check_vma=False,
         )
         return mapped(params, opt_state, batch)
 

@@ -52,19 +52,8 @@ def initialize_distributed(coordinator_address: Optional[str] = None) -> None:
     """
     # NB: do not probe jax.process_count() here — it would itself initialize
     # the backend, making the jax.distributed.initialize below illegal
-    is_initialized = getattr(jax.distributed, "is_initialized", None)
-    if is_initialized is not None:
-        if is_initialized():
-            return
-    else:
-        # jax < 0.5: no is_initialized(); the global client handle is the signal
-        try:
-            from jax._src.distributed import global_state
-
-            if global_state.client is not None:
-                return
-        except Exception:
-            pass
+    if jax.distributed.is_initialized():
+        return
     num_processes = os.environ.get("TRLX_NUM_PROCESSES")
     coordinator_address = coordinator_address or os.environ.get("TRLX_COORDINATOR")
     if coordinator_address or num_processes:

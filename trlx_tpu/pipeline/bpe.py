@@ -2,8 +2,8 @@
 
 The reference's recipes ride HF's pretrained BPE vocabularies (gpt2 / gpt-j
 tokenizers); with zero egress those vocab files don't exist here, and the
-char/byte fallbacks the examples used instead change the task's fidelity —
-VERDICT r4 flagged the hh chain's char-level policy as its weakest link. This
+char/byte fallbacks the examples used instead change the task's fidelity (the
+hh chain's char-level policy was its weakest link). This
 module closes that gap the way GPT-2's own tokenizer was built: byte-level BPE
 (Sennrich-style merges over UTF-8 bytes, words pre-split on whitespace with
 the leading-space convention) TRAINED on the task corpus, saved as JSON, and

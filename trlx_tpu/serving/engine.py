@@ -22,7 +22,7 @@ tracks *live* sequences, not the longest straggler in a padded batch.
 
 Two optional multi-token modes attack the decode bandwidth bound (each round
 reads all params + live KV; emitting one token per slot per read is the
-ceiling BENCH_r05 measured at ~0.44 of bandwidth):
+ceiling):
 
 - **speculative decoding** (``spec_k > 0``) — a host-side prompt-lookup
   n-gram draft proposes up to K tokens per slot; one fixed-shape verify step
@@ -71,6 +71,7 @@ import jax.numpy as jnp
 from trlx_tpu.analysis.rt import watcher as rt_watcher
 from trlx_tpu.obs.flight import flight
 from trlx_tpu.ops.generation import left_pad_batch, pad_to_bucket
+from trlx_tpu.ops.paged_attention import paged_slots, scatter_paged_rows
 from trlx_tpu.ops.sampling import count_accepted_drafts, sample_token
 from trlx_tpu.resilience.chaos import chaos
 from trlx_tpu.serving.allocator import PagedBlockAllocator
@@ -180,6 +181,7 @@ class ServingEngine:
         tenants: Optional[TenantRegistry] = None,
         gauge_prefix: str = "serving/",
         replica_id: Optional[int] = None,
+        state_sharding=None,
     ):
         """``trunk`` is a built ``TransformerLM`` (its config decides the KV
         dtype via ``kv_cache_quant`` and the kernel via
@@ -197,7 +199,15 @@ class ServingEngine:
         ``serving/*`` keys; the fleet router gives each replica
         ``serving/replica/<i>/`` so N live engines stop clobbering each
         other. ``replica_id`` tags typed errors with the raising replica
-        (None outside a fleet)."""
+        (None outside a fleet).
+
+        ``state_sharding`` commits the engine's own device state (KV pools,
+        tables, rng) to a sharding. A caller whose params are committed — a
+        trainer's are, to its mesh — passes that mesh's replicated sharding:
+        what a jitted step returns beside committed params is committed, so
+        state that began uncommitted would make every program compile a
+        second time for its own outputs. None leaves it uncommitted, which is
+        stable beside uncommitted params."""
         c = trunk.config
         if c.stacked:
             raise NotImplementedError("serving engine: per-layer list layout only")
@@ -276,10 +286,11 @@ class ServingEngine:
         self._island_version = -1
 
         # device state
-        self.cache = trunk.init_paged_cache(
+        self._state_sharding = state_sharding
+        self.cache = self._own(trunk.init_paged_cache(
             self.num_blocks, self.block_size, self.max_blocks_per_seq, self.num_slots
-        )
-        self._rng = jax.random.PRNGKey(seed)
+        ))
+        self._rng = self._own(jax.random.PRNGKey(seed))
         # host mirrors of the table/length leaves; pushed when dirty
         self._tables = np.zeros((self.num_slots, self.max_blocks_per_seq), np.int32)
         self._lens = np.zeros((self.num_slots,), np.int32)
@@ -297,6 +308,11 @@ class ServingEngine:
         self._prefill = jax.jit(self._prefill_impl)
         pack_donate = (0,) if jax.default_backend() == "tpu" else ()
         self._pack = jax.jit(self._pack_impl, donate_argnums=pack_donate)
+
+    def _own(self, tree):
+        """``tree`` on the device as engine-owned state lives there: committed
+        to ``state_sharding``, or uncommitted on the default device."""
+        return jax.device_put(tree, self._state_sharding)
 
     # -- compiled programs ---------------------------------------------------
 
@@ -405,29 +421,21 @@ class ServingEngine:
         ``rows`` [n, MB] block-table rows; ``lens`` [n] prompt lengths.
         Rewriting a shared prefix block stores the identical values it
         already holds (same tokens, same params) — benign by construction."""
-        n, P = rows.shape[0], cont["k"][0].shape[2]
-        NB, BS = self.num_blocks, self.block_size
+        P = cont["k"][0].shape[2]
         s = jnp.arange(P)[None, :]  # source slot in the left-padded cache
-        pos = s - (P - lens[:, None])  # logical token position, <0 on padding
-        pos_c = jnp.clip(pos, 0, self.max_blocks_per_seq * BS - 1)
-        blk = jnp.take_along_axis(rows, pos_c // BS, axis=1)
-        flat = jnp.where(pos >= 0, blk * BS + pos_c % BS, NB * BS).reshape(-1)
-
-        def scatter(pool, cont_layer):
-            # cont [n, Hkv, P, ...] -> rows [n*P, Hkv, ...]
-            vals = jnp.moveaxis(cont_layer, 2, 1).reshape(n * P, *pool.shape[2:])
-            return (
-                pool.reshape((NB * BS,) + pool.shape[2:])
-                .at[flat].set(vals.astype(pool.dtype), mode="drop")
-                .reshape(pool.shape)
-            )
+        pos = s - (P - lens[:, None])  # logical token position, <0 on padding: dropped
+        block, offset = paged_slots(rows, pos, self.num_blocks, self.block_size)
 
         out = {}
         for key in pools:
             cl = cont[key]
             if key.endswith("_scale"):
                 cl = [x[..., 0] for x in cl]  # [n,Hkv,P,1] -> [n,Hkv,P]
-            out[key] = [scatter(p, c) for p, c in zip(pools[key], cl)]
+            # cont [n, Hkv, P, ...] -> rows [n, P, Hkv, ...]
+            out[key] = [
+                scatter_paged_rows(p, block, offset, jnp.moveaxis(c, 2, 1))
+                for p, c in zip(pools[key], cl)
+            ]
         return out
 
     # -- host loop -----------------------------------------------------------
@@ -790,8 +798,8 @@ class ServingEngine:
         if prefill_active:
             tables[self._prefilling] = 0
             lens[self._prefilling] = 0
-        self.cache["block_tables"] = jnp.asarray(tables)
-        self.cache["context_lens"] = jnp.asarray(lens)
+        self.cache["block_tables"] = self._own(tables)
+        self.cache["context_lens"] = self._own(lens)
         self._tables_dirty = prefill_active
 
     def _decode_round(self) -> List[Request]:

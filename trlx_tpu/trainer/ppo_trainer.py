@@ -39,7 +39,12 @@ from trlx_tpu.trainer import register_trainer
 from trlx_tpu.trainer.mesh_trainer import MeshRLTrainer
 from trlx_tpu.utils import infinite_loader, logging
 from trlx_tpu.utils.metrics import gauges
-from trlx_tpu.utils.modeling import RunningMoments, flatten_dict, logprobs_of_labels
+from trlx_tpu.utils.modeling import (
+    RunningMoments,
+    flatten_dict,
+    logprobs_of_labels,
+    next_token_logprobs,
+)
 
 logger = logging.get_logger(__name__)
 
@@ -378,7 +383,7 @@ class PPOTrainer(MeshRLTrainer):
             # only correct (and only possible — np.asarray of a non-addressable
             # sharded jax.Array raises) in a single-process run; on multi-host
             # a pinned_host failure is a real configuration error, not
-            # something to paper over (ADVICE r4)
+            # something to paper over
             if jax.process_count() > 1:
                 raise
             logger.info(f"offload_ref: pinned_host placement unavailable ({type(e).__name__}: {e}); "
@@ -571,7 +576,7 @@ class PPOTrainer(MeshRLTrainer):
             logits, values, branch_hidden, _ = module.apply(
                 {"params": params}, seq, mask, branch_layer=branch_start
             )
-            logprobs = logprobs_of_labels(logits[:, :-1], seq[:, 1:])
+            logprobs = next_token_logprobs(logits, seq)
             if peft_base_ref:
                 # same (frozen) trunk params, adapters structurally disabled
                 ref_logits, _, _, _ = base_trunk.apply(
@@ -585,7 +590,7 @@ class PPOTrainer(MeshRLTrainer):
                 )
             else:
                 ref_logits, _, _, _ = trunk.apply({"params": ref_params}, seq, mask)
-            ref_logprobs = logprobs_of_labels(ref_logits[:, :-1], seq[:, 1:])
+            ref_logprobs = next_token_logprobs(ref_logits, seq)
             start = P - 1
             return (
                 logprobs[:, start : start + R],
@@ -694,6 +699,7 @@ class PPOTrainer(MeshRLTrainer):
                 spec_ngram=cfg.spec_ngram,
                 prefill_chunk=cfg.prefill_chunk,
                 tenants=tenants,
+                state_sharding=mesh_lib.replicated(self.mesh),
             )
 
         svf = self.config.train.serving_fleet
@@ -1144,8 +1150,8 @@ class PPOTrainer(MeshRLTrainer):
             from collections import deque
             from concurrent.futures import ThreadPoolExecutor
 
-            # Multihost + reward_on_process_zero composes with overlap (VERDICT
-            # r3 weak #4): only process 0's reward_fn runs on the worker thread
+            # Multihost + reward_on_process_zero composes with overlap: only
+            # process 0's reward_fn runs on the worker thread
             # (pure RPC/python, no collectives); the broadcast — a collective —
             # happens at future-drain time on the MAIN thread, which reaches
             # each drain in the same program order on every host.
@@ -1592,7 +1598,7 @@ class PPOTrainer(MeshRLTrainer):
             seq = jnp.concatenate([mb.query_tensors, mb.response_tensors], axis=1)
             mask = jnp.concatenate([mb.attention_mask, mb.response_mask], axis=1)
             logits, values_pred, _, _ = module.apply({"params": params}, seq, mask)
-            logprobs = logprobs_of_labels(logits[:, :-1], seq[:, 1:])
+            logprobs = next_token_logprobs(logits, seq)
             start = mb.query_tensors.shape[1] - 1
             Rr = mb.response_tensors.shape[1]
             logprobs = logprobs[:, start : start + Rr]

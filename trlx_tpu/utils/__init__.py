@@ -97,12 +97,17 @@ def filter_non_scalars(xs: Dict) -> Dict:
 
 
 def get_git_tag() -> Tuple[str, str]:
-    """(commit hash, branch) of the current repo, or placeholders outside git."""
+    """(commit hash, branch) of the current repo, or placeholders outside git
+    (a copy that is no repository) and where there is no ``git`` to ask."""
     try:
-        output = subprocess.check_output("git log --format='%h/%as' -n1".split())
-        branch = subprocess.check_output("git rev-parse --abbrev-ref HEAD".split())
+        output = subprocess.check_output(
+            "git log --format='%h/%as' -n1".split(), stderr=subprocess.DEVNULL
+        )
+        branch = subprocess.check_output(
+            "git rev-parse --abbrev-ref HEAD".split(), stderr=subprocess.DEVNULL
+        )
         return output.decode()[1:-2], branch.decode()[:-1]
-    except subprocess.CalledProcessError:
+    except (subprocess.CalledProcessError, OSError):
         return "unknown", "unknown"
 
 

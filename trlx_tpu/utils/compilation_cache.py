@@ -6,22 +6,20 @@ compile of the process, so configuring the cache after anything has compiled
 (even a ``jax.random.PRNGKey``) silently disables it for the whole process.
 Callers therefore invoke :func:`configure_compilation_cache` as the first
 jax-touching act: ``MeshRLTrainer.__init__`` before it derives its RNG key,
-and ``python -m trlx_tpu.analysis.ir`` before lowering.
+``bench.py`` and ``chip_smoke.py`` before their first phase, and
+``python -m trlx_tpu.analysis.ir`` before lowering.
 
-Resolution order for the cache dir: explicit argument, then
-``train.compilation_cache_dir``, then ``mesh.compilation_cache_dir`` (the
-pre-existing knob), then ``$TRLX_COMPILE_CACHE``. Unset everywhere = cache
-off (jax default).
+Where the cache lives, in order:
 
-On the CPU backend the cache is configured only for callers that never
-*execute* what they deserialize (``compile_only=True``, e.g. the graftcheck-ir
-AOT gate): with jaxlib 0.4.36, re-loading the PPO grad-accum train step from
-the disk cache and running it corrupts the heap (glibc abort at the next
-step; numerics up to that point are correct, which points at a temp-buffer
-sizing bug in XLA:CPU executable deserialization — other cached executables,
-including the decode step, round-trip fine). TPU/GPU backends are unaffected
-and always honor the configured dir. ``TRLX_COMPILE_CACHE_FORCE=1`` overrides
-the CPU guard for debugging.
+1. ``$JAX_COMPILATION_CACHE_DIR`` — jax reads it itself. When it is set this
+   module sets no directory at all: whoever runs the program placed the
+   cache, and the path is part of the cache's key.
+2. the explicit argument, ``train.compilation_cache_dir``, then
+   ``mesh.compilation_cache_dir``.
+3. on a TPU backend, ``<checkout>/.jax_cache`` (:data:`REPO_CACHE_DIR`): one
+   fixed path, so two runs from the same checkout share their compiles.
+4. otherwise the cache stays off (the CPU compiles of the tests are cheap and
+   many; nobody asked to keep them).
 """
 
 import os
@@ -31,65 +29,54 @@ from trlx_tpu.utils import logging
 
 logger = logging.get_logger(__name__)
 
-ENV_VAR = "TRLX_COMPILE_CACHE"
-FORCE_ENV_VAR = "TRLX_COMPILE_CACHE_FORCE"
+JAX_ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+REPO_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
 
 
 def resolve_cache_dir(config=None, cache_dir: Optional[str] = None) -> Optional[str]:
-    """The effective cache dir for a TRLConfig (or None)."""
+    """The directory this module would set for a TRLConfig, or None when it
+    sets none: ``$JAX_COMPILATION_CACHE_DIR`` is in force, or nothing is
+    configured off-TPU."""
+    if os.environ.get(JAX_ENV_VAR):
+        return None
     if cache_dir:
         return cache_dir
-    if config is not None:
-        train_dir = getattr(getattr(config, "train", None), "compilation_cache_dir", None)
-        if train_dir:
-            return train_dir
-        mesh_dir = getattr(getattr(config, "mesh", None), "compilation_cache_dir", None)
-        if mesh_dir:
-            return mesh_dir
-    return os.environ.get(ENV_VAR) or None
+    for section in ("train", "mesh"):
+        configured = getattr(getattr(config, section, None), "compilation_cache_dir", None)
+        if configured:
+            return configured
+
+    import jax
+
+    return REPO_CACHE_DIR if jax.default_backend() == "tpu" else None
 
 
 def configure_compilation_cache(
     cache_dir: Optional[str] = None,
     config=None,
     min_compile_time_secs: float = 0.5,
-    compile_only: bool = False,
 ) -> Optional[str]:
-    """Point jax at an on-disk compile cache; returns the dir, or None when
-    no dir is configured anywhere (or the CPU guard declined — see the module
-    docstring). ``min_compile_time_secs`` trades cache-dir churn for coverage
-    — 0.5s keeps real model steps while skipping the trivial host-side jits;
-    tests pass 0.0 to cache everything. ``compile_only=True`` asserts the
-    caller never executes deserialized executables, which sidesteps the
-    XLA:CPU deserialization bug and so lifts the CPU guard."""
-    cache_dir = resolve_cache_dir(config, cache_dir)
-    if not cache_dir:
-        return None
-
+    """Turn the on-disk compile cache on where the module docstring says, and
+    return the directory in force (None = cache off). ``min_compile_time_secs``
+    trades cache-dir churn for coverage — 0.5s keeps real model steps while
+    skipping the trivial host-side jits; tests pass 0.0 to cache everything."""
     import jax
 
-    if (
-        not compile_only
-        and os.environ.get(FORCE_ENV_VAR) != "1"
-        and jax.default_backend() == "cpu"
-    ):
-        logger.warning(
-            f"ignoring compilation cache dir {cache_dir}: executing "
-            "cache-deserialized donated executables corrupts the heap on the "
-            "CPU backend (jaxlib 0.4.36, see trlx_tpu/utils/"
-            f"compilation_cache.py); set {FORCE_ENV_VAR}=1 to force"
-        )
+    cache_dir = resolve_cache_dir(config, cache_dir)
+    if cache_dir:
+        os.makedirs(cache_dir, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    in_force = jax.config.jax_compilation_cache_dir
+    if not in_force:
         return None
-
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update(
         "jax_persistent_cache_min_compile_time_secs", float(min_compile_time_secs)
     )
-    try:
-        # cache regardless of artifact size (the default skips small modules)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except AttributeError:
-        pass  # knob absent on older jax; size-based skipping just applies
-    logger.info(f"persistent compilation cache at {cache_dir}")
-    return cache_dir
+    # cache regardless of artifact size (the default skips small modules)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    placed_by = f" (${JAX_ENV_VAR})" if os.environ.get(JAX_ENV_VAR) else ""
+    logger.info(f"persistent compilation cache at {in_force}{placed_by}")
+    return in_force

@@ -35,6 +35,21 @@ def logprobs_of_labels(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
     return jnp.take_along_axis(logprobs, labels[..., None], axis=-1)[..., 0]
 
 
+def next_token_logprobs(logits: jnp.ndarray, tokens: jnp.ndarray) -> jnp.ndarray:
+    """Log-probability of each token's successor: logits [B, T, V], tokens
+    [B, T] -> [B, T-1].
+
+    The labels are aligned at every position and the [B, T] result is sliced,
+    never the [B, T, V] logits: a slice there puts a vocab-sized ``pad`` into
+    the backward, and the TPU compiler failed on exactly that in the sharded
+    (fsdp=4) PPO step at gpt2's vocab of 50257 ("INTERNAL: ... Bitcast cannot
+    have different shape sizes of output and operand", PR 22). The values are
+    those of ``logprobs_of_labels(logits[:, :-1], tokens[:, 1:])``.
+    """
+    labels = jnp.concatenate([tokens[:, 1:], tokens[:, :1]], axis=1)  # the last is never read
+    return logprobs_of_labels(logits, labels)[:, :-1]
+
+
 def masked_mean(x: jnp.ndarray, mask: jnp.ndarray, axis=None) -> jnp.ndarray:
     """Mean of ``x`` over positions where ``mask`` is 1."""
     mask = mask.astype(x.dtype)
