@@ -139,6 +139,70 @@ def test_span_disabled_is_noop_and_records_nothing(tmp_path):
         assert json.load(f)["traceEvents"] == []
 
 
+class _RecordingAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records what is entered."""
+
+    entered = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        type(self).entered.append(self.name)
+
+    def __exit__(self, *exc):
+        return False
+
+
+class _NoLock:
+    """A lock that fails the test if anything takes it."""
+
+    def __enter__(self):
+        raise AssertionError("a disabled tracer's span() took the tracer's lock")
+
+    def __exit__(self, *exc):
+        return False
+
+
+def test_span_disabled_enters_the_profiler_annotation_and_nothing_else(monkeypatch):
+    from trlx_tpu.obs import spans
+
+    monkeypatch.setattr(spans, "TraceAnnotation", _RecordingAnnotation)
+    monkeypatch.setattr(_RecordingAnnotation, "entered", [])
+    tracer = SpanTracer(enabled=False, trace_path="t.json")
+    tracer._lock = _NoLock()
+    with tracer.span("learn"):
+        with tracer.span("learn.put"):
+            pass
+    assert _RecordingAnnotation.entered == ["trlx/learn", "trlx/learn.put"]
+    # no aggregate, no event, no per-thread stack was touched
+    assert tracer._step_times == {} and tracer._step_counts == {} and tracer._events == []
+    assert not hasattr(tracer._local, "stack")
+
+
+def test_span_profiler_name_is_the_prefixed_leaf_name_enabled_or_not(monkeypatch):
+    from trlx_tpu.obs import spans
+
+    monkeypatch.setattr(spans, "TraceAnnotation", _RecordingAnnotation)
+    names = {}
+    for enabled in (False, True):
+        monkeypatch.setattr(_RecordingAnnotation, "entered", [])
+        tracer = SpanTracer(enabled=enabled)
+        with tracer.span("experience"):
+            with tracer.span("generate"):
+                pass
+            with tracer.span("learn"):
+                with tracer.span("learn.put"):
+                    pass
+        names[enabled] = list(_RecordingAnnotation.entered)
+        if enabled:  # the aggregates keep the dotted per-thread path, a carried prefix once
+            assert {k for k in tracer.drain_step_times() if not k.endswith("_n")} == {
+                "time/span/experience", "time/span/experience.generate",
+                "time/span/experience.learn", "time/span/experience.learn.put"}
+    assert names[False] == names[True] == [
+        "trlx/experience", "trlx/generate", "trlx/learn", "trlx/learn.put"]
+
+
 def test_span_event_cap_reports_dropped(tmp_path):
     path = str(tmp_path / "trace.json")
     tracer = SpanTracer(enabled=True, trace_path=path, max_events=3)
@@ -412,7 +476,7 @@ def test_observability_enabled_step_stats_and_trace(tmp_path):
     gauges.clear(prefix="time/")
     obs = Observability(
         obs_cfg(
-            enabled=True, trace_path="trace.json", trace_device=False,
+            enabled=True, trace_path="trace.json",
             peak_device_tflops=1.0, watchdog_timeout_s=30.0,
         ),
         logging_dir=str(tmp_path),
@@ -430,7 +494,12 @@ def test_observability_enabled_step_stats_and_trace(tmp_path):
         with obs.span("learn"):
             pass
         obs.beat()
+        from trlx_tpu.obs import compile_log
+
+        compile_log.log.record_compile(0.5, "obs_test_entry")
         second = obs.step_stats(tokens=64, samples=4, seq_len=16)
+        # a compile since the last step: the log's gauges ride the obs/ export
+        assert second["obs/compile/obs_test_entry/compiles"] >= 1
         # from the second step on: wall step time, histogram, throughput + MFU
         assert second["time/step"] > 0
         assert "time/step_p50" in second and "time/step_p95" in second
@@ -470,6 +539,222 @@ def test_observability_config_roundtrip_and_dotted_update():
     assert new.train.observability.peak_device_tflops == 197.0
     with pytest.raises(ValueError):
         TRLConfig.update(d, {"train.observability.bogus_knob": 1})
+
+
+# ------------------------------------------------------------ compile log
+
+
+def test_compile_log_records_a_forced_compile_with_its_entry():
+    import jax
+    import jax.numpy as jnp
+
+    from trlx_tpu.obs import compile_log
+
+    compile_log.install()
+    before = compile_log.log.total
+    t0 = time.monotonic()
+
+    def forced(x):
+        return x * 3 + 1
+
+    with compile_log.attributed("outer"):
+        with compile_log.attributed("forced_entry"):
+            jax.block_until_ready(jax.jit(forced)(jnp.zeros((3,), jnp.float32)))
+    assert compile_log.log.total > before
+    mine = [c for c in compile_log.log.compiles() if c[0] >= t0]
+    assert mine and all(entry == "forced_entry" for _, _, entry in mine)
+    assert all(seconds > 0 for _, seconds, _ in mine)
+    assert compile_log.log.compiles(before=t0) == [c for c in compile_log.log.compiles() if c[0] < t0]
+    assert compile_log.log.compile_seconds() >= compile_log.log.compile_seconds(before=t0)
+    # outside any scope a compile is kept with no entry
+    jax.block_until_ready(jax.jit(lambda x: x - 2)(jnp.zeros((5,), jnp.float32)))
+    assert compile_log.log.compiles()[-1][2] is None
+    registry = GaugeRegistry()
+    compile_log.log.export_gauges(registry)
+    assert registry.get("obs/compile/forced_entry/compiles") >= 1
+    assert registry.get("obs/compile/forced_entry/compile_time_s") > 0
+    assert registry.get(f"obs/compile/{compile_log.UNATTRIBUTED}/compiles") >= 1
+
+
+def test_compile_log_counts_a_cache_hit_as_a_hit():
+    """The dispatcher counts jax's own cache events: a look-up that hits is
+    not compiled anew, one that misses is."""
+    import jax.monitoring
+
+    from trlx_tpu.obs import compile_log
+
+    compile_log.install()
+    log = compile_log.log
+    anew, t0 = log.compiled_anew(), time.monotonic()
+    jax.monitoring.record_event(compile_log.CACHE_REQUEST_EVENT)
+    jax.monitoring.record_event(compile_log.CACHE_HIT_EVENT)
+    assert log.compiled_anew() == anew  # a hit
+    jax.monitoring.record_event(compile_log.CACHE_REQUEST_EVENT)
+    assert log.compiled_anew() == anew + 1  # a miss
+    assert log.compiled_anew(before=t0) == anew
+    jax.monitoring.record_event("/jax/compilation_cache/some_other_event")
+    assert log.compiled_anew() == anew + 1
+
+
+def test_compile_log_is_bounded_and_total_counts_on():
+    from trlx_tpu.obs.compile_log import CompileLog
+
+    log = CompileLog(capacity=3)
+    for i in range(5):
+        log.record_compile(0.5, "e" if i % 2 else None, now=float(i))
+    assert log.total == 5 and [c[0] for c in log.compiles()] == [2.0, 3.0, 4.0]
+    assert log.compile_seconds(before=4.0) == 1.0
+    assert log.by_entry() == {"e": (1, 0.5), "__unattributed__": (2, 1.0)}
+    log.reset()
+    assert log.total == 0 and log.compiles() == []
+
+
+def test_learn_loop_warns_which_step_recompiled(trlx_caplog):
+    from trlx_tpu.obs import compile_log
+    from trlx_tpu.trainer.mesh_trainer import MeshRLTrainer
+
+    settled = compile_log.log.total
+    compile_log.log.record_compile(1.5, "ppo_train_step")
+    compile_log.log.record_compile(0.25, None)
+    with trlx_caplog.at_level(py_logging.WARNING, logger="trlx_tpu.trainer.mesh_trainer"):
+        now = MeshRLTrainer._warn_recompiled(SimpleNamespace(iter_count=17), settled)
+    assert now == settled + 2
+    [record] = [r for r in trlx_caplog.records if "XLA compile" in r.getMessage()]
+    message = record.getMessage()
+    assert "step 17" in message and "2 XLA compile(s)" in message and "1.75 s" in message
+    assert "ppo_train_step" in message and compile_log.UNATTRIBUTED in message
+
+
+# ------------------------------------- the program in an open profiler session
+
+
+class _EndOfTrace(Exception):
+    pass
+
+
+@pytest.fixture(scope="module")
+def traced_ppo_iteration(tmp_path_factory):
+    """One tiny PPO iteration (4 optimizer steps, then the next experience)
+    through ``trlx_tpu.train()`` inside a CPU profiler session, the tracer
+    disabled: what the profiler holds of the program's own spans and programs."""
+    import jax
+    from jax.profiler import ProfileData
+
+    import trlx_tpu
+    from tests.test_trainers import base_kwargs, dog_reward
+    from trlx_tpu.data.configs import TRLConfig
+    from trlx_tpu.methods.ppo import PPOConfig
+    from trlx_tpu.obs import compile_log
+    from trlx_tpu.trainer import register_trainer
+    from trlx_tpu.trainer.ppo_trainer import PPOTrainer
+
+    tmp_path = tmp_path_factory.mktemp("traced_ppo")
+    trace_dir = str(tmp_path / "trace")
+    state = {"marks": 0, "t_start": None}
+
+    @register_trainer
+    class ProfiledPPOTrainer(PPOTrainer):
+        def post_epoch_callback(self, epoch):
+            super().post_epoch_callback(epoch)
+            state["marks"] += 1
+            if state["marks"] == 1:
+                options = jax.profiler.ProfileOptions()
+                options.python_tracer_level = 0  # as the benchmark's harness: quick
+                options.host_tracer_level = 2
+                state["t_start"] = time.monotonic()
+                jax.profiler.start_trace(trace_dir, profiler_options=options)
+            else:
+                jax.profiler.stop_trace()
+                raise _EndOfTrace()
+
+    kwargs = base_kwargs(tmp_path, "ProfiledPPOTrainer", total_steps=10 ** 6)
+    kwargs["train"].epochs = 10 ** 6
+    kwargs["train"].checkpoint_interval = kwargs["train"].eval_interval = 10 ** 6
+    kwargs["train"].tracker = None
+    config = TRLConfig(
+        method=PPOConfig(
+            num_rollouts=8, chunk_size=4, ppo_epochs=2, init_kl_coef=0.01, target=None,
+            gen_kwargs=dict(max_new_tokens=6, min_new_tokens=6, do_sample=True, top_k=0, top_p=1.0),
+        ),
+        **kwargs,
+    )
+    assert config.train.observability.enabled is False
+    with pytest.raises(_EndOfTrace):
+        trlx_tpu.train(
+            reward_fn=dog_reward, prompts=["ab", "cd ef", "gh", "a b c"] * 2,
+            eval_prompts=["ab", "cd"], config=config,
+        )
+    [path] = [
+        os.path.join(root, f) for root, _, files in os.walk(trace_dir)
+        for f in files if f.endswith(".xplane.pb")
+    ]
+    host, modules = [], set()
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for event in line.events:
+                host.append((event.name, int(event.start_ns), int(event.start_ns + event.duration_ns)))
+                if line.name.startswith("tf_XLA"):
+                    modules.update(str(v) for k, v in event.stats if k == "hlo_module")
+    return {
+        "host": host, "modules": modules, "t_start": state["t_start"],
+        "compiles": compile_log.log.compiles(),
+    }
+
+
+def _named(events, name):
+    """The program's spans of that name: the profiler holds them as ``trlx/<name>``."""
+    return [(start, end) for n, start, end in events if n == f"trlx/{name}"]
+
+
+def _inside(children, parents):
+    return all(any(ps <= cs and ce <= pe for ps, pe in parents) for cs, ce in children)
+
+
+def test_profiler_session_holds_the_span_vocabulary(traced_ppo_iteration):
+    host = traced_ppo_iteration["host"]
+    counts = {name: len(_named(host, name)) for name in (
+        "experience", "generate", "reward", "score", "learn",
+        "learn.put", "learn.step", "learn.sync", "data", "log")}
+    # 8 rollouts, batch 4, 2 ppo epochs: 4 optimizer steps (and the fetch that
+    # ends the loader), then one experience of two generations of 4
+    assert counts == {
+        "experience": 1, "generate": 2, "reward": 2, "score": 2, "learn": 4,
+        "learn.put": 4, "learn.step": 4, "learn.sync": 4, "data": 5, "log": 4}, counts
+
+
+def test_profiler_session_nests_children_inside_their_parents(traced_ppo_iteration):
+    host = traced_ppo_iteration["host"]
+    learn, experience = _named(host, "learn"), _named(host, "experience")
+    for child in ("learn.put", "learn.step", "learn.sync"):
+        assert _inside(_named(host, child), learn), child
+    for child in ("generate", "reward", "score"):
+        assert _inside(_named(host, child), experience), child
+    for outside in ("data", "log"):  # between two learn spans, in neither
+        assert not any(
+            ls < e and s < le for s, e in _named(host, outside) for ls, le in learn), outside
+    # put, step, sync follow one another inside each learn
+    for (ls, le) in learn:
+        inner = sorted((s, e, n) for n in ("learn.put", "learn.step", "learn.sync")
+                       for s, e in _named(host, n) if ls <= s and e <= le)
+        assert [n for _, _, n in inner] == ["learn.put", "learn.step", "learn.sync"]
+
+
+def test_profiler_session_names_every_executed_program(traced_ppo_iteration):
+    modules = traced_ppo_iteration["modules"]
+    assert {"jit_generate", "jit_ppo_score", "jit_ppo_train_step"} <= modules, modules
+    assert not any("lambda" in m for m in modules), modules
+
+
+def test_learn_attributes_the_programs_compiles(traced_ppo_iteration):
+    """The trainer installed the log as it was built; the three hot programs'
+    compiles carry their names, and none happened inside the traced (second) iteration."""
+    compiles, t_start = traced_ppo_iteration["compiles"], traced_ppo_iteration["t_start"]
+    entries = {entry for _, _, entry in compiles}
+    assert {"generate", "ppo_score", "ppo_train_step"} <= entries, entries
+    hot = ("generate", "ppo_score", "ppo_train_step")
+    assert not [c for c in compiles if c[0] >= t_start and c[2] in hot]
 
 
 # ------------------------------------------------------------- end-to-end

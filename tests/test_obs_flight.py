@@ -548,7 +548,7 @@ def test_observability_runtime_wires_flight_series_and_exporters(tmp_path):
 
     cfg = ObservabilityConfig(
         enabled=True, trace_path=str(tmp_path / "trace.json"),
-        trace_device=False, mfu=False, memory_interval=0,
+        mfu=False, memory_interval=0,
         flight=True, series_capacity=16,
         series_path=str(tmp_path / "series.jsonl"),
         prom_path=str(tmp_path / "metrics.prom"),

@@ -14,28 +14,27 @@ Two complementary measurement channels, because neither alone is enough:
   serving engine's step fns, bench's train step).
 - ``jax.monitoring`` compile-duration events
   (``/jax/core/compile/backend_compile_duration``) — fire for *every* compile
-  in the process but carry no function identity. The watcher attributes them
-  to the innermost active :meth:`attributed` scope on the current thread, and
-  accumulates their durations into ``compile_time_warmup_s``. jax has no
-  per-listener unregister, so ONE module-level dispatcher is installed at
-  most once per process and forwards to whichever watcher is active.
+  in the process but carry no function identity. The process's one dispatcher
+  (:mod:`trlx_tpu.obs.compile_log`) attributes them to the innermost active
+  :func:`attributed` scope on the current thread and forwards them to the
+  active watcher, which accumulates their durations into
+  ``compile_time_warmup_s``.
 
 Each entry carries a *phase* (``warmup`` → ``steady``, flipped by
 :meth:`mark_steady`); compiles land in the counter for the phase current at
 poll/event time. The ledger exports as ``obs/compile/*`` gauges
 (:func:`export_gauges`) and as the bench ``compile_ledger`` key.
 
-Production code never imports jax through this module at import time:
-``jax.monitoring`` is touched lazily inside :meth:`install`.
+Production call sites take :func:`attributed` from
+:mod:`trlx_tpu.obs.compile_log`; it is re-exported here for the probes.
 """
 
 import threading
-from contextlib import contextmanager
 from dataclasses import dataclass
 from typing import Dict, List, Optional
 
-#: monitoring event keys that mean "one XLA compile happened"
-_COMPILE_EVENTS = ("/jax/core/compile/backend_compile_duration",)
+from trlx_tpu.obs import compile_log
+from trlx_tpu.obs.compile_log import UNATTRIBUTED, attributed  # noqa: F401  (re-exported)
 
 WARMUP = "warmup"
 STEADY = "steady"
@@ -101,60 +100,9 @@ def _cache_size(fn) -> int:
         return 0
 
 
-# -- the module-level dispatcher ---------------------------------------------
-# jax.monitoring only supports clearing ALL listeners, never removing one, so
-# we install exactly one process-wide listener and point it at the active
-# watcher. Watchers activate/deactivate; the listener stays.
-
-_ACTIVE: Optional["CompileWatcher"] = None
-_LISTENER_INSTALLED = False
-_INSTALL_LOCK = threading.Lock()
-_TLS = threading.local()
-
-
-def _attribution_stack() -> List[str]:
-    stack = getattr(_TLS, "stack", None)
-    if stack is None:
-        stack = []
-        _TLS.stack = stack
-    return stack
-
-
-def _dispatch_event(event: str, duration_s: float, **kwargs):
-    watcher = _ACTIVE
-    if watcher is None or event not in _COMPILE_EVENTS:
-        return
-    stack = _attribution_stack()
-    entry = stack[-1] if stack else None
-    watcher._on_compile_event(entry, duration_s)
-
-
-def _ensure_listener():
-    global _LISTENER_INSTALLED
-    with _INSTALL_LOCK:
-        if _LISTENER_INSTALLED:
-            return
-        import jax.monitoring as monitoring
-
-        monitoring.register_event_duration_secs_listener(_dispatch_event)
-        _LISTENER_INSTALLED = True
-
-
-@contextmanager
-def attributed(name: str):
-    """Attribute monitoring compile events on this thread to ``name`` while
-    the scope is open. A cheap no-op when no watcher is active — production
-    call sites (serving engine, trainer, bench) wrap their jit invocations in
-    this unconditionally."""
-    if _ACTIVE is None:
-        yield
-        return
-    stack = _attribution_stack()
-    stack.append(name)
-    try:
-        yield
-    finally:
-        stack.pop()
+# The one ``jax.monitoring`` dispatcher lives in :mod:`trlx_tpu.obs.compile_log`
+# (the trainer's always-on compile log uses it on every run); it forwards each
+# compile, with its attributed entry, to whichever watcher is active.
 
 
 class CompileWatcher:
@@ -173,20 +121,14 @@ class CompileWatcher:
     # -- lifecycle ------------------------------------------------------------
 
     def install(self) -> "CompileWatcher":
-        global _ACTIVE
-        _ensure_listener()
-        with _INSTALL_LOCK:
-            if _ACTIVE is not None:
-                raise RuntimeError("another CompileWatcher is already active")
-            _ACTIVE = self
+        compile_log.install()
+        if not compile_log.set_watcher(self, expect=None):
+            raise RuntimeError("another CompileWatcher is already active")
         return self
 
     def uninstall(self):
-        global _ACTIVE
         self.poll()
-        with _INSTALL_LOCK:
-            if _ACTIVE is self:
-                _ACTIVE = None
+        compile_log.set_watcher(None, expect=self)
 
     def __enter__(self) -> "CompileWatcher":
         return self.install()
@@ -252,8 +194,7 @@ class CompileWatcher:
             t.last_size = size
 
     def _on_compile_event(self, entry: Optional[str], duration_s: float):
-        name = entry if entry is not None else "__unattributed__"
-        self.entry(name).record_event(duration_s)
+        self.entry(entry if entry is not None else UNATTRIBUTED).record_event(duration_s)
 
     # -- reporting -------------------------------------------------------------
 

@@ -334,9 +334,6 @@ class ObservabilityConfig:
         ``learn()`` exit (viewable in chrome://tracing / Perfetto). Relative
         paths land under the tracker logging dir. None records no events
         (span timings are still aggregated per step).
-    :param trace_device: additionally wrap each span in
-        ``jax.profiler.TraceAnnotation`` so host spans appear as named ranges
-        in xprof profiles captured via ``train.profile_dir``.
     :param max_trace_events: hard bound on recorded trace events (the trace
         notes how many were dropped past it).
     :param mfu: compute throughput/MFU stats per step.
@@ -367,7 +364,6 @@ class ObservabilityConfig:
 
     enabled: bool = False
     trace_path: Optional[str] = None
-    trace_device: bool = True
     max_trace_events: int = 100_000
     mfu: bool = True
     peak_device_tflops: Optional[float] = None

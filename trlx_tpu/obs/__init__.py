@@ -1,7 +1,7 @@
 """Unified observability layer: span tracing, throughput/MFU accounting,
 device-memory gauges, and a stall watchdog (docs/observability.md).
 
-Four primitives, each usable standalone, plus the :class:`Observability`
+Primitives, each usable standalone, plus the :class:`Observability`
 facade the trainer drives from ``TRLConfig.train.observability``:
 
 - :mod:`trlx_tpu.obs.spans` — thread-safe hierarchical span tracer;
@@ -17,6 +17,9 @@ facade the trainer drives from ``TRLConfig.train.observability``:
 - :mod:`trlx_tpu.obs.flight` — per-uid request-flight journal reducing
   lifecycle events to a per-phase latency decomposition
   (docs/observability.md "Request flights").
+- :mod:`trlx_tpu.obs.compile_log` — the always-on, bounded record of the
+  process's XLA compiles with the entry each is attributed to; the one
+  ``jax.monitoring`` dispatcher of the process.
 - :mod:`trlx_tpu.obs.timeseries` / :mod:`trlx_tpu.obs.export` — bounded
   gauge time-series with windowed reductions, plus atomic JSONL and
   Prometheus text exporters.

@@ -16,8 +16,8 @@ to ``TRLConfig.train.observability``:
   is idempotent and safe to call from ``learn()``'s finally.
 
 When ``observability.enabled`` is False everything here short-circuits:
-``step_stats`` returns ``{}``, the tracer stays disabled (spans cost one
-attribute check), and no watchdog thread exists — per-step stats are exactly
+``step_stats`` returns ``{}``, the tracer stays disabled (a span is only its
+profiler annotation), and no watchdog thread exists — per-step stats are exactly
 the pre-obs ones.
 """
 
@@ -25,6 +25,7 @@ import os
 import time
 from typing import Any, Dict, Optional
 
+from trlx_tpu.obs.compile_log import log as compile_log
 from trlx_tpu.obs.flight import flight as global_flight
 from trlx_tpu.obs.memory import device_memory_stats
 from trlx_tpu.obs.spans import tracer as global_tracer
@@ -56,6 +57,7 @@ class Observability:
         self._series_path: Optional[str] = None
         self._prom_path: Optional[str] = None
         self._step_count = 0
+        self._compiles_exported = 0
         self._last_step_end: Optional[float] = None
         self._closed = False
         if not self.enabled:
@@ -67,7 +69,6 @@ class Observability:
         self.tracer.configure(
             enabled=True,
             trace_path=trace_path,
-            annotate_device=cfg.trace_device,
             max_events=cfg.max_trace_events,
         )
         # getattr-defensive config reads: older ObservabilityConfig instances
@@ -155,6 +156,9 @@ class Observability:
         # flight percentiles refresh before the obs/ snapshot so the
         # per-tenant phase gauges ride the same per-step export
         self.flight.export_gauges()
+        if compile_log.total != self._compiles_exported:  # only after a compile
+            self._compiles_exported = compile_log.total
+            compile_log.export_gauges()
         stats.update(gauges.snapshot("obs/"))
         # resilience gauges (retry counts, inflight checkpoint writes, commit
         # latency) ride the same per-step export to every tracker backend

@@ -17,14 +17,17 @@ does exactly that:
   :meth:`write_trace` emits a ``trace.json`` that chrome://tracing and
   Perfetto load directly — producer and learner phases interleaved on one
   timeline, the visual answer to "did generation overlap learning?".
-- With ``annotate_device=True`` each span also enters a
-  ``jax.profiler.TraceAnnotation``, so host spans appear as named ranges in
-  xprof/tensorboard profiles captured via ``train.profile_dir`` and line up
-  with the device-side timeline.
+- Every span, whether or not the tracer is enabled, enters a
+  ``jax.profiler.TraceAnnotation`` named ``trlx/<leaf name>``
+  (``trlx/learn.put``), so the program's spans appear as named host ranges
+  in any open profiler session (``train.profile_dir``, the benchmark's
+  ``--trace 1``) on the device trace's clock, told apart from a harness's or
+  an operator's own annotations of the same names. The profiler session is
+  the only switch: with none open the annotation costs about a microsecond.
 
-A disabled tracer (the default) short-circuits ``span()`` before taking any
-lock or timestamp — the hot path costs one attribute check, which is the
-"overhead is negligible with flags off" contract.
+A disabled tracer (the default) does nothing else: ``span()`` hands back the
+bare annotation before taking any lock, timestamp or dict — the "overhead is
+negligible with flags off" contract.
 
 The process-global :data:`tracer` mirrors :data:`trlx_tpu.utils.metrics.gauges`:
 subsystems call the module-level :func:`span` without knowing who configured
@@ -38,10 +41,12 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
-try:  # TraceAnnotation exists on every supported jax; guard anyway (CPU wheels)
-    from jax.profiler import TraceAnnotation as _TraceAnnotation
-except Exception:  # pragma: no cover - defensive
-    _TraceAnnotation = None
+from jax.profiler import TraceAnnotation
+
+#: what the profiler's name of a span starts with: the benchmark's harness (and
+#: an operator) annotates ``learn``, ``score``, ``reward`` itself, around the
+#: same calls, and counts its own
+PROFILER_PREFIX = "trlx/"
 
 
 class SpanTracer:
@@ -51,12 +56,10 @@ class SpanTracer:
         self,
         enabled: bool = False,
         trace_path: Optional[str] = None,
-        annotate_device: bool = False,
         max_events: int = 100_000,
     ):
         self.enabled = enabled
         self.trace_path = trace_path
-        self.annotate_device = annotate_device
         self.max_events = int(max_events)
         self._lock = threading.Lock()
         self._local = threading.local()
@@ -72,46 +75,44 @@ class SpanTracer:
         self,
         enabled: bool,
         trace_path: Optional[str] = None,
-        annotate_device: bool = False,
         max_events: int = 100_000,
     ):
         """Reconfigure in place (the global tracer outlives any one trainer)."""
         with self._lock:
             self.enabled = enabled
             self.trace_path = trace_path
-            self.annotate_device = annotate_device
             self.max_events = int(max_events)
 
     # ------------------------------------------------------------------ spans
 
-    def _stack(self) -> List[str]:
+    def _stack(self) -> List[tuple]:
         stack = getattr(self._local, "stack", None)
         if stack is None:
             stack = self._local.stack = []
         return stack
 
-    @contextlib.contextmanager
     def span(self, name: str):
-        """Time a phase; nested calls build a dotted path per thread."""
+        """Time a phase; nested calls build a dotted path per thread. The
+        profiler sees ``trlx/<name>``, the leaf, in both states of the tracer."""
         # lock-free read is the "flags off costs one attribute check" contract;
         # a configure() racing a span at worst mistimes that one span
         if not self.enabled:  # graftcheck: noqa[TH001,CC001]
-            yield
-            return
-        stack = self._stack()
-        stack.append(name)
-        path = ".".join(stack)
-        annot = (
-            _TraceAnnotation(path)
-            # lock-free like `enabled` above (grandfathered in the graftcheck
-            # baseline): a reconfigure racing span-open at worst drops the
-            # device annotation for that one span
-            if self.annotate_device and _TraceAnnotation is not None
-            else contextlib.nullcontext()
-        )
+            return TraceAnnotation(PROFILER_PREFIX + name)
+        return self._timed_span(name)
+
+    @contextlib.contextmanager
+    def _timed_span(self, name: str):
+        stack = self._stack()  # (name, path) of the open spans, the innermost last
+        if not stack:
+            path = name
+        else:
+            parent, parent_path = stack[-1]
+            # ``learn.put`` under ``learn`` carries its parent's name already
+            path = parent_path + (name[len(parent):] if name.startswith(parent + ".") else f".{name}")
+        stack.append((name, path))
         t0 = time.perf_counter()
         try:
-            with annot:
+            with TraceAnnotation(PROFILER_PREFIX + name):
                 yield
         finally:
             dur = time.perf_counter() - t0

@@ -89,7 +89,8 @@ def generate(
     full_mask = jnp.concatenate([attention_mask.astype(jnp.int32), jnp.zeros((B, N), jnp.int32)], axis=1)
 
     positions = jnp.clip(jnp.cumsum(attention_mask, axis=1) - 1, 0, None).astype(jnp.int32)
-    logits, hidden, cache = step_fn(params, input_ids, full_mask, positions, cache)
+    with jax.named_scope("prefill"):
+        logits, hidden, cache = step_fn(params, input_ids, full_mask, positions, cache)
     last_logits = logits[:, -1, :]
     if logits_processor is not None:
         last_logits = logits_processor(params, hidden[:, -1, :], last_logits, input_ids[:, -1])
@@ -143,7 +144,8 @@ def generate(
         return step + 1, seqs, full_mask, new_finished, cache, rng, new_tok
 
     state = (jnp.array(1, jnp.int32), seqs, full_mask, finished, cache, rng, tok)
-    step, seqs, full_mask, finished, cache, rng, tok = jax.lax.while_loop(cond, body, state)
+    with jax.named_scope("decode"):
+        step, seqs, full_mask, finished, cache, rng, tok = jax.lax.while_loop(cond, body, state)
 
     response_mask = full_mask[:, P:]
     # zero out mask past each sample's eos is already handled: finished samples write
@@ -194,8 +196,9 @@ def generate_seq2seq(
     B = input_ids.shape[0]
     N = int(max_new_tokens)
 
-    enc = encode_fn(params, input_ids, attention_mask)
-    cross_kvs = cross_kv_fn(params, enc)
+    with jax.named_scope("prefill"):
+        enc = encode_fn(params, input_ids, attention_mask)
+        cross_kvs = cross_kv_fn(params, enc)
     cache = init_cache_fn(params, B, N + 1)
 
     seqs = jnp.full((B, N + 1), pad_token_id, jnp.int32)
@@ -240,7 +243,8 @@ def generate_seq2seq(
     state = (
         jnp.array(0, jnp.int32), seqs, dec_mask, jnp.zeros((B,), bool), cache, rng, tok0
     )
-    step, seqs, dec_mask, finished, cache, rng, tok = jax.lax.while_loop(cond, body, state)
+    with jax.named_scope("decode"):
+        step, seqs, dec_mask, finished, cache, rng, tok = jax.lax.while_loop(cond, body, state)
 
     response_mask = dec_mask[:, 1:]
     if eos_token_id is not None:

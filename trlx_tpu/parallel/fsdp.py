@@ -279,6 +279,7 @@ def make_overlapped_grad_accum_step(
     max_grad_norm: Optional[float] = None,
     lr_schedule: Optional[Callable] = None,
     donate: bool = True,
+    name: str = "train_step",
 ) -> Callable:
     """Build the jitted overlapped step: ``step(params, opt_state, batch) ->
     (params, opt_state, stats)`` (stats ``{}`` when ``has_aux=False``).
@@ -385,6 +386,7 @@ def make_overlapped_grad_accum_step(
         )
         return mapped(params, opt_state, batch)
 
+    step.__name__ = name  # the compiled program's: module events read ``jit_<name>``
     return jax.jit(step, donate_argnums=(0, 1) if donate else ())
 
 

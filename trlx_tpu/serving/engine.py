@@ -68,7 +68,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from trlx_tpu.analysis.rt import watcher as rt_watcher
+from trlx_tpu.obs import compile_log
 from trlx_tpu.obs.flight import flight
 from trlx_tpu.ops.generation import left_pad_batch, pad_to_bucket
 from trlx_tpu.ops.paged_attention import paged_slots, scatter_paged_rows
@@ -563,7 +563,7 @@ class ServingEngine:
             counts = np.zeros((n_b,), np.int32)
             for i, (_, req, _) in enumerate(group):
                 counts[i] = len(req.generated)
-            with rt_watcher.attributed("serving_prefill"):
+            with compile_log.attributed("serving_prefill"):
                 tok, cont, self._rng = self._prefill(
                     self.params,  # graftcheck: noqa[TH001] — under step()'s lock
                     jnp.asarray(ids), jnp.asarray(mask), self._rng,
@@ -580,7 +580,7 @@ class ServingEngine:
                 if k not in ("block_tables", "context_lens")
             }
             cont_pools = {k: cont[k] for k in pools}
-            with rt_watcher.attributed("serving_pack_step"):
+            with compile_log.attributed("serving_pack_step"):
                 packed = self._pack(pools, cont_pools, jnp.asarray(rows), jnp.asarray(lens))
             self.cache.update(packed)
             tok_np = np.asarray(jax.device_get(tok))
@@ -636,7 +636,7 @@ class ServingEngine:
             cache1 = {key: self.cache[key] for key in pool_keys}
             cache1["block_tables"] = jnp.asarray(row)
             cache1["context_lens"] = jnp.asarray(np.array([start], np.int32))
-            with rt_watcher.attributed("serving_chunk_step"):
+            with compile_log.attributed("serving_chunk_step"):
                 tok, pools, self._rng = self._chunk_step(
                     self.params,  # graftcheck: noqa[TH001] — under step()'s lock
                     jnp.asarray(ids), cache1, self._rng,
@@ -831,7 +831,7 @@ class ServingEngine:
         if self.spec_k > 0:
             finished.extend(self._spec_round(live, new_counts))
         else:
-            with rt_watcher.attributed("serving_decode_step"):
+            with compile_log.attributed("serving_decode_step"):
                 next_tok, self.cache, self._rng = self._decode_step(
                     self.params,  # graftcheck: noqa[TH001] — under step()'s lock
                     jnp.asarray(self._pending_tok), self.cache,
@@ -869,7 +869,7 @@ class ServingEngine:
                 self.spec_ngram, self.pad_token_id,
             )
         tok = np.concatenate([self._pending_tok[:, None], drafts], axis=1)
-        with rt_watcher.attributed("serving_verify_step"):
+        with compile_log.attributed("serving_verify_step"):
             y, accepted, self.cache, self._rng = self._verify_step(
                 self.params,  # graftcheck: noqa[TH001] — under step()'s lock
                 jnp.asarray(tok), self.cache, self._rng, jnp.asarray(new_counts),

@@ -54,6 +54,8 @@ logger = logging.get_logger(__name__)
 
 @register_trainer
 class GRPOTrainer(PPOTrainer):
+    train_step_name = "grpo_train_step"
+
     def __init__(self, config: TRLConfig, **kwargs):
         super().__init__(config, **kwargs)
         if not isinstance(config.method, GRPOConfig):

@@ -274,7 +274,7 @@ class ILQLTrainer(MeshRLTrainer):
             loss, stats = method.loss((action_logits, (qs, target_qs, vs)), mb)
             return loss, flatten_dict(stats)
 
-        self._train_steps[key] = self.make_grad_accum_step(loss_fn, self.num_mb)
+        self._train_steps[key] = self.make_grad_accum_step(loss_fn, self.num_mb, name="ilql_train_step")
         return self._train_steps[key]
 
     def _get_train_step_s2s(self, B: int, T: int, D: int):
@@ -292,7 +292,7 @@ class ILQLTrainer(MeshRLTrainer):
             loss, stats = method.loss((action_logits, (qs, target_qs, vs)), mb)
             return loss, flatten_dict(stats)
 
-        self._train_steps[key] = self.make_grad_accum_step(loss_fn, self.num_mb)
+        self._train_steps[key] = self.make_grad_accum_step(loss_fn, self.num_mb, name="ilql_train_step")
         return self._train_steps[key]
 
     def train_step(self, batch: ILQLBatch) -> Dict[str, float]:

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 import optax
 
 from trlx_tpu.data.configs import TRLConfig
-from trlx_tpu.obs import Observability, batch_token_count
+from trlx_tpu.obs import Observability, batch_token_count, compile_log
 from trlx_tpu.ops.generation import generate as generate_op
 from trlx_tpu.ops.generation import generate_seq2seq, left_pad_batch, pad_to_bucket
 from trlx_tpu.parallel import mesh as mesh_lib
@@ -88,6 +88,9 @@ class MeshRLTrainer(BaseRLTrainer):
         # first compile — jax latches cache-enablement at that point, and even
         # the PRNGKey below compiles a module
         configure_compilation_cache(config=config)
+        # always on, and before the first compile too, so that the log holds
+        # the model's init as well: its callbacks fire per compile, never per step
+        compile_log.install()
         self.np_rng = set_seed(config.train.seed)
         # identical on EVERY process: rng is a replicated jit input to generate,
         # and jax requires replicated inputs to be equal across hosts
@@ -346,12 +349,15 @@ class MeshRLTrainer(BaseRLTrainer):
 
     # -------------------------------------------------------------- train step
 
-    def make_grad_accum_step(self, loss_fn: Callable, num_mb: int, donate: bool = True):
+    def make_grad_accum_step(
+        self, loss_fn: Callable, num_mb: int, donate: bool = True, name: str = "train_step"
+    ):
         """Build the jitted optimizer step: scan over ``num_mb`` microbatches
         accumulating grads (replaces torch grad-accum no_sync windows,
         accelerate_base_trainer.py:502-516), then one optax update.
 
-        ``loss_fn(params, microbatch) -> (loss, stats_dict)``.
+        ``loss_fn(params, microbatch) -> (loss, stats_dict)``. ``name`` is the
+        compiled program's: the profiler's module events read ``jit_<name>``.
 
         With the self-healing health guard active (``train.self_healing``),
         the step takes one extra *traced* scalar — the grad-norm cap — and
@@ -390,6 +396,7 @@ class MeshRLTrainer(BaseRLTrainer):
                 max_grad_norm=self._overlap_max_grad_norm,
                 lr_schedule=self.lr_schedule,
                 donate=donate,
+                name=name,
             )
 
         def compute_update(params, opt_state, batch):
@@ -401,10 +408,12 @@ class MeshRLTrainer(BaseRLTrainer):
                 return grads_acc, (loss, stats)
 
             zero_grads = jax.tree.map(jnp.zeros_like, params)
-            grads, (losses, stats) = jax.lax.scan(body, zero_grads, mbs)
-            grads = jax.tree.map(lambda g: g / num_mb, grads)
-            updates, new_opt_state = self.tx.update(grads, opt_state, params)
-            new_params = optax.apply_updates(params, updates)
+            with jax.named_scope("loss"):  # forward and backward
+                grads, (losses, stats) = jax.lax.scan(body, zero_grads, mbs)
+            with jax.named_scope("optimizer"):
+                grads = jax.tree.map(lambda g: g / num_mb, grads)
+                updates, new_opt_state = self.tx.update(grads, opt_state, params)
+                new_params = optax.apply_updates(params, updates)
             mean_stats = jax.tree.map(lambda x: jnp.mean(x, axis=0), stats)
             mean_stats["learning_rate_group_0"] = self.lr_schedule(
                 _opt_step_count(opt_state)
@@ -417,6 +426,7 @@ class MeshRLTrainer(BaseRLTrainer):
 
         guard = self.health
         if guard is None:
+            step.__name__ = name
             return jax.jit(step, donate_argnums=(0, 1) if donate else ())
 
         def guarded_step(params, opt_state, batch, grad_norm_cap):
@@ -437,6 +447,7 @@ class MeshRLTrainer(BaseRLTrainer):
             mean_stats["health/update_applied"] = ok.astype(jnp.float32)
             return new_params, new_opt_state, mean_stats
 
+        guarded_step.__name__ = name
         jitted = jax.jit(guarded_step, donate_argnums=(0, 1) if donate else ())
 
         def run(params, opt_state, batch):
@@ -477,9 +488,12 @@ class MeshRLTrainer(BaseRLTrainer):
                 def cast(x):
                     return x.astype(dtype) if jnp.issubdtype(x.dtype, jnp.floating) else x
 
+                def cast_rollout_params(p):
+                    return jax.tree.map(cast, p)
+
                 # built once: a fresh jit wrapper per re-cast would re-trace the
                 # full param tree every optimizer step
-                self._cast_rollout_params = jax.jit(lambda p: jax.tree.map(cast, p))
+                self._cast_rollout_params = jax.jit(cast_rollout_params)
             with self.mesh:
                 self._rollout_params = self._cast_rollout_params(self.params)
         return self._rollout_params
@@ -533,12 +547,9 @@ class MeshRLTrainer(BaseRLTrainer):
                     logits_processor=self.gen_logits_processor(**proc_kwargs),
                     **gen_kwargs,
                 )
-                # outputs replicated: every host must address the full result
-                # (host-side decode/reward runs identically on all processes)
-                self._compiled_generate[key] = jax.jit(
-                    lambda params, i, m, r: fn(params=params, input_ids=i, attention_mask=m, rng=r),
-                    out_shardings=mesh_lib.replicated(self.mesh),
-                )
+
+                def program(params, i, m, r):
+                    return fn(params=params, input_ids=i, attention_mask=m, rng=r)
             else:
                 step_fn, init_cache_fn = self.gen_step_fn()
                 fn = partial(
@@ -549,17 +560,25 @@ class MeshRLTrainer(BaseRLTrainer):
                     logits_processor=self.gen_logits_processor(**proc_kwargs),
                     **gen_kwargs,
                 )
-                self._compiled_generate[key] = jax.jit(
-                    lambda params, i, m, r: fn(params, input_ids=i, attention_mask=m, rng=r),
-                    out_shardings=mesh_lib.replicated(self.mesh),
-                )
+
+                def program(params, i, m, r):
+                    return fn(params, input_ids=i, attention_mask=m, rng=r)
+
+            # one name for every trainer's generate program: the profiler's
+            # module events read ``jit_generate``
+            program.__name__ = "generate"
+            # outputs replicated: every host must address the full result
+            # (host-side decode/reward runs identically on all processes)
+            self._compiled_generate[key] = jax.jit(
+                program, out_shardings=mesh_lib.replicated(self.mesh)
+            )
         self.rng, sub = jax.random.split(self.rng)
         batch = mesh_lib.put_batch(self.mesh, {"ids": ids, "mask": mask})
         gen_params = params if params is not None else self.generation_params()
         # the span covers dispatch + the device_get sync: decode is async until
         # the host fetch, so timing only the dispatch would undercount wildly
         with self.obs.span("generate"):
-            with self.mesh:
+            with self.mesh, compile_log.attributed("generate"):
                 out = self._compiled_generate[key](
                     gen_params, batch["ids"], batch["mask"], sub
                 )
@@ -834,6 +853,33 @@ class MeshRLTrainer(BaseRLTrainer):
             # after on_learn_end: producer teardown spans still get recorded
             self.obs.close()
 
+    def _warn_recompiled(self, settled: int) -> int:
+        """One warning for the compiles recorded since ``settled``, if there
+        are any; returns the count now."""
+        total = compile_log.log.total
+        if total == settled:
+            return settled
+        new = compile_log.log.compiles()[-(total - settled):]
+        entries = sorted({entry or compile_log.UNATTRIBUTED for _, _, entry in new})
+        logger.warning(
+            f"step {self.iter_count}: {total - settled} XLA compile(s) after the first full "
+            f"iteration ({sum(s for _, s, _ in new):.2f} s; entries: {', '.join(entries)}) — "
+            "a shape, dtype or static argument changed"
+        )
+        return total
+
+    def _spanned_batches(self, loader):
+        """The loader's batches, each fetch (shuffle, collate) under a ``data``
+        span — the host work between two ``learn`` spans that is not logging."""
+        batches = iter(loader)
+        while True:
+            with self.obs.span("data"):
+                try:
+                    batch = next(batches)
+                except StopIteration:
+                    return
+            yield batch
+
     def _maybe_resume(self, train_config):
         """Restore from an explicit resume path (missing → hard error, never a
         silent fresh start) or, under resilience auto-resume, from the newest
@@ -872,9 +918,12 @@ class MeshRLTrainer(BaseRLTrainer):
             return results
 
         profiling = False
+        # compiles recorded when the first full iteration ended: every shape is
+        # compiled by then, so a later one is the operator's "which step recompiled"
+        compiles_settled = None
         try:
             for epoch in range(train_config.epochs):
-                for batch in self.create_train_dataloader():
+                for batch in self._spanned_batches(self.create_train_dataloader()):
                     if train_config.profile_dir:
                         if self.iter_count == train_config.profile_start_step and not profiling:
                             jax.profiler.start_trace(train_config.profile_dir)
@@ -895,6 +944,8 @@ class MeshRLTrainer(BaseRLTrainer):
                     self.iter_count += 1
                     self.obs.beat("learner")
                     self.post_backward_callback()
+                    if compiles_settled is not None:
+                        compiles_settled = self._warn_recompiled(compiles_settled)
 
                     if self.health is not None:
                         action = self.health.observe(stats, self.iter_count)
@@ -944,11 +995,12 @@ class MeshRLTrainer(BaseRLTrainer):
                             self._report_sweep_result(results)
                             return results
 
-                    if self.obs.enabled:
-                        tokens, samples, seq_len = batch_token_count(batch)
-                        stats.update(self.obs.step_stats(tokens, samples, seq_len))
-                    stats = {k: significant(v) if isinstance(v, float) else v for k, v in stats.items()}
-                    self.tracker.log(stats, self.iter_count)
+                    with self.obs.span("log"):
+                        if self.obs.enabled:
+                            tokens, samples, seq_len = batch_token_count(batch)
+                            stats.update(self.obs.step_stats(tokens, samples, seq_len))
+                        stats = {k: significant(v) if isinstance(v, float) else v for k, v in stats.items()}
+                        self.tracker.log(stats, self.iter_count)
                     if self.iter_count % 10 == 0 or self.iter_count == 1:
                         brief = {k: v for k, v in stats.items() if "loss" in k or "reward" in k}
                         logger.info(f"step {self.iter_count}/{train_config.total_steps} {brief}")
@@ -962,6 +1014,10 @@ class MeshRLTrainer(BaseRLTrainer):
                         self._report_sweep_result(results)
                         return results
                 self.post_epoch_callback(epoch)
+                if compiles_settled is None:
+                    compiles_settled = compile_log.log.total
+                else:
+                    compiles_settled = self._warn_recompiled(compiles_settled)
         finally:
             # the profiler window must close however the loop exits (total_steps
             # return, sweep early stop, or an exception mid-window) — otherwise

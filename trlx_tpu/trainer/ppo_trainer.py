@@ -29,7 +29,7 @@ from trlx_tpu.models.policy import (
     branch_param_subtree,
 )
 from trlx_tpu.models.transformer import TransformerLM
-from trlx_tpu.obs import span
+from trlx_tpu.obs import compile_log, span
 from trlx_tpu.obs.flight import flight
 from trlx_tpu.parallel import mesh as mesh_lib
 from trlx_tpu.parallel.sharding import make_param_shardings
@@ -57,6 +57,13 @@ _STREAM_MAX_R_BUCKETS = rt_contracts.get("stream_score_ladder").max_shapes
 
 #: the shared pow2 padding ladder (8 .. 8192) every bucketing path draws from
 _POW2_BUCKETS = [2 ** i for i in range(3, 14)]
+
+
+@jax.jit
+def copy_params(tree):
+    """A donate-free device copy of a parameter tree (its own program name,
+    ``jit_copy_params``, in the profiler's module events)."""
+    return jax.tree.map(lambda x: x.copy(), tree)
 
 
 def overlap_r_buckets(max_new: int) -> List[int]:
@@ -108,6 +115,10 @@ def check_stream_bucket_family(families, B: int, P: int, R: int, limit: int = _S
 
 @register_trainer
 class PPOTrainer(MeshRLTrainer):
+    #: the compiled train step's name (module events read ``jit_<name>``) and
+    #: the entry its compiles are attributed to
+    train_step_name = "ppo_train_step"
+
     def __init__(self, config: TRLConfig, **kwargs):
         super().__init__(config, **kwargs)
         if not isinstance(config.method, PPOConfig):
@@ -244,7 +255,7 @@ class PPOTrainer(MeshRLTrainer):
         # would be deleted after the first optimizer step.
         def device_copy(tree):
             with self.mesh:
-                return jax.jit(lambda t: jax.tree.map(lambda x: x.copy(), t))(tree)
+                return copy_params(tree)
 
         n_unfrozen = self.config.model.num_layers_unfrozen
         if n_unfrozen > self.model_config.num_layers:
@@ -319,7 +330,7 @@ class PPOTrainer(MeshRLTrainer):
         # T5Branch shape (modeling_ppo.py:1483-1593); otherwise a full frozen copy
         def device_copy(tree):
             with self.mesh:
-                return jax.jit(lambda t: jax.tree.map(lambda x: x.copy(), t))(tree)
+                return copy_params(tree)
 
         n_unfrozen = self.config.model.num_layers_unfrozen
         if n_unfrozen > self.model_config.num_decoder_layers:
@@ -530,7 +541,7 @@ class PPOTrainer(MeshRLTrainer):
             peft_base_ref = self.peft_base_ref
             base_t5 = getattr(self, "base_t5_module", None)
 
-            def score_s2s(params, ref_params, frozen_branch, q_ids, q_mask, r_ids, r_mask):
+            def ppo_score(params, ref_params, frozen_branch, q_ids, q_mask, r_ids, r_mask):
                 Bs = q_ids.shape[0]
                 dec_in = jnp.concatenate(
                     [jnp.full((Bs, 1), start_tok, jnp.int32), r_ids[:, :-1]], axis=1
@@ -558,12 +569,13 @@ class PPOTrainer(MeshRLTrainer):
                 else:
                     logits, values, _ = module.apply({"params": params}, q_ids, q_mask, dec_in, dec_mask)
                     ref_logits, _, _ = t5.apply({"params": ref_params}, q_ids, q_mask, dec_in, dec_mask)
-                logprobs = logprobs_of_labels(logits, r_ids)
-                ref_logprobs = logprobs_of_labels(ref_logits, r_ids)
+                with jax.named_scope("logprobs"):
+                    logprobs = logprobs_of_labels(logits, r_ids)
+                    ref_logprobs = logprobs_of_labels(ref_logits, r_ids)
                 return logprobs, values.astype(jnp.float32), ref_logprobs
 
             self._score_fns[key] = jax.jit(
-                score_s2s, out_shardings=mesh_lib.replicated(self.mesh)
+                ppo_score, out_shardings=mesh_lib.replicated(self.mesh)
             )
             return self._score_fns[key]
 
@@ -572,25 +584,29 @@ class PPOTrainer(MeshRLTrainer):
         peft_base_ref = self.peft_base_ref
         base_trunk = getattr(self, "base_trunk_module", None)
 
-        def score(params, ref_params, frozen_branch, seq, mask):
-            logits, values, branch_hidden, _ = module.apply(
-                {"params": params}, seq, mask, branch_layer=branch_start
-            )
-            logprobs = next_token_logprobs(logits, seq)
-            if peft_base_ref:
-                # same (frozen) trunk params, adapters structurally disabled
-                ref_logits, _, _, _ = base_trunk.apply(
-                    {"params": params["transformer"]}, seq, mask
+        def ppo_score(params, ref_params, frozen_branch, seq, mask):
+            with jax.named_scope("policy_forward"):
+                logits, values, branch_hidden, _ = module.apply(
+                    {"params": params}, seq, mask, branch_layer=branch_start
                 )
-            elif branch_start is not None:
-                ref_logits = module.apply(
-                    {"params": {"transformer": frozen_branch}},
-                    branch_hidden, mask, None, branch_start,
-                    method=module.forward_branch,
-                )
-            else:
-                ref_logits, _, _, _ = trunk.apply({"params": ref_params}, seq, mask)
-            ref_logprobs = next_token_logprobs(ref_logits, seq)
+            with jax.named_scope("logprobs"):
+                logprobs = next_token_logprobs(logits, seq)
+            with jax.named_scope("reference_forward"):
+                if peft_base_ref:
+                    # same (frozen) trunk params, adapters structurally disabled
+                    ref_logits, _, _, _ = base_trunk.apply(
+                        {"params": params["transformer"]}, seq, mask
+                    )
+                elif branch_start is not None:
+                    ref_logits = module.apply(
+                        {"params": {"transformer": frozen_branch}},
+                        branch_hidden, mask, None, branch_start,
+                        method=module.forward_branch,
+                    )
+                else:
+                    ref_logits, _, _, _ = trunk.apply({"params": ref_params}, seq, mask)
+            with jax.named_scope("logprobs"):
+                ref_logprobs = next_token_logprobs(ref_logits, seq)
             start = P - 1
             return (
                 logprobs[:, start : start + R],
@@ -599,7 +615,7 @@ class PPOTrainer(MeshRLTrainer):
             )
 
         self._score_fns[key] = jax.jit(
-            score, out_shardings=mesh_lib.replicated(self.mesh)
+            ppo_score, out_shardings=mesh_lib.replicated(self.mesh)
         )
         return self._score_fns[key]
 
@@ -903,7 +919,7 @@ class PPOTrainer(MeshRLTrainer):
                 seq = np.concatenate([q_ids, r_ids], axis=1)
                 smask = np.concatenate([q_mask, r_mask], axis=1)
                 dbatch = mesh_lib.put_batch(self.mesh, {"seq": seq, "mask": smask})
-                with self.mesh:
+                with self.mesh, compile_log.attributed("ppo_score"):
                     logprobs, values, ref_logprobs = score_fn(
                         self.params, self._ref_scoring_params(), self.frozen_branch_params,
                         dbatch["seq"], dbatch["mask"],
@@ -1122,99 +1138,102 @@ class PPOTrainer(MeshRLTrainer):
         worker thread while chunk i+1 generates on the device — double-buffering
         that hides a served reward model's RPC round-trip (the reference runs its
         Triton reward scoring serially on rank 0, :303-317)."""
-        logger.info(f"Collecting {num_rollouts} rollouts")
-        ppo_rl_elements: List[PPORLElement] = []
-        accumulated_kl = []
-        all_scores_log = []
-        self.clock.tick()
+        # the parent of generate / reward / score: its self time is the host work
+        # between them (tokenizer decode, element assembly, push_to_store)
+        with span("experience"):
+            logger.info(f"Collecting {num_rollouts} rollouts")
+            ppo_rl_elements: List[PPORLElement] = []
+            accumulated_kl = []
+            all_scores_log = []
+            self.clock.tick()
 
-        overlap = self.method.overlap_reward_scoring
-        stream = (
-            self._serving_client is not None
-            and self.config.train.serving.stream_overlap
-            and jax.process_count() == 1
-        )
-        if self.config.train.serving.stream_overlap and self._serving_client is not None and not stream:
-            logger.warning(
-                "serving.stream_overlap is single-process only: "
-                "running the serial serving consumption path"
+            overlap = self.method.overlap_reward_scoring
+            stream = (
+                self._serving_client is not None
+                and self.config.train.serving.stream_overlap
+                and jax.process_count() == 1
             )
-        if stream:
-            # stream-overlapped PPO: reward/score/learn-stage while the tail
-            # of the batch is still decoding (docs/serving.md)
-            self._make_experience_streamed(
-                num_rollouts, iter_count, ppo_rl_elements, accumulated_kl, all_scores_log
-            )
-        elif overlap:
-            import copy
-            from collections import deque
-            from concurrent.futures import ThreadPoolExecutor
-
-            # Multihost + reward_on_process_zero composes with overlap: only
-            # process 0's reward_fn runs on the worker thread
-            # (pure RPC/python, no collectives); the broadcast — a collective —
-            # happens at future-drain time on the MAIN thread, which reaches
-            # each drain in the same program order on every host.
-            broadcasting = self.reward_on_process_zero and jax.process_count() > 1
-            score_locally = not broadcasting or jax.process_index() == 0
-            if broadcasting:
-                logger.info(
-                    "overlap_reward_scoring active with reward_on_process_zero: "
-                    "process-0 worker-thread scoring + main-thread broadcast"
+            if self.config.train.serving.stream_overlap and self._serving_client is not None and not stream:
+                logger.warning(
+                    "serving.stream_overlap is single-process only: "
+                    "running the serial serving consumption path"
                 )
+            if stream:
+                # stream-overlapped PPO: reward/score/learn-stage while the tail
+                # of the batch is still decoding (docs/serving.md)
+                self._make_experience_streamed(
+                    num_rollouts, iter_count, ppo_rl_elements, accumulated_kl, all_scores_log
+                )
+            elif overlap:
+                import copy
+                from collections import deque
+                from concurrent.futures import ThreadPoolExecutor
 
-            # reward_fn runs on a worker thread while the main thread keeps using
-            # self.tokenizer in decode(); HF fast tokenizers are not re-entrant
-            # ("Already borrowed"), so the worker gets its own copy
-            if not hasattr(self, "_reward_tokenizer"):
-                self._reward_tokenizer = copy.deepcopy(self.tokenizer)
-            generated = 0  # count at generation time: len(ppo_rl_elements) lags
-            with ThreadPoolExecutor(max_workers=1) as pool:
-                pending = deque()
-                while generated < num_rollouts or pending:
-                    if generated < num_rollouts:
-                        new = [
-                            (chunk, pool.submit(self._spanned_reward_fn, **kw) if score_locally else None)
-                            for chunk, kw in self._generate_chunks(self._reward_tokenizer)
-                        ]
-                        generated += sum(len(chunk[0]) for chunk, _ in new)
-                    else:
-                        new = []
-                    # drain the previous generation's scores while this one's
-                    # reward futures run behind the next device generation
-                    while pending:
-                        pchunk, pfut = pending.popleft()
-                        scores = pfut.result() if pfut is not None else None
-                        if broadcasting:
-                            scores = self.broadcast_scores(scores, len(pchunk[0]))
-                        self._score_and_store(
-                            pchunk, scores, ppo_rl_elements, accumulated_kl, all_scores_log
-                        )
-                    pending.extend(new)
-        else:
-            while len(ppo_rl_elements) < num_rollouts:
-                for chunk, reward_kwargs in self._generate_chunks(self.tokenizer):
-                    with span("reward"):
-                        scores = self.call_reward_fn(**reward_kwargs)
-                    self._score_and_store(chunk, scores, ppo_rl_elements, accumulated_kl, all_scores_log)
+                # Multihost + reward_on_process_zero composes with overlap: only
+                # process 0's reward_fn runs on the worker thread
+                # (pure RPC/python, no collectives); the broadcast — a collective —
+                # happens at future-drain time on the MAIN thread, which reaches
+                # each drain in the same program order on every host.
+                broadcasting = self.reward_on_process_zero and jax.process_count() > 1
+                score_locally = not broadcasting or jax.process_index() == 0
+                if broadcasting:
+                    logger.info(
+                        "overlap_reward_scoring active with reward_on_process_zero: "
+                        "process-0 worker-thread scoring + main-thread broadcast"
+                    )
 
-        self.mean_kl = float(np.mean(accumulated_kl))
-        rollout_time = self.clock.tick()
-        self.rollout_stats = {
-            "rollout_scores/mean": float(np.mean(all_scores_log)),
-            "rollout_scores/std": float(np.std(all_scores_log)),
-            "rollout_scores/running_mean": float(self.running_moments.mean),
-            "rollout_scores/running_std": float(self.running_moments.std),
-            "policy/sqrt_kl": float(np.sqrt(max(self.mean_kl, 0.0))),
-            "kl_ctl_value": float(self.kl_ctl.value),
-            "time/rollout_time": rollout_time,
-        }
-        if self.log_rollouts:
-            self.store.export_history(location=self.rollout_logging_dir, tokenizer=self.tokenizer)
-        self.push_to_store(ppo_rl_elements[:num_rollouts])
-        # offloaded ref: drop the device copy before the update phase (where
-        # grads + optimizer state peak HBM); no-op otherwise
-        self._release_ref()
+                # reward_fn runs on a worker thread while the main thread keeps using
+                # self.tokenizer in decode(); HF fast tokenizers are not re-entrant
+                # ("Already borrowed"), so the worker gets its own copy
+                if not hasattr(self, "_reward_tokenizer"):
+                    self._reward_tokenizer = copy.deepcopy(self.tokenizer)
+                generated = 0  # count at generation time: len(ppo_rl_elements) lags
+                with ThreadPoolExecutor(max_workers=1) as pool:
+                    pending = deque()
+                    while generated < num_rollouts or pending:
+                        if generated < num_rollouts:
+                            new = [
+                                (chunk, pool.submit(self._spanned_reward_fn, **kw) if score_locally else None)
+                                for chunk, kw in self._generate_chunks(self._reward_tokenizer)
+                            ]
+                            generated += sum(len(chunk[0]) for chunk, _ in new)
+                        else:
+                            new = []
+                        # drain the previous generation's scores while this one's
+                        # reward futures run behind the next device generation
+                        while pending:
+                            pchunk, pfut = pending.popleft()
+                            scores = pfut.result() if pfut is not None else None
+                            if broadcasting:
+                                scores = self.broadcast_scores(scores, len(pchunk[0]))
+                            self._score_and_store(
+                                pchunk, scores, ppo_rl_elements, accumulated_kl, all_scores_log
+                            )
+                        pending.extend(new)
+            else:
+                while len(ppo_rl_elements) < num_rollouts:
+                    for chunk, reward_kwargs in self._generate_chunks(self.tokenizer):
+                        with span("reward"):
+                            scores = self.call_reward_fn(**reward_kwargs)
+                        self._score_and_store(chunk, scores, ppo_rl_elements, accumulated_kl, all_scores_log)
+
+            self.mean_kl = float(np.mean(accumulated_kl))
+            rollout_time = self.clock.tick()
+            self.rollout_stats = {
+                "rollout_scores/mean": float(np.mean(all_scores_log)),
+                "rollout_scores/std": float(np.std(all_scores_log)),
+                "rollout_scores/running_mean": float(self.running_moments.mean),
+                "rollout_scores/running_std": float(self.running_moments.std),
+                "policy/sqrt_kl": float(np.sqrt(max(self.mean_kl, 0.0))),
+                "kl_ctl_value": float(self.kl_ctl.value),
+                "time/rollout_time": rollout_time,
+            }
+            if self.log_rollouts:
+                self.store.export_history(location=self.rollout_logging_dir, tokenizer=self.tokenizer)
+            self.push_to_store(ppo_rl_elements[:num_rollouts])
+            # offloaded ref: drop the device copy before the update phase (where
+            # grads + optimizer state peak HBM); no-op otherwise
+            self._release_ref()
 
     def _spanned_reward_fn(self, **kwargs):
         """reward_fn under a ``reward`` span (overlap path runs it on a worker
@@ -1274,7 +1293,7 @@ class PPOTrainer(MeshRLTrainer):
                 dbatch = mesh_lib.put_batch(
                     self.mesh, {"q": q_ids, "qm": q_mask, "r": r_ids, "rm": r_mask}
                 )
-                with self.mesh:
+                with self.mesh, compile_log.attributed("ppo_score"):
                     logprobs, values, ref_logprobs = score_fn(
                         policy_params, self._ref_scoring_params(), self.frozen_branch_params,
                         dbatch["q"], dbatch["qm"], dbatch["r"], dbatch["rm"],
@@ -1283,7 +1302,7 @@ class PPOTrainer(MeshRLTrainer):
                 seq = np.concatenate([q_ids, r_ids], axis=1)
                 mask = np.concatenate([q_mask, r_mask], axis=1)
                 dbatch = mesh_lib.put_batch(self.mesh, {"seq": seq, "mask": mask})
-                with self.mesh:
+                with self.mesh, compile_log.attributed("ppo_score"):
                     logprobs, values, ref_logprobs = score_fn(
                         policy_params, self._ref_scoring_params(), self.frozen_branch_params,
                         dbatch["seq"], dbatch["mask"],
@@ -1371,7 +1390,7 @@ class PPOTrainer(MeshRLTrainer):
             # so the producer must read an independent copy (same pattern as the
             # frozen KL reference in setup_model)
             with self.mesh:
-                return jax.jit(lambda t: jax.tree.map(lambda x: x.copy(), t))(tree)
+                return copy_params(tree)
 
         icfg = getattr(self.config.train, "islands", None)
         if icfg is not None and icfg.enabled and self._serving_engine is None:
@@ -1591,7 +1610,9 @@ class PPOTrainer(MeshRLTrainer):
                 )
                 return loss, flatten_dict(stats)
 
-            self._train_steps[key] = self.make_grad_accum_step(loss_fn_s2s, self.num_mb)
+            self._train_steps[key] = self.make_grad_accum_step(
+                loss_fn_s2s, self.num_mb, name=self.train_step_name
+            )
             return self._train_steps[key]
 
         def loss_fn(params, mb: PPORLBatch):
@@ -1612,7 +1633,9 @@ class PPOTrainer(MeshRLTrainer):
             )
             return loss, flatten_dict(stats)
 
-        self._train_steps[key] = self.make_grad_accum_step(loss_fn, self.num_mb)
+        self._train_steps[key] = self.make_grad_accum_step(
+            loss_fn, self.num_mb, name=self.train_step_name
+        )
         return self._train_steps[key]
 
     def train_step(self, batch: PPORLBatch) -> Dict[str, float]:
@@ -1630,16 +1653,18 @@ class PPOTrainer(MeshRLTrainer):
         # stream-overlap learn seam: consume the device copy staged during the
         # decode window when it matches this batch exactly; fresh transfer
         # otherwise (identical data either way)
-        dbatch = self._pop_staged_learn(batch)
-        if dbatch is None:
-            dbatch = mesh_lib.put_batch(self.mesh, batch)
+        with span("learn.put"):  # host -> device input
+            dbatch = self._pop_staged_learn(batch)
+            if dbatch is None:
+                dbatch = mesh_lib.put_batch(self.mesh, batch)
         step = self._get_train_step(
             batch.query_tensors.shape[0], batch.query_tensors.shape[1], batch.response_tensors.shape[1]
         )
         t_learn0 = time.monotonic()
-        with self.mesh:
+        with span("learn.step"), self.mesh, compile_log.attributed(self.train_step_name):  # dispatch
             self.params, self.opt_state, stats = step(self.params, self.opt_state, dbatch)
-        out = {k: float(v) for k, v in jax.device_get(stats).items()}
+        with span("learn.sync"):  # the host waiting for the device
+            out = {k: float(v) for k, v in jax.device_get(stats).items()}
         if self._island is not None:
             # device_get above synced the step; the interval is real compute
             self._island.note_learn(t_learn0, time.monotonic())

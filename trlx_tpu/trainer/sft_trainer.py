@@ -238,7 +238,7 @@ class SFTTrainer(MeshRLTrainer):
 
             return loss, flatten_dict(dict(losses=dict(loss=loss)))
 
-        self._train_steps[key] = self.make_grad_accum_step(loss_fn, self.num_mb)
+        self._train_steps[key] = self.make_grad_accum_step(loss_fn, self.num_mb, name="sft_train_step")
         return self._train_steps[key]
 
     def _get_train_step(self, B: int, T: int):
@@ -258,7 +258,7 @@ class SFTTrainer(MeshRLTrainer):
 
             return loss, flatten_dict(stats)
 
-        self._train_steps[key] = self.make_grad_accum_step(loss_fn, self.num_mb)
+        self._train_steps[key] = self.make_grad_accum_step(loss_fn, self.num_mb, name="sft_train_step")
         return self._train_steps[key]
 
     def train_step(self, batch) -> Dict[str, float]:
