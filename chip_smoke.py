@@ -145,7 +145,7 @@ def phase_kernels(sizes: Sizes) -> None:
 
     def flash(q, k, v):
         return attention.flash_attention(
-            q, k, v, kv_valid, True, scale, 128, 128, sizes.interpret
+            q, k, v, kv_valid, True, scale, sizes.interpret
         )
 
     def plain(q, k, v):

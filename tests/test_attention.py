@@ -6,7 +6,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from trlx_tpu.ops.attention import flash_attention, xla_attention
+import trlx_tpu.ops.attention as attn
+from trlx_tpu.ops.attention import choose_tiles, flash_attention, xla_attention
 
 
 def make_inputs(B=2, H=2, T=64, S=64, D=16, seed=0):
@@ -21,7 +22,7 @@ def make_inputs(B=2, H=2, T=64, S=64, D=16, seed=0):
 def test_flash_matches_xla(causal):
     q, k, v = make_inputs()
     kv_valid = jnp.ones((2, 64), jnp.int32)
-    out = flash_attention(q, k, v, kv_valid, causal, None, 32, 32, True)
+    out = flash_attention(q, k, v, kv_valid, causal, None, True)
     ref = xla_attention(q, k, v, kv_valid, causal, 1.0 / 4.0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=1e-5)
 
@@ -31,7 +32,7 @@ def test_flash_respects_padding_mask():
     kv_valid = np.ones((2, 64), np.int32)
     kv_valid[0, :16] = 0  # left padding on sample 0
     kv_valid = jnp.asarray(kv_valid)
-    out = flash_attention(q, k, v, kv_valid, True, None, 32, 32, True)
+    out = flash_attention(q, k, v, kv_valid, True, None, True)
     ref = xla_attention(q, k, v, kv_valid, True, 1.0 / 4.0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=1e-5)
 
@@ -41,7 +42,7 @@ def test_flash_gradients_match_xla():
     kv_valid = jnp.ones((1, 32), jnp.int32)
 
     def loss_flash(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, kv_valid, True, None, 16, 16, True) ** 2)
+        return jnp.sum(flash_attention(q, k, v, kv_valid, True, None, True) ** 2)
 
     def loss_ref(q, k, v):
         return jnp.sum(xla_attention(q, k, v, kv_valid, True, 1.0 / np.sqrt(8)) ** 2)
@@ -60,7 +61,7 @@ def test_flash_non_block_multiple_shapes(T, S):
     kv_valid = np.ones((2, S), np.int32)
     kv_valid[0, : S // 4] = 0
     kv_valid = jnp.asarray(kv_valid)
-    out = flash_attention(q, k, v, kv_valid, False, None, 32, 32, True)
+    out = flash_attention(q, k, v, kv_valid, False, None, True)
     ref = xla_attention(q, k, v, kv_valid, False, 1.0 / 4.0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=1e-5)
 
@@ -184,7 +185,7 @@ def test_flash_gqa_kernel_matches_xla():
     kv_valid = np.ones((2, 48), np.int32)
     kv_valid[1, :9] = 0
     kv_valid = jnp.asarray(kv_valid)
-    out = flash_attention(q, k, v, kv_valid, True, None, 16, 16, True)
+    out = flash_attention(q, k, v, kv_valid, True, None, True)
     ref = xla_attention(q, k, v, kv_valid, True, 1.0 / 4.0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=1e-5)
 
@@ -209,7 +210,7 @@ def test_pallas_backward_matches_xla_backward(B, H, Hkv, T, S, maskfrac):
     kv_valid = jnp.asarray(kv_valid)
 
     def loss(q, k, v):
-        out = flash_attention(q, k, v, kv_valid, True, None, 32, 32, True)
+        out = flash_attention(q, k, v, kv_valid, True, None, True)
         # non-uniform cotangent exercises dO properly
         w = jnp.arange(out.size, dtype=jnp.float32).reshape(out.shape) / out.size
         return jnp.sum(out * w) + jnp.sum(out**2)
@@ -234,7 +235,7 @@ def test_pallas_backward_fully_masked_row_is_zero():
     kv_valid = jnp.zeros((1, 16), jnp.int32)  # everything masked
 
     def loss(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, kv_valid, True, None, 16, 16, True) ** 2)
+        return jnp.sum(flash_attention(q, k, v, kv_valid, True, None, True) ** 2)
 
     gq, gk, gv = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
     for g in (gq, gk, gv):
@@ -289,3 +290,147 @@ def test_model_gqa_grouped_einsum_matches_repeat():
     np.testing.assert_allclose(
         np.asarray(logits) * valid, np.asarray(logits_full) * valid, atol=2e-4, rtol=1e-4
     )
+
+
+# ------------------------------------------------------------ the tile chooser
+
+CELL_LENGTHS = (64, 512, 513, 576, 640)  # prefill, learner and scoring lengths of the benchmark's cells
+CHOOSER_SHAPES = [
+    (T, T, 64, 1, jnp.bfloat16) for T in CELL_LENGTHS + (8, 96, 104, 130, 1024, 1100, 4096)
+] + [
+    (T, T, 128, rep, jnp.bfloat16) for T in (2048, 8192, 131072) for rep in (1, 4, 16)
+] + [(72, 136, 16, 1, jnp.float32), (33, 62, 16, 4, jnp.float32), (513, 513, 128, 8, jnp.float32)]
+
+
+@pytest.mark.parametrize("T,S,D,rep,dtype", CHOOSER_SHAPES)
+def test_chooser_tiles_fit_the_chip(T, S, D, rep, dtype):
+    """Lane side in multiples of 128, rows in the dtype's sublane multiple,
+    padding only up to the tile, the reckoned VMEM under the budget."""
+    tiles = choose_tiles(T, S, D, rep, dtype)
+    sublane = 32 // jnp.dtype(dtype).itemsize
+    assert tiles.block_q % 128 == 0 and tiles.block_k % 128 == 0
+    assert tiles.block_q not in (96, 104) and tiles.block_k not in (96, 104)
+    assert tiles.Tp % tiles.block_q == 0 and tiles.Sp % tiles.block_k == 0
+    assert T <= tiles.Tp < T + tiles.block_q and S <= tiles.Sp < S + tiles.block_k
+    assert tiles.Tr % sublane == 0 and T <= tiles.Tr <= tiles.Tp
+    assert tiles.Sr % sublane == 0 and S <= tiles.Sr <= tiles.Sp
+    assert tiles.sub in (128, 256, 512) and max(tiles.block_q, tiles.block_k) <= 8 * tiles.sub
+    assert 1 <= tiles.heads <= 8 and (tiles.whole or tiles.heads == 1)
+    assert 0 < tiles.vmem_bytes <= 12 * 2**20
+    assert tiles == choose_tiles(T, S, D, rep, dtype)  # a pure function of the shape
+
+
+@pytest.mark.parametrize("T", CELL_LENGTHS)
+def test_chooser_takes_the_cells_sequences_whole(T):
+    tiles = choose_tiles(T, T, 64, 1, jnp.bfloat16)
+    assert tiles.whole and tiles.Tp == -(-T // 128) * 128
+    assert tiles.heads >= 2  # several heads to a program: the grid is about (B, H / heads)
+
+
+@pytest.mark.parametrize("rep", [1, 4, 16])
+@pytest.mark.parametrize("T", [2048, 8192])
+def test_chooser_walks_long_sequences_in_large_tiles(T, rep):
+    tiles = choose_tiles(T, T, 128, rep, jnp.bfloat16)
+    assert not tiles.whole and tiles.heads == 1
+    assert 128 <= tiles.block_q <= 512 and 128 <= tiles.block_k <= 512
+    assert tiles.Tp == T and tiles.Sp == T  # no padding where the tile divides the length
+
+
+def test_chooser_budget_decides_whole_or_walked():
+    assert choose_tiles(513, 513, 64, 1, jnp.bfloat16).whole
+    small = choose_tiles(513, 513, 64, 1, jnp.bfloat16, vmem_budget=2 * 2**20)
+    assert not small.whole and small.vmem_bytes <= 2 * 2**20
+    with pytest.raises(ValueError, match="no flash-attention tiling"):
+        choose_tiles(513, 513, 64, 1, jnp.bfloat16, vmem_budget=2**16)
+
+
+@pytest.mark.parametrize(
+    "H,rep,most,want",
+    [(12, 1, 4, 4), (12, 1, 8, 6), (16, 1, 8, 8), (16, 4, 8, 8), (16, 4, 6, 4), (16, 16, 4, 4), (48, 48, 8, 8),
+     (7, 1, 4, 1)],
+)
+def test_heads_per_program_divides_heads_and_groups(H, rep, most, want):
+    assert attn._heads_per_program(H, rep, most) == want
+
+
+# ------------------------------------- parity on both sides of the chooser's threshold
+
+
+def _walked(T, S, D, rep, dtype):
+    """Tiles of 128 walked over the grid, as a sequence too long for VMEM gets:
+    the chooser with its budget shrunk to the least that still holds a tile."""
+    for mib in (1, 1.5, 2, 3):
+        try:
+            tiles = choose_tiles(T, S, D, rep, dtype, vmem_budget=int(mib * 2**20))
+        except ValueError:
+            continue
+        assert not tiles.whole and min(tiles.block_q, tiles.block_k) == 128
+        return tiles
+    raise AssertionError("no budget tried gives walked tiles")
+
+
+PARITY_CASES = {
+    # name: (B, H, Hkv, T, S, dtype, masking)
+    "mha-left-padded": (2, 2, 2, 200, 200, jnp.float32, "left"),
+    "ragged-T-S": (2, 2, 2, 140, 272, jnp.float32, "left"),
+    "fully-masked-sample": (2, 2, 2, 160, 160, jnp.float32, "none-valid"),
+    "gqa": (1, 4, 2, 150, 150, jnp.float32, "left"),
+    "mqa": (2, 4, 1, 133, 262, jnp.float32, "left"),
+    "bf16": (2, 2, 2, 200, 200, jnp.bfloat16, "left"),
+    "bf16-gqa": (1, 4, 2, 150, 150, jnp.bfloat16, "left"),
+}
+
+
+@pytest.mark.parametrize("regime", ["whole", "walked"])
+@pytest.mark.parametrize("case", sorted(PARITY_CASES))
+def test_forward_and_gradient_match_xla_in_both_regimes(case, regime):
+    """Forward and all three gradients against ``xla_attention`` on the same
+    inputs, with the chooser's own tiles (the whole sequence in one program) and
+    with tiles of 128 walked over the grid."""
+    B, H, Hkv, T, S, dtype, masking = PARITY_CASES[case]
+    D = 16
+    rng = np.random.default_rng(11)
+    q, g = (jnp.asarray(rng.normal(size=(B, H, T, D)), dtype) for _ in range(2))
+    k, v = (jnp.asarray(rng.normal(size=(B, Hkv, S, D)), dtype) for _ in range(2))
+    kv_valid = np.ones((B, S), np.int32)
+    kv_valid[0, : S // 3] = 0  # left padding: sample 0's first queries see no key at all
+    if masking == "none-valid":
+        kv_valid[-1, :] = 0
+    kv_valid = jnp.asarray(kv_valid)
+    scale = D ** -0.5
+    tiles = choose_tiles(T, S, D, H // Hkv, dtype) if regime == "whole" else _walked(T, S, D, H // Hkv, dtype)
+    assert tiles.whole == (regime == "whole")
+
+    out, lse = attn._flash_forward(q, k, v, kv_valid, True, scale, True, with_lse=True, tiles=tiles)
+    assert lse.shape == (B, H, 1, tiles.Tp) and lse.dtype == jnp.float32  # T on the lanes, once
+    got = (out,) + attn._flash_backward(q, k, v, kv_valid, out, lse, g, True, scale, True, tiles=tiles)
+    want_out, vjp = jax.vjp(lambda q, k, v: xla_attention(q, k, v, kv_valid, True, scale), q, k, v)
+    want = (want_out,) + vjp(g)
+    tol = dict(atol=2e-4, rtol=2e-4) if dtype == jnp.float32 else dict(atol=6e-2, rtol=3e-2)
+    for a, b, name in zip(got, want, ("out", "dq", "dk", "dv")):
+        assert a.dtype == b.dtype == dtype, name  # the backward writes the dtype of its inputs
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.all(np.isfinite(a)), name
+        np.testing.assert_allclose(a, b, err_msg=name, **tol)
+    if masking == "none-valid":
+        for a in got:
+            np.testing.assert_array_equal(np.asarray(a[-1], np.float32), 0.0)
+
+
+def test_chooser_choice_is_logged_once_per_shape(caplog):
+    import logging
+
+    q, k, v = make_inputs(B=1, H=3, T=40, S=40, D=8, seed=4)
+    kv_valid = jnp.ones((1, 40), jnp.int32)
+    attn._log_tiles.cache_clear()
+    root = logging.getLogger("trlx_tpu")
+    root.addHandler(caplog.handler)
+    try:
+        with caplog.at_level(logging.INFO, logger="trlx_tpu"):
+            for _ in range(2):
+                jax.grad(lambda q: flash_attention(q, k, v, kv_valid, True, None, True).sum())(q)
+    finally:
+        root.removeHandler(caplog.handler)
+    lines = [r.getMessage() for r in caplog.records if "flash attention q[1,3,40,8]" in r.getMessage()]
+    assert len(lines) == 1, lines
+    assert "tiles 128x128" in lines[0] and "3 head(s) a program" in lines[0] and "grid (1, 1, 1, 1)" in lines[0]

@@ -13,6 +13,7 @@ module is imported would break the other xdist workers' collection.
 """
 
 import functools
+import re
 
 import pytest
 
@@ -21,6 +22,7 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from trlx_tpu.models.presets import PRESETS
+from trlx_tpu.models.transformer import TransformerLM
 from trlx_tpu.ops import attention
 from trlx_tpu.ops.paged_attention import (
     paged_attention_pallas,
@@ -62,18 +64,49 @@ def no_persistent_cache():
     cc.reset_cache()
 
 
-def _flash(grad):
+def _flash(grad, shape=None, kv_heads=None, dtype=jnp.bfloat16):
     c = PRESETS["gpt2"]
-    shape = (FLASH_B, c.num_heads, FLASH_T, c.dim_per_head)
+    B, H, T, D = shape or (FLASH_B, c.num_heads, FLASH_T, c.dim_per_head)
+    kv_shape = (B, kv_heads or H, T, D)
 
     def fwd(q, k, v, kv_valid):
-        return attention.flash_attention(q, k, v, kv_valid, True, None, 128, 128, False)
+        return attention.flash_attention(q, k, v, kv_valid, True, None, False)
 
     def loss(q, k, v, kv_valid):
         return fwd(q, k, v, kv_valid).astype(jnp.float32).sum()
 
     fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
-    return fn, [(shape, jnp.bfloat16)] * 3 + [((FLASH_B, FLASH_T), jnp.int32)]
+    return fn, [((B, H, T, D), dtype)] + [(kv_shape, dtype)] * 2 + [((B, T), jnp.int32)]
+
+
+def _attention_instructions(text):
+    """Names of the compiled program's Pallas instructions, as the device trace
+    shows them and ``benchmark/metrics/flash_attn_roofline.json`` matches them."""
+    return [
+        f"{m.group(1)} custom-call"
+        for m in re.finditer(r"^\s*(?:ROOT )?(%[\w.\-]+) = .* custom-call\(.*tpu_custom_call", text, re.M)
+    ]
+
+
+def _model_grad():
+    """The flash kernels as ``TransformerLM`` calls them: forward, dq and dkv of
+    two gpt2-width layers at the learner's length."""
+    c = PRESETS["gpt2"].replace(num_layers=2, attention_impl="flash", compute_dtype=jnp.bfloat16)
+    model = TransformerLM(c)
+    B, T = 2, 513
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((B, T), jnp.int32), jnp.ones((B, T), jnp.int32))["params"]
+    )
+
+    def loss(params, ids, mask):
+        return model.apply({"params": params}, ids, mask)[0].astype(jnp.float32).sum()
+
+    leaves, treedef = jax.tree_util.tree_flatten(params)
+
+    def fn(ids, mask, *leaves):
+        return jax.grad(loss)(jax.tree_util.tree_unflatten(treedef, leaves), ids, mask)
+
+    return fn, [((B, T), jnp.int32)] * 2 + [(x.shape, x.dtype) for x in leaves]
 
 
 def _paged(preset, quant, q_len):
@@ -102,7 +135,19 @@ def _paged(preset, quant, q_len):
 CASES = {
     "flash_fwd-gpt2": functools.partial(_flash, grad=False),
     "flash_grad_pallas_bwd-gpt2": functools.partial(_flash, grad=True),
+    "flash_grad-transformer_lm-attn_names": _model_grad,
+    "flash_grad-float32-2x12x513x64": functools.partial(_flash, True, (2, 12, 513, 64), None, jnp.float32),
 }
+# [B, H, T, D] of the benchmark's cells: learner (gpt2, gpt2-medium), scoring, prefill
+CELL_SHAPES = [
+    (32, 12, 513, 64), (2, 16, 513, 64), (32, 12, 576, 64), (32, 16, 640, 64), (128, 12, 64, 64), (64, 16, 512, 64),
+]
+# no cell runs these yet: long contexts at D = 128, multi-head, grouped (rep 4) and multi-query
+LONG_SHAPES = [((1, 16, T, 128), kv_heads) for T in (2048, 8192) for kv_heads in (16, 4, 1)]
+for _shape, _kv_heads in [(s, None) for s in CELL_SHAPES] + LONG_SHAPES:
+    _name = "x".join(map(str, _shape)) + (f"-hkv{_kv_heads}" if _kv_heads else "")
+    CASES[f"flash_fwd-{_name}"] = functools.partial(_flash, False, _shape, _kv_heads)
+    CASES[f"flash_grad-{_name}"] = functools.partial(_flash, True, _shape, _kv_heads)
 for _preset in ("gpt2", "gpt_bigcode"):  # Hkv=12 rep=1 D=64; Hkv=1 rep=16 D=128
     for _pool, _quant in (("bf16", False), ("int8", True)):
         CASES[f"paged_decode-{_pool}-{_preset}"] = functools.partial(
@@ -116,7 +161,14 @@ for _preset in ("gpt2", "gpt_bigcode"):  # Hkv=12 rep=1 D=64; Hkv=1 rep=16 D=128
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_kernel_compiles_for_v5e(case, one_chip, no_persistent_cache, monkeypatch):
     monkeypatch.setattr(attention, "BACKWARD_IMPL", "pallas")  # read at trace time
+    # the model asks the backend whether to interpret its kernels; the target here is the chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     fn, shapes = CASES[case]()
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()  # raises what the chip's compiler would
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    if case.endswith("attn_names"):
+        names = _attention_instructions(text)
+        assert len(names) == 6, names  # forward, dkv and dq of two layers
+        assert all(re.match(r"^%attn[.0-9]* custom-call$", name) for name in names), names

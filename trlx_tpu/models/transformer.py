@@ -627,13 +627,13 @@ class Attention(nn.Module):
             )
             if flash_mesh is not None:
                 out = flash_attention_sharded(
-                    q.transpose(0, 2, 1, 3), kh, vh, kv_valid, True, scale, 128, 128,
+                    q.transpose(0, 2, 1, 3), kh, vh, kv_valid, True, scale,
                     target == "cpu", flash_mesh, BATCH_AXES, MODEL_AXIS,
                 )
             else:
                 out = flash_attention(
                     q.transpose(0, 2, 1, 3), kh, vh,
-                    kv_valid, True, scale, 128, 128, target == "cpu",
+                    kv_valid, True, scale, target == "cpu",
                 )
             out = out.transpose(0, 2, 1, 3).astype(c.compute_dtype)
         elif c.kv_heads != c.num_heads:

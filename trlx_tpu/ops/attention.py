@@ -2,57 +2,72 @@
 
 The reference relies on external CUDA attention kernels (HF/NeMo, SURVEY.md §2.4.5);
 this is the TPU-native equivalent. Forward is an online-softmax (FlashAttention-style)
-Pallas kernel: grid = (batch, heads, q_blocks, kv_blocks) with the kv axis innermost —
-TPU grids execute sequentially, so running max / denominator / accumulator live in
-VMEM scratch across kv steps and the output tile is written once on the last step.
-Causal blocks above the diagonal are skipped with ``@pl.when``.
+Pallas kernel; backward is the standard recompute scheme (two kernels, as in the
+in-tree TPU flash attention): the forward saves only O and the per-row logsumexp and
+the backward recomputes P = exp(S - L) tile by tile, so training memory is O(T·tile)
+rather than the O(T·S) score matrix. The XLA fallback is kept behind
+``BACKWARD_IMPL`` and used for grad-parity tests.
 
-Backward is the standard recompute-per-block scheme (two kernels, as in the in-tree
-TPU flash attention): the forward saves only O and the per-row logsumexp; backward
-recomputes P = exp(S - L) tile by tile, so training memory is O(T·block) rather than
-the O(T·S) score matrix the old XLA-recompute fallback materialized. ``dkv`` runs
-grid (B, Hkv, kv_blocks, q_blocks) accumulating dK/dV in VMEM across the inner q
-steps; ``dq`` runs the forward's grid accumulating dQ across kv steps. The XLA
-fallback is kept behind ``BACKWARD_IMPL`` and used for grad-parity tests.
+How the work is cut into programs is decided from the shape by one pure function,
+:func:`choose_tiles`. Where the whole padded sequence fits VMEM — every length RL
+runs at gpt2 widths: 64 to 1024 — a program takes the whole sequence of one or more
+heads: grid (batch, heads / heads-per-program, 1, 1), nothing carried across grid
+steps, and the body walks the score matrix in row tiles whose causal extents are
+static, so nothing above the diagonal is computed. Where it does not (long contexts
+at D = 128), the kv side is walked on the last grid axis in tiles of 128-512 —
+TPU grids execute sequentially, so running max / denominator / accumulator live in
+VMEM scratch across kv steps — and blocks above the diagonal are skipped with
+``pl.when``. One kernel body per pass serves both.
+
+Operands are multiplied in the dtype they arrive in (bfloat16 on the MXU in one
+pass, float32 in full) and accumulated in float32; softmax statistics, the mask
+arithmetic and every accumulator are float32, and P and dS are cast to the operand
+dtype for their second matmuls, as the XLA path does. The backward writes dq, dk, dv
+in the dtype of q, k, v.
 
 Grouped-query attention is native: K/V arrive with their own head count ``Hkv`` and
-the kernels map query head h -> kv head h // (H // Hkv) in the BlockSpec index maps,
-so grouped K/V are never materialized at full head count (the old path ``jnp.repeat``-ed
-them, multiplying HBM traffic by the group size).
+the BlockSpec index maps hand a program its query heads' kv heads, so grouped K/V
+are never materialized at full head count. ``dkv`` programs take whole query-head
+groups and sum over them.
 
 Masking model matches :mod:`trlx_tpu.models.transformer`: slot-based causality plus a
 [B, S] key-validity mask (left-padded prompts). Engaged on every multi-token forward:
 the training loss, the logprob/value scoring passes, and generation *prefill* (which
 attends over the just-computed prefix k/v while the cache write happens separately).
 Only single-token decode steps stay on the XLA path. Arbitrary T/S are supported via
-internal padding + block selection (see ``_flash_forward``).
+internal padding (see ``_flash_forward``).
 
-Mosaic tiling note: small per-row tensors (kv mask, logsumexp, delta) are carried with
-a trailing lane dim equal to the array's own last dim (8 sublane-replicated lanes),
-which tiles legally where a bare [B, S]/(1, block) layout does not (observed as a
-real-TPU lowering failure in round 2; interpret mode on CPU never checks).
+Layout note: per-row statistics (logsumexp, delta) travel between the kernels as
+``[B, H, 1, T]`` float32 rows with T on the lanes — B·H·T·4 bytes in HBM, where a
+trailing dimension of 1 or 8 would be padded to 128 lanes. The forward turns its
+column of row statistics into such a row with one transpose per head; ``dq`` turns
+it back; ``dkv`` works on transposed score tiles (keys down the sublanes, queries
+along the lanes) and subtracts the rows as they are. The key mask travels as
+``[B, 1, S]``.
 """
 
 import functools
 import math
 import os
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from trlx_tpu.utils import logging
+
+logger = logging.get_logger(__name__)
+
 NEG_INF = -1e30
 
 # "pallas" (default) or "xla": which backward the flash custom_vjp traces.
-# Pallas recomputes attention per block from the saved logsumexp — O(T·block)
-# memory, mandatory at long context — but the recompute costs real throughput
-# at small context: switching the backward from the XLA O(T·S) recompute to
-# the Pallas kernels is what slid gpt2-small train MFU 0.43 -> 0.30 between
-# bench rounds r02 and r05 (S=256, where the materialized score matrix is
-# cheap). Pick per scale via set_flash_backward / TRLX_FLASH_BWD; tests also
-# flip this to check grad parity between the two backwards.
+# Pallas recomputes attention per tile from the saved logsumexp — O(T·tile)
+# memory, mandatory at long context. The XLA O(T·S) recompute is kept for
+# grad-parity tests and as `learner_overlap.flash_bwd`; it has not been timed
+# against the kernels since their tiles come from the shape (ROADMAP D5). Pick
+# via set_flash_backward / TRLX_FLASH_BWD.
 BACKWARD_IMPL = os.environ.get("TRLX_FLASH_BWD", "pallas")
 
 
@@ -66,90 +81,343 @@ def set_flash_backward(impl: str) -> str:
     prev, BACKWARD_IMPL = BACKWARD_IMPL, impl
     return prev
 
-LANES = 8  # trailing lane width for per-row tensors (lse / delta / kv mask rows)
+LANE = 128  # lanes of a vector register; the lane side of every score tile is a multiple
+# What a program costs to start, in score elements (0.4 us of a v5e program against
+# some 60 k score elements a microsecond): the chooser trades it against padding.
+_PROGRAM_COST = 24 * 1024
+# Score elements a program should not exceed when it takes several heads.
+_PROGRAM_AREA = 2 * 1024 * 1024
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+class FlashTiles(NamedTuple):
+    """How one attention shape is cut into programs (see :func:`choose_tiles`)."""
+
+    block_q: int  # query rows a program holds; Tp where the whole sequence is one tile
+    block_k: int  # key rows a grid step holds; Sp where the whole sequence is one tile
+    sub: int  # rows of a score tile the body works on at a time
+    Tp: int  # padded query length, a multiple of block_q
+    Sp: int  # padded key length, a multiple of block_k
+    Tr: int  # query rows that hold data, rounded up to the dtype's sublane tile
+    Sr: int  # key rows that hold data, the same
+    heads: int  # most query heads one program takes
+    vmem_bytes: int  # reckoned VMEM of the largest pass (the dkv backward)
+
+    @property
+    def whole(self) -> bool:
+        """One program sees the whole padded sequence: nothing is carried
+        across grid steps and causal extents are static."""
+        return self.block_q == self.Tp and self.block_k == self.Sp
+
+
+def _reckon_vmem(block_q, block_k, sub, heads, D, rep, itemsize, whole) -> int:
+    """VMEM bytes of the pass that holds most. Each pass: its operands twice
+    (Pallas double-buffers them), score-shaped float32 temporaries of ``sub``
+    rows (scores, probabilities and the mask bias in the forward; dP and dS
+    besides in the two backward passes), the mask bias kept for every row tile
+    where extents are static, and what it keeps in scratch. The forward and dq
+    passes take ``heads`` query heads, the dkv pass their kv heads' whole groups."""
+    d_lanes = _round_up(D, LANE)
+    kv_heads = max(1, heads // rep)
+    group = kv_heads * rep
+    q_tile, k_tile = block_q * d_lanes * itemsize, block_k * d_lanes * itemsize
+    row = 8 * block_q * 4  # a [1, block_q] float32 row takes a sublane tile
+    mask = 8 * block_k * 4
+    bias = block_q * block_k * 4 if whole else 0
+    carried = 0 if whole else 4  # bytes of float32 scratch per carried element
+    forward = (
+        2 * (2 * heads * q_tile + 2 * kv_heads * k_tile + heads * row + mask)
+        + 3 * sub * block_k * 4 + bias
+        + block_q * LANE * 4 + carried * heads * block_q * (d_lanes + 2 * LANE)
+    )
+    dq = (
+        2 * (3 * heads * q_tile + 2 * kv_heads * k_tile + 2 * heads * row + mask)
+        + 5 * sub * block_k * 4 + bias
+        + 2 * block_q * LANE * 4 + carried * heads * block_q * d_lanes
+    )
+    dkv = (
+        2 * (2 * group * q_tile + 4 * kv_heads * k_tile + 2 * group * row + mask)
+        + 5 * sub * block_q * 4 + bias
+        + block_k * LANE * 4 + carried * 2 * kv_heads * block_k * d_lanes
+    )
+    return max(forward, dq, dkv)
+
+
+def choose_tiles(T: int, S: int, D: int, rep: int, dtype, vmem_budget: int = 12 * 2**20) -> FlashTiles:
+    """Tiles for q ``[.., T, D]`` against k, v ``[.., S, D]`` with ``rep`` query
+    heads to a kv head. A pure function of the shape; the one place tiles are
+    chosen.
+
+    Lengths are padded to multiples of 128 (the lane side of a score tile: keys
+    in the forward and dq passes, queries in the transposed dkv pass); rows are
+    worked on up to the dtype's sublane multiple only. Where the whole padded
+    sequence fits ``vmem_budget`` a program takes it whole, for one or more
+    heads; where it does not, the kv side is walked on the last grid axis with
+    tiles of 128-512. Among the tilings that fit, the cheapest by padded score
+    area plus a fixed cost per program wins."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sublane = 32 // itemsize  # 8 rows of float32, 16 of bfloat16 to a tile
+    Tr, Sr = _round_up(T, sublane), _round_up(S, sublane)
+    T128, S128 = _round_up(T, LANE), _round_up(S, LANE)
+
+    best = None
+    walked = [(bq, bk) for bq in (512, 256, 128) for bk in (512, 256, 128) if bq < T128 or bk < S128]
+    for block_q, block_k in [(T128, S128)] + walked:
+        block_q, block_k = min(block_q, T128), min(block_k, S128)
+        Tp, Sp = _round_up(T, block_q), _round_up(S, block_k)
+        whole = block_q == Tp and block_k == Sp
+        # the smallest row tile that leaves the body at most eight to unroll
+        sub = next((n for n in (128, 256, 512) if max(block_q, block_k) <= 8 * n), None)
+        fits = sub is not None and _reckon_vmem(block_q, block_k, sub, 1, D, rep, itemsize, whole) <= vmem_budget
+        if not fits:  # graftcheck: noqa[JX004] — static shape/int, not traced
+            continue
+        cost = Tp * Sp + (Tp // block_q) * (Sp // block_k) * _PROGRAM_COST
+        if best is None or cost < best[0]:  # graftcheck: noqa[JX004] — static shape/int, not traced
+            best = (cost, block_q, block_k, sub, Tp, Sp, whole)
+    if best is None:
+        raise ValueError(f"no flash-attention tiling of T={T} S={S} D={D} rep={rep} fits {vmem_budget} bytes of VMEM")
+    _, block_q, block_k, sub, Tp, Sp, whole = best
+
+    def takes(heads):  # several heads to a program: only whole sequences, within the area and the budget
+        return (
+            whole
+            and heads <= 8
+            and heads * Tp * Sp <= _PROGRAM_AREA
+            and _reckon_vmem(block_q, block_k, sub, heads, D, rep, itemsize, whole) <= vmem_budget
+        )
+
+    heads = max(h for h in (1, 2, 4, 8) if h == 1 or takes(h))
+    return FlashTiles(
+        block_q, block_k, sub, Tp, Sp, min(Tr, Tp), min(Sr, Sp), heads,
+        _reckon_vmem(block_q, block_k, sub, heads, D, rep, itemsize, whole),
+    )
+
+
+def _heads_per_program(H: int, rep: int, most: int) -> int:
+    """The most query heads, at most ``most``, that programs can share out evenly
+    with their kv heads: a divisor of the group, or whole groups dividing Hkv."""
+    fits = [
+        g for g in range(1, min(most, H) + 1)
+        if (rep % g == 0) or (g % rep == 0 and (H // rep) % (g // rep) == 0)
+    ]
+    return max(fits)
+
+
+@functools.lru_cache(maxsize=None)
+def _log_tiles(B, H, Hkv, T, S, D, dtype, tiles, heads):
+    """The chooser's choice, once per traced shape."""
+    kv_heads = max(1, heads // (H // Hkv))
+    steps = (tiles.Tp // tiles.block_q, tiles.Sp // tiles.block_k)
+    logger.info(  # graftcheck: noqa[JX003] — once per traced shape is the point
+        f"flash attention q[{B},{H},{T},{D}] kv[{B},{Hkv},{S},{D}] {dtype}: tiles {tiles.block_q}x{tiles.block_k}"
+        f" in rows of {tiles.sub}, padded {tiles.Tp}x{tiles.Sp}, {heads} head(s) a program, grid"
+        f" {(B, H // heads) + steps} (dkv {(B, Hkv // kv_heads) + steps[::-1]}), VMEM reckoned"
+        f" {tiles.vmem_bytes / 2**20:.1f} MiB"
+    )
+
+
+def _loop(body, *, count: int) -> None:
+    """``body(i)`` for i < count; no loop around a single pass."""
+    if count == 1:
+        body(0)
+    else:
+        jax.lax.fori_loop(0, count, lambda i, c: (body(i), c)[1], 0)
+
+
+def _for_each_head(heads: int, rep: int, fn) -> None:
+    """``fn(h, kh)`` for a program's query heads h with their local kv head kh:
+    whole groups of ``rep`` where the program holds several kv heads, else a
+    part of one group."""
+    group = min(heads, rep)
+    _loop(lambda kh: _loop(lambda r: fn(kh * group + r, kh), count=group), count=heads // group)
+
+
+def _row_tiles(rows: int, sub: int):
+    return [(r0, min(sub, rows - r0)) for r0 in range(0, rows, sub)]
+
+
+def _precision(dtype):
+    """Operands multiply in the dtype they arrive in, whatever the process-wide
+    default says: float32 in full, bfloat16 in one MXU pass (the chip's compiler
+    takes no other for it); accumulation is float32 either way."""
+    return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else jax.lax.Precision.DEFAULT
+
+
+def _nt_dot(a, b):
+    """a [m, d], b [n, d] -> a b^T [m, n], accumulated in float32."""
+    return jax.lax.dot_general(
+        a, b, (((1,), (1,)), ((), ())), precision=_precision(a.dtype), preferred_element_type=jnp.float32
+    )
+
+
+def _dot(a, b):
+    return jax.lax.dot_general(
+        a, b, (((1,), (0,)), ((), ())), precision=_precision(a.dtype), preferred_element_type=jnp.float32
+    )
+
+
+def _to_rows(col):
+    """[n, LANE] (a column, lane-replicated) -> [1, n] with n on the lanes."""
+    return jnp.transpose(col)[:1]
+
+
+def _to_cols(row):
+    """[1, n] -> [n, 1]: the lane-dense row a statistic travels as, back to
+    the column a [n, keys] score tile subtracts."""
+    return jnp.transpose(jnp.broadcast_to(row, (LANE, row.shape[1])))[:, :1]
+
+
+def _query_row_tiles(tiles: FlashTiles, causal: bool):
+    """Row tiles ``(first row, rows, keys needed)`` of a program's [queries, keys]
+    score block, for the forward and dq passes. Rows past the data are left out
+    where the q side is one tile; keys above the diagonal where the whole
+    sequence is one program and causal extents are therefore static."""
+    rows = tiles.Tr if tiles.Tp == tiles.block_q else tiles.block_q
+    return [
+        (r0, n, min(tiles.block_k, _round_up(r0 + n, LANE)) if causal and tiles.whole else tiles.block_k)
+        for r0, n in _row_tiles(rows, tiles.sub)
+    ]
+
+
+def _visit_below_diagonal(visit, qi, kj, *, causal: bool, tiles: FlashTiles) -> None:
+    """Run ``visit`` for the (q block, kv block) of this grid step, unless the
+    kv side is walked in blocks and this one lies wholly above the diagonal."""
+    if causal and not tiles.whole:
+        pl.when(kj * tiles.block_k <= qi * tiles.block_q + (tiles.block_q - 1))(visit)
+    else:
+        visit()
+
+
+def _mask_biases(kv_valid, q0, k0, *, row_tiles, causal: bool):
+    """Per row tile ``(first row, rows, keys)`` of a [queries, keys] score
+    block at (q0, k0): 0 where a query may see a key, NEG_INF elsewhere. Added
+    to the scores; the heads of a program share it."""
+    valid = jnp.where(kv_valid > 0, 0.0, NEG_INF)  # [1, block_k]
+    biases = []
+    for r0, n, keys in row_tiles:
+        bias = valid[:, :keys]
+        if causal:
+            q_pos = q0 + r0 + jax.lax.broadcasted_iota(jnp.int32, (n, keys), 0)
+            k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, (n, keys), 1)
+            bias = jnp.where(k_pos <= q_pos, bias, NEG_INF)
+        biases.append(bias)
+    return biases
 
 
 def _flash_kernel(
-    kv_valid_ref,  # [1, 1, 8, block_k] int32 (sublane-replicated, per kv block)
-    q_ref,  # [1, 1, block_q, D]
-    k_ref,  # [1, 1, block_k, D]
-    v_ref,  # [1, 1, block_k, D]
-    o_ref,  # [1, 1, block_q, D]
-    lse_ref,  # [1, 1, block_q, LANES] f32 or None (when with_lse)
-    m_scratch,  # [block_q, 1] f32
-    l_scratch,  # [block_q, 1] f32
-    acc_scratch,  # [block_q, D] f32
-    *,
+    kv_valid_ref,  # [1, 1, block_k] int32
+    q_ref,  # [1, heads, block_q, D]
+    k_ref,  # [1, kv heads, block_k, D]
+    v_ref,
+    o_ref,  # [1, heads, block_q, D]
+    *rest,  # lse_ref [1, heads, 1, block_q] f32 and its [block_q, LANE] scratch (with_lse);
+    # m, l [heads, block_q, 1] and acc [heads, block_q, D] f32 scratch (kv walked)
     causal: bool,
     scale: float,
-    block_q: int,
-    block_k: int,
-    kv_steps: int,
+    tiles: FlashTiles,
+    rep: int,
+    with_lse: bool,
 ):
-    qi = pl.program_id(2)
-    kj = pl.program_id(3)
+    rest = list(rest)
+    lse_ref, lse_cols = (rest.pop(0), rest.pop(-1)) if with_lse else (None, None)
+    heads = q_ref.shape[1]
+    block_q, block_k = tiles.block_q, tiles.block_k
+    kv_steps = tiles.Sp // block_k
+    carried = kv_steps > 1  # running max / sum / accumulator live in scratch across kv steps
+    qi, kj = pl.program_id(2), pl.program_id(3)
+    row_tiles = _query_row_tiles(tiles, causal)
 
-    @pl.when(kj == 0)
-    def _init():
-        m_scratch[...] = jnp.full_like(m_scratch, NEG_INF)
-        l_scratch[...] = jnp.zeros_like(l_scratch)
-        acc_scratch[...] = jnp.zeros_like(acc_scratch)
+    def finish(h, r0, n, m, l, acc):
+        seen = m > NEG_INF / 2  # rows with no valid key give 0, not NaN
+        o_ref[0, h, r0:r0 + n, :] = (acc * jnp.where(seen, 1.0 / l, 0.0)).astype(o_ref.dtype)
+        if with_lse:
+            lse = jnp.where(seen, m + jnp.log(l), NEG_INF)
+            lse_cols[r0:r0 + n, :] = jnp.broadcast_to(lse, (n, LANE))
 
-    # skip fully-masked blocks above the causal diagonal
-    run = jnp.logical_or(
-        jnp.logical_not(causal), kj * block_k <= qi * block_q + (block_q - 1)
-    )
+    if carried:
+        m_scratch, l_scratch, acc_scratch = rest
 
-    @pl.when(run)
-    def _step():
-        q = q_ref[0, 0].astype(jnp.float32)  # [bq, D]
-        k = k_ref[0, 0].astype(jnp.float32)  # [bk, D]
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # [bq, bk]
+        @pl.when(kj == 0)
+        def _init():
+            m_scratch[...] = jnp.full_like(m_scratch, NEG_INF)
+            l_scratch[...] = jnp.zeros_like(l_scratch)
+            acc_scratch[...] = jnp.zeros_like(acc_scratch)
 
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-        k_pos = kj * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-        mask = kv_valid_ref[0, 0, 0][None, :] > 0
-        if causal:
-            mask = jnp.logical_and(mask, k_pos <= q_pos)
-        s = jnp.where(mask, s, NEG_INF)
+    data_rows = row_tiles[-1][0] + row_tiles[-1][1]
+    if with_lse and data_rows < block_q:  # the backward must find no NaN past the data
+        lse_cols[data_rows:, :] = jnp.full((block_q - data_rows, LANE), NEG_INF, jnp.float32)
 
-        m_prev = m_scratch[...]  # [bq, 1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        # fully-masked rows keep m == NEG_INF; exp(s - m) would be exp(0) = 1 there
-        p = jnp.where(m_new > NEG_INF / 2, jnp.exp(s - m_new), 0.0)  # [bq, bk]
-        alpha = jnp.exp(m_prev - m_new)  # [bq, 1]
-        l_new = alpha * l_scratch[...] + jnp.sum(p, axis=1, keepdims=True)
-        acc_scratch[...] = acc_scratch[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        m_scratch[...] = m_new
-        l_scratch[...] = l_new
+    def visit():
+        biases = _mask_biases(kv_valid_ref[0], qi * block_q, kj * block_k, row_tiles=row_tiles, causal=causal)
 
-    @pl.when(kj == kv_steps - 1)
-    def _finalize():
-        l = l_scratch[...]
-        # rows with no valid keys (fully masked) produce 0, not NaN
-        safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0, ...] = (acc_scratch[...] / safe_l).astype(o_ref.dtype)
-        if lse_ref is not None:
-            lse = jnp.where(l > 0.0, m_scratch[...] + jnp.log(safe_l), NEG_INF)
-            lse_ref[0, 0, ...] = jnp.broadcast_to(lse, (block_q, LANES))
+        def head(h, kh):
+            k, v = k_ref[0, kh], v_ref[0, kh]  # loaded once; the row tiles slice the values
+            for (r0, n, keys), bias in zip(row_tiles, biases):
+                q = q_ref[0, h, r0:r0 + n, :]
+                s = _nt_dot(q, k[:keys]) * scale + bias  # [n, keys]
+                m = jnp.max(s, axis=1, keepdims=True)
+                if carried:
+                    m_prev = m_scratch[h, r0:r0 + n]
+                    m = jnp.maximum(m_prev, m)
+                # a row with no key seen yet has m == NEG_INF and p == 1: a later
+                # visit's alpha wipes that, and finish() zeroes what is left
+                p = jnp.exp(s - m)
+                l = jnp.sum(p, axis=1, keepdims=True)
+                acc = _dot(p.astype(v.dtype), v[:keys])  # [n, D]
+                if carried:
+                    alpha = jnp.exp(m_prev - m)
+                    l_scratch[h, r0:r0 + n] = alpha * l_scratch[h, r0:r0 + n] + l
+                    acc_scratch[h, r0:r0 + n] = alpha * acc_scratch[h, r0:r0 + n] + acc
+                    m_scratch[h, r0:r0 + n] = m
+                else:
+                    finish(h, r0, n, m, l, acc)
+            if with_lse and not carried:
+                lse_ref[0, h] = _to_rows(lse_cols[...])
+
+        _for_each_head(heads, rep, head)
+
+    _visit_below_diagonal(visit, qi, kj, causal=causal, tiles=tiles)
+
+    if carried:
+
+        @pl.when(kj == kv_steps - 1)
+        def _finalize():
+            def head(h):
+                for r0, n, _ in row_tiles:
+                    finish(h, r0, n, m_scratch[h, r0:r0 + n], l_scratch[h, r0:r0 + n], acc_scratch[h, r0:r0 + n])
+                if with_lse:
+                    lse_ref[0, h] = _to_rows(lse_cols[...])
+
+            _loop(head, count=heads)
 
 
-def _pick_block(n: int, max_block: int) -> int:
-    """Largest multiple-of-8 block <= max_block dividing ceil8(n) (min padding)."""
-    n8 = -(-n // 8) * 8
-    return max(b for b in range(8, min(max_block, n8) + 1, 8) if n8 % b == 0)
+def _kv_block_map(heads: int, rep: int):
+    """Block index of a program's kv heads from the block index of its query
+    heads: the same where it holds whole groups, else the group's kv head."""
+    return (lambda h: h) if heads >= rep else (lambda h: (h * heads) // rep)
 
 
-def _kv_head_map(H: int, Hkv: int):
-    """Query head -> kv head index map factor for grouped-query attention."""
-    rep = H // Hkv
-    return lambda h: h // rep
+def _grid_semantics():
+    """Every grid axis but the last is independent; the last carries scratch."""
+    return pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
+
+
+def _pad_to(x, *, axis: int, n: int):
+    """Zero-pad ``axis`` of x up to length n."""
+    if x.shape[axis] == n:
+        return x
+    widths = [(0, 0)] * x.ndim
+    widths[axis] = (0, n - x.shape[axis])
+    return jnp.pad(x, widths)
+
+
+def _key_mask(kv_valid, Sp: int):
+    """[B, S] -> [B, 1, Sp] int32, the layout the kernels take a row of it in;
+    padded keys are invalid."""
+    return _pad_to(kv_valid.astype(jnp.int32), axis=1, n=Sp)[:, None, :]
 
 
 def _flash_forward(
@@ -159,325 +427,284 @@ def _flash_forward(
     kv_valid: jnp.ndarray,  # [B, S] int32
     causal: bool,
     scale: float,
-    block_q: int,
-    block_k: int,
     interpret: bool,
     with_lse: bool = False,
+    tiles: Optional[FlashTiles] = None,
 ):
-    B, H, T, D = q.shape
-    S = k.shape[2]
-    # any T/S supported: pad to a sublane multiple and pick the largest block
-    # (<= requested) that divides the padded length — e.g. T=144 (P16+R128) runs
-    # at block 72 with no extra padding. Padded keys are masked via kv_valid;
-    # padded query rows are sliced off. This lets the kernel cover prefill and
-    # mixed P+R training shapes.
-    block_q = _pick_block(T, block_q)
-    block_k = _pick_block(S, block_k)
-    pad_t = -T % block_q
-    pad_s = -S % block_k
-    if pad_t or pad_s:
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, pad_t), (0, 0)))
-        k = jnp.pad(k, ((0, 0), (0, 0), (0, pad_s), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_s), (0, 0)))
-        kv_valid = jnp.pad(kv_valid, ((0, 0), (0, pad_s)))
-    out, lse = _flash_padded(
-        q, k, v, kv_valid, causal, scale, block_q, block_k, interpret, with_lse
-    )
-    if pad_t:
-        out = out[:, :, :T, :]
-        lse = lse[:, :, :T] if lse is not None else None
-    return (out, lse) if with_lse else out
-
-
-def _tile_kv_valid(kv_valid, B, kv_steps, block_k):
-    """[B, S] -> [B, kv_steps, 8, block_k] sublane-replicated (tiles legally)."""
-    return jnp.broadcast_to(
-        kv_valid.astype(jnp.int32).reshape(B, kv_steps, 1, block_k),
-        (B, kv_steps, 8, block_k),
-    )
-
-
-def _flash_padded(q, k, v, kv_valid, causal, scale, block_q, block_k, interpret, with_lse):
+    """Pad to the chooser's tiles, run the forward kernel, slice the padding
+    off. Any T/S: padded keys are masked through ``kv_valid``, padded query
+    rows are sliced off. With ``with_lse`` also returns the per-row logsumexp
+    as the backward takes it: ``[B, H, 1, Tp]`` float32, T on the lanes."""
     B, H, T, D = q.shape
     Hkv, S = k.shape[1], k.shape[2]
-    assert T % block_q == 0 and S % block_k == 0, (T, S, block_q, block_k)
     assert H % Hkv == 0, (H, Hkv)
-    kvh = _kv_head_map(H, Hkv)
-    kv_steps = S // block_k
-    grid = (B, H, T // block_q, kv_steps)
+    rep = H // Hkv
+    if tiles is None:
+        tiles = choose_tiles(T, S, D, rep, q.dtype)
+    heads = _heads_per_program(H, rep, tiles.heads)
+    _log_tiles(B, H, Hkv, T, S, D, jnp.dtype(q.dtype).name, tiles, heads)
+    kv_heads = max(1, heads // rep)
+    block_q, block_k = tiles.block_q, tiles.block_k
+    kvh = _kv_block_map(heads, rep)
 
-    kv_valid_tiled = _tile_kv_valid(kv_valid, B, kv_steps, block_k)
+    q = _pad_to(q, axis=2, n=tiles.Tp)
+    k, v = _pad_to(k, axis=2, n=tiles.Sp), _pad_to(v, axis=2, n=tiles.Sp)
+    kv_valid = _key_mask(kv_valid, tiles.Sp)
 
-    kernel = functools.partial(
-        _flash_kernel,
-        causal=causal,
-        scale=scale,
-        block_q=block_q,
-        block_k=block_k,
-        kv_steps=kv_steps,
-    )
-    out_shape = [jax.ShapeDtypeStruct((B, H, T, D), q.dtype)]
-    out_specs = [pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0))]
+    q_spec = pl.BlockSpec((1, heads, block_q, D), lambda b, h, i, j: (b, h, i, 0))
+    kv_spec = pl.BlockSpec((1, kv_heads, block_k, D), lambda b, h, i, j: (b, kvh(h), j, 0))
+    out_shape = [jax.ShapeDtypeStruct((B, H, tiles.Tp, D), q.dtype)]
+    out_specs = [q_spec]
+    scratch = []
     if with_lse:
-        out_shape.append(jax.ShapeDtypeStruct((B, H, T, LANES), jnp.float32))
-        out_specs.append(
-            pl.BlockSpec((1, 1, block_q, LANES), lambda b, h, i, j: (b, h, i, 0))
-        )
-    else:
-        kernel = functools.partial(_drop_last_ref, kernel)
+        out_shape.append(jax.ShapeDtypeStruct((B, H, 1, tiles.Tp), jnp.float32))
+        out_specs.append(pl.BlockSpec((1, heads, 1, block_q), lambda b, h, i, j: (b, h, 0, i)))
+    if tiles.Sp > block_k:  # graftcheck: noqa[JX004] — static shape/int, not traced
+        scratch += [
+            pltpu.VMEM((heads, block_q, 1), jnp.float32),
+            pltpu.VMEM((heads, block_q, 1), jnp.float32),
+            pltpu.VMEM((heads, block_q, D), jnp.float32),
+        ]
+    if with_lse:
+        scratch.append(pltpu.VMEM((block_q, LANE), jnp.float32))
 
     res = pl.pallas_call(
-        kernel,
-        grid=grid,
+        functools.partial(
+            _flash_kernel, causal=causal, scale=scale, tiles=tiles, rep=rep, with_lse=with_lse
+        ),
+        grid=(B, H // heads, tiles.Tp // block_q, tiles.Sp // block_k),
         in_specs=[
-            pl.BlockSpec((1, 1, 8, block_k), lambda b, h, i, j: (b, j, 0, 0)),  # kv_valid
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, i, j: (b, kvh(h), j, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, i, j: (b, kvh(h), j, 0)),
+            pl.BlockSpec((1, 1, block_k), lambda b, h, i, j: (b, 0, j)),
+            q_spec, kv_spec, kv_spec,
         ],
-        out_specs=out_specs if with_lse else out_specs[0],
-        out_shape=out_shape if with_lse else out_shape[0],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
         interpret=interpret,
-    )(kv_valid_tiled, q, k, v)
-    if with_lse:
-        out, lse = res
-        return out, lse[..., 0]  # [B, H, T]
-    return res, None
-
-
-def _drop_last_ref(kernel, *refs):
-    """Adapt the shared kernel to the no-lse pallas_call signature: insert
-    lse_ref=None between the single output ref and the scratch refs."""
-    # refs = (kv_valid, q, k, v, o, m_s, l_s, acc_s)
-    return kernel(*refs[:5], None, *refs[5:])
+        compiler_params=_grid_semantics(),
+    )(kv_valid, q, k, v)
+    out = res[0][:, :, :T, :]
+    return (out, res[1]) if with_lse else out
 
 
 # ----------------------------------------------------------------- backward
 
 
 def _flash_bwd_dkv_kernel(
-    kv_valid_ref,  # [1, 1, 8, block_k]
-    q_ref,  # [1, rep, block_q, D] — the kv head's whole query-head group
-    k_ref,  # [1, 1, block_k, D]
-    v_ref,  # [1, 1, block_k, D]
-    do_ref,  # [1, rep, block_q, D]
-    lse_ref,  # [1, rep, block_q, LANES]
-    delta_ref,  # [1, rep, block_q, LANES]
-    dk_ref,  # [1, 1, block_k, D] out
-    dv_ref,  # [1, 1, block_k, D] out
-    dk_scratch,  # [block_k, D] f32
-    dv_scratch,  # [block_k, D] f32
-    *,
+    kv_valid_ref,  # [1, 1, block_k]
+    q_ref,  # [1, kv heads * rep, block_q, D]: each kv head's whole query-head group
+    k_ref,  # [1, kv heads, block_k, D]
+    v_ref,
+    do_ref,  # as q_ref
+    lse_ref,  # [1, kv heads * rep, 1, block_q] f32
+    delta_ref,
+    dk_ref,  # [1, kv heads, block_k, D] out
+    dv_ref,
+    *scratch,  # dk, dv [kv heads, block_k, D] f32 where the q side is walked
     causal: bool,
     scale: float,
-    block_q: int,
-    block_k: int,
-    q_steps: int,
+    tiles: FlashTiles,
     rep: int,
 ):
-    kj = pl.program_id(2)
-    qi = pl.program_id(3)
+    """Works on transposed score tiles, keys down the sublanes and queries along
+    the lanes: lse and delta are subtracted as the rows they arrive as, and all
+    four matmuls (K Q^T, V dO^T, P^T dO, dS^T Q) contract without a transpose."""
+    kv_heads = k_ref.shape[1]
+    block_q, block_k = tiles.block_q, tiles.block_k
+    q_steps = tiles.Tp // block_q
+    carried = q_steps > 1
+    kj, qi = pl.program_id(2), pl.program_id(3)
+    # (first key row, rows, first query needed): key rows past the data are left out where
+    # the kv side is one tile, queries before the diagonal where causal extents are static
+    row_tiles = [
+        (c0, n, min(c0 // LANE * LANE, block_q - LANE) if causal and tiles.whole else 0)
+        for c0, n in _row_tiles(tiles.Sr if tiles.Sp == block_k else block_k, tiles.sub)
+    ]
 
-    @pl.when(qi == 0)
-    def _init():
-        dk_scratch[...] = jnp.zeros_like(dk_scratch)
-        dv_scratch[...] = jnp.zeros_like(dv_scratch)
+    if carried:
+        dk_scratch, dv_scratch = scratch
 
-    run = jnp.logical_or(
-        jnp.logical_not(causal), kj * block_k <= qi * block_q + (block_q - 1)
-    )
+        @pl.when(qi == 0)
+        def _init():
+            dk_scratch[...] = jnp.zeros_like(dk_scratch)
+            dv_scratch[...] = jnp.zeros_like(dv_scratch)
 
-    @pl.when(run)
-    def _step():
-        k = k_ref[0, 0].astype(jnp.float32)  # [bk, D]
-        v = v_ref[0, 0].astype(jnp.float32)
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-        k_pos = kj * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-        mask = kv_valid_ref[0, 0, 0][None, :] > 0
-        if causal:
-            mask = jnp.logical_and(mask, k_pos <= q_pos)
+    def visit():
+        valid = _to_cols(jnp.where(kv_valid_ref[0] > 0, 0.0, NEG_INF))  # [block_k, 1]
+        biases = []
+        for c0, n, first in row_tiles:
+            bias = valid[c0:c0 + n]
+            if causal:
+                shape = (n, block_q - first)
+                k_pos = kj * block_k + c0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+                q_pos = qi * block_q + first + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+                bias = jnp.where(k_pos <= q_pos, bias, NEG_INF)
+            biases.append(bias)
 
-        # dK/dV for a kv head sum over its whole query-head group; the group is
-        # fetched as a block dim and the loop unrolls statically (rep is 1 for MHA)
-        for r in range(rep):
-            q = q_ref[0, r].astype(jnp.float32)  # [bq, D]
-            do = do_ref[0, r].astype(jnp.float32)
-            lse = lse_ref[0, r, :, :1]  # [bq, 1]
-            delta = delta_ref[0, r, :, :1]
+        def kv_head(kh):
+            k, v = k_ref[0, kh], v_ref[0, kh]
 
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-            ) * scale  # [bq, bk]
-            # fully-masked rows have lse == NEG_INF; guard the exp to avoid inf*0
-            lse_safe = jnp.where(lse > NEG_INF / 2, lse, 0.0)
-            p = jnp.where(mask, jnp.exp(s - lse_safe), 0.0)  # [bq, bk]
-            # dv += P^T dO
-            dv_scratch[...] += jax.lax.dot_general(
-                p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-            )
-            dp = jax.lax.dot_general(
-                do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-            )  # [bq, bk]
-            ds = p * (dp - delta) * scale
-            # dk += dS^T Q
-            dk_scratch[...] += jax.lax.dot_general(
-                ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-            )
+            # dK/dV of a kv head sum over its query-head group
+            def group(r, sums):
+                h = kh * rep + r
+                q, do = q_ref[0, h], do_ref[0, h]
+                out = []
+                for (c0, n, first), bias, (dk, dv) in zip(row_tiles, biases, sums):
+                    lse = lse_ref[0, h, :, first:]  # [1, queries]
+                    # fully-masked rows have lse == NEG_INF; guard the exp against inf * 0
+                    lse = jnp.where(lse > NEG_INF / 2, lse, 0.0)
+                    p = jnp.exp(_nt_dot(k[c0:c0 + n], q[first:]) * scale + bias - lse)  # [n, queries]
+                    dv += _dot(p.astype(do.dtype), do[first:])
+                    dp = _nt_dot(v[c0:c0 + n], do[first:])
+                    ds = p * (dp - delta_ref[0, h, :, first:]) * scale
+                    dk += _dot(ds.astype(q.dtype), q[first:])
+                    out.append((dk, dv))
+                return out
 
-    @pl.when(qi == q_steps - 1)
-    def _finalize():
-        dk_ref[0, 0, ...] = dk_scratch[...].astype(dk_ref.dtype)
-        dv_ref[0, 0, ...] = dv_scratch[...].astype(dv_ref.dtype)
+            sums = [(jnp.zeros((n, k.shape[1]), jnp.float32),) * 2 for _, n, _ in row_tiles]
+            sums = group(0, sums) if rep == 1 else jax.lax.fori_loop(0, rep, group, sums)
+            for (c0, n, _), (dk, dv) in zip(row_tiles, sums):
+                if carried:
+                    dk_scratch[kh, c0:c0 + n] += dk
+                    dv_scratch[kh, c0:c0 + n] += dv
+                else:
+                    dk_ref[0, kh, c0:c0 + n, :] = dk.astype(dk_ref.dtype)
+                    dv_ref[0, kh, c0:c0 + n, :] = dv.astype(dv_ref.dtype)
+
+        _loop(kv_head, count=kv_heads)
+
+    _visit_below_diagonal(visit, qi, kj, causal=causal, tiles=tiles)
+
+    if carried:
+
+        @pl.when(qi == q_steps - 1)
+        def _finalize():
+            dk_ref[0] = dk_scratch[...].astype(dk_ref.dtype)
+            dv_ref[0] = dv_scratch[...].astype(dv_ref.dtype)
 
 
 def _flash_bwd_dq_kernel(
-    kv_valid_ref,
-    q_ref,
-    k_ref,
+    kv_valid_ref,  # [1, 1, block_k]
+    q_ref,  # [1, heads, block_q, D]
+    k_ref,  # [1, kv heads, block_k, D]
     v_ref,
     do_ref,
-    lse_ref,
+    lse_ref,  # [1, heads, 1, block_q] f32
     delta_ref,
-    dq_ref,  # [1, 1, block_q, D] out
-    dq_scratch,  # [block_q, D] f32
-    *,
+    dq_ref,  # [1, heads, block_q, D] out
+    *scratch,  # dq [heads, block_q, D] f32 where the kv side is walked
     causal: bool,
     scale: float,
-    block_q: int,
-    block_k: int,
-    kv_steps: int,
+    tiles: FlashTiles,
+    rep: int,
 ):
-    qi = pl.program_id(2)
-    kj = pl.program_id(3)
+    heads = q_ref.shape[1]
+    block_q, block_k = tiles.block_q, tiles.block_k
+    kv_steps = tiles.Sp // block_k
+    carried = kv_steps > 1
+    qi, kj = pl.program_id(2), pl.program_id(3)
+    row_tiles = _query_row_tiles(tiles, causal)
 
-    @pl.when(kj == 0)
-    def _init():
-        dq_scratch[...] = jnp.zeros_like(dq_scratch)
+    if carried:
+        (dq_scratch,) = scratch
 
-    run = jnp.logical_or(
-        jnp.logical_not(causal), kj * block_k <= qi * block_q + (block_q - 1)
-    )
+        @pl.when(kj == 0)
+        def _init():
+            dq_scratch[...] = jnp.zeros_like(dq_scratch)
 
-    @pl.when(run)
-    def _step():
-        q = q_ref[0, 0].astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0, :, :1]
-        delta = delta_ref[0, 0, :, :1]
+    def visit():
+        biases = _mask_biases(kv_valid_ref[0], qi * block_q, kj * block_k, row_tiles=row_tiles, causal=causal)
 
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-        k_pos = kj * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-        mask = kv_valid_ref[0, 0, 0][None, :] > 0
-        if causal:
-            mask = jnp.logical_and(mask, k_pos <= q_pos)
-        lse_safe = jnp.where(lse > NEG_INF / 2, lse, 0.0)
-        p = jnp.where(mask, jnp.exp(s - lse_safe), 0.0)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta) * scale
-        dq_scratch[...] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+        def head(h, kh):
+            lse = _to_cols(lse_ref[0, h])  # [block_q, 1]
+            lse = jnp.where(lse > NEG_INF / 2, lse, 0.0)
+            delta = _to_cols(delta_ref[0, h])
+            k, v = k_ref[0, kh], v_ref[0, kh]
+            for (r0, n, keys), bias in zip(row_tiles, biases):
+                q = q_ref[0, h, r0:r0 + n, :]
+                do = do_ref[0, h, r0:r0 + n, :]
+                p = jnp.exp(_nt_dot(q, k[:keys]) * scale + bias - lse[r0:r0 + n])  # [n, keys]
+                dp = _nt_dot(do, v[:keys])
+                ds = p * (dp - delta[r0:r0 + n]) * scale
+                dq = _dot(ds.astype(k.dtype), k[:keys])  # [n, D]
+                if carried:
+                    dq_scratch[h, r0:r0 + n] += dq
+                else:
+                    dq_ref[0, h, r0:r0 + n, :] = dq.astype(dq_ref.dtype)
 
-    @pl.when(kj == kv_steps - 1)
-    def _finalize():
-        dq_ref[0, 0, ...] = dq_scratch[...].astype(dq_ref.dtype)
+        _for_each_head(heads, rep, head)
+
+    _visit_below_diagonal(visit, qi, kj, causal=causal, tiles=tiles)
+
+    if carried:
+
+        @pl.when(kj == kv_steps - 1)
+        def _finalize():
+            dq_ref[0] = dq_scratch[...].astype(dq_ref.dtype)
 
 
-def _flash_backward(q, k, v, kv_valid, out, lse, g, causal, scale, block_q, block_k, interpret):
-    """Pallas backward: recompute P per block from saved lse. Returns dq, dk, dv.
+def _flash_backward(q, k, v, kv_valid, out, lse, g, causal, scale, interpret, tiles=None):
+    """Pallas backward: recompute P per tile from the saved logsumexp (``lse`` as
+    ``_flash_forward`` returns it, ``[B, H, 1, Tp]``). Returns dq, dk, dv in the
+    dtype of q, k, v.
 
-    Two kernels: ``dkv`` runs grid (B, Hkv, kv_blocks, q_blocks) — one program per
-    *kv* head, its query-head group fetched as a block dimension so dK/dV sum over
-    the group without output-block write conflicts; ``dq`` runs the forward's grid
-    (B, H, q_blocks, kv_blocks) with dQ accumulated in VMEM across kv steps."""
+    Two kernels: ``dkv`` runs grid (B, kv-head blocks, kv steps, q steps), a
+    program taking whole query-head groups so dK/dV sum over the group without
+    output-block write conflicts; ``dq`` runs the forward's grid. Where the whole
+    sequence is one tile neither carries anything across grid steps."""
     B, H, T, D = q.shape
     Hkv, S = k.shape[1], k.shape[2]
     rep = H // Hkv
-    block_q = _pick_block(T, block_q)
-    block_k = _pick_block(S, block_k)
-    pad_t = -T % block_q
-    pad_s = -S % block_k
-    if pad_t or pad_s:
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, pad_t), (0, 0)))
-        g = jnp.pad(g, ((0, 0), (0, 0), (0, pad_t), (0, 0)))
-        out = jnp.pad(out, ((0, 0), (0, 0), (0, pad_t), (0, 0)))
-        # padded query rows: lse = NEG_INF marks them fully-masked (p == 0)
-        lse = jnp.pad(lse, ((0, 0), (0, 0), (0, pad_t)), constant_values=NEG_INF)
-        k = jnp.pad(k, ((0, 0), (0, 0), (0, pad_s), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_s), (0, 0)))
-        kv_valid = jnp.pad(kv_valid, ((0, 0), (0, pad_s)))
-    Tp, Sp = q.shape[2], k.shape[2]
-    q_steps, kv_steps = Tp // block_q, Sp // block_k
-    kvh = _kv_head_map(H, Hkv)
+    if tiles is None:
+        tiles = choose_tiles(T, S, D, rep, q.dtype)
+    heads = _heads_per_program(H, rep, tiles.heads)
+    kv_heads = max(1, heads // rep)
+    block_q, block_k = tiles.block_q, tiles.block_k
+    q_steps, kv_steps = tiles.Tp // block_q, tiles.Sp // block_k
+    kvh = _kv_block_map(heads, rep)
 
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)  # [B,H,Tp]
-    lse_l = jnp.broadcast_to(lse[..., None], (B, H, Tp, LANES))
-    delta_l = jnp.broadcast_to(delta[..., None], (B, H, Tp, LANES))
-    kv_valid_tiled = _tile_kv_valid(kv_valid, B, kv_steps, block_k)
+    # padded query rows: dO == 0 and delta == 0 there, so they add nothing
+    q, g, out = (_pad_to(x, axis=2, n=tiles.Tp) for x in (q, g, out))
+    k, v = _pad_to(k, axis=2, n=tiles.Sp), _pad_to(v, axis=2, n=tiles.Sp)
+    kv_valid = _key_mask(kv_valid, tiles.Sp)
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)[:, :, None, :]  # [B, H, 1, Tp]
 
-    # block coordinate hk in a dim of block size `rep` addresses elements
-    # [hk*rep, (hk+1)*rep) — exactly kv head hk's query-head group
-    qo_spec = pl.BlockSpec((1, rep, block_q, D), lambda b, hk, kj, qi: (b, hk, qi, 0))
-    kv_spec = pl.BlockSpec((1, 1, block_k, D), lambda b, hk, kj, qi: (b, hk, kj, 0))
-    row_spec = pl.BlockSpec((1, rep, block_q, LANES), lambda b, hk, kj, qi: (b, hk, qi, 0))
-    mask_spec = pl.BlockSpec((1, 1, 8, block_k), lambda b, hk, kj, qi: (b, kj, 0, 0))
-
+    # a block of kv_heads * rep query heads at block index hk is kv-head block hk's groups
+    group_spec = pl.BlockSpec((1, kv_heads * rep, block_q, D), lambda b, hk, kj, qi: (b, hk, qi, 0))
+    group_row_spec = pl.BlockSpec((1, kv_heads * rep, 1, block_q), lambda b, hk, kj, qi: (b, hk, 0, qi))
+    kv_spec = pl.BlockSpec((1, kv_heads, block_k, D), lambda b, hk, kj, qi: (b, hk, kj, 0))
     dk, dv = pl.pallas_call(
-        functools.partial(
-            _flash_bwd_dkv_kernel,
-            causal=causal, scale=scale, block_q=block_q, block_k=block_k,
-            q_steps=q_steps, rep=rep,
-        ),
-        grid=(B, Hkv, kv_steps, q_steps),
-        in_specs=[mask_spec, qo_spec, kv_spec, kv_spec, qo_spec, row_spec, row_spec],
+        functools.partial(_flash_bwd_dkv_kernel, causal=causal, scale=scale, tiles=tiles, rep=rep),
+        grid=(B, Hkv // kv_heads, kv_steps, q_steps),
+        in_specs=[
+            pl.BlockSpec((1, 1, block_k), lambda b, hk, kj, qi: (b, 0, kj)),
+            group_spec, kv_spec, kv_spec, group_spec, group_row_spec, group_row_spec,
+        ],
         out_specs=[kv_spec, kv_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, Hkv, Sp, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, Sp, D), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
-        ],
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype), jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((kv_heads, block_k, D), jnp.float32)] * 2 if q_steps > 1 else [],
         interpret=interpret,
-    )(kv_valid_tiled, q, k, v, g, lse_l, delta_l)
+        compiler_params=_grid_semantics(),
+    )(kv_valid, q, k, v, g, lse, delta)
 
-    dq_q_spec = pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, kj: (b, h, qi, 0))
-    dq_kv_spec = pl.BlockSpec((1, 1, block_k, D), lambda b, h, qi, kj: (b, kvh(h), kj, 0))
-    dq_row_spec = pl.BlockSpec((1, 1, block_q, LANES), lambda b, h, qi, kj: (b, h, qi, 0))
-    dq_mask_spec = pl.BlockSpec((1, 1, 8, block_k), lambda b, h, qi, kj: (b, kj, 0, 0))
-
+    q_spec = pl.BlockSpec((1, heads, block_q, D), lambda b, h, qi, kj: (b, h, qi, 0))
+    row_spec = pl.BlockSpec((1, heads, 1, block_q), lambda b, h, qi, kj: (b, h, 0, qi))
+    dq_kv_spec = pl.BlockSpec((1, kv_heads, block_k, D), lambda b, h, qi, kj: (b, kvh(h), kj, 0))
     dq = pl.pallas_call(
-        functools.partial(
-            _flash_bwd_dq_kernel,
-            causal=causal, scale=scale, block_q=block_q, block_k=block_k, kv_steps=kv_steps,
-        ),
-        grid=(B, H, q_steps, kv_steps),
-        in_specs=[dq_mask_spec, dq_q_spec, dq_kv_spec, dq_kv_spec, dq_q_spec, dq_row_spec, dq_row_spec],
-        out_specs=dq_q_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, Tp, D), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+        functools.partial(_flash_bwd_dq_kernel, causal=causal, scale=scale, tiles=tiles, rep=rep),
+        grid=(B, H // heads, q_steps, kv_steps),
+        in_specs=[
+            pl.BlockSpec((1, 1, block_k), lambda b, h, qi, kj: (b, 0, kj)),
+            q_spec, dq_kv_spec, dq_kv_spec, q_spec, row_spec, row_spec,
+        ],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        scratch_shapes=[pltpu.VMEM((heads, block_q, D), jnp.float32)] if kv_steps > 1 else [],
         interpret=interpret,
-    )(kv_valid_tiled, q, k, v, g, lse_l, delta_l)
+        compiler_params=_grid_semantics(),
+    )(kv_valid, q, k, v, g, lse, delta)
 
-    if pad_t:
-        dq = dq[:, :, :T, :]
-    if pad_s:
-        dk = dk[:, :, :S, :]
-        dv = dv[:, :, :S, :]
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+    return dq[:, :, :T, :], dk[:, :, :S, :], dv[:, :, :S, :]
 
 
 def xla_attention(q, k, v, kv_valid, causal: bool, scale: float) -> jnp.ndarray:
@@ -500,37 +727,31 @@ def xla_attention(q, k, v, kv_valid, causal: bool, scale: float) -> jnp.ndarray:
     return jnp.einsum("bhts,bhsd->bhtd", p, v.astype(jnp.float32)).astype(q.dtype)
 
 
-@functools.partial(
-    jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8)
-)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def flash_attention(
-    q, k, v, kv_valid, causal: bool = True, scale: Optional[float] = None,
-    block_q: int = 128, block_k: int = 128, interpret: bool = False,
+    q, k, v, kv_valid, causal: bool = True, scale: Optional[float] = None, interpret: bool = False,
 ):
     """Flash attention, [B,H,T,D] layout; K/V may carry fewer (grouped) heads.
-    Differentiable: backward runs Pallas dq/dkv kernels recomputing attention
-    per block from the saved logsumexp (O(T·block) memory, matching the memory
-    model of the reference's fused CUDA kernels — SURVEY.md §2.4.5)."""
+    Tiles come from the shape (:func:`choose_tiles`). Differentiable: backward
+    runs Pallas dq/dkv kernels recomputing attention per tile from the saved
+    logsumexp (O(T·tile) memory, matching the memory model of the reference's
+    fused CUDA kernels — SURVEY.md §2.4.5)."""
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    return _flash_forward(q, k, v, kv_valid, causal, scale, block_q, block_k, interpret)
+    return _flash_forward(q, k, v, kv_valid, causal, scale, interpret)
 
 
-def _fwd(q, k, v, kv_valid, causal, scale, block_q, block_k, interpret):
+def _fwd(q, k, v, kv_valid, causal, scale, interpret):
     scale_ = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    out, lse = _flash_forward(
-        q, k, v, kv_valid, causal, scale_, block_q, block_k, interpret, with_lse=True
-    )
+    out, lse = _flash_forward(q, k, v, kv_valid, causal, scale_, interpret, with_lse=True)
     return out, (q, k, v, kv_valid, out, lse)
 
 
-def _bwd(causal, scale, block_q, block_k, interpret, res, g):
+def _bwd(causal, scale, interpret, res, g):
     q, k, v, kv_valid, out, lse = res
     scale_ = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
 
     if BACKWARD_IMPL == "pallas":
-        dq, dk, dv = _flash_backward(
-            q, k, v, kv_valid, out, lse, g, causal, scale_, block_q, block_k, interpret
-        )
+        dq, dk, dv = _flash_backward(q, k, v, kv_valid, out, lse, g, causal, scale_, interpret)
         return dq, dk, dv, None
 
     def ref(q, k, v):
@@ -545,8 +766,7 @@ flash_attention.defvjp(_fwd, _bwd)
 
 
 def flash_attention_sharded(
-    q, k, v, kv_valid, causal: bool, scale: Optional[float],
-    block_q: int, block_k: int, interpret: bool,
+    q, k, v, kv_valid, causal: bool, scale: Optional[float], interpret: bool,
     mesh, batch_axes, head_axis,
 ):
     """SPMD placement for the flash kernel: Mosaic kernels cannot be
@@ -561,9 +781,7 @@ def flash_attention_sharded(
     from jax.sharding import PartitionSpec as P
 
     def local(q, k, v, kv_valid):
-        return flash_attention(
-            q, k, v, kv_valid, causal, scale, block_q, block_k, interpret
-        )
+        return flash_attention(q, k, v, kv_valid, causal, scale, interpret)
 
     # The map must be manual over EVERY mesh axis the SPMD partitioner would
     # otherwise see — a Mosaic op under any remaining auto axis (e.g. `pipe`
