@@ -356,12 +356,12 @@ def test_heads_per_program_divides_heads_and_groups(H, rep, most, want):
 # ------------------------------------- parity on both sides of the chooser's threshold
 
 
-def _walked(T, S, D, rep, dtype):
+def _walked(T, S, D, rep, dtype, Dv=None):
     """Tiles of 128 walked over the grid, as a sequence too long for VMEM gets:
     the chooser with its budget shrunk to the least that still holds a tile."""
     for mib in (1, 1.5, 2, 3):
         try:
-            tiles = choose_tiles(T, S, D, rep, dtype, vmem_budget=int(mib * 2**20))
+            tiles = choose_tiles(T, S, D, rep, dtype, vmem_budget=int(mib * 2**20), Dv=Dv)
         except ValueError:
             continue
         assert not tiles.whole and min(tiles.block_q, tiles.block_k) == 128
@@ -378,6 +378,9 @@ PARITY_CASES = {
     "mqa": (2, 4, 1, 133, 262, jnp.float32, "left"),
     "bf16": (2, 2, 2, 200, 200, jnp.bfloat16, "left"),
     "bf16-gqa": (1, 4, 2, 150, 150, jnp.bfloat16, "left"),
+    # keys 24 wide, values 16 (latent attention's 192 / 128 in small): a trailing (D, Dv)
+    "value-width-apart": (2, 4, 4, 150, 150, jnp.float32, "left", 24, 16),
+    "value-width-apart-bf16": (2, 4, 4, 140, 272, jnp.bfloat16, "left", 24, 16),
 }
 
 
@@ -387,18 +390,19 @@ def test_forward_and_gradient_match_xla_in_both_regimes(case, regime):
     """Forward and all three gradients against ``xla_attention`` on the same
     inputs, with the chooser's own tiles (the whole sequence in one program) and
     with tiles of 128 walked over the grid."""
-    B, H, Hkv, T, S, dtype, masking = PARITY_CASES[case]
-    D = 16
+    B, H, Hkv, T, S, dtype, masking = PARITY_CASES[case][:7]
+    D, Dv = PARITY_CASES[case][7:] or (16, 16)
     rng = np.random.default_rng(11)
-    q, g = (jnp.asarray(rng.normal(size=(B, H, T, D)), dtype) for _ in range(2))
-    k, v = (jnp.asarray(rng.normal(size=(B, Hkv, S, D)), dtype) for _ in range(2))
+    q, k = (jnp.asarray(rng.normal(size=(B, heads, n, D)), dtype) for heads, n in ((H, T), (Hkv, S)))
+    g, v = (jnp.asarray(rng.normal(size=(B, heads, n, Dv)), dtype) for heads, n in ((H, T), (Hkv, S)))
     kv_valid = np.ones((B, S), np.int32)
     kv_valid[0, : S // 3] = 0  # left padding: sample 0's first queries see no key at all
     if masking == "none-valid":
         kv_valid[-1, :] = 0
     kv_valid = jnp.asarray(kv_valid)
     scale = D ** -0.5
-    tiles = choose_tiles(T, S, D, H // Hkv, dtype) if regime == "whole" else _walked(T, S, D, H // Hkv, dtype)
+    rep = H // Hkv
+    tiles = choose_tiles(T, S, D, rep, dtype, Dv=Dv) if regime == "whole" else _walked(T, S, D, rep, dtype, Dv)
     assert tiles.whole == (regime == "whole")
 
     out, lse = attn._flash_forward(q, k, v, kv_valid, True, scale, True, with_lse=True, tiles=tiles)

@@ -64,10 +64,11 @@ def no_persistent_cache():
     cc.reset_cache()
 
 
-def _flash(grad, shape=None, kv_heads=None, dtype=jnp.bfloat16):
+def _flash(grad, shape=None, kv_heads=None, dtype=jnp.bfloat16, v_width=None):
     c = PRESETS["gpt2"]
     B, H, T, D = shape or (FLASH_B, c.num_heads, FLASH_T, c.dim_per_head)
     kv_shape = (B, kv_heads or H, T, D)
+    v_shape = kv_shape[:3] + (v_width or D,)
 
     def fwd(q, k, v, kv_valid):
         return attention.flash_attention(q, k, v, kv_valid, True, None, False)
@@ -76,7 +77,28 @@ def _flash(grad, shape=None, kv_heads=None, dtype=jnp.bfloat16):
         return fwd(q, k, v, kv_valid).astype(jnp.float32).sum()
 
     fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
-    return fn, [((B, H, T, D), dtype)] + [(kv_shape, dtype)] * 2 + [((B, T), jnp.int32)]
+    return fn, [((B, H, T, D), dtype), (kv_shape, dtype), (v_shape, dtype), ((B, T), jnp.int32)]
+
+
+def _grouped_products(grad, tokens):
+    """The expert layer's sorted grouped products (``ops/moe.py``) at the
+    kimi-vl-a3b cell's shapes: 8 experts of 2048 x 1408 held, 6 assignments a
+    token, a row for every assignment."""
+    from trlx_tpu.ops import moe
+
+    E, d, f, k = 8, 2048, 1408, 6
+
+    def fwd(x, chosen, weights, gate, up, down):
+        return moe.expert_ffn(x, chosen, weights, gate, up, down, expert_offset=0)[0]
+
+    def loss(x, chosen, weights, gate, up, down):
+        return fwd(x, chosen, weights, gate, up, down).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 2, 3, 4, 5)) if grad else fwd
+    return fn, [
+        ((tokens, d), jnp.bfloat16), ((tokens, k), jnp.int32), ((tokens, k), jnp.float32),
+        ((E, d, f), jnp.float32), ((E, d, f), jnp.float32), ((E, f, d), jnp.float32),
+    ]
 
 
 def _attention_instructions(text):
@@ -148,6 +170,14 @@ for _shape, _kv_heads in [(s, None) for s in CELL_SHAPES] + LONG_SHAPES:
     _name = "x".join(map(str, _shape)) + (f"-hkv{_kv_heads}" if _kv_heads else "")
     CASES[f"flash_fwd-{_name}"] = functools.partial(_flash, False, _shape, _kv_heads)
     CASES[f"flash_grad-{_name}"] = functools.partial(_flash, True, _shape, _kv_heads)
+# latent attention's expanded form: keys 192 wide, values 128 (kimi-vl-a3b's learner, scoring, padded)
+for _T in (513, 576, 640):
+    CASES[f"flash_fwd-4x16x{_T}x192-v128"] = functools.partial(_flash, False, (4, 16, _T, 192), None, jnp.bfloat16, 128)
+    CASES[f"flash_grad-4x16x{_T}x192-v128"] = functools.partial(_flash, True, (4, 16, _T, 192), None, jnp.bfloat16, 128)
+# the grouped expert products: a learner microbatch (4 x 513 tokens) and a decode step (128)
+for _tokens in (2052, 128):
+    CASES[f"grouped_products_fwd-{_tokens}"] = functools.partial(_grouped_products, False, _tokens)
+    CASES[f"grouped_products_grad-{_tokens}"] = functools.partial(_grouped_products, True, _tokens)
 for _preset in ("gpt2", "gpt_bigcode"):  # Hkv=12 rep=1 D=64; Hkv=1 rep=16 D=128
     for _pool, _quant in (("bf16", False), ("int8", True)):
         CASES[f"paged_decode-{_pool}-{_preset}"] = functools.partial(
@@ -165,9 +195,20 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_persistent_cache, monkeypatc
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     fn, shapes = CASES[case]()
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
-    compiled = jax.jit(fn).lower(*args).compile()  # raises what the chip's compiler would
+    # jax's grouped matmul multiplies at the process's default precision, which conftest.py sets to
+    # float32 and the chip's compiler refuses for bfloat16 operands: on the chip it is the default
+    precision = "bfloat16" if case.startswith("grouped_products") else "float32"
+    with jax.default_matmul_precision(precision):
+        compiled = jax.jit(fn).lower(*args).compile()  # raises what the chip's compiler would
     text = compiled.as_text()
     assert "tpu_custom_call" in text
+    if case.startswith("grouped_products"):
+        # one named family of device ops, as benchmark/metrics/moe_gmm_roofline.json matches it,
+        # and none that flash_attn_roofline's pattern would take for its own
+        names = _attention_instructions(text)
+        products = [n for n in names if re.match(r"^%t?gmm[.0-9]* custom-call$", n)]
+        assert len(products) >= (6 if "grad" in case else 3), names
+        assert not any(re.match(r"^%attn[.0-9]* custom-call$", n) for n in names), names
     if case.endswith("attn_names"):
         names = _attention_instructions(text)
         assert len(names) == 6, names  # forward, dkv and dq of two layers

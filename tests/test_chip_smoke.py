@@ -52,6 +52,26 @@ def test_ppo_phase_at_tiny_widths(tmp_path, capsys, serving):
         assert "resolved paged impl: xla" in out  # the CPU's own path
 
 
+def test_ppo_moe_phase_at_tiny_widths(tmp_path, capsys):
+    """The sparse-expert family's phase: latent attention and routed experts
+    through ``trlx_tpu.train()`` on a one-device mesh."""
+    import dataclasses
+
+    tiny_moe = dataclasses.replace(
+        TINY, model_path=chip_smoke.MOE.model_path, steps=chip_smoke.MOE.steps,
+        model_overrides=dict(
+            vocab_size=300, hidden_size=32, num_layers=2, num_heads=2, intermediate_size=64,
+            max_position_embeddings=64, kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4,
+            v_head_dim=8, num_experts=8, experts_held=4, experts_per_token=2, moe_intermediate_size=16,
+        ),
+    )
+    with _only_devices(jax.devices()[:1]):
+        trainer = chip_smoke.phase_ppo(tiny_moe, str(tmp_path), phase="ppo_moe")
+    assert trainer.model_config.attention_kind == "mla" and trainer.model_config.is_expert_layer(1)
+    out = capsys.readouterr().out
+    assert "[ppo_moe] step 1/1:" in out and "0 after it (CompileWatcher)" in out
+
+
 def test_sharded_phase_on_four_virtual_devices(tmp_path, capsys):
     with _only_devices(jax.devices()[:4]):
         chip_smoke.phase_sharded(TINY, str(tmp_path))

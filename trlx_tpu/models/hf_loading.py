@@ -484,6 +484,68 @@ def _bigcode_from_params(p, c):
     return sd
 
 
+def _rotary_halves(kernel: np.ndarray, rope: int) -> np.ndarray:
+    """The last ``rope`` columns of each ``[in, ..., width]`` output group from
+    the published checkpoint's interleaved rotary pairs (2i, 2i + 1) to this
+    program's halves (i, i + rope / 2)."""
+    order = np.concatenate([np.arange(0, rope, 2), np.arange(1, rope, 2)])
+    head = kernel.shape[-1] - rope
+    return np.concatenate([kernel[..., :head], kernel[..., head:][..., order]], axis=-1)
+
+
+def _kimi_prefix(sd) -> str:
+    """``""`` for a bare language model, ``"language_model."`` inside the
+    vision-language checkpoint (whose tower and projector are not loaded)."""
+    for key in sd:
+        if key.endswith("model.embed_tokens.weight"):
+            return key[: -len("model.embed_tokens.weight")]
+    raise KeyError("no model.embed_tokens.weight in the checkpoint")
+
+
+def _kimi_to_params(sd, c):
+    """Kimi-VL-A3B's language model (DeepSeek-V3 names). Only the experts held
+    (``experts_held`` from ``expert_offset``) and the first ``vocab_size`` rows
+    of the vocabulary are taken; the rotary columns of ``q_proj`` and
+    ``kv_a_proj_with_mqa`` go from interleaved pairs to halves."""
+    root = _kimi_prefix(sd)
+    H, rope, V = c.num_heads, c.qk_rope_head_dim, c.vocab_size
+    p = {
+        "embed_tokens": {"embedding": sd[f"{root}model.embed_tokens.weight"][:V]},
+        "ln_f": {"scale": sd[f"{root}model.norm.weight"]},
+    }
+    if not c.tie_word_embeddings:
+        p["lm_head"] = {"kernel": sd[f"{root}lm_head.weight"][:V].T}
+    for i in range(c.num_layers):
+        pre = f"{root}model.layers.{i}"
+        q = sd[f"{pre}.self_attn.q_proj.weight"].T  # [d, H * (nope + rope)]
+        q = _rotary_halves(q.reshape(q.shape[0], H, -1), rope).reshape(q.shape)
+        layer = {
+            "ln_1": {"scale": sd[f"{pre}.input_layernorm.weight"]},
+            "ln_2": {"scale": sd[f"{pre}.post_attention_layernorm.weight"]},
+            "attn": {
+                "q_proj": {"kernel": q},
+                "kv_a_proj": {"kernel": _rotary_halves(sd[f"{pre}.self_attn.kv_a_proj_with_mqa.weight"].T, rope)},
+                "kv_a_norm": {"scale": sd[f"{pre}.self_attn.kv_a_layernorm.weight"]},
+                "kv_b_proj": _linear(sd, f"{pre}.self_attn.kv_b_proj"),
+                "o_proj": _linear(sd, f"{pre}.self_attn.o_proj"),
+            },
+        }
+        if c.is_expert_layer(i):
+            held = range(c.expert_offset, c.expert_offset + c.held_experts)
+            stack = lambda name: np.stack([sd[f"{pre}.mlp.experts.{e}.{name}_proj.weight"].T for e in held])
+            layer["mlp"] = {
+                "router": {"kernel": sd[f"{pre}.mlp.gate.weight"].T,
+                           "bias": sd[f"{pre}.mlp.gate.e_score_correction_bias"]},
+                "experts": {name: stack(name) for name in ("gate", "up", "down")},
+                "shared": {f"{name}_proj": _linear(sd, f"{pre}.mlp.shared_experts.{name}_proj")
+                           for name in ("gate", "up", "down")},
+            }
+        else:
+            layer["mlp"] = {f"{name}_proj": _linear(sd, f"{pre}.mlp.{name}_proj") for name in ("gate", "up", "down")}
+        p[f"layers_{i}"] = layer
+    return p
+
+
 CONVERTERS = {
     "gpt2": (_gpt2_to_params, _gpt2_from_params),
     "llama": (_llama_to_params, _llama_from_params),
@@ -492,6 +554,8 @@ CONVERTERS = {
     "opt": (_opt_to_params, _opt_from_params),
     "bloom": (_bloom_to_params, _bloom_from_params),
     "gpt_bigcode": (_bigcode_to_params, _bigcode_from_params),
+    # load only: a share of the experts and of the vocabulary is no whole checkpoint to export
+    "kimi_vl": (_kimi_to_params, None),
 }
 # "t5" is registered below once its converters are defined (seq2seq section)
 
@@ -506,7 +570,7 @@ def hf_state_dict_to_params(model_type: str, sd: Dict[str, np.ndarray], config: 
 def params_to_hf_state_dict(
     model_type: str, params: Dict[str, Any], config: TransformerConfig
 ) -> Dict[str, np.ndarray]:
-    if model_type not in CONVERTERS:
+    if model_type not in CONVERTERS or CONVERTERS[model_type][1] is None:
         raise ValueError(f"No converter for model_type {model_type!r}")
     params = jax.tree.map(lambda x: np.asarray(jax.device_get(x), dtype=np.float32), params)
     return CONVERTERS[model_type][1](params, config)
@@ -593,9 +657,9 @@ def load_pretrained(
 
 def _family_of(name: str) -> str:
     key = name.lower().replace("-", "").replace("_", "")
-    for family in ("gptbigcode", "gptneox", "gptj", "gpt2", "llama", "opt", "bloom"):
+    for family in ("gptbigcode", "gptneox", "gptj", "gpt2", "llama", "opt", "bloom", "kimivl"):
         if family in key:
-            return {"gptneox": "gpt_neox", "gptbigcode": "gpt_bigcode"}.get(family, family)
+            return {"gptneox": "gpt_neox", "gptbigcode": "gpt_bigcode", "kimivl": "kimi_vl"}.get(family, family)
     if "pythia" in key or "neox" in key:
         return "gpt_neox"
     if "starcoder" in key or "santacoder" in key:

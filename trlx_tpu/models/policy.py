@@ -44,7 +44,11 @@ class CausalLMWithValueHead(nn.Module):
         self.transformer = TransformerLM(self.config)
         self.v_head = ValueHead(self.config)
         if self.num_value_layers > 0:
-            self.value_blocks = [Block(self.config) for _ in range(self.num_value_layers)]
+            start = self.config.num_layers - self.num_value_layers  # copies of the trunk's top layers
+            self.value_blocks = [
+                Block(self.config, expert_layer=self.config.is_expert_layer(start + i))
+                for i in range(self.num_value_layers)
+            ]
             self.value_ln = _norm_module(self.config)
 
     def _value_branch(self, hidden, attention_mask, positions):

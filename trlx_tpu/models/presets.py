@@ -58,6 +58,20 @@ PRESETS: Dict[str, TransformerConfig] = {
         norm="layernorm", activation="gelu_new", attn_bias=True, mlp_bias=True,
         tie_word_embeddings=True,
     ),
+    # Kimi-VL-A3B's language model (the vision tower is not run): latent
+    # attention over a latent cache, one leading dense layer, then 64
+    # sigmoid-routed experts (6 a token) beside 2 shared ones. experts_held /
+    # expert_offset / vocab_size cut a chip's share of a layer (model_overrides).
+    "kimi_vl": TransformerConfig(
+        vocab_size=163840, hidden_size=2048, num_layers=27, num_heads=16,
+        intermediate_size=11264, max_position_embeddings=131072, pos_embedding="rotary",
+        rope_style="neox", rope_theta=800000.0, norm="rmsnorm", norm_eps=1e-5,
+        activation="silu", glu=True, attn_bias=False, mlp_bias=False, tie_word_embeddings=False,
+        attention_kind="mla", kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, num_experts=64, experts_per_token=6, num_shared_experts=2,
+        moe_intermediate_size=1408, first_dense_layers=1, routed_scaling_factor=2.446,
+        norm_topk_prob=True,
+    ),
 }
 
 
@@ -68,7 +82,7 @@ def get_preset(name: str, overrides: Optional[Dict[str, Any]] = None) -> Transfo
     if key in PRESETS:
         config = PRESETS[key]
     else:
-        for family in ("gpt_bigcode", "gpt_neox", "gptj", "gpt2", "llama", "opt", "bloom"):
+        for family in ("gpt_bigcode", "gpt_neox", "gptj", "gpt2", "llama", "opt", "bloom", "kimi_vl"):
             if family.replace("_", "") in key.replace("_", "").replace("-", ""):
                 config = PRESETS[family]
                 break
@@ -146,6 +160,31 @@ def from_hf_config(hf_config, overrides: Optional[Dict[str, Any]] = None) -> Tra
             intermediate_size=getattr(hf_config, "n_inner", None),
             max_position_embeddings=hf_config.n_positions,
             norm_eps=hf_config.layer_norm_epsilon,
+        )
+    elif mt == "kimi_vl":
+        text = getattr(hf_config, "text_config", hf_config)  # kimi_vl nests the language model's
+        unsupported = {
+            "q_lora_rank": getattr(text, "q_lora_rank", None) is not None,
+            "n_group > 1": getattr(text, "n_group", 1) != 1,
+            "rope_scaling": getattr(text, "rope_scaling", None) is not None,
+            "scoring_func other than sigmoid": getattr(text, "scoring_func", "sigmoid") != "sigmoid",
+            "moe_layer_freq other than 1": getattr(text, "moe_layer_freq", 1) != 1,
+        }
+        if any(unsupported.values()):
+            raise ValueError(f"{mt}: not supported: {[k for k, v in unsupported.items() if v]}")
+        config = PRESETS["kimi_vl"].replace(
+            vocab_size=text.vocab_size, hidden_size=text.hidden_size,
+            num_layers=text.num_hidden_layers, num_heads=text.num_attention_heads,
+            intermediate_size=text.intermediate_size,
+            max_position_embeddings=text.max_position_embeddings,
+            rope_theta=float(getattr(text, "rope_theta", 10000.0)), norm_eps=text.rms_norm_eps,
+            tie_word_embeddings=getattr(text, "tie_word_embeddings", False),
+            kv_lora_rank=text.kv_lora_rank, qk_nope_head_dim=text.qk_nope_head_dim,
+            qk_rope_head_dim=text.qk_rope_head_dim, v_head_dim=text.v_head_dim,
+            num_experts=text.n_routed_experts, experts_per_token=text.num_experts_per_tok,
+            num_shared_experts=text.n_shared_experts, moe_intermediate_size=text.moe_intermediate_size,
+            first_dense_layers=text.first_k_dense_replace,
+            routed_scaling_factor=text.routed_scaling_factor, norm_topk_prob=text.norm_topk_prob,
         )
     else:
         raise ValueError(f"Unsupported HF model_type {mt!r}")

@@ -120,10 +120,44 @@ class TransformerConfig:
     # branches (same restrictions as pipeline_stages > 1).
     scan_layers: bool = False
 
+    # Latent attention ("mla"): queries of num_heads x (qk_nope_head_dim +
+    # qk_rope_head_dim); one kv_lora_rank-wide latent and one shared rotary key
+    # per token, keys and values (v_head_dim wide) expanded per head from the
+    # latent. The cache holds the latent and the rotary key, [B, S, rank] +
+    # [B, S, rope] a layer, and decode attends over it in the absorbed form.
+    attention_kind: str = "mha"  # "mha" | "mla"
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # Sparse experts (ops/moe.py): num_experts > 0 gives every layer from
+    # first_dense_layers on a router over num_experts (sigmoid scores, the
+    # experts_per_token largest of score + selection bias chosen, weights
+    # normalised and scaled) beside num_shared_experts always-on experts of the
+    # same width. experts_held / expert_offset say which routed experts this
+    # process holds (None: all): one chip's share of a layer divided over
+    # several; an assignment to an expert held elsewhere adds nothing here.
+    num_experts: int = 0
+    experts_per_token: int = 0
+    experts_held: Optional[int] = None
+    expert_offset: int = 0
+    num_shared_experts: int = 0
+    moe_intermediate_size: int = 0
+    first_dense_layers: int = 0
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
+
     @property
     def stacked(self) -> bool:
         """Whether block params use the stacked [num_layers, ...] layout."""
         return self.pipeline_stages > 1 or self.scan_layers
+
+    def is_expert_layer(self, index: int) -> bool:
+        return self.num_experts > 0 and index >= self.first_dense_layers
+
+    @property
+    def held_experts(self) -> int:
+        return self.num_experts if self.experts_held is None else self.experts_held
     # Megatron-SP analogue: shard the residual stream's sequence dim over the
     # `model` axis between blocks (reference sequence_parallel cfg,
     # modeling_nemo_ppo.py:160-164). Applied on cache-free forwards.
@@ -253,7 +287,12 @@ def make_rotary(config: TransformerConfig, positions: jnp.ndarray) -> Tuple[jnp.
     """cos/sin tables [B, T, rot_dim/2] for the given positions."""
     rot_dim = int(config.dim_per_head * config.rotary_pct)
     rot_dim -= rot_dim % 2
-    inv_freq = 1.0 / (config.rope_theta ** (jnp.arange(0, rot_dim, 2, dtype=jnp.float32) / rot_dim))
+    return rotary_tables(rot_dim, config.rope_theta, positions)
+
+
+def rotary_tables(rot_dim: int, theta: float, positions: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """cos/sin [B, T, rot_dim/2] of ``rot_dim`` rotary dimensions."""
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, rot_dim, 2, dtype=jnp.float32) / rot_dim))
     freqs = positions[..., None].astype(jnp.float32) * inv_freq  # [B,T,rot/2]
     return jnp.cos(freqs), jnp.sin(freqs)
 
@@ -393,6 +432,64 @@ def kv_cache_layout(shape: Tuple[int, ...], dtype, quant: bool) -> Dict[str, Tup
     return {"k": (shape, dtype), "v": (shape, dtype)}
 
 
+def _flash_placement(c: TransformerConfig, B: int, T: int, kv_valid, heads: int, kv_heads: int):
+    """(whether this forward takes the flash kernel, the mesh to place it over
+    or None for a plain call).
+
+    The flash path serves every multi-token forward: training loss, the
+    logprob/value scoring passes, AND generation prefill. With a cache present,
+    a non-None kv_valid IS the prefill-from-zero marker: TransformerLM only
+    passes it when the cache index was a concrete 0 at trace time (checked
+    there, outside the remat wrapper — inside a block cache["index"] may be a
+    remat tracer even at prefill)."""
+    use_flash = (
+        c.attention_impl == "flash"
+        and kv_valid is not None
+        and T > 1
+        and c.pos_embedding != "alibi"  # kernel takes no additive bias
+        and c.peft_type != "prefix"  # prefix keys break the kernel's causal index math
+    )
+    # Mosaic kernels cannot be auto-partitioned by XLA SPMD: on a
+    # multi-device mesh the flash call must be placed explicitly (batch and
+    # head axes are embarrassingly parallel) via shard_map, and a shape
+    # that cannot divide those axes falls back to the einsum paths.
+    flash_mesh = None
+    if use_flash:
+        flash_mesh = ambient_mesh()
+        if flash_mesh is not None:
+            n_batch = int(np.prod([flash_mesh.shape.get(a, 1) for a in BATCH_AXES]))
+            n_model = flash_mesh.shape.get(MODEL_AXIS, 1)
+            if flash_mesh.size == 1:
+                # single device: plain call. (Any larger mesh must go via
+                # the shard_map wrapper even when batch/model axes are
+                # trivial — e.g. a pipe-only mesh still has an auto axis
+                # the Mosaic kernel cannot sit under.)
+                flash_mesh = None
+            elif B % n_batch or heads % n_model or kv_heads % n_model:
+                use_flash = False  # kernel cannot place; XLA attention
+    return use_flash, flash_mesh
+
+
+def _flash(q, kh, vh, kv_valid, scale: float, flash_mesh):
+    """q, kh, vh [B, heads, T, D] through the flash kernel, plainly or placed
+    over ``flash_mesh``; the kernel maps query head h -> kv head h // rep
+    natively, so grouped K/V are never materialized at full head count."""
+    from trlx_tpu.ops.attention import flash_attention, flash_attention_sharded
+
+    # interpret (XLA-emulated) mode iff the COMPILE TARGET is CPU. The
+    # ambient mesh's devices name the target; default_backend alone is
+    # wrong under deviceless TPU AOT compilation (scripts/scale_proof.py
+    # runs with a CPU host backend but lowers for a TPU topology, where
+    # interpret mode would re-materialize the score matrices the kernel
+    # exists to avoid).
+    target = flash_mesh.devices.flat[0].platform if flash_mesh is not None else jax.default_backend()
+    if flash_mesh is not None:
+        return flash_attention_sharded(
+            q, kh, vh, kv_valid, True, scale, target == "cpu", flash_mesh, BATCH_AXES, MODEL_AXIS,
+        )
+    return flash_attention(q, kh, vh, kv_valid, True, scale, target == "cpu")
+
+
 class Attention(nn.Module):
     config: TransformerConfig
 
@@ -488,44 +585,15 @@ class Attention(nn.Module):
         else:
             new_cache = None
 
-        # The flash path serves every multi-token forward: training loss, the
-        # logprob/value scoring passes, AND generation prefill. For prefill
-        # (cache present, T > 1, writes starting at slot 0) attention over the
-        # just-computed prefix k/v is exactly attention over the cache, since all
-        # cache slots >= T are still empty; k/v are written to the cache above
-        # regardless. The slot-0 requirement is enforced structurally: the cache
-        # index must be a concrete 0 at trace time (true for generate()'s prefill,
-        # never true inside the decode while_loop or for chunked appends, which
-        # fall back to attending over the full cache via XLA).
-        # With a cache present, a non-None kv_valid IS the prefill-from-zero
-        # marker: TransformerLM only passes it when the cache index was a
-        # concrete 0 at trace time (checked there, outside the remat wrapper —
-        # in here cache["index"] may be a remat tracer even at prefill).
-        use_flash = (
-            c.attention_impl == "flash"
-            and kv_valid is not None
-            and T > 1
-            and c.pos_embedding != "alibi"  # kernel takes no additive bias
-            and c.peft_type != "prefix"  # prefix keys break the kernel's causal index math
-        )
-        # Mosaic kernels cannot be auto-partitioned by XLA SPMD: on a
-        # multi-device mesh the flash call must be placed explicitly (batch and
-        # head axes are embarrassingly parallel) via shard_map, and a shape
-        # that cannot divide those axes falls back to the einsum paths below.
-        flash_mesh = None
-        if use_flash:
-            flash_mesh = ambient_mesh()
-            if flash_mesh is not None:
-                n_batch = int(np.prod([flash_mesh.shape.get(a, 1) for a in BATCH_AXES]))
-                n_model = flash_mesh.shape.get(MODEL_AXIS, 1)
-                if flash_mesh.size == 1:
-                    # single device: plain call. (Any larger mesh must go via
-                    # the shard_map wrapper even when batch/model axes are
-                    # trivial — e.g. a pipe-only mesh still has an auto axis
-                    # the Mosaic kernel cannot sit under.)
-                    flash_mesh = None
-                elif B % n_batch or c.num_heads % n_model or c.kv_heads % n_model:
-                    use_flash = False  # kernel cannot place; XLA attention below
+        # For prefill (cache present, T > 1, writes starting at slot 0) attention
+        # over the just-computed prefix k/v is exactly attention over the cache,
+        # since all cache slots >= T are still empty; k/v are written to the
+        # cache above regardless. The slot-0 requirement is enforced
+        # structurally: the cache index must be a concrete 0 at trace time (true
+        # for generate()'s prefill, never true inside the decode while_loop or
+        # for chunked appends, which fall back to attending over the full cache
+        # via XLA).
+        use_flash, flash_mesh = _flash_placement(c, B, T, kv_valid, c.num_heads, c.kv_heads)
         # kh/vh [B, Hkv, S, D]: the layout attention consumes (and the cache layout)
         k_row_scale = v_row_scale = None
         if cache is not None and not use_flash:
@@ -610,31 +678,7 @@ class Attention(nn.Module):
             # fall through to XLA when the mesh/shape can't ring
 
         if use_flash:
-            # the kernel maps query head h -> kv head h // rep natively: grouped
-            # K/V are never materialized at full head count
-            from trlx_tpu.ops.attention import flash_attention, flash_attention_sharded
-
-            # interpret (XLA-emulated) mode iff the COMPILE TARGET is CPU. The
-            # ambient mesh's devices name the target; default_backend alone is
-            # wrong under deviceless TPU AOT compilation (scripts/scale_proof.py
-            # runs with a CPU host backend but lowers for a TPU topology, where
-            # interpret mode would re-materialize the score matrices the kernel
-            # exists to avoid).
-            target = (
-                flash_mesh.devices.flat[0].platform
-                if flash_mesh is not None
-                else jax.default_backend()
-            )
-            if flash_mesh is not None:
-                out = flash_attention_sharded(
-                    q.transpose(0, 2, 1, 3), kh, vh, kv_valid, True, scale,
-                    target == "cpu", flash_mesh, BATCH_AXES, MODEL_AXIS,
-                )
-            else:
-                out = flash_attention(
-                    q.transpose(0, 2, 1, 3), kh, vh,
-                    kv_valid, True, scale, target == "cpu",
-                )
+            out = _flash(q.transpose(0, 2, 1, 3), kh, vh, kv_valid, scale, flash_mesh)
             out = out.transpose(0, 2, 1, 3).astype(c.compute_dtype)
         elif c.kv_heads != c.num_heads:
             # grouped-query einsum: batch scores over kv heads with the group as
@@ -673,6 +717,122 @@ class Attention(nn.Module):
         return out, new_cache
 
 
+class _Kernel(nn.Module):
+    """A bare ``kernel`` parameter under the module's name, for a projection
+    that is applied in more than one form."""
+
+    shape: Tuple[int, ...]
+    std: float
+    param_dtype: Any
+
+    @nn.compact
+    def __call__(self) -> jnp.ndarray:
+        return self.param("kernel", nn.initializers.normal(self.std), self.shape, self.param_dtype)
+
+
+def latent_cache_layout(batch_size: int, max_length: int, config: TransformerConfig, dtype) -> Dict[str, Tuple]:
+    """A latent-attention layer's cache buffers as {key: (shape, dtype)}: the
+    normed latent and the rotated shared key of every token."""
+    return {
+        "c": ((batch_size, max_length, config.kv_lora_rank), dtype),
+        "k_rope": ((batch_size, max_length, config.qk_rope_head_dim), dtype),
+    }
+
+
+_MLA_REFUSALS = {
+    "paged": "the paged cache's blocks are kv_heads x head_dim rows; latent attention caches one "
+             "kv_lora_rank + qk_rope_head_dim row a token, which the paged kernels cannot read",
+    "kv_cache_quant": "kv_cache_quant scales int8 rows per head; a latent row has no heads",
+    "stacked": "scan_layers / pipeline_stages > 1 stack one cache array [L, B, Hkv, S, D]; latent "
+               "attention caches [B, S, kv_lora_rank] + [B, S, qk_rope_head_dim] a layer",
+}
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (``attention_kind="mla"``).
+
+    ``q = x W_q`` as heads of ``nope + rope``; ``[c_raw, k_rope] = x W_kva``,
+    ``c = RMSNorm(c_raw)``, ``k_rope`` one rotary head shared by all; keys and
+    values are ``c W_kvb`` per head, values ``v_head_dim`` wide. Multi-token
+    forwards (cache-free, and prefill from slot 0) expand k and v and go
+    through the flash kernels at key width ``nope + rope`` and value width
+    ``v_head_dim``. A forward over the cache (decode) holds only ``c`` and the
+    rotated ``k_rope`` per token and attends in the absorbed form: ``q_nope``
+    is carried into the latent through W_kvb's key part, scores are taken
+    against ``c`` and ``k_rope`` directly, the probabilities weigh ``c``, and
+    W_kvb's value part brings the result back out — every head over one
+    ``rank + rope``-wide key and one ``rank``-wide value."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, mask_bias, positions, cache=None, kv_valid=None):
+        c = self.config
+        if c.peft_type == "prefix":
+            raise ValueError("prefix tuning prepends per-head keys and values; latent attention has none")
+        if cache is not None and "block_tables" in cache:
+            raise ValueError(_MLA_REFUSALS["paged"])
+        B, T, _ = x.shape
+        H, nope, rope, vdim, rank = c.num_heads, c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim, c.kv_lora_rank
+        dense = lambda feats, name, std=c.initializer_range: LoraDense(
+            feats, use_bias=c.attn_bias, dtype=c.compute_dtype, param_dtype=c.param_dtype,
+            kernel_init=nn.initializers.normal(std), name=name,
+            r=c.lora_r if name in c.lora_targets else 0, alpha=c.lora_alpha,
+        )
+        scale = 1.0 / math.sqrt(nope + rope)
+        with jax.named_scope("mla"):
+            q = dense(H * (nope + rope), "q_proj")(x).reshape(B, T, H, nope + rope)
+            kva = dense(rank + rope, "kv_a_proj")(x)
+            latent = nn.RMSNorm(
+                epsilon=c.norm_eps, dtype=c.compute_dtype, param_dtype=c.param_dtype, name="kv_a_norm"
+            )(kva[..., :rank])
+            cos, sin = rotary_tables(rope, c.rope_theta, positions)
+            q_nope = q[..., :nope]
+            q_rope = apply_rotary(q[..., nope:], cos, sin, c.rope_style)
+            k_rope = apply_rotary(kva[..., None, rank:], cos, sin, c.rope_style)  # [B, T, 1, rope]
+            w_kvb = _Kernel((rank, H * (nope + vdim)), c.initializer_range, c.param_dtype, name="kv_b_proj")()
+            w_kvb = w_kvb.astype(c.compute_dtype).reshape(rank, H, nope + vdim)
+
+            new_cache = None
+            if cache is not None:
+                at = (0, cache["index"], 0)
+                new_cache = {
+                    "c": jax.lax.dynamic_update_slice(cache["c"], latent.astype(cache["c"].dtype), at),
+                    "k_rope": jax.lax.dynamic_update_slice(
+                        cache["k_rope"], k_rope[:, :, 0].astype(cache["k_rope"].dtype), at),
+                }
+            use_flash, flash_mesh = _flash_placement(c, B, T, kv_valid, H, H)
+            expanded = cache is None or use_flash  # per-head keys and values of this forward's own tokens
+            if expanded:
+                kv = jnp.einsum("btl,lhm->bthm", latent, w_kvb)
+                k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(k_rope, (B, T, H, rope))], axis=-1)
+                q = jnp.concatenate([q_nope, q_rope], axis=-1)
+                v = kv[..., nope:]
+        if expanded:
+            if use_flash:
+                # outside the "mla" scope: the kernels keep the module's name, %attn.N
+                out = _flash(
+                    q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
+                    kv_valid, scale, flash_mesh,
+                ).transpose(0, 2, 1, 3)
+            else:
+                scores = jnp.einsum("bthd,bshd->bhts", q, k).astype(jnp.float32) * scale + mask_bias
+                probs = jax.nn.softmax(scores, axis=-1).astype(c.compute_dtype)
+                out = jnp.einsum("bhts,bshd->bthd", probs, v)
+        else:
+            with jax.named_scope("mla"):  # absorbed, over the cache
+                ck, kr = new_cache["c"].astype(c.compute_dtype), new_cache["k_rope"].astype(c.compute_dtype)
+                q_latent = jnp.einsum("bthn,lhn->bthl", q_nope, w_kvb[..., :nope])
+                scores = jnp.einsum("bthl,bsl->bhts", q_latent, ck) + jnp.einsum("bthr,bsr->bhts", q_rope, kr)
+                probs = jax.nn.softmax(scores.astype(jnp.float32) * scale + mask_bias, axis=-1)
+                out_latent = jnp.einsum("bhts,bsl->bthl", probs.astype(c.compute_dtype), ck)
+                out = jnp.einsum("bthl,lhv->bthv", out_latent, w_kvb[..., nope:])
+        with jax.named_scope("mla"):
+            out = out.astype(c.compute_dtype).reshape(B, T, H * vdim)
+            out = dense(c.hidden_size, "o_proj", c.residual_init_std())(out)
+        return out, new_cache
+
+
 class MLP(nn.Module):
     config: TransformerConfig
 
@@ -692,26 +852,99 @@ class MLP(nn.Module):
         return dense(c.hidden_size, "down_proj", c.residual_init_std())(h)
 
 
+class _Router(nn.Module):
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self):
+        c = self.config
+        kernel = self.param(
+            "kernel", nn.initializers.normal(c.initializer_range), (c.hidden_size, c.num_experts), c.param_dtype)
+        # the selection bias (published ``e_score_correction_bias``): it chooses and does not weigh
+        bias = self.param("bias", nn.initializers.zeros, (c.num_experts,), c.param_dtype)
+        return kernel, bias
+
+
+class _Experts(nn.Module):
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self):
+        c = self.config
+        E, d, f = c.held_experts, c.hidden_size, c.moe_intermediate_size
+        init = lambda std: nn.initializers.normal(std)
+        return (
+            self.param("gate", init(c.initializer_range), (E, d, f), c.param_dtype),
+            self.param("up", init(c.initializer_range), (E, d, f), c.param_dtype),
+            self.param("down", init(c.residual_init_std()), (E, f, d), c.param_dtype),
+        )
+
+
+class SparseMLP(nn.Module):
+    """Routed experts (``ops/moe.py``: route, sort by expert, grouped products
+    over the experts held, combine — dropless) beside the shared experts. The
+    load of each held expert is sown into the ``moe_stats`` collection, which
+    costs one small reduction a layer and nothing where no caller asks for it."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+        from trlx_tpu.ops import moe
+
+        c = self.config
+        B, T, d = x.shape
+        kernel, bias = _Router(c, name="router")()
+        gate, up, down = _Experts(c, name="experts")()
+        flat = x.reshape(B * T, d)
+        chosen, weights = moe.route(
+            flat, kernel, bias, top_k=c.experts_per_token, norm_topk=c.norm_topk_prob, scale=c.routed_scaling_factor)
+        routed, load = moe.expert_ffn(
+            flat, chosen, weights, gate, up, down, expert_offset=c.expert_offset, act=_act(c.activation))
+        self.sow("moe_stats", "load", load)
+        out = routed.reshape(B, T, d)
+        if c.num_shared_experts:
+            with jax.named_scope("moe.shared"):
+                shared = c.replace(intermediate_size=c.num_shared_experts * c.moe_intermediate_size)
+                out = out + MLP(shared, name="shared")(x)
+        return out
+
+
+def moe_counters(moe_stats) -> Dict[str, jnp.ndarray]:
+    """The ``moe_stats`` collection of one forward as counters, summed over the
+    expert layers: assignments that fell to held experts, the largest and the
+    mean load of a held expert (float32 scalars)."""
+    loads = [x.astype(jnp.float32) for x in jax.tree.leaves(moe_stats)]
+    return {
+        "moe/assignments_held": sum(x.sum() for x in loads),
+        "moe/load_max": sum(x.max() for x in loads),
+        "moe/load_mean": sum(x.mean() for x in loads),
+    }
+
+
 class Block(nn.Module):
     config: TransformerConfig
+    expert_layer: bool = False  # this layer's FFN is the sparse one (config.is_expert_layer)
 
     @nn.compact
     def __call__(self, x, mask_bias, positions, cache=None, kv_valid=None):
         c = self.config
+        attention = LatentAttention if c.attention_kind == "mla" else Attention
+        mlp = SparseMLP if self.expert_layer else MLP
         if c.parallel_residual:
             h1 = _norm_module(c, "ln_1")(x)
             h2 = h1 if c.shared_parallel_ln else _norm_module(c, "ln_2")(x)
-            attn_out, new_cache = Attention(c, name="attn")(h1, mask_bias, positions, cache, kv_valid)
-            mlp_out = MLP(c, name="mlp")(h2)
+            attn_out, new_cache = attention(c, name="attn")(h1, mask_bias, positions, cache, kv_valid)
+            mlp_out = mlp(c, name="mlp")(h2)
             out = x + attn_out + mlp_out
             if c.sequence_sharding and cache is None:
                 out = constrain_seq(out)
             return out, new_cache
-        attn_out, new_cache = Attention(c, name="attn")(
+        attn_out, new_cache = attention(c, name="attn")(
             _norm_module(c, "ln_1")(x), mask_bias, positions, cache, kv_valid
         )
         x = x + attn_out
-        x = x + MLP(c, name="mlp")(_norm_module(c, "ln_2")(x))
+        x = x + mlp(c, name="mlp")(_norm_module(c, "ln_2")(x))
         # per-layer Megatron-SP residual constraint lives HERE (not in the caller's
         # layer loop) so every path — listed loop, nn.scan stack, value branch,
         # forward_from — gets it identically
@@ -750,6 +983,16 @@ class TransformerLM(nn.Module):
         block = Block
         if c.remat != "none":
             block = nn.remat(Block, policy=remat_policy(c.remat))
+        if c.stacked and c.num_experts > 0:
+            raise ValueError(
+                "scan_layers / pipeline_stages > 1 run one scanned Block over stacked parameters, "
+                "and the layers of this model are not alike: the first "
+                f"{c.first_dense_layers} have a dense FFN, the rest routed experts"
+            )
+        if c.attention_kind == "mla":
+            for refused, why in (("stacked", c.stacked), ("kv_cache_quant", c.kv_cache_quant)):
+                if why:
+                    raise ValueError(_MLA_REFUSALS[refused])
         if c.stacked:
             if c.pipeline_stages > 1:
                 if c.num_layers % c.pipeline_stages != 0:
@@ -781,7 +1024,7 @@ class TransformerLM(nn.Module):
             )(c, name="layers_scan")
             self.layers = ()
         else:
-            self.layers = [block(c) for _ in range(c.num_layers)]
+            self.layers = [block(c, expert_layer=c.is_expert_layer(i)) for i in range(c.num_layers)]
         if c.final_norm:
             self.ln_f = _norm_module(c)
         if not c.tie_word_embeddings:
@@ -831,9 +1074,12 @@ class TransformerLM(nn.Module):
         # Virtual rows occupy slots/positions 0..nv-1; real positions shift +nv.
         nv_rows = 0  # virtual rows present in this forward's activations
         if cache is not None:
-            ck = cache["k"]
-            # list layout: per-layer [B,H,S,D]; stacked layout: [L,B,H,S,D]
-            S = ck[0].shape[2] if isinstance(ck, (list, tuple)) else ck.shape[3]
+            if c.attention_kind == "mla":
+                S = cache["c"][0].shape[1]  # per-layer [B,S,rank]
+            else:
+                ck = cache["k"]
+                # list layout: per-layer [B,H,S,D]; stacked layout: [L,B,H,S,D]
+                S = ck[0].shape[2] if isinstance(ck, (list, tuple)) else ck.shape[3]
             idx = cache["index"]
             # a concrete-zero index marks prefill-from-zero (any T, including 1);
             # a traced index is a decode step inside the generation while_loop
@@ -1054,8 +1300,15 @@ class TransformerLM(nn.Module):
         dtype = dtype or c.compute_dtype
         if c.peft_type == "prompt":
             max_length += c.num_virtual_tokens  # virtual rows live in the cache too
-        shape = (batch_size, c.kv_heads, max_length, c.dim_per_head)
-        per_layer = kv_cache_layout(shape, dtype, c.kv_cache_quant)
+        if c.attention_kind == "mla":
+            from trlx_tpu.utils.metrics import gauges
+
+            per_layer = latent_cache_layout(batch_size, max_length, c, dtype)
+            gauges.set("mla/cache_bytes_per_token", c.num_layers * sum(
+                shp[-1] * jnp.dtype(dt).itemsize for shp, dt in per_layer.values()))
+        else:
+            shape = (batch_size, c.kv_heads, max_length, c.dim_per_head)
+            per_layer = kv_cache_layout(shape, dtype, c.kv_cache_quant)
         if c.stacked:
             # nn.scan layout needs one [L, ...] array per k/v
             out = {
@@ -1091,6 +1344,8 @@ class TransformerLM(nn.Module):
         from trlx_tpu.ops.paged_attention import paged_pool_layout
 
         c = self.config
+        if c.attention_kind == "mla":
+            raise ValueError(_MLA_REFUSALS["paged"])
         layout = paged_pool_layout(
             num_blocks, block_size, c.kv_heads, c.dim_per_head,
             dtype or c.compute_dtype, c.kv_cache_quant,

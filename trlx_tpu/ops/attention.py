@@ -113,43 +113,48 @@ class FlashTiles(NamedTuple):
         return self.block_q == self.Tp and self.block_k == self.Sp
 
 
-def _reckon_vmem(block_q, block_k, sub, heads, D, rep, itemsize, whole) -> int:
+def _reckon_vmem(block_q, block_k, sub, heads, D, rep, itemsize, whole, Dv=None) -> int:
     """VMEM bytes of the pass that holds most. Each pass: its operands twice
     (Pallas double-buffers them), score-shaped float32 temporaries of ``sub``
     rows (scores, probabilities and the mask bias in the forward; dP and dS
     besides in the two backward passes), the mask bias kept for every row tile
     where extents are static, and what it keeps in scratch. The forward and dq
-    passes take ``heads`` query heads, the dkv pass their kv heads' whole groups."""
-    d_lanes = _round_up(D, LANE)
+    passes take ``heads`` query heads, the dkv pass their kv heads' whole groups.
+    ``Dv`` is the width of v, o and dO where it is not q's and k's ``D``."""
+    d_lanes, dv_lanes = _round_up(D, LANE), _round_up(Dv or D, LANE)
     kv_heads = max(1, heads // rep)
     group = kv_heads * rep
+    # q, k, dq, dk are D wide; v, o, dO, dv are Dv wide
     q_tile, k_tile = block_q * d_lanes * itemsize, block_k * d_lanes * itemsize
+    o_tile, v_tile = block_q * dv_lanes * itemsize, block_k * dv_lanes * itemsize
     row = 8 * block_q * 4  # a [1, block_q] float32 row takes a sublane tile
     mask = 8 * block_k * 4
     bias = block_q * block_k * 4 if whole else 0
     carried = 0 if whole else 4  # bytes of float32 scratch per carried element
     forward = (
-        2 * (2 * heads * q_tile + 2 * kv_heads * k_tile + heads * row + mask)
+        2 * (heads * (q_tile + o_tile) + kv_heads * (k_tile + v_tile) + heads * row + mask)
         + 3 * sub * block_k * 4 + bias
-        + block_q * LANE * 4 + carried * heads * block_q * (d_lanes + 2 * LANE)
+        + block_q * LANE * 4 + carried * heads * block_q * (dv_lanes + 2 * LANE)
     )
     dq = (
-        2 * (3 * heads * q_tile + 2 * kv_heads * k_tile + 2 * heads * row + mask)
+        2 * (heads * (2 * q_tile + o_tile) + kv_heads * (k_tile + v_tile) + 2 * heads * row + mask)
         + 5 * sub * block_k * 4 + bias
         + 2 * block_q * LANE * 4 + carried * heads * block_q * d_lanes
     )
     dkv = (
-        2 * (2 * group * q_tile + 4 * kv_heads * k_tile + 2 * group * row + mask)
+        2 * (group * (q_tile + o_tile) + 2 * kv_heads * (k_tile + v_tile) + 2 * group * row + mask)
         + 5 * sub * block_q * 4 + bias
-        + block_k * LANE * 4 + carried * 2 * kv_heads * block_k * d_lanes
+        + block_k * LANE * 4 + carried * kv_heads * block_k * (d_lanes + dv_lanes)
     )
     return max(forward, dq, dkv)
 
 
-def choose_tiles(T: int, S: int, D: int, rep: int, dtype, vmem_budget: int = 12 * 2**20) -> FlashTiles:
-    """Tiles for q ``[.., T, D]`` against k, v ``[.., S, D]`` with ``rep`` query
-    heads to a kv head. A pure function of the shape; the one place tiles are
-    chosen.
+def choose_tiles(
+    T: int, S: int, D: int, rep: int, dtype, vmem_budget: int = 12 * 2**20, Dv: Optional[int] = None
+) -> FlashTiles:
+    """Tiles for q ``[.., T, D]`` against k ``[.., S, D]`` and v ``[.., S, Dv]``
+    (``Dv`` = ``D`` where it is not given) with ``rep`` query heads to a kv
+    head. A pure function of the shape; the one place tiles are chosen.
 
     Lengths are padded to multiples of 128 (the lane side of a score tile: keys
     in the forward and dq passes, queries in the transposed dkv pass); rows are
@@ -171,14 +176,16 @@ def choose_tiles(T: int, S: int, D: int, rep: int, dtype, vmem_budget: int = 12 
         whole = block_q == Tp and block_k == Sp
         # the smallest row tile that leaves the body at most eight to unroll
         sub = next((n for n in (128, 256, 512) if max(block_q, block_k) <= 8 * n), None)
-        fits = sub is not None and _reckon_vmem(block_q, block_k, sub, 1, D, rep, itemsize, whole) <= vmem_budget
+        fits = sub is not None and _reckon_vmem(block_q, block_k, sub, 1, D, rep, itemsize, whole, Dv) <= vmem_budget
         if not fits:  # graftcheck: noqa[JX004] — static shape/int, not traced
             continue
         cost = Tp * Sp + (Tp // block_q) * (Sp // block_k) * _PROGRAM_COST
         if best is None or cost < best[0]:  # graftcheck: noqa[JX004] — static shape/int, not traced
             best = (cost, block_q, block_k, sub, Tp, Sp, whole)
     if best is None:
-        raise ValueError(f"no flash-attention tiling of T={T} S={S} D={D} rep={rep} fits {vmem_budget} bytes of VMEM")
+        raise ValueError(
+            f"no flash-attention tiling of T={T} S={S} D={D} Dv={Dv or D} rep={rep} fits {vmem_budget} bytes of VMEM"
+        )
     _, block_q, block_k, sub, Tp, Sp, whole = best
 
     def takes(heads):  # several heads to a program: only whole sequences, within the area and the budget
@@ -186,13 +193,13 @@ def choose_tiles(T: int, S: int, D: int, rep: int, dtype, vmem_budget: int = 12 
             whole
             and heads <= 8
             and heads * Tp * Sp <= _PROGRAM_AREA
-            and _reckon_vmem(block_q, block_k, sub, heads, D, rep, itemsize, whole) <= vmem_budget
+            and _reckon_vmem(block_q, block_k, sub, heads, D, rep, itemsize, whole, Dv) <= vmem_budget
         )
 
     heads = max(h for h in (1, 2, 4, 8) if h == 1 or takes(h))
     return FlashTiles(
         block_q, block_k, sub, Tp, Sp, min(Tr, Tp), min(Sr, Sp), heads,
-        _reckon_vmem(block_q, block_k, sub, heads, D, rep, itemsize, whole),
+        _reckon_vmem(block_q, block_k, sub, heads, D, rep, itemsize, whole, Dv),
     )
 
 
@@ -207,12 +214,14 @@ def _heads_per_program(H: int, rep: int, most: int) -> int:
 
 
 @functools.lru_cache(maxsize=None)
-def _log_tiles(B, H, Hkv, T, S, D, dtype, tiles, heads):
+def _log_tiles(B, H, Hkv, T, S, D, Dv, dtype, tiles, heads):
     """The chooser's choice, once per traced shape."""
     kv_heads = max(1, heads // (H // Hkv))
     steps = (tiles.Tp // tiles.block_q, tiles.Sp // tiles.block_k)
+    values = "" if Dv == D else f" v[{B},{Hkv},{S},{Dv}]"
     logger.info(  # graftcheck: noqa[JX003] — once per traced shape is the point
-        f"flash attention q[{B},{H},{T},{D}] kv[{B},{Hkv},{S},{D}] {dtype}: tiles {tiles.block_q}x{tiles.block_k}"
+        f"flash attention q[{B},{H},{T},{D}] kv[{B},{Hkv},{S},{D}]{values} {dtype}:"
+        f" tiles {tiles.block_q}x{tiles.block_k}"
         f" in rows of {tiles.sub}, padded {tiles.Tp}x{tiles.Sp}, {heads} head(s) a program, grid"
         f" {(B, H // heads) + steps} (dkv {(B, Hkv // kv_heads) + steps[::-1]}), VMEM reckoned"
         f" {tiles.vmem_bytes / 2**20:.1f} MiB"
@@ -311,10 +320,10 @@ def _flash_kernel(
     kv_valid_ref,  # [1, 1, block_k] int32
     q_ref,  # [1, heads, block_q, D]
     k_ref,  # [1, kv heads, block_k, D]
-    v_ref,
-    o_ref,  # [1, heads, block_q, D]
+    v_ref,  # [1, kv heads, block_k, Dv]
+    o_ref,  # [1, heads, block_q, Dv]
     *rest,  # lse_ref [1, heads, 1, block_q] f32 and its [block_q, LANE] scratch (with_lse);
-    # m, l [heads, block_q, 1] and acc [heads, block_q, D] f32 scratch (kv walked)
+    # m, l [heads, block_q, 1] and acc [heads, block_q, Dv] f32 scratch (kv walked)
     causal: bool,
     scale: float,
     tiles: FlashTiles,
@@ -366,7 +375,7 @@ def _flash_kernel(
                 # visit's alpha wipes that, and finish() zeroes what is left
                 p = jnp.exp(s - m)
                 l = jnp.sum(p, axis=1, keepdims=True)
-                acc = _dot(p.astype(v.dtype), v[:keys])  # [n, D]
+                acc = _dot(p.astype(v.dtype), v[:keys])  # [n, Dv]
                 if carried:
                     alpha = jnp.exp(m_prev - m)
                     l_scratch[h, r0:r0 + n] = alpha * l_scratch[h, r0:r0 + n] + l
@@ -423,7 +432,7 @@ def _key_mask(kv_valid, Sp: int):
 def _flash_forward(
     q: jnp.ndarray,  # [B, H, T, D]
     k: jnp.ndarray,  # [B, Hkv, S, D]
-    v: jnp.ndarray,
+    v: jnp.ndarray,  # [B, Hkv, S, Dv]
     kv_valid: jnp.ndarray,  # [B, S] int32
     causal: bool,
     scale: float,
@@ -436,13 +445,13 @@ def _flash_forward(
     rows are sliced off. With ``with_lse`` also returns the per-row logsumexp
     as the backward takes it: ``[B, H, 1, Tp]`` float32, T on the lanes."""
     B, H, T, D = q.shape
-    Hkv, S = k.shape[1], k.shape[2]
+    Hkv, S, Dv = k.shape[1], k.shape[2], v.shape[3]
     assert H % Hkv == 0, (H, Hkv)
     rep = H // Hkv
     if tiles is None:
-        tiles = choose_tiles(T, S, D, rep, q.dtype)
+        tiles = choose_tiles(T, S, D, rep, q.dtype, Dv=Dv)
     heads = _heads_per_program(H, rep, tiles.heads)
-    _log_tiles(B, H, Hkv, T, S, D, jnp.dtype(q.dtype).name, tiles, heads)
+    _log_tiles(B, H, Hkv, T, S, D, Dv, jnp.dtype(q.dtype).name, tiles, heads)
     kv_heads = max(1, heads // rep)
     block_q, block_k = tiles.block_q, tiles.block_k
     kvh = _kv_block_map(heads, rep)
@@ -451,10 +460,12 @@ def _flash_forward(
     k, v = _pad_to(k, axis=2, n=tiles.Sp), _pad_to(v, axis=2, n=tiles.Sp)
     kv_valid = _key_mask(kv_valid, tiles.Sp)
 
-    q_spec = pl.BlockSpec((1, heads, block_q, D), lambda b, h, i, j: (b, h, i, 0))
-    kv_spec = pl.BlockSpec((1, kv_heads, block_k, D), lambda b, h, i, j: (b, kvh(h), j, 0))
-    out_shape = [jax.ShapeDtypeStruct((B, H, tiles.Tp, D), q.dtype)]
-    out_specs = [q_spec]
+    q_spec, o_spec = (pl.BlockSpec((1, heads, block_q, w), lambda b, h, i, j: (b, h, i, 0)) for w in (D, Dv))
+    k_spec, v_spec = (
+        pl.BlockSpec((1, kv_heads, block_k, w), lambda b, h, i, j: (b, kvh(h), j, 0)) for w in (D, Dv)
+    )
+    out_shape = [jax.ShapeDtypeStruct((B, H, tiles.Tp, Dv), q.dtype)]
+    out_specs = [o_spec]
     scratch = []
     if with_lse:
         out_shape.append(jax.ShapeDtypeStruct((B, H, 1, tiles.Tp), jnp.float32))
@@ -463,7 +474,7 @@ def _flash_forward(
         scratch += [
             pltpu.VMEM((heads, block_q, 1), jnp.float32),
             pltpu.VMEM((heads, block_q, 1), jnp.float32),
-            pltpu.VMEM((heads, block_q, D), jnp.float32),
+            pltpu.VMEM((heads, block_q, Dv), jnp.float32),
         ]
     if with_lse:
         scratch.append(pltpu.VMEM((block_q, LANE), jnp.float32))
@@ -475,7 +486,7 @@ def _flash_forward(
         grid=(B, H // heads, tiles.Tp // block_q, tiles.Sp // block_k),
         in_specs=[
             pl.BlockSpec((1, 1, block_k), lambda b, h, i, j: (b, 0, j)),
-            q_spec, kv_spec, kv_spec,
+            q_spec, k_spec, v_spec,
         ],
         out_specs=out_specs,
         out_shape=out_shape,
@@ -494,13 +505,13 @@ def _flash_bwd_dkv_kernel(
     kv_valid_ref,  # [1, 1, block_k]
     q_ref,  # [1, kv heads * rep, block_q, D]: each kv head's whole query-head group
     k_ref,  # [1, kv heads, block_k, D]
-    v_ref,
-    do_ref,  # as q_ref
+    v_ref,  # [1, kv heads, block_k, Dv]
+    do_ref,  # as q_ref, Dv wide
     lse_ref,  # [1, kv heads * rep, 1, block_q] f32
     delta_ref,
-    dk_ref,  # [1, kv heads, block_k, D] out
-    dv_ref,
-    *scratch,  # dk, dv [kv heads, block_k, D] f32 where the q side is walked
+    dk_ref,  # as k_ref, out
+    dv_ref,  # as v_ref, out
+    *scratch,  # dk, dv as their blocks, f32, where the q side is walked
     causal: bool,
     scale: float,
     tiles: FlashTiles,
@@ -561,7 +572,10 @@ def _flash_bwd_dkv_kernel(
                     out.append((dk, dv))
                 return out
 
-            sums = [(jnp.zeros((n, k.shape[1]), jnp.float32),) * 2 for _, n, _ in row_tiles]
+            sums = [
+                (jnp.zeros((n, k.shape[1]), jnp.float32), jnp.zeros((n, v.shape[1]), jnp.float32))
+                for _, n, _ in row_tiles
+            ]
             sums = group(0, sums) if rep == 1 else jax.lax.fori_loop(0, rep, group, sums)
             for (c0, n, _), (dk, dv) in zip(row_tiles, sums):
                 if carried:
@@ -587,8 +601,8 @@ def _flash_bwd_dq_kernel(
     kv_valid_ref,  # [1, 1, block_k]
     q_ref,  # [1, heads, block_q, D]
     k_ref,  # [1, kv heads, block_k, D]
-    v_ref,
-    do_ref,
+    v_ref,  # [1, kv heads, block_k, Dv]
+    do_ref,  # [1, heads, block_q, Dv]
     lse_ref,  # [1, heads, 1, block_q] f32
     delta_ref,
     dq_ref,  # [1, heads, block_q, D] out
@@ -653,10 +667,10 @@ def _flash_backward(q, k, v, kv_valid, out, lse, g, causal, scale, interpret, ti
     output-block write conflicts; ``dq`` runs the forward's grid. Where the whole
     sequence is one tile neither carries anything across grid steps."""
     B, H, T, D = q.shape
-    Hkv, S = k.shape[1], k.shape[2]
+    Hkv, S, Dv = k.shape[1], k.shape[2], v.shape[3]
     rep = H // Hkv
     if tiles is None:
-        tiles = choose_tiles(T, S, D, rep, q.dtype)
+        tiles = choose_tiles(T, S, D, rep, q.dtype, Dv=Dv)
     heads = _heads_per_program(H, rep, tiles.heads)
     kv_heads = max(1, heads // rep)
     block_q, block_k = tiles.block_q, tiles.block_k
@@ -670,32 +684,36 @@ def _flash_backward(q, k, v, kv_valid, out, lse, g, causal, scale, interpret, ti
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)[:, :, None, :]  # [B, H, 1, Tp]
 
     # a block of kv_heads * rep query heads at block index hk is kv-head block hk's groups
-    group_spec = pl.BlockSpec((1, kv_heads * rep, block_q, D), lambda b, hk, kj, qi: (b, hk, qi, 0))
+    group_spec, group_do_spec = (
+        pl.BlockSpec((1, kv_heads * rep, block_q, w), lambda b, hk, kj, qi: (b, hk, qi, 0)) for w in (D, Dv)
+    )
     group_row_spec = pl.BlockSpec((1, kv_heads * rep, 1, block_q), lambda b, hk, kj, qi: (b, hk, 0, qi))
-    kv_spec = pl.BlockSpec((1, kv_heads, block_k, D), lambda b, hk, kj, qi: (b, hk, kj, 0))
+    k_spec, v_spec = (pl.BlockSpec((1, kv_heads, block_k, w), lambda b, hk, kj, qi: (b, hk, kj, 0)) for w in (D, Dv))
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, causal=causal, scale=scale, tiles=tiles, rep=rep),
         grid=(B, Hkv // kv_heads, kv_steps, q_steps),
         in_specs=[
             pl.BlockSpec((1, 1, block_k), lambda b, hk, kj, qi: (b, 0, kj)),
-            group_spec, kv_spec, kv_spec, group_spec, group_row_spec, group_row_spec,
+            group_spec, k_spec, v_spec, group_do_spec, group_row_spec, group_row_spec,
         ],
-        out_specs=[kv_spec, kv_spec],
+        out_specs=[k_spec, v_spec],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype), jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        scratch_shapes=[pltpu.VMEM((kv_heads, block_k, D), jnp.float32)] * 2 if q_steps > 1 else [],
+        scratch_shapes=[pltpu.VMEM((kv_heads, block_k, w), jnp.float32) for w in (D, Dv)] if q_steps > 1 else [],
         interpret=interpret,
         compiler_params=_grid_semantics(),
     )(kv_valid, q, k, v, g, lse, delta)
 
-    q_spec = pl.BlockSpec((1, heads, block_q, D), lambda b, h, qi, kj: (b, h, qi, 0))
+    q_spec, do_spec = (pl.BlockSpec((1, heads, block_q, w), lambda b, h, qi, kj: (b, h, qi, 0)) for w in (D, Dv))
     row_spec = pl.BlockSpec((1, heads, 1, block_q), lambda b, h, qi, kj: (b, h, 0, qi))
-    dq_kv_spec = pl.BlockSpec((1, kv_heads, block_k, D), lambda b, h, qi, kj: (b, kvh(h), kj, 0))
+    dq_k_spec, dq_v_spec = (
+        pl.BlockSpec((1, kv_heads, block_k, w), lambda b, h, qi, kj: (b, kvh(h), kj, 0)) for w in (D, Dv)
+    )
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, causal=causal, scale=scale, tiles=tiles, rep=rep),
         grid=(B, H // heads, q_steps, kv_steps),
         in_specs=[
             pl.BlockSpec((1, 1, block_k), lambda b, h, qi, kj: (b, 0, kj)),
-            q_spec, dq_kv_spec, dq_kv_spec, q_spec, row_spec, row_spec,
+            q_spec, dq_k_spec, dq_v_spec, do_spec, row_spec, row_spec,
         ],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -731,7 +749,8 @@ def xla_attention(q, k, v, kv_valid, causal: bool, scale: float) -> jnp.ndarray:
 def flash_attention(
     q, k, v, kv_valid, causal: bool = True, scale: Optional[float] = None, interpret: bool = False,
 ):
-    """Flash attention, [B,H,T,D] layout; K/V may carry fewer (grouped) heads.
+    """Flash attention, [B,H,T,D] layout; K/V may carry fewer (grouped) heads, and
+    V (with it the output) a width of its own, ``[B,Hkv,S,Dv]``.
     Tiles come from the shape (:func:`choose_tiles`). Differentiable: backward
     runs Pallas dq/dkv kernels recomputing attention per tile from the saved
     logsumexp (O(T·tile) memory, matching the memory model of the reference's

@@ -58,6 +58,16 @@ def default_lm_rules() -> List[Rule]:
         (r".*(q_proj|k_proj|v_proj)/kernel$", PartitionSpec(FSDP_AXIS, MODEL_AXIS)),
         (r".*(q_proj|k_proj|v_proj)/bias$", PartitionSpec(MODEL_AXIS)),
         (r".*o_proj/kernel$", PartitionSpec(MODEL_AXIS, FSDP_AXIS)),
+        # latent attention: the down-projection to latent + rotary key is narrow (fsdp
+        # alone), the per-head expansion out of the latent is column-parallel
+        (r".*kv_a_proj/kernel$", PartitionSpec(FSDP_AXIS, None)),
+        (r".*kv_b_proj/kernel$", PartitionSpec(FSDP_AXIS, MODEL_AXIS)),
+        # routed experts [experts held, in, out]: experts stay whole on their leading
+        # axis (the mesh has no expert axis yet), fsdp / model over the weight dims
+        # as in the dense mlp; the router [hidden, experts] over fsdp
+        (r".*experts/(gate|up)$", PartitionSpec(None, FSDP_AXIS, MODEL_AXIS)),
+        (r".*experts/down$", PartitionSpec(None, MODEL_AXIS, FSDP_AXIS)),
+        (r".*router/kernel$", PartitionSpec(FSDP_AXIS, None)),
         # mlp: up/gate column-parallel; down row-parallel
         (r".*(up_proj|gate_proj)/kernel$", PartitionSpec(FSDP_AXIS, MODEL_AXIS)),
         (r".*(up_proj|gate_proj)/bias$", PartitionSpec(MODEL_AXIS)),

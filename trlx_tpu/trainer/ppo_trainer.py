@@ -28,7 +28,7 @@ from trlx_tpu.models.policy import (
     CausalLMWithValueHead,
     branch_param_subtree,
 )
-from trlx_tpu.models.transformer import TransformerLM
+from trlx_tpu.models.transformer import TransformerLM, moe_counters
 from trlx_tpu.obs import compile_log, span
 from trlx_tpu.obs.flight import flight
 from trlx_tpu.parallel import mesh as mesh_lib
@@ -1615,10 +1615,17 @@ class PPOTrainer(MeshRLTrainer):
             )
             return self._train_steps[key]
 
+        counts_experts = self.model_config.num_experts > 0
+
         def loss_fn(params, mb: PPORLBatch):
             seq = jnp.concatenate([mb.query_tensors, mb.response_tensors], axis=1)
             mask = jnp.concatenate([mb.attention_mask, mb.response_mask], axis=1)
-            logits, values_pred, _, _ = module.apply({"params": params}, seq, mask)
+            if counts_experts:  # the expert layers' loads come out beside the forward's answers
+                (logits, values_pred, _, _), sown = module.apply(
+                    {"params": params}, seq, mask, mutable=["moe_stats"]
+                )
+            else:
+                logits, values_pred, _, _ = module.apply({"params": params}, seq, mask)
             logprobs = next_token_logprobs(logits, seq)
             start = mb.query_tensors.shape[1] - 1
             Rr = mb.response_tensors.shape[1]
@@ -1631,7 +1638,14 @@ class PPOTrainer(MeshRLTrainer):
                 logprobs, values_pred, mb.logprobs, mb.values, advantages, returns,
                 mb.response_mask, **loss_extra(mb),
             )
-            return loss, flatten_dict(stats)
+            stats = flatten_dict(stats)
+            if counts_experts:
+                stats.update(moe_counters(sown["moe_stats"]))
+                stats["moe/assignments"] = jnp.float32(
+                    seq.size * self.model_config.experts_per_token
+                    * sum(map(self.model_config.is_expert_layer, range(self.model_config.num_layers)))
+                )
+            return loss, stats
 
         self._train_steps[key] = self.make_grad_accum_step(
             loss_fn, self.num_mb, name=self.train_step_name
@@ -1665,6 +1679,9 @@ class PPOTrainer(MeshRLTrainer):
             self.params, self.opt_state, stats = step(self.params, self.opt_state, dbatch)
         with span("learn.sync"):  # the host waiting for the device
             out = {k: float(v) for k, v in jax.device_get(stats).items()}
+        for name, value in out.items():
+            if name.startswith("moe/"):  # a microbatch's counters, summed over the expert layers
+                gauges.set(name, value)
         if self._island is not None:
             # device_get above synced the step; the interval is real compute
             self._island.note_learn(t_learn0, time.monotonic())
