@@ -1,4 +1,6 @@
-"""Flash-attention kernel tests (interpret mode on CPU) against the XLA reference."""
+"""Flash-attention and decode kernel tests (interpret mode on CPU) against the XLA reference."""
+
+import functools
 
 import numpy as np
 import pytest
@@ -438,3 +440,135 @@ def test_chooser_choice_is_logged_once_per_shape(caplog):
     lines = [r.getMessage() for r in caplog.records if "flash attention q[1,3,40,8]" in r.getMessage()]
     assert len(lines) == 1, lines
     assert "tiles 128x128" in lines[0] and "3 head(s) a program" in lines[0] and "grid (1, 1, 1, 1)" in lines[0]
+
+
+# ------------------------------------------------------------ the decode kernel
+
+
+def einsum_decode(q, k, v, mask_bias, scale):
+    """The model's einsum path for a single-token step (grouped form): float32
+    softmax over the whole cache behind an additive mask, the probabilities cast
+    to the operands' dtype before they meet v."""
+    B, H, D = q.shape
+    Hkv = k.shape[1]
+    qg = q.reshape(B, 1, Hkv, H // Hkv, D)
+    scores = jnp.einsum("btkrd,bksd->bkrts", qg, k).astype(jnp.float32) * scale
+    probs = jax.nn.softmax(scores + mask_bias[:, :, None], axis=-1).astype(q.dtype)
+    return jnp.einsum("bkrts,bksd->btkrd", probs, v).reshape(B, H, D)
+
+
+# name: (B, H, Hkv, S, D, dtype, block or None for the chooser's)
+DECODE_CASES = {
+    "mha-float32": (3, 2, 2, 40, 16, jnp.float32, None),
+    "grouped-float32": (2, 8, 2, 48, 16, jnp.float32, 16),
+    "multi-query-bfloat16": (4, 4, 1, 64, 16, jnp.bfloat16, 8),
+    "mha-bfloat16-576": (2, 2, 2, 576, 16, jnp.bfloat16, None),
+    "mha-float32-block-does-not-divide": (2, 2, 2, 41, 8, jnp.float32, 8),
+}
+
+
+def _decode_tiles(case):
+    B, H, Hkv, S, D, dtype, block = DECODE_CASES[case]
+    tiles = attn.choose_decode_tiles(B, Hkv, H // Hkv, S, D, dtype)
+    return tiles if block is None else tiles._replace(block=block)
+
+
+@functools.lru_cache(maxsize=None)
+def _decode_fns(case):
+    """(kernel, einsum) for a case, jitted over a traced index as the decode loop has it."""
+    B, H, Hkv, S, D, dtype, _ = DECODE_CASES[case]
+    tiles = _decode_tiles(case)
+    kernel = jax.jit(lambda q, k, v, bias, index: attn.decode_attention(q, k, v, bias, index, None, True, tiles))
+    return kernel, jax.jit(lambda q, k, v, bias: einsum_decode(q, k, v, bias, 1.0 / np.sqrt(D)))
+
+
+def _decode_index(case, where):
+    S, block = DECODE_CASES[case][3], _decode_tiles(case).block
+    return {"first": 0, "inside": block + 2, "block-end": block - 1, "block-start": block, "last": S - 1}[where]
+
+
+@pytest.mark.parametrize("where", ["first", "inside", "block-end", "block-start", "last"])
+@pytest.mark.parametrize("case", sorted(DECODE_CASES))
+def test_decode_kernel_matches_einsum_and_never_reads_past_the_index(case, where):
+    """Left-padded rows of different lengths; the slots past the index hold NaN
+    for the kernel and zeros behind the mask for the einsum."""
+    B, H, Hkv, S, D, dtype, _ = DECODE_CASES[case]
+    index = _decode_index(case, where)
+    rng = np.random.default_rng(index)
+    q = jnp.asarray(rng.normal(size=(B, H, D)), dtype)
+    k, v = (rng.normal(size=(B, Hkv, S, D)).astype(np.float32) for _ in range(2))
+    pads = [min(index, n) for n in [0, 3, 11, 20][:B]]  # row b's first slots are padding; the index is never
+    valid = np.arange(S)[None, :] >= np.asarray(pads)[:, None]
+    seen = valid & (np.arange(S)[None, :] <= index)
+    bias = jnp.where(jnp.asarray(seen), 0.0, -1e9).astype(jnp.float32)[:, None, None, :]
+    written = (np.arange(S) <= index)[None, None, :, None]
+    kernel, einsum = _decode_fns(case)
+    want = einsum(q, jnp.asarray(np.where(written, k, 0.0), dtype), jnp.asarray(np.where(written, v, 0.0), dtype), bias)
+    got = kernel(
+        q, jnp.asarray(np.where(written, k, np.nan), dtype), jnp.asarray(np.where(written, v, np.nan), dtype),
+        bias, jnp.int32(index),
+    )
+    assert got.shape == (B, H, D) and got.dtype == dtype
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert np.all(np.isfinite(got))
+    tol = dict(atol=2e-5, rtol=1e-5) if dtype == jnp.float32 else dict(atol=2e-2, rtol=2e-2)
+    np.testing.assert_allclose(got, want, **tol)
+
+
+# (B, Hkv, rep, S, D): the cells' decode steps, a grouped model at D = 128, a small model
+DECODE_CHOOSER_SHAPES = [(128, 12, 1, 512, 64), (64, 16, 1, 576, 64), (32, 8, 4, 1024, 128), (128, 2, 1, 1024, 64)]
+
+
+@pytest.mark.parametrize("B,Hkv,rep,S,D", DECODE_CHOOSER_SHAPES)
+def test_decode_chooser_programs_move_a_megabyte_and_fit_the_chip(B, Hkv, rep, S, D):
+    tiles = attn.choose_decode_tiles(B, Hkv, rep, S, D, jnp.bfloat16)
+    assert tiles.rows == (128 if B % 128 == 0 else B) and tiles.block in (8, 16, 32)
+    k_block = tiles.block * Hkv * D * max(tiles.rows, 128) * 2
+    assert k_block >= 2**20 or tiles.block == 32
+    assert tiles.vmem_bytes <= 16 * 2**20  # the call asks for twice what is reckoned, 32 MiB at least
+    if tiles.block > 8:  # no smaller block would have reached a mebibyte
+        assert (tiles.block // 2) * Hkv * D * max(tiles.rows, 128) * 2 < 2**20
+
+
+def test_decode_chooser_keeps_to_its_budget():
+    assert attn.choose_decode_tiles(128, 2, 1, 1024, 64, jnp.bfloat16).block == 32
+    assert attn.choose_decode_tiles(128, 2, 1, 1024, 64, jnp.bfloat16, vmem_budget=3 * 2**20).block == 16
+    assert attn.choose_decode_tiles(128, 2, 1, 20, 64, jnp.bfloat16).block == 16  # no longer than the cache
+
+
+@pytest.mark.parametrize(
+    "prompt,steps,cache,block,want",
+    [
+        (64, 447, 512, None, 1.0),  # the einsum path reads every slot at every step
+        (64, 0, 512, 8, 1.0),  # no decode step ran
+        (64, 447, 512, 8, sum(-(-(64 + t) // 8) * 8 for t in range(1, 448)) / (447 * 512)),
+        (512, 63, 576, 8, sum(-(-(512 + t) // 8) * 8 for t in range(1, 64)) / (63 * 576)),
+        (6, 3, 10, 8, (8 + 8 + 10) / 30),  # the last block ends with the cache
+    ],
+)
+def test_cache_read_share_counts_visited_slots(prompt, steps, cache, block, want):
+    got = attn.cache_read_share(prompt, steps, cache, block)
+    assert got == pytest.approx(want)
+    assert 0.0 < got <= 1.0
+    if block and steps == 447:
+        assert 0.56 < got < 0.58  # cell 1: 56 % of the slots hold a token on average
+
+
+def test_decode_chooser_choice_is_logged_once_per_shape(caplog):
+    import logging
+
+    q = jnp.ones((2, 3, 8), jnp.float32)
+    k = v = jnp.ones((2, 3, 24, 8), jnp.float32)
+    bias = jnp.zeros((2, 1, 1, 24), jnp.float32)
+    attn._log_decode_tiles.cache_clear()
+    root = logging.getLogger("trlx_tpu")
+    root.addHandler(caplog.handler)
+    try:
+        with caplog.at_level(logging.INFO, logger="trlx_tpu"):
+            for index in (3, 4):
+                attn.decode_attention(q, k, v, bias, index, None, True)
+    finally:
+        root.removeHandler(caplog.handler)
+    lines = [r.getMessage() for r in caplog.records if "decode attention q[2,3,8]" in r.getMessage()]
+    assert len(lines) == 1, lines
+    assert "cache[2,3,24,8] float32: rows 2 block 16, grid (1, 2), VMEM reckoned" in lines[0]

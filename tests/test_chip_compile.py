@@ -101,6 +101,26 @@ def _grouped_products(grad, tokens):
     ]
 
 
+def _decode(shape, kv_heads=None, dtype=jnp.bfloat16):
+    """The decode kernel as the generator's loop calls it: inside a ``while_loop``,
+    over a cache ``[B, Hkv, S, D]`` with this step's token just written, the index traced."""
+    B, H, S, D = shape
+    Hkv = kv_heads or H
+
+    def fn(q, k, v, mask_bias, k_new, steps):
+        def body(carry):
+            index, q, k = carry
+            k = jax.lax.dynamic_update_slice(k, k_new, (0, 0, index, 0))
+            return index + 1, attention.decode_attention(q, k, v, mask_bias, index), k
+
+        return jax.lax.while_loop(lambda carry: carry[0] < steps, body, (jnp.int32(1), q, k))
+
+    return fn, [
+        ((B, H, D), dtype), ((B, Hkv, S, D), dtype), ((B, Hkv, S, D), dtype), ((B, 1, 1, S), jnp.float32),
+        ((B, Hkv, 1, D), dtype), ((), jnp.int32),
+    ]
+
+
 def _attention_instructions(text):
     """Names of the compiled program's Pallas instructions, as the device trace
     shows them and ``benchmark/metrics/flash_attn_roofline.json`` matches them."""
@@ -178,6 +198,10 @@ for _T in (513, 576, 640):
 for _tokens in (2052, 128):
     CASES[f"grouped_products_fwd-{_tokens}"] = functools.partial(_grouped_products, False, _tokens)
     CASES[f"grouped_products_grad-{_tokens}"] = functools.partial(_grouped_products, True, _tokens)
+# the decode kernel at the cells' decode steps ([B, H, S, D]; cell 2's cache is 576 slots), and a grouped model's
+CASES["decode-128x12x512x64"] = functools.partial(_decode, (128, 12, 512, 64))
+CASES["decode-64x16x576x64"] = functools.partial(_decode, (64, 16, 576, 64))
+CASES["decode-32x32x1024x128-hkv8"] = functools.partial(_decode, (32, 32, 1024, 128), 8)
 for _preset in ("gpt2", "gpt_bigcode"):  # Hkv=12 rep=1 D=64; Hkv=1 rep=16 D=128
     for _pool, _quant in (("bf16", False), ("int8", True)):
         CASES[f"paged_decode-{_pool}-{_preset}"] = functools.partial(
@@ -209,6 +233,20 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_persistent_cache, monkeypatc
         products = [n for n in names if re.match(r"^%t?gmm[.0-9]* custom-call$", n)]
         assert len(products) >= (6 if "grad" in case else 3), names
         assert not any(re.match(r"^%attn[.0-9]* custom-call$", n) for n in names), names
+    if case.startswith("decode"):
+        # a name of its own, which flash_attn_roofline's pattern does not take: 447 x 12 such events
+        # an iteration would otherwise be summed into the flash kernels' seconds
+        names = _attention_instructions(text)
+        assert names and all(re.match(r"^%decode_attn[.0-9]* custom-call$", name) for name in names), names
+        # the cache reaches the kernel slots outermost, batch on the lanes, without a copy: the
+        # transposes around the call are bitcasts of the layout the loop carries it in
+        B, Hkv, S, D = shapes[1][0]
+        cache = f"(?:{B},{Hkv},{S},{D}|{S},{Hkv},{D},{B})"
+        body = re.search(r"\bwhile\(.*?body=(%[\w.\-]+)", text).group(1)
+        body = text[text.index(f"\n{body} ("):]
+        body = body[:body.index("\n}\n")]
+        assert "tpu_custom_call" in body and re.search(rf"= bf16\[{cache}\]\S* bitcast\(", body)
+        assert not re.search(rf"= bf16\[{cache}\]\S* (?:copy|transpose)\(", body), "the cache is copied every step"
     if case.endswith("attn_names"):
         names = _attention_instructions(text)
         assert len(names) == 6, names  # forward, dkv and dq of two layers

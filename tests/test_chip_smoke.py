@@ -45,6 +45,10 @@ def test_ppo_phase_at_tiny_widths(tmp_path, capsys, serving):
         trainer = chip_smoke.phase_ppo(TINY, str(tmp_path), serving=serving)
     assert trainer.iter_count == TINY.steps
     assert (trainer._serving_client is not None) == serving
+    if not serving:  # set after every one-shot generate; at these sizes one block holds the whole cache
+        from trlx_tpu.utils.metrics import gauges
+
+        assert gauges.snapshot("rollout/").get("rollout/cache_read_share") == 1.0
     out = capsys.readouterr().out
     assert "0 after it (CompileWatcher)" in out
     assert f"step {TINY.steps}/{TINY.steps}:" in out

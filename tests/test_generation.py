@@ -303,3 +303,88 @@ def test_sample_token_candidate_space_impls():
     assert ta.dtype == jnp.int32 and ta.shape == (16,)
     np.testing.assert_array_equal(np.asarray(ta), np.asarray(tb))
     assert (np.asarray(ta) >= 0).all() and (np.asarray(ta) < 211).all()
+
+
+# name: (config overrides, mesh axes or None, cache dtype, whether an eos token ends rows inside the run)
+DECODE_KERNEL_CASES = {
+    "mha": (dict(), None, jnp.float32, False),
+    "mha-eos-inside": (dict(), None, jnp.float32, True),
+    "grouped-eos-inside": (dict(num_heads=4, num_kv_heads=2, hidden_size=32), None, jnp.float32, True),
+    "bfloat16-cache": (dict(compute_dtype=jnp.bfloat16), None, jnp.bfloat16, False),
+    "placed-over-a-mesh": (dict(num_heads=4, hidden_size=32), dict(data=2, fsdp=2, model=2), jnp.float32, True),
+    "mesh-the-heads-do-not-divide": (dict(), dict(data=2, model=4), jnp.float32, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DECODE_KERNEL_CASES))
+def test_generate_is_the_same_with_the_decode_kernel_and_the_einsum(case):
+    """`attention_impl="flash"` sends the single-token steps through the decode
+    kernel (and the prefill through the flash kernel), `"xla"` keeps the einsum:
+    the same tokens and the same `response_mask`, left-padded prompts of
+    different lengths, rows ending on an eos at different steps."""
+    import contextlib
+
+    from trlx_tpu.models import transformer
+    from trlx_tpu.parallel.mesh import make_mesh
+
+    overrides, axes, cache_dtype, with_eos = DECODE_KERNEL_CASES[case]
+    base = PRESETS["gpt2"].replace(**{**TINY, **overrides})
+    prompts = [np.array(p, np.int32) for p in ([5, 9, 11, 2, 30, 7, 7], [3], [8, 1, 4], [6, 6, 12, 19, 2])]
+    ids, mask = left_pad_batch(prompts, pad_token_id=0, target_len=8)
+    ids, mask = jnp.asarray(ids), jnp.asarray(mask)
+    params = TransformerLM(base).init(jax.random.PRNGKey(0), ids, mask)["params"]
+    n_new = 12
+    mesh = make_mesh(**axes) if axes else None
+
+    def run(impl, eos):
+        model = TransformerLM(base.replace(attention_impl=impl))
+        fn = jax.jit(lambda params, ids, mask: generate(
+            model_step_fn(model), params, lambda b, s: model.init_cache(b, s, cache_dtype), ids, mask,
+            jax.random.PRNGKey(0), max_new_tokens=n_new, do_sample=False, pad_token_id=0, eos_token_id=eos,
+        ))
+        with mesh or contextlib.nullcontext():
+            return jax.tree.map(np.asarray, fn(params, ids, mask))
+
+    eos = None
+    if with_eos:  # a token that some row emits inside the run, and not all rows at once
+        free = run("xla", None)["sequences"][:, 8:]
+        eos = int(free[0, 3])
+    want, got = run("xla", eos), run("flash", eos)
+    np.testing.assert_array_equal(got["sequences"], want["sequences"])
+    np.testing.assert_array_equal(got["response_mask"], want["response_mask"])
+    if with_eos:
+        lengths = want["response_mask"].sum(axis=1)
+        assert lengths.min() < n_new, lengths
+    with mesh or contextlib.nullcontext():
+        placed = transformer.decode_kernel_placement(base.replace(attention_impl="flash"), len(prompts))[0]
+    assert placed == (case != "mesh-the-heads-do-not-divide")
+
+
+CELL_1 = dict(B=128, prompt_len=64, new_tokens=448, steps=447)  # gpt2.ppo-long-response's rollout
+
+
+@pytest.mark.parametrize(
+    "overrides,axes,low,high",
+    [
+        (dict(attention_impl="flash"), None, 0.56, 0.58),  # 56 % of the slots hold a token, rounded up to blocks of 8
+        (dict(attention_impl="xla"), None, 1.0, 1.0),
+        (dict(attention_impl="flash", kv_cache_quant=True), None, 1.0, 1.0),
+        (dict(attention_impl="flash", pos_embedding="alibi"), None, 1.0, 1.0),
+        (dict(attention_impl="flash", peft_type="prefix", num_virtual_tokens=4), None, 1.0, 1.0),
+        (dict(attention_impl="flash", attention_kind="mla"), None, 1.0, 1.0),  # its own absorbed decode
+        (dict(attention_impl="flash", peft_type="prompt", num_virtual_tokens=8), None, 0.57, 0.60),  # in the cache too
+        (dict(attention_impl="flash"), dict(data=4, model=2), 0.56, 0.59),  # a shard's rows and heads
+        (dict(attention_impl="flash"), dict(data=1, model=8), 1.0, 1.0),  # 12 heads over 8: the einsum
+    ],
+)
+def test_cache_read_share_follows_who_takes_the_decode_kernel(overrides, axes, low, high):
+    import contextlib
+
+    from trlx_tpu.models.transformer import decode_cache_read_share
+    from trlx_tpu.parallel.mesh import make_mesh
+
+    config = PRESETS["gpt2"].replace(compute_dtype=jnp.bfloat16, **overrides)
+    with make_mesh(**axes) if axes else contextlib.nullcontext():
+        share = decode_cache_read_share(config, **CELL_1)
+    assert low <= share <= high
+    assert decode_cache_read_share(config, **{**CELL_1, "steps": 0}) == 1.0

@@ -432,6 +432,42 @@ def kv_cache_layout(shape: Tuple[int, ...], dtype, quant: bool) -> Dict[str, Tup
     return {"k": (shape, dtype), "v": (shape, dtype)}
 
 
+def _kernel_shards(mesh) -> Tuple[int, int]:
+    """(shards of the batch, shards of the heads) a kernel placed over ``mesh``
+    (None: a plain call) is cut into."""
+    if mesh is None:
+        return 1, 1
+    return int(np.prod([mesh.shape.get(a, 1) for a in BATCH_AXES])), mesh.shape.get(MODEL_AXIS, 1)
+
+
+def _kernel_placement(c: TransformerConfig, B: int, heads: int, kv_heads: int):
+    """(whether a Pallas attention kernel may run for this configuration and
+    shape, the mesh to place it over or None for a plain call)."""
+    use = (
+        c.attention_impl == "flash"
+        and c.pos_embedding != "alibi"  # the kernels take no additive score bias
+        and c.peft_type != "prefix"  # prefix keys break the kernels' slot arithmetic
+    )
+    # Mosaic kernels cannot be auto-partitioned by XLA SPMD: on a
+    # multi-device mesh the call must be placed explicitly (batch and
+    # head axes are embarrassingly parallel) via shard_map, and a shape
+    # that cannot divide those axes falls back to the einsum paths.
+    mesh = None
+    if use:
+        mesh = ambient_mesh()
+        if mesh is not None:
+            n_batch, n_model = _kernel_shards(mesh)
+            if mesh.size == 1:
+                # single device: plain call. (Any larger mesh must go via
+                # the shard_map wrapper even when batch/model axes are
+                # trivial — e.g. a pipe-only mesh still has an auto axis
+                # the Mosaic kernel cannot sit under.)
+                mesh = None
+            elif B % n_batch or heads % n_model or kv_heads % n_model:
+                use = False  # kernel cannot place; XLA attention
+    return use, mesh
+
+
 def _flash_placement(c: TransformerConfig, B: int, T: int, kv_valid, heads: int, kv_heads: int):
     """(whether this forward takes the flash kernel, the mesh to place it over
     or None for a plain call).
@@ -442,32 +478,19 @@ def _flash_placement(c: TransformerConfig, B: int, T: int, kv_valid, heads: int,
     passes it when the cache index was a concrete 0 at trace time (checked
     there, outside the remat wrapper — inside a block cache["index"] may be a
     remat tracer even at prefill)."""
-    use_flash = (
-        c.attention_impl == "flash"
-        and kv_valid is not None
-        and T > 1
-        and c.pos_embedding != "alibi"  # kernel takes no additive bias
-        and c.peft_type != "prefix"  # prefix keys break the kernel's causal index math
-    )
-    # Mosaic kernels cannot be auto-partitioned by XLA SPMD: on a
-    # multi-device mesh the flash call must be placed explicitly (batch and
-    # head axes are embarrassingly parallel) via shard_map, and a shape
-    # that cannot divide those axes falls back to the einsum paths.
-    flash_mesh = None
-    if use_flash:
-        flash_mesh = ambient_mesh()
-        if flash_mesh is not None:
-            n_batch = int(np.prod([flash_mesh.shape.get(a, 1) for a in BATCH_AXES]))
-            n_model = flash_mesh.shape.get(MODEL_AXIS, 1)
-            if flash_mesh.size == 1:
-                # single device: plain call. (Any larger mesh must go via
-                # the shard_map wrapper even when batch/model axes are
-                # trivial — e.g. a pipe-only mesh still has an auto axis
-                # the Mosaic kernel cannot sit under.)
-                flash_mesh = None
-            elif B % n_batch or heads % n_model or kv_heads % n_model:
-                use_flash = False  # kernel cannot place; XLA attention
-    return use_flash, flash_mesh
+    if kv_valid is None or T <= 1:
+        return False, None
+    return _kernel_placement(c, B, heads, kv_heads)
+
+
+def _kernel_target(mesh) -> str:
+    """The platform a kernel is compiled for: interpret (XLA-emulated) mode iff
+    it is the CPU. The ambient mesh's devices name the target; default_backend
+    alone is wrong under deviceless TPU AOT compilation
+    (scripts/scale_proof.py runs with a CPU host backend but lowers for a TPU
+    topology, where interpret mode would re-materialize the score matrices the
+    kernel exists to avoid)."""
+    return mesh.devices.flat[0].platform if mesh is not None else jax.default_backend()
 
 
 def _flash(q, kh, vh, kv_valid, scale: float, flash_mesh):
@@ -476,18 +499,56 @@ def _flash(q, kh, vh, kv_valid, scale: float, flash_mesh):
     natively, so grouped K/V are never materialized at full head count."""
     from trlx_tpu.ops.attention import flash_attention, flash_attention_sharded
 
-    # interpret (XLA-emulated) mode iff the COMPILE TARGET is CPU. The
-    # ambient mesh's devices name the target; default_backend alone is
-    # wrong under deviceless TPU AOT compilation (scripts/scale_proof.py
-    # runs with a CPU host backend but lowers for a TPU topology, where
-    # interpret mode would re-materialize the score matrices the kernel
-    # exists to avoid).
-    target = flash_mesh.devices.flat[0].platform if flash_mesh is not None else jax.default_backend()
+    target = _kernel_target(flash_mesh)
     if flash_mesh is not None:
         return flash_attention_sharded(
             q, kh, vh, kv_valid, True, scale, target == "cpu", flash_mesh, BATCH_AXES, MODEL_AXIS,
         )
     return flash_attention(q, kh, vh, kv_valid, True, scale, target == "cpu")
+
+
+def decode_kernel_placement(c: TransformerConfig, B: int):
+    """(whether a single-token step of ``B`` rows over the contiguous cache
+    takes the Pallas decode kernel, the mesh to place it over or None): the
+    flash kernels' rule, and per-head float rows in the cache. Everything else
+    — ``attention_impl="xla"``, the int8 cache, alibi, prefix tuning, latent
+    attention (its own absorbed decode), a mesh the call cannot be placed over
+    — keeps the einsum path."""
+    if c.attention_kind == "mla" or c.kv_cache_quant:
+        return False, None
+    return _kernel_placement(c, B, c.num_heads, c.kv_heads)
+
+
+def decode_cache_read_share(c: TransformerConfig, B: int, prompt_len: int, new_tokens: int, steps: int) -> float:
+    """Cache slots the decode steps of one rollout visited over the slots the
+    cache holds (``rollout/cache_read_share``): host arithmetic from the
+    prompt's bucket, the ``steps`` the decode loop ran and the kernel's block;
+    1.0 where the steps took the einsum path. Call under the trainer's mesh."""
+    from trlx_tpu.ops.attention import cache_read_share, choose_decode_tiles
+
+    use, mesh = decode_kernel_placement(c, B)
+    if not use:
+        return 1.0
+    n_batch, n_model = _kernel_shards(mesh)
+    virtual = c.num_virtual_tokens if c.peft_type == "prompt" else 0  # they live in the cache too
+    cache_len = prompt_len + new_tokens + virtual
+    tiles = choose_decode_tiles(
+        B // n_batch, c.kv_heads // n_model, c.num_heads // c.kv_heads, cache_len, c.dim_per_head, c.compute_dtype
+    )
+    return cache_read_share(prompt_len + virtual, steps, cache_len, tiles.block)
+
+
+def _decode(q, ck, cv, mask_bias, index, scale: float, mesh):
+    """q [B, heads, D] over the cache ck, cv [B, kv heads, S, D] up to slot
+    ``index`` through the decode kernel, plainly or placed over ``mesh``."""
+    from trlx_tpu.ops.attention import decode_attention, decode_attention_sharded
+
+    interpret = _kernel_target(mesh) == "cpu"
+    if mesh is not None:
+        return decode_attention_sharded(
+            q, ck, cv, mask_bias, index, scale, interpret, mesh, BATCH_AXES, MODEL_AXIS
+        )
+    return decode_attention(q, ck, cv, mask_bias, index, scale, interpret)
 
 
 class Attention(nn.Module):
@@ -506,7 +567,8 @@ class Attention(nn.Module):
         [B,Hkv,S,D] plus the global write index. ``kv_valid`` [B,T] enables the
         Pallas flash path on any multi-token forward — cache-free (training /
         scoring) or generation prefill (cache written from slot 0, attention over
-        the prefix k/v only); single-token decode steps use XLA over the cache."""
+        the prefix k/v only); single-token decode steps take the Pallas decode
+        kernel over the cache up to the write index."""
         c = self.config
         B, T, _ = x.shape
         dense = lambda feats, name, bias, std=c.initializer_range: LoraDense(
@@ -585,6 +647,23 @@ class Attention(nn.Module):
         else:
             new_cache = None
 
+        # A single-token step over the contiguous cache takes the Pallas decode
+        # kernel, which reads the cache up to the write index only; who takes
+        # it is decided by what is visible here (decode_kernel_placement), and
+        # appends of several tokens keep the einsum path below like everything
+        # that rule leaves out. (A kernel with a grid of (B, Hkv) programs of
+        # 32 KB each lost to the einsum by 1.3x at B=32 S=256 before PR 1;
+        # this one, 2026-10-02, the batch on the lanes and 1.5 MB an operand a
+        # program: 2.38 against 4.13 ms a 12-layer step at B=128 S=512 on one
+        # v5e, PERF.md §6, PR 30.)
+        if cache is not None and T == 1 and "k_scale" not in new_cache:
+            use_kernel, kernel_mesh = decode_kernel_placement(c, B)
+            if use_kernel:
+                out = _decode(q[:, 0], ck, cv, mask_bias, idx, 1.0 / math.sqrt(c.dim_per_head), kernel_mesh)
+                out = out.reshape(B, T, c.num_heads * c.dim_per_head).astype(c.compute_dtype)
+                out = dense(c.hidden_size, "o_proj", c.attn_bias, res_std)(out)
+                return out, new_cache
+
         # For prefill (cache present, T > 1, writes starting at slot 0) attention
         # over the just-computed prefix k/v is exactly attention over the cache,
         # since all cache slots >= T are still empty; k/v are written to the
@@ -646,13 +725,6 @@ class Attention(nn.Module):
             )
 
         scale = 1.0 / math.sqrt(c.dim_per_head)
-
-        # Single-token decode stays on the XLA einsum path BY MEASUREMENT: a
-        # fused Pallas decode kernel (grid (B,Hkv) or (B,) + in-kernel head
-        # loop) ran 1.3x slower per layer than XLA's multiply-reduce fusions on
-        # one v5e chip (441us vs 337us per 12-layer step, B=32 S=256) — decode
-        # attention is a batched matvec, too fine-grained for TPU pallas grids,
-        # and XLA's VPU reduce already streams the cache near bandwidth.
 
         if (
             c.attention_impl == "ring"

@@ -1,4 +1,4 @@
-"""Attention ops: Pallas TPU flash-attention forward + backward kernels + XLA reference.
+"""Attention ops: Pallas TPU flash-attention forward + backward kernels, the decode kernel, XLA reference.
 
 The reference relies on external CUDA attention kernels (HF/NeMo, SURVEY.md §2.4.5);
 this is the TPU-native equivalent. Forward is an online-softmax (FlashAttention-style)
@@ -34,8 +34,9 @@ Masking model matches :mod:`trlx_tpu.models.transformer`: slot-based causality p
 [B, S] key-validity mask (left-padded prompts). Engaged on every multi-token forward:
 the training loss, the logprob/value scoring passes, and generation *prefill* (which
 attends over the just-computed prefix k/v while the cache write happens separately).
-Only single-token decode steps stay on the XLA path. Arbitrary T/S are supported via
-internal padding (see ``_flash_forward``).
+Arbitrary T/S are supported via internal padding (see ``_flash_forward``). Single-token
+decode steps over the contiguous cache have a kernel of their own, :func:`decode_attention`
+(the section "decode" below), which reads the cache only up to the write index.
 
 Layout note: per-row statistics (logsumexp, delta) travel between the kernels as
 ``[B, H, 1, T]`` float32 rows with T on the lanes — B·H·T·4 bytes in HBM, where a
@@ -784,11 +785,233 @@ def _bwd(causal, scale, interpret, res, g):
 flash_attention.defvjp(_fwd, _bwd)
 
 
-def flash_attention_sharded(
-    q, k, v, kv_valid, causal: bool, scale: Optional[float], interpret: bool,
-    mesh, batch_axes, head_axis,
+# ----------------------------------------------------------------- decode
+#
+# One new token a row against the contiguous cache ``[B, Hkv, S, D]`` of the
+# one-shot generator. The work is a matrix-vector product per (row, head) with
+# a matrix of its own each, so the MXU has nothing to latch: it runs on the
+# VPU, a lane per batch row. The kernel therefore takes the cache as
+# ``[S, Hkv, D, B]`` — slots outermost, the batch on the lanes, the head
+# dimension down the sublanes — which is the physical layout the chip's
+# compiler gives a cache carried through the decode ``while`` anyway
+# (``{0,3,1,2}``, read off the compiled program), so the transposes around the
+# call are bitcasts. Scores reduce over sublanes, the weighted sum over slots,
+# neither over lanes. With slots outermost the written part of the cache is a
+# prefix of the buffer: a grid step holds ``block`` slots, a block past the
+# write index does nothing, and its index map is clamped to the last block that
+# holds a token, so the pipeline sees the same block again and moves no bytes.
+
+
+class DecodeTiles(NamedTuple):
+    """How one decode shape is cut into programs (see :func:`choose_decode_tiles`)."""
+
+    rows: int  # batch rows a program takes, on the lanes
+    block: int  # cache slots a grid step holds
+    vmem_bytes: int  # reckoned VMEM
+
+
+def choose_decode_tiles(
+    B: int, Hkv: int, rep: int, S: int, D: int, dtype, vmem_budget: int = 12 * 2**20, Dv: Optional[int] = None
+) -> DecodeTiles:
+    """Programs for q ``[B, Hkv * rep, D]`` against a cache ``[B, Hkv, S, D]``.
+    A pure function of the shape, beside :func:`choose_tiles`.
+
+    A program takes 128 batch rows (all of them where 128 does not divide B)
+    and all their heads, and the fewest slots — 8, 16 or 32 — that make its k
+    block a mebibyte, within ``vmem_budget`` (k and v blocks twice, q, o, the
+    float32 accumulator): at gpt2's widths and batch 128 a slot of one operand
+    is 196 KB, so 8 slots and 1.5 MB an operand a grid step; the chip reads
+    16 and 32 within 1 % and 4 % of that (PERF.md §6, PR 30)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    rows = LANE if B % LANE == 0 else B
+    lanes = _round_up(rows, LANE)
+    sublane = 32 // itemsize
+    k_slot = Hkv * _round_up(D, sublane) * lanes * itemsize
+    v_slot = Hkv * _round_up(Dv or D, sublane) * lanes * itemsize
+
+    def reckon(block):
+        q_o = rep * (k_slot + v_slot)
+        carried = Hkv * rep * (_round_up(Dv or D, 8) + 2 * 8) * lanes * 4  # accumulator, max, sum
+        tiles = (1 + 2 * rep) * block * lanes * 4  # mask bias, a kv head's scores and probabilities
+        return 2 * block * (k_slot + v_slot) + 2 * q_o + carried + 2 * tiles
+
+    fits = [n for n in (8, 16, 32) if n == 8 or (n <= _round_up(S, 8) and reckon(n) <= vmem_budget)]
+    block = next((n for n in fits if n * k_slot >= 2**20), fits[-1])
+    return DecodeTiles(rows, block, reckon(block))
+
+
+@functools.lru_cache(maxsize=None)
+def _log_decode_tiles(B, H, Hkv, S, D, dtype, tiles):
+    """The chooser's choice, once per traced shape."""
+    logger.info(  # graftcheck: noqa[JX003] — once per traced shape is the point
+        f"decode attention q[{B},{H},{D}] cache[{B},{Hkv},{S},{D}] {dtype}: rows {tiles.rows} block {tiles.block},"
+        f" grid {(B // tiles.rows, -(-S // tiles.block))}, VMEM reckoned {tiles.vmem_bytes / 2**20:.1f} MiB"
+    )
+
+
+def cache_read_share(prompt_len: int, steps: int, cache_len: int, block: Optional[int]) -> float:
+    """Slots the decode kernel's grid visits over slots the cache holds, summed
+    over ``steps`` decode steps after a prefill of ``prompt_len`` slots: step t
+    (from 1) reads the ``prompt_len + t`` written slots rounded up to
+    ``block``. 1.0 where no kernel runs (``block`` None) or no step did."""
+    if block is None or steps <= 0:
+        return 1.0
+    visited = sum(min(cache_len, _round_up(prompt_len + t, block)) for t in range(1, steps + 1))
+    return visited / (steps * cache_len)
+
+
+def _decode_kernel(
+    index_ref,  # scalar prefetch: [1] int32, the slot this step's token was written to
+    bias_ref,  # [block, rows] f32: 0 where a row may see a slot, -1e9 elsewhere
+    q_ref,  # [Hkv, rep, D, rows]
+    k_ref,  # [block, Hkv, D, rows]
+    v_ref,  # [block, Hkv, Dv, rows]
+    o_ref,  # [Hkv, rep, Dv, rows]
+    m_ref,  # [H, 1, rows] f32 running max
+    l_ref,  # [H, 1, rows] f32 running sum
+    acc_ref,  # [H, Dv, rows] f32
+    s_ref,  # [rep, block, rows] f32: a kv head's scores, slot by slot
+    p_ref,  # [rep, block, rows] f32: its probabilities
+    *,
+    scale: float,
 ):
-    """SPMD placement for the flash kernel: Mosaic kernels cannot be
+    block, kv_heads = k_ref.shape[0], k_ref.shape[1]
+    rep = q_ref.shape[1]
+    j = pl.program_id(1)
+    held = index_ref[0] + 1 - j * block  # slots of this block that hold a token
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def visit(ragged: bool):
+        """All of the block's slots, unrolled so that their chains interleave;
+        ``ragged``: the block the write index lies in, where what is past the
+        index (never written, or past the cache's end) is selected away and not
+        multiplied by zero, so that nothing there can reach the result."""
+        bias = bias_ref[...]
+        written = jax.lax.broadcasted_iota(jnp.int32, bias.shape, 0) < held
+
+        def kv_head(h):
+            qs = [q_ref[h, r].astype(jnp.float32) for r in range(rep)]  # [D, rows]
+            for s in range(block):
+                k = k_ref[s, h].astype(jnp.float32)
+                for r in range(rep):
+                    s_ref[r, s:s + 1, :] = jnp.sum(qs[r] * k, axis=0, keepdims=True)
+            accs = []
+            for r in range(rep):
+                hr = h * rep + r
+                scores = s_ref[r] * scale + bias
+                if ragged:
+                    scores = jnp.where(written, scores, NEG_INF)
+                m_prev = m_ref[hr]
+                m = jnp.maximum(m_prev, jnp.max(scores, axis=0, keepdims=True))
+                p = jnp.exp(scores - m)
+                alpha = jnp.exp(m_prev - m)
+                l_ref[hr] = alpha * l_ref[hr] + jnp.sum(p, axis=0, keepdims=True)
+                m_ref[hr] = m
+                # the probabilities meet v in v's dtype, as the einsum path's do
+                p_ref[r] = p.astype(v_ref.dtype).astype(jnp.float32)
+                accs.append(alpha * acc_ref[hr])
+            for s in range(block):
+                v = v_ref[s, h].astype(jnp.float32)  # [Dv, rows]
+                if ragged:
+                    v = jnp.where(s < held, v, 0.0)
+                for r in range(rep):
+                    accs[r] = accs[r] + p_ref[r, s:s + 1, :] * v
+            for r in range(rep):
+                acc_ref[h * rep + r] = accs[r]
+
+        _loop(kv_head, count=kv_heads)
+
+    pl.when(held >= block)(lambda: visit(False))
+    pl.when(jnp.logical_and(held > 0, held < block))(lambda: visit(True))
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finalize():
+        def head(hr):
+            o_ref[hr // rep, hr % rep] = (acc_ref[hr] / l_ref[hr]).astype(o_ref.dtype)
+
+        _loop(head, count=kv_heads * rep)
+
+
+# jitted so that a model's layers, which call it at one shape, share one trace and one
+# lowering of the kernel: traced a layer, 24 layers add 5 s to every program that decodes
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "tiles"))
+def decode_attention(
+    q: jnp.ndarray,  # [B, H, D]: one new token a row
+    k: jnp.ndarray,  # [B, Hkv, S, D]: the cache, this step's token written at slot ``index``
+    v: jnp.ndarray,  # [B, Hkv, S, Dv]
+    mask_bias: jnp.ndarray,  # additive, B * S elements ([B, 1, 1, S]): 0 where a row may see a slot
+    index,  # scalar int32: the last slot that holds a token, the same for every row
+    scale: Optional[float] = None,
+    interpret: bool = False,
+    tiles: Optional[DecodeTiles] = None,
+) -> jnp.ndarray:
+    """Attention of one query token a row over cache slots ``0..index``, in one
+    Pallas call: scores, the float32 softmax (taken block by block, with a
+    running max and sum) and the weighted sum. Slots past ``index`` are neither
+    computed on nor, beyond the block ``index`` lies in, moved. ``mask_bias``
+    is the einsum path's (left padding; its causal part is implied by
+    ``index``). Grouped K/V map h -> h // rep. Returns ``[B, H, Dv]`` in q's dtype."""
+    B, H, D = q.shape
+    Hkv, S, Dv = k.shape[1], k.shape[2], v.shape[3]
+    assert H % Hkv == 0, (H, Hkv)
+    rep = H // Hkv
+    scale = scale if scale is not None else 1.0 / math.sqrt(D)
+    if tiles is None:
+        tiles = choose_decode_tiles(B, Hkv, rep, S, D, k.dtype, Dv=Dv)
+    _log_decode_tiles(B, H, Hkv, S, D, jnp.dtype(k.dtype).name, tiles)
+    rows, block = tiles.rows, tiles.block
+
+    # slots outermost, the batch on the lanes: bitcasts of the cache as the chip's compiler lays it out
+    q_t = q.reshape(B, Hkv, rep, D).transpose(1, 2, 3, 0)
+    k_t, v_t = k.transpose(2, 1, 3, 0), v.transpose(2, 1, 3, 0)
+    bias = mask_bias.reshape(B, S).astype(jnp.float32).T
+    index = jnp.asarray(index, jnp.int32).reshape(1)
+
+    def last_held(j, idx):  # a block past the write index is the last held one again: no new DMA
+        return jnp.minimum(j, idx[0] // block)
+
+    q_spec, o_spec = (pl.BlockSpec((Hkv, rep, w, rows), lambda i, j, idx: (0, 0, 0, i)) for w in (D, Dv))
+    k_spec, v_spec = (
+        pl.BlockSpec((block, Hkv, w, rows), lambda i, j, idx: (last_held(j, idx), 0, 0, i)) for w in (D, Dv)
+    )
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B // rows, -(-S // block)),
+            in_specs=[
+                pl.BlockSpec((block, rows), lambda i, j, idx: (last_held(j, idx), i)),
+                q_spec, k_spec, v_spec,
+            ],
+            out_specs=o_spec,
+            scratch_shapes=[
+                pltpu.VMEM((H, 1, rows), jnp.float32),
+                pltpu.VMEM((H, 1, rows), jnp.float32),
+                pltpu.VMEM((H, Dv, rows), jnp.float32),
+                pltpu.VMEM((rep, block, rows), jnp.float32),
+                pltpu.VMEM((rep, block, rows), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((Hkv, rep, Dv, B), q.dtype),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=max(32 * 2**20, 2 * tiles.vmem_bytes),
+        ),
+        # a name of its own: unnamed under the model's `attn` scope it would be `%attn.N custom-call`
+        # on the chip, which is how the benchmark finds the flash calls
+        name="decode_attn",
+    )(index, bias, q_t, k_t, v_t)
+    return out.transpose(3, 0, 1, 2).reshape(B, H, Dv)
+
+
+def _placed(local, mesh, batch_axes, head_axis, operands, out_ndim: int):
+    """SPMD placement for a Pallas attention call: Mosaic kernels cannot be
     auto-partitioned by XLA's SPMD pass (it raises at compile time on any
     multi-device mesh), so shard the embarrassingly-parallel grid axes
     explicitly — batch over ``batch_axes``, heads over ``head_axis`` — and run
@@ -796,11 +1019,12 @@ def flash_attention_sharded(
     each (batch, head) pair's softmax is independent, and the grouped-KV head
     map stays consistent because H_local/Hkv_local equals the global ratio
     when both divide the axis. Differentiable: autodiff enters the shard_map
-    and applies the kernel's custom VJP per shard."""
-    from jax.sharding import PartitionSpec as P
+    and applies the kernel's custom VJP per shard.
 
-    def local(q, k, v, kv_valid):
-        return flash_attention(q, k, v, kv_valid, causal, scale, interpret)
+    ``operands``: ``(array, has_heads)`` pairs — dimension 0 of an array is the
+    batch, dimension 1 the heads where ``has_heads``; a scalar is replicated.
+    The result has ``out_ndim`` dimensions, batch then heads first."""
+    from jax.sharding import PartitionSpec as P
 
     # The map must be manual over EVERY mesh axis the SPMD partitioner would
     # otherwise see — a Mosaic op under any remaining auto axis (e.g. `pipe`
@@ -829,9 +1053,39 @@ def flash_attention_sharded(
     batch_entry = tuple(batch_axes) if isinstance(batch_axes, tuple) else (batch_axes,)
     batch_entry = tuple(a for a in batch_entry if a in axes)
     head_entry = head_axis if head_axis in axes else None
-    spec = P(batch_entry or None, head_entry, None, None)
-    vspec = P(batch_entry or None, None)
+
+    def spec(ndim, has_heads):
+        entries = [batch_entry or None, head_entry if has_heads else None] + [None] * (ndim - 2)
+        return P(*entries[:ndim])
+
     return jax.shard_map(
-        local, mesh=mesh, in_specs=(spec, spec, spec, vspec), out_specs=spec,
-        check_vma=False, axis_names=axes,
-    )(q, k, v, kv_valid)
+        local, mesh=mesh, in_specs=tuple(spec(jnp.ndim(x), heads) for x, heads in operands),
+        out_specs=spec(out_ndim, True), check_vma=False, axis_names=axes,
+    )(*(x for x, _ in operands))
+
+
+def flash_attention_sharded(
+    q, k, v, kv_valid, causal: bool, scale: Optional[float], interpret: bool,
+    mesh, batch_axes, head_axis,
+):
+    """:func:`flash_attention` placed over a multi-device mesh (:func:`_placed`)."""
+
+    def local(q, k, v, kv_valid):
+        return flash_attention(q, k, v, kv_valid, causal, scale, interpret)
+
+    return _placed(
+        local, mesh, batch_axes, head_axis, [(q, True), (k, True), (v, True), (kv_valid, False)], out_ndim=4
+    )
+
+
+def decode_attention_sharded(
+    q, k, v, mask_bias, index, scale: Optional[float], interpret: bool, mesh, batch_axes, head_axis,
+):
+    """:func:`decode_attention` placed over a multi-device mesh (:func:`_placed`):
+    every shard reads its rows' and heads' part of the cache up to the same index."""
+
+    def local(q, k, v, mask_bias, index):
+        return decode_attention(q, k, v, mask_bias, index, scale, interpret)
+
+    operands = [(q, True), (k, True), (v, True), (mask_bias, False), (jnp.asarray(index, jnp.int32), False)]
+    return _placed(local, mesh, batch_axes, head_axis, operands, out_ndim=3)

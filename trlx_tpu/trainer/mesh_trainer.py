@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import optax
 
 from trlx_tpu.data.configs import TRLConfig
+from trlx_tpu.models.transformer import TransformerConfig, decode_cache_read_share
 from trlx_tpu.obs import Observability, batch_token_count, compile_log
 from trlx_tpu.ops.generation import generate as generate_op
 from trlx_tpu.ops.generation import generate_seq2seq, left_pad_batch, pad_to_bucket
@@ -43,6 +44,7 @@ from trlx_tpu.utils import (
 )
 from trlx_tpu.utils import logging
 from trlx_tpu.utils.compilation_cache import configure_compilation_cache
+from trlx_tpu.utils.metrics import gauges
 from trlx_tpu.utils.trackers import make_tracker
 
 logger = logging.get_logger(__name__)
@@ -584,6 +586,12 @@ class MeshRLTrainer(BaseRLTrainer):
                 )
             sequences = np.asarray(jax.device_get(out["sequences"]))
             response_mask = np.asarray(jax.device_get(out["response_mask"]))
+        if isinstance(getattr(self, "model_config", None), TransformerConfig):
+            # the loop ran until the longest row ended; its first token came from the prefill
+            steps = int(response_mask.sum(axis=1).max()) - 1
+            with self.mesh:
+                gauges.set("rollout/cache_read_share", decode_cache_read_share(
+                    self.model_config, ids.shape[0], P, max_new, steps))
         # seq2seq sequences are [decoder_start] + response: pad_len for decode() is 1
         return sequences, response_mask, 1 if is_seq2seq else P
 
