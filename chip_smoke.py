@@ -121,8 +121,8 @@ def phase_kernels(sizes: Sizes) -> None:
     import jax.numpy as jnp
 
     from trlx_tpu.models.presets import get_preset
-    from trlx_tpu.models.transformer import quantize_kv_rows
     from trlx_tpu.ops import attention
+    from trlx_tpu.ops.kv_cache import quantize_kv_rows
     from trlx_tpu.ops.paged_attention import (
         paged_pool_layout,
         paged_verify_attention_pallas,
@@ -159,11 +159,7 @@ def phase_kernels(sizes: Sizes) -> None:
 
         return jax.jit(run)(q, k, v, g)  # arguments, not constants for XLA to fold
 
-    previous = attention.set_flash_backward("pallas")  # read at trace time
-    try:
-        got = out_and_grads(flash)
-    finally:
-        attention.set_flash_backward(previous)
+    got = out_and_grads(flash)
     want = out_and_grads(plain)
     for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
         _check("kernels", f"flash {name} [B={B} H={H} T={T} D={D} {dtype.name}]",

@@ -6,10 +6,10 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 
 echo "== syntax (compileall)"
-python -m compileall -q trlx_tpu examples tests scripts bench.py __graft_entry__.py
+python -m compileall -q trlx_tpu examples tests scripts __graft_entry__.py
 
 echo "== lint (scripts/lint.py)"
-python scripts/lint.py trlx_tpu examples tests scripts bench.py __graft_entry__.py
+python scripts/lint.py trlx_tpu examples tests scripts __graft_entry__.py
 
 echo "== graftcheck (python -m trlx_tpu.analysis)"
 # semantic gate: JAX RNG/tracing discipline, thread/lock discipline, and the
@@ -20,16 +20,16 @@ echo "== graftcheck (python -m trlx_tpu.analysis)"
 # any finding that is neither noqa'd at the line nor justified in
 # graftcheck-baseline.txt. --jobs fans per-file checks over a fork pool,
 # clamped to the core count (serial on 1-core runners)
-JAX_PLATFORMS=cpu python -m trlx_tpu.analysis trlx_tpu tests examples scripts bench.py __graft_entry__.py --jobs 4
+JAX_PLATFORMS=cpu python -m trlx_tpu.analysis trlx_tpu tests examples scripts __graft_entry__.py --jobs 4
 
 echo "== graftcheck-conc gate (must fail on the seeded race)"
 # the conc gate proves itself: the same command that must pass on the clean
 # tree must exit 1 when TRLX_CONC_SEED_REGRESSION re-introduces the PR-8
 # scheduler race in memory — a gate that cannot catch the bug it was built
 # for is not a gate (mirrors TRLX_IR_SEED_REGRESSION below)
-JAX_PLATFORMS=cpu python -m trlx_tpu.analysis trlx_tpu tests examples scripts bench.py __graft_entry__.py --select CC
+JAX_PLATFORMS=cpu python -m trlx_tpu.analysis trlx_tpu tests examples scripts __graft_entry__.py --select CC
 if JAX_PLATFORMS=cpu TRLX_CONC_SEED_REGRESSION=scheduler_race \
-    python -m trlx_tpu.analysis trlx_tpu tests examples scripts bench.py __graft_entry__.py --select CC > /dev/null 2>&1; then
+    python -m trlx_tpu.analysis trlx_tpu tests examples scripts __graft_entry__.py --select CC > /dev/null 2>&1; then
     echo "FATAL: seeded scheduler_race regression was NOT caught by the CC gate" >&2
     exit 1
 fi

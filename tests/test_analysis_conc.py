@@ -688,7 +688,7 @@ def test_repo_tree_is_cc_clean():
     env = {k: v for k, v in os.environ.items() if k != seeds.ENV_VAR}
     proc = subprocess.run(
         [sys.executable, "-m", "trlx_tpu.analysis", "trlx_tpu", "tests",
-         "examples", "scripts", "bench.py", "__graft_entry__.py", "--select", "CC"],
+         "examples", "scripts", "__graft_entry__.py", "--select", "CC"],
         cwd=REPO_ROOT, capture_output=True, text=True, env=env,
     )
     assert proc.returncode == 0, proc.stdout + proc.stderr
@@ -700,7 +700,7 @@ def test_repo_tree_seeded_race_fails_the_gate():
     env = dict(os.environ, **{seeds.ENV_VAR: "scheduler_race"})
     proc = subprocess.run(
         [sys.executable, "-m", "trlx_tpu.analysis", "trlx_tpu", "tests",
-         "examples", "scripts", "bench.py", "__graft_entry__.py", "--select", "CC"],
+         "examples", "scripts", "__graft_entry__.py", "--select", "CC"],
         cwd=REPO_ROOT, capture_output=True, text=True, env=env,
     )
     assert proc.returncode == 1, proc.stdout + proc.stderr

@@ -780,7 +780,7 @@ def test_repo_tree_sh_clean():
     live in graftcheck-baseline.txt with justifications)."""
     rc = subprocess.call(
         [sys.executable, "-m", "trlx_tpu.analysis",
-         "trlx_tpu", "tests", "examples", "scripts", "bench.py",
+         "trlx_tpu", "tests", "examples", "scripts",
          "--select", "SH", "--jobs", "4"],
         cwd=REPO_ROOT,
         env={**os.environ, "JAX_PLATFORMS": "cpu"},

@@ -202,30 +202,20 @@ def test_flash_gqa_kernel_matches_xla():
     ],
 )
 def test_pallas_backward_matches_xla_backward(B, H, Hkv, T, S, maskfrac):
-    """Grad parity: the Pallas dq/dkv kernels against the XLA recompute fallback,
-    including left-padding masks, non-block-multiple shapes, and grouped heads."""
-    import trlx_tpu.ops.attention as attn
-
+    """Grad parity: the Pallas dq/dkv kernels against the gradients of the plain
+    XLA reference under the forward's cotangent, including left-padding masks,
+    non-block-multiple shapes, and grouped heads."""
     q, k, v = make_gqa_inputs(B=B, H=H, Hkv=Hkv, T=T, S=S, seed=7)
     kv_valid = np.ones((B, S), np.int32)
     kv_valid[0, : int(S * maskfrac)] = 0
     kv_valid = jnp.asarray(kv_valid)
 
-    def loss(q, k, v):
-        out = flash_attention(q, k, v, kv_valid, True, None, True)
-        # non-uniform cotangent exercises dO properly
-        w = jnp.arange(out.size, dtype=jnp.float32).reshape(out.shape) / out.size
-        return jnp.sum(out * w) + jnp.sum(out**2)
-
-    prev = attn.BACKWARD_IMPL
-    try:
-        attn.BACKWARD_IMPL = "pallas"
-        gp = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-        attn.BACKWARD_IMPL = "xla"
-        gx = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-    finally:
-        attn.BACKWARD_IMPL = prev
-    for a, b, name in zip(gp, gx, "qkv"):
+    out, flash_vjp = jax.vjp(lambda q, k, v: flash_attention(q, k, v, kv_valid, True, None, True), q, k, v)
+    # non-uniform cotangent exercises dO properly: that of sum(out * w) + sum(out ** 2)
+    g = jnp.arange(out.size, dtype=jnp.float32).reshape(out.shape) / out.size + 2 * out
+    scale = 1.0 / np.sqrt(q.shape[-1])
+    _, xla_vjp = jax.vjp(lambda q, k, v: xla_attention(q, k, v, kv_valid, True, scale), q, k, v)
+    for a, b, name in zip(flash_vjp(g), xla_vjp(g), "qkv"):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=2e-4, rtol=1e-4, err_msg=f"d{name}"
         )
@@ -572,3 +562,113 @@ def test_decode_chooser_choice_is_logged_once_per_shape(caplog):
     lines = [r.getMessage() for r in caplog.records if "decode attention q[2,3,8]" in r.getMessage()]
     assert len(lines) == 1, lines
     assert "cache[2,3,24,8] float32: rows 2 block 16, grid (1, 2), VMEM reckoned" in lines[0]
+
+
+# ------------------------------------------------------- attend: the dispatch
+
+
+def _attend_case(case, seed=11):
+    """Operands of one ``attend`` call and the plain reference's answer for them.
+    Multi-token forwards are cache-free over T tokens, the first rows left-padded;
+    single-token steps attend over a cache whose slots 0..index hold tokens."""
+    from trlx_tpu.ops import kv_cache
+
+    impl, H, Hkv, decode, quant = {
+        "flash-kernel": ("flash", 4, 2, False, False),
+        "decode-kernel": ("flash", 4, 2, True, False),
+        "grouped-einsum": ("xla", 4, 2, False, False),
+        "multi-head-einsum": ("xla", 4, 4, False, False),
+        "int8-einsum": ("flash", 4, 2, True, True),
+    }[case]
+    B, T, S, D, index = 2, 24, 32, 16, 19
+    rng = np.random.default_rng(seed)
+    scale = 1.0 / np.sqrt(D)
+    valid = np.ones((B, S if decode else T), np.int32)
+    valid[0, :5] = 0  # left padding
+    if decode:
+        q = jnp.asarray(rng.normal(size=(B, 1, H, D)), jnp.float32)
+        rows = [jnp.asarray(rng.normal(size=(B, Hkv, index + 1, D)), jnp.float32) for _ in range(2)]
+        layout = kv_cache.kv_cache_layout((B, Hkv, S, D), jnp.float32, quant)
+        cache = {key: jnp.zeros(shape, dtype) for key, (shape, dtype) in layout.items()}
+        cache = kv_cache.write_kv_cache(cache, rows[0], rows[1], 0)
+        k = v = jnp.zeros((B, 1, Hkv, D), jnp.float32)  # this step's rows are in the cache already
+        seen = jnp.asarray(valid) * (jnp.arange(S)[None, :] <= index)
+        mask_bias = jnp.where(seen[:, None, None, :] > 0, 0.0, -1e9).astype(jnp.float32)
+        kh, vh = kv_cache.read_kv_cache(cache, jnp.float32)  # the int8 rows as they read back
+        want = xla_attention(q.transpose(0, 2, 1, 3), kh, vh, seen, False, scale)
+        return dict(q=q, k=k, v=v, cache=cache, mask_bias=mask_bias, kv_valid=None, index=jnp.int32(index),
+                    scale=scale, impl=impl), want.transpose(0, 2, 1, 3).reshape(B, 1, H * D)
+    q = jnp.asarray(rng.normal(size=(B, T, H, D)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(B, T, Hkv, D)), jnp.float32) for _ in range(2))
+    kv_valid = jnp.asarray(valid)
+    causal = jnp.tril(jnp.ones((T, T), bool))[None, None] & (kv_valid[:, None, None, :] > 0)
+    mask_bias = jnp.where(causal, 0.0, -1e9).astype(jnp.float32)
+    want = xla_attention(*(x.transpose(0, 2, 1, 3) for x in (q, k, v)), kv_valid, True, scale)
+    return dict(q=q, k=k, v=v, cache=None, mask_bias=mask_bias, kv_valid=kv_valid, index=None, scale=scale,
+                impl=impl), want.transpose(0, 2, 1, 3).reshape(B, T, H * D)
+
+
+def _spy_on_kernels(monkeypatch):
+    taken = []
+    for name in ("flash_attention", "decode_attention"):
+        kernel = getattr(attn, name)
+        monkeypatch.setattr(
+            attn, name, lambda *a, _name=name, _kernel=kernel, **kw: (taken.append(_name), _kernel(*a, **kw))[1])
+    return taken
+
+
+@pytest.mark.parametrize(
+    "case,kernel",
+    [("flash-kernel", "flash_attention"), ("decode-kernel", "decode_attention"), ("grouped-einsum", None),
+     ("multi-head-einsum", None), ("int8-einsum", None)],
+)
+def test_attend_reaches_each_path_and_agrees_with_the_plain_reference(case, kernel, monkeypatch):
+    """The five outcomes ``attend`` can reach on the CPU — the flash kernel and
+    the decode kernel (interpret mode), the grouped and the multi-head einsum,
+    the einsum over an int8 cache with its row scales folded in — each taken
+    for the operands that should take it, each equal to ``xla_attention``."""
+    taken = _spy_on_kernels(monkeypatch)
+    operands, want = _attend_case(case)
+    got = attn.attend(**operands, biased=False, prefix=None)
+    assert taken == ([kernel] if kernel else [])
+    assert got.shape == want.shape and got.dtype == operands["q"].dtype
+    # rows with no key to see (a padded query) are the reference's zeros and anyone's guess elsewhere
+    rows = np.asarray(operands["kv_valid"] if operands["kv_valid"] is not None else np.ones(got.shape[:2]), bool)
+    np.testing.assert_allclose(np.asarray(got)[rows], np.asarray(want)[rows], atol=3e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("what", ["alibi", "prefix"])
+@pytest.mark.parametrize("case", ["flash-kernel", "decode-kernel"])
+def test_a_biased_or_prefixed_forward_never_takes_a_kernel(case, what, monkeypatch):
+    """Alibi (a bias on the scores beyond the mask) and prefix tuning (learned
+    rows in front of the keys) keep the einsum whatever ``impl`` says, and the
+    prefix rows are joined in front of the cache with a zero bias."""
+    taken = _spy_on_kernels(monkeypatch)
+    operands, plain = _attend_case(case)
+    q, Hkv, D = operands["q"], operands["k"].shape[2], operands["q"].shape[-1]
+    prefix = None
+    if what == "alibi":
+        slopes = jnp.asarray([0.5, 0.25, 0.125, 0.0625])[None, :, None, None]
+        positions = jnp.arange(operands["mask_bias"].shape[-1], dtype=jnp.float32)[None, None, None, :]
+        operands["mask_bias"] = operands["mask_bias"] + slopes * positions
+    else:
+        rng = np.random.default_rng(5)
+        prefix = tuple(jnp.asarray(rng.normal(size=(3, Hkv, D)), jnp.float32) for _ in range(2))
+    got = attn.attend(**operands, biased=True, prefix=prefix)
+    assert taken == []
+
+    # the same by hand in float32: [prefix; keys] under [0; bias]
+    cache = operands["cache"]
+    kh, vh = (cache["k"], cache["v"]) if cache is not None else (
+        operands["k"].transpose(0, 2, 1, 3), operands["v"].transpose(0, 2, 1, 3))
+    bias = operands["mask_bias"]
+    if prefix is not None:
+        B = q.shape[0]
+        kh = jnp.concatenate([jnp.broadcast_to(prefix[0].transpose(1, 0, 2)[None], (B, Hkv, 3, D)), kh], axis=2)
+        vh = jnp.concatenate([jnp.broadcast_to(prefix[1].transpose(1, 0, 2)[None], (B, Hkv, 3, D)), vh], axis=2)
+        bias = jnp.concatenate([jnp.zeros(bias.shape[:-1] + (3,)), bias], axis=-1)
+    rep = q.shape[2] // Hkv
+    scores = jnp.einsum("bthd,bhsd->bhts", q, jnp.repeat(kh, rep, axis=1)) * operands["scale"] + bias
+    want = jnp.einsum("bhts,bhsd->bthd", jax.nn.softmax(scores, axis=-1), jnp.repeat(vh, rep, axis=1))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want.reshape(got.shape)), atol=3e-5, rtol=1e-5)
+    assert not np.allclose(np.asarray(got), np.asarray(plain), atol=1e-3)  # the bias or the prefix was felt

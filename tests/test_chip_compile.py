@@ -214,7 +214,6 @@ for _preset in ("gpt2", "gpt_bigcode"):  # Hkv=12 rep=1 D=64; Hkv=1 rep=16 D=128
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_kernel_compiles_for_v5e(case, one_chip, no_persistent_cache, monkeypatch):
-    monkeypatch.setattr(attention, "BACKWARD_IMPL", "pallas")  # read at trace time
     # the model asks the backend whether to interpret its kernels; the target here is the chip
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     fn, shapes = CASES[case]()

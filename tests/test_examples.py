@@ -1,7 +1,7 @@
 """Bitrot guard over the example surface (parity: the reference ships 20+ example
 scripts as its integration contract, SURVEY.md §2.2): every example module must
 import cleanly and, where it exposes a config builder, produce a valid TRLConfig.
-Full runs are covered by the slow trainer tests and scripts/benchmark.sh."""
+Full runs are covered by the slow trainer tests."""
 
 import importlib
 import os

@@ -324,7 +324,7 @@ def test_generate_is_the_same_with_the_decode_kernel_and_the_einsum(case):
     different lengths, rows ending on an eos at different steps."""
     import contextlib
 
-    from trlx_tpu.models import transformer
+    from trlx_tpu.ops import attention
     from trlx_tpu.parallel.mesh import make_mesh
 
     overrides, axes, cache_dtype, with_eos = DECODE_KERNEL_CASES[case]
@@ -356,7 +356,9 @@ def test_generate_is_the_same_with_the_decode_kernel_and_the_einsum(case):
         lengths = want["response_mask"].sum(axis=1)
         assert lengths.min() < n_new, lengths
     with mesh or contextlib.nullcontext():
-        placed = transformer.decode_kernel_placement(base.replace(attention_impl="flash"), len(prompts))[0]
+        cache = jax.eval_shape(lambda: TransformerLM(base).init_cache(len(prompts), 8 + n_new))
+        placed = attention.decode_kernel_placement(
+            "flash", base.biased_attention, {"k": cache["k"][0], "v": cache["v"][0]}, base.num_heads)[0]
     assert placed == (case != "mesh-the-heads-do-not-divide")
 
 
@@ -380,11 +382,17 @@ CELL_1 = dict(B=128, prompt_len=64, new_tokens=448, steps=447)  # gpt2.ppo-long-
 def test_cache_read_share_follows_who_takes_the_decode_kernel(overrides, axes, low, high):
     import contextlib
 
-    from trlx_tpu.models.transformer import decode_cache_read_share
+    from trlx_tpu.ops.attention import decode_cache_read_share
     from trlx_tpu.parallel.mesh import make_mesh
 
     config = PRESETS["gpt2"].replace(compute_dtype=jnp.bfloat16, **overrides)
+
+    def share(B, prompt_len, new_tokens, steps):  # as MeshRLTrainer.generate asks
+        return decode_cache_read_share(
+            config.attention_impl, config.biased_attention, config.num_heads,
+            config.cache_layout(B, prompt_len + new_tokens), new_tokens, steps,
+        )
+
     with make_mesh(**axes) if axes else contextlib.nullcontext():
-        share = decode_cache_read_share(config, **CELL_1)
-    assert low <= share <= high
-    assert decode_cache_read_share(config, **{**CELL_1, "steps": 0}) == 1.0
+        assert low <= share(**CELL_1) <= high
+    assert share(**{**CELL_1, "steps": 0}) == 1.0

@@ -379,30 +379,16 @@ def test_learner_overlap_config_roundtrip():
 
     cfg = TrainConfig.from_dict(
         {"learner_overlap": {"enabled": True, "int8_opt_state": True,
-                             "remat": "per_layer", "flash_bwd": "xla"}}
+                             "remat": "per_layer"}}
     )
     assert isinstance(cfg.learner_overlap, LearnerOverlapConfig)
     assert cfg.learner_overlap.enabled
     assert cfg.learner_overlap.int8_opt_state
     assert cfg.learner_overlap.remat == "per_layer"
-    assert cfg.learner_overlap.flash_bwd == "xla"
     assert not TrainConfig.from_dict({}).learner_overlap.enabled
-    assert TrainConfig.from_dict({}).learner_overlap.flash_bwd is None
-
-
-def test_set_flash_backward_roundtrip():
-    # the r02->r05 gpt2_train_mfu bisect knob: selectable flash backward
-    from trlx_tpu.ops import attention as attn
-
-    prev = attn.set_flash_backward("xla")
-    try:
-        assert attn.BACKWARD_IMPL == "xla"
-        assert attn.set_flash_backward("pallas") == "xla"
-        with pytest.raises(ValueError):
-            attn.set_flash_backward("cuda")
-        assert attn.BACKWARD_IMPL == "pallas"  # rejected value left no trace
-    finally:
-        attn.BACKWARD_IMPL = prev
+    # the flash backward is the kernels': a yml that still asks is told which field is unknown
+    with pytest.raises(TypeError, match="flash_bwd"):
+        TrainConfig.from_dict({"learner_overlap": {"enabled": True, "flash_bwd": "xla"}})
 
 
 def test_per_layer_remat_policy_registered():

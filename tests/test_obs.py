@@ -337,10 +337,14 @@ def test_device_memory_stats_always_reports_something():
 # ---------------------------------------------------------------- watchdog
 
 
-def test_watchdog_fires_on_stalled_fake_producer(trlx_caplog):
+def test_watchdog_fires_on_stalled_fake_producer(trlx_caplog, monkeypatch):
     """A deliberately-stalled fake producer (blocked on an Event, like a
     wedged reward RPC) must be detected: structured warning + all-thread
-    stack dump naming the stalled heartbeat."""
+    stack dump naming the stalled heartbeat. The watchdog reads a clock the
+    test moves, so a loaded machine cannot age the healthy heartbeat too."""
+    clock = [100.0]
+    # the module, not the handle of the same name that trlx_tpu.obs exports
+    monkeypatch.setattr(sys.modules[StallWatchdog.__module__], "time", SimpleNamespace(monotonic=lambda: clock[0]))
     release = threading.Event()
 
     def stalled_producer():
@@ -353,7 +357,7 @@ def test_watchdog_fires_on_stalled_fake_producer(trlx_caplog):
     try:
         dog.beat("rollout-producer")
         dog.beat("learner")
-        time.sleep(0.12)
+        clock[0] += 0.12
         dog.beat("learner")  # learner is healthy; only the producer is stale
         with trlx_caplog.at_level(py_logging.WARNING, logger="trlx_tpu.obs.watchdog"):
             dog.check()
@@ -368,7 +372,7 @@ def test_watchdog_fires_on_stalled_fake_producer(trlx_caplog):
         assert dog.stall_count == 1
         dog.beat("rollout-producer")
         dog.beat("learner")
-        time.sleep(0.08)
+        clock[0] += 0.08
         dog.beat("learner")
         dog.check()
         assert dog.stall_count == 2

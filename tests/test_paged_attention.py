@@ -11,7 +11,8 @@ import jax
 import jax.numpy as jnp
 
 from trlx_tpu.models.presets import PRESETS
-from trlx_tpu.models.transformer import TransformerLM, quantize_kv_rows
+from trlx_tpu.models.transformer import TransformerLM
+from trlx_tpu.ops.kv_cache import quantize_kv_rows
 from trlx_tpu.ops.paged_attention import (
     paged_attention_pallas,
     paged_attention_xla,

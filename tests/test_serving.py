@@ -162,10 +162,9 @@ def tiny_engine_parts():
 
 def _reference_generate(model, params, prompts, max_new, eos=None):
     """The one-shot path: ops.generation.generate, greedy."""
-    from trlx_tpu.ops.generation import generate, left_pad_batch, pad_to_bucket
-    from trlx_tpu.serving.engine import PREFILL_LEN_BUCKETS
+    from trlx_tpu.ops.generation import LENGTH_BUCKETS, generate, left_pad_batch, pad_to_bucket
 
-    P = pad_to_bucket(max(len(p) for p in prompts), PREFILL_LEN_BUCKETS)
+    P = pad_to_bucket(max(len(p) for p in prompts), LENGTH_BUCKETS)
     ids, mask = left_pad_batch([np.asarray(p, np.int32) for p in prompts], 0, P)
 
     def step(p, i, m, pos, cache):

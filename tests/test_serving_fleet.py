@@ -60,7 +60,11 @@ TERMINAL_REASONS = {
 
 
 @pytest.fixture(autouse=True)
-def _disarm_chaos():
+def _own_gauges_and_disarm_chaos():
+    # the registry is the process's, and an xdist worker runs several files in one process: an
+    # engine of an earlier file may have left serving/* behind, which these tests assert empty
+    gauges.clear(prefix="serving/")
+    gauges.clear(prefix="fleet/")
     yield
     chaos.configure(None)
 

@@ -15,8 +15,7 @@ index order, the groups for "a collective over mesh axes S" are computable
 from the mesh shape alone — we precompute them for every axis subset and name
 each parsed collective by the matching subset (``fsdp``, ``data+fsdp``, ...),
 falling back to an anonymous ``g<n>x<size>`` signature. These names are the
-budget keys in ``graftcheck-ir-budget.json`` and the bench keys bench.py
-emits, so static budgets and runtime benches share vocabulary.
+budget keys in ``graftcheck-ir-budget.json``.
 """
 
 import itertools
@@ -231,8 +230,7 @@ def parse_collectives(hlo_text: str, mesh) -> Dict[str, Dict[str, int]]:
 
 def measure(lowered: LoweredEntry) -> Dict[str, Any]:
     """The budget-facing measurement record for one compiled entrypoint —
-    exactly what ``graftcheck-ir-budget.json`` commits and what bench.py
-    re-emits as ``ir_*`` keys."""
+    exactly what ``graftcheck-ir-budget.json`` commits."""
     if lowered.compiled is None:
         raise ValueError("measure() needs a compiled entry (compile=True)")
     return {

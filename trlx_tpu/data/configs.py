@@ -846,18 +846,11 @@ class LearnerOverlapConfig:
         overlap step is active (``"nothing_saveable"`` / ``"dots_saveable"``
         / ``"per_layer"`` / ``"full"``); ``None`` keeps the mesh setting.
         Guidance per scale: docs/parallelism.md.
-    :param flash_bwd: flash-attention backward for the learner
-        (``"pallas"`` | ``"xla"``; ``None`` keeps the process default).
-        ``"xla"`` materializes the O(T·S) score matrix — cheap and ~1.4x
-        faster at small context (the r02→r05 gpt2 train-MFU bisect,
-        ``ops/attention.py``); ``"pallas"`` recomputes per block and is
-        mandatory at long context.
     """
 
     enabled: bool = False
     int8_opt_state: bool = False
     remat: Optional[str] = None
-    flash_bwd: Optional[str] = None
 
     @classmethod
     def from_dict(cls, config: Dict[str, Any]):

@@ -9,7 +9,7 @@ replicas (docs/serving.md "Fleet serving").
 - :class:`~trlx_tpu.fleet.ledger.FleetLedger` — fleet-wide per-tenant /
   per-class SLO accounting into the ``fleet/*`` gauge namespace;
 - :func:`~trlx_tpu.fleet.scenario.run_fleet_scenario` — the deterministic
-  fleet chaos harness (tests/test_serving_fleet.py, bench.py ``fleet`` leg).
+  fleet chaos harness (tests/test_serving_fleet.py).
 """
 
 from trlx_tpu.fleet.autoscaler import FleetAutoscaler

@@ -130,9 +130,8 @@ class PPOConfig(MethodConfig):
     # prompts per *generation* device batch during make_experience (defaults to
     # chunk_size). Decode is bandwidth-bound on the weights — every step streams
     # all parameters regardless of batch — so the decode batch wants to be as
-    # wide as memory allows, independently of the reward/scoring chunk. The
-    # batch-width effect is what bench.py's gpt2_rollout_new_tok_s (B=256) vs
-    # gpt2_rollout_new_tok_s_b32 keys record.
+    # wide as memory allows, independently of the reward/scoring chunk (the
+    # benchmark's cells decode 128 and 64 rows and score in chunks of 32).
     decode_batch_size: Optional[int] = None
 
     def kl_controller(self):

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from trlx_tpu.ops import kv_cache
+
 
 @dataclass(frozen=True)
 class T5Config:
@@ -173,10 +175,8 @@ class T5Attention(nn.Module):
             kh = k.transpose(0, 2, 1, 3)
             vh = v.transpose(0, 2, 1, 3)
             if cache is not None:
-                from trlx_tpu.models.transformer import read_kv_cache, write_kv_cache
-
-                new_cache = write_kv_cache(cache, kh, vh, cache["index"])
-                kh, vh = read_kv_cache(new_cache, c.compute_dtype)
+                new_cache = kv_cache.write_kv_cache(cache, kh, vh, cache["index"])
+                kh, vh = kv_cache.read_kv_cache(new_cache, c.compute_dtype)
             else:
                 new_cache = None
         scores = jnp.einsum("bthd,bhsd->bhts", q, kh).astype(jnp.float32)
@@ -465,10 +465,8 @@ class T5LM(nn.Module):
         dtype = dtype or c.compute_dtype
         # per-layer list layout: in-place single-token writes in the decode loop
         # (a stacked [L, ...] array forces full-cache slice/restack copies per step)
-        from trlx_tpu.models.transformer import kv_cache_layout
-
         shape = (batch_size, c.num_heads, max_length, c.d_kv)
-        per_layer = kv_cache_layout(shape, dtype, c.kv_cache_quant)
+        per_layer = kv_cache.kv_cache_layout(shape, dtype, c.kv_cache_quant)
         out = {
             key: [jnp.zeros(shp, dt) for _ in range(c.num_decoder_layers)]
             for key, (shp, dt) in per_layer.items()

@@ -26,17 +26,17 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from trlx_tpu.ops import kv_cache
+from trlx_tpu.ops.attention import attend, flash_placement
 from trlx_tpu.parallel.mesh import BATCH_AXES, MODEL_AXIS, PIPE_AXIS
 from trlx_tpu.parallel.sharding import (
     ambient_mesh,
-    batch_divisible,
     constrain_gathered,
     constrain_seq,
 )
 
-# {"k": ..., "v": ..., "index": i32[]} where k/v are a list of L arrays, each
-# [B,Hkv,S,D] (default: per-layer carries -> in-place decode writes), or one
-# stacked [L,B,Hkv,S,D] array when config.stacked (nn.scan layout)
+# a layer's buffers (ops/kv_cache.py) under their keys, each a list of L arrays (per-layer carries ->
+# in-place decode writes) or one stacked [L, ...] array when config.stacked, plus "index": i32[]
 KVCache = Dict[str, Any]
 
 
@@ -185,6 +185,22 @@ class TransformerConfig:
     @property
     def ffn_dim(self) -> int:
         return self.intermediate_size or 4 * self.hidden_size
+
+    @property
+    def biased_attention(self) -> bool:
+        """Whether scores carry a bias of their own (alibi) or keys learned rows in front
+        (prefix tuning): what no attention kernel takes (``ops.attention.attend``)."""
+        return self.pos_embedding == "alibi" or self.peft_type == "prefix"
+
+    def cache_layout(self, batch_size: int, max_length: int, dtype=None) -> Dict[str, Tuple]:
+        """One layer of the contiguous cache for ``max_length`` tokens a row (``ops/kv_cache.py``)."""
+        dtype = dtype or self.compute_dtype
+        if self.peft_type == "prompt":
+            max_length += self.num_virtual_tokens  # virtual rows live in the cache too
+        if self.attention_kind == "mla":
+            return kv_cache.latent_cache_layout(batch_size, max_length, self.kv_lora_rank, self.qk_rope_head_dim, dtype)
+        shape = (batch_size, self.kv_heads, max_length, self.dim_per_head)
+        return kv_cache.kv_cache_layout(shape, dtype, self.kv_cache_quant)
 
     def residual_init_std(self) -> float:
         """Init std for projections writing into the residual stream
@@ -377,180 +393,6 @@ def merge_lora_params(params: Dict[str, Any], config: "TransformerConfig") -> Di
     return walk(params)
 
 
-def quantize_kv_rows(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Symmetric per-row int8 quantization over the trailing (head) dim:
-    x [..., D] -> (int8 values [..., D], f32 scales [..., 1])."""
-    amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    scale = jnp.maximum(amax, 1e-8) / 127.0
-    q = jnp.clip(jnp.round(x.astype(jnp.float32) / scale), -127, 127).astype(jnp.int8)
-    return q, scale
-
-
-def write_kv_cache(cache: Dict[str, jnp.ndarray], kT: jnp.ndarray, vT: jnp.ndarray, idx):
-    """Append [B,H,T,D] rows at slot ``idx``; quantizes when the cache carries
-    scale planes (kv_cache_quant layout). Shared by the causal and T5 decoders —
-    the quant scheme must stay identical between them."""
-    at = (0, 0, idx, 0)
-    if "k_scale" in cache:
-        kq, ks = quantize_kv_rows(kT)
-        vq, vs = quantize_kv_rows(vT)
-        return {
-            "k": jax.lax.dynamic_update_slice(cache["k"], kq, at),
-            "v": jax.lax.dynamic_update_slice(cache["v"], vq, at),
-            "k_scale": jax.lax.dynamic_update_slice(cache["k_scale"], ks, at),
-            "v_scale": jax.lax.dynamic_update_slice(cache["v_scale"], vs, at),
-        }
-    return {
-        "k": jax.lax.dynamic_update_slice(cache["k"], kT.astype(cache["k"].dtype), at),
-        "v": jax.lax.dynamic_update_slice(cache["v"], vT.astype(cache["v"].dtype), at),
-    }
-
-
-def read_kv_cache(cache: Dict[str, jnp.ndarray], compute_dtype):
-    """(kh, vh) to attend over; int8 caches dequantize on read — XLA fuses the
-    convert+scale into the score einsum's operand stream, so HBM moves int8."""
-    if "k_scale" in cache:
-        # multiply int8 values by the f32 scale at full precision, THEN cast:
-        # casting the scale to bf16 first would truncate it to 8 mantissa bits
-        # and stack avoidable error on top of the int8 quantization
-        return (
-            (cache["k"].astype(jnp.float32) * cache["k_scale"]).astype(compute_dtype),
-            (cache["v"].astype(jnp.float32) * cache["v_scale"]).astype(compute_dtype),
-        )
-    return cache["k"], cache["v"]
-
-
-def kv_cache_layout(shape: Tuple[int, ...], dtype, quant: bool) -> Dict[str, Tuple]:
-    """Per-layer cache buffers as {key: (shape, dtype)} — int8 values + one f32
-    scale per row when ``quant``."""
-    if quant:
-        return {
-            "k": (shape, jnp.int8), "v": (shape, jnp.int8),
-            "k_scale": (shape[:-1] + (1,), jnp.float32),
-            "v_scale": (shape[:-1] + (1,), jnp.float32),
-        }
-    return {"k": (shape, dtype), "v": (shape, dtype)}
-
-
-def _kernel_shards(mesh) -> Tuple[int, int]:
-    """(shards of the batch, shards of the heads) a kernel placed over ``mesh``
-    (None: a plain call) is cut into."""
-    if mesh is None:
-        return 1, 1
-    return int(np.prod([mesh.shape.get(a, 1) for a in BATCH_AXES])), mesh.shape.get(MODEL_AXIS, 1)
-
-
-def _kernel_placement(c: TransformerConfig, B: int, heads: int, kv_heads: int):
-    """(whether a Pallas attention kernel may run for this configuration and
-    shape, the mesh to place it over or None for a plain call)."""
-    use = (
-        c.attention_impl == "flash"
-        and c.pos_embedding != "alibi"  # the kernels take no additive score bias
-        and c.peft_type != "prefix"  # prefix keys break the kernels' slot arithmetic
-    )
-    # Mosaic kernels cannot be auto-partitioned by XLA SPMD: on a
-    # multi-device mesh the call must be placed explicitly (batch and
-    # head axes are embarrassingly parallel) via shard_map, and a shape
-    # that cannot divide those axes falls back to the einsum paths.
-    mesh = None
-    if use:
-        mesh = ambient_mesh()
-        if mesh is not None:
-            n_batch, n_model = _kernel_shards(mesh)
-            if mesh.size == 1:
-                # single device: plain call. (Any larger mesh must go via
-                # the shard_map wrapper even when batch/model axes are
-                # trivial — e.g. a pipe-only mesh still has an auto axis
-                # the Mosaic kernel cannot sit under.)
-                mesh = None
-            elif B % n_batch or heads % n_model or kv_heads % n_model:
-                use = False  # kernel cannot place; XLA attention
-    return use, mesh
-
-
-def _flash_placement(c: TransformerConfig, B: int, T: int, kv_valid, heads: int, kv_heads: int):
-    """(whether this forward takes the flash kernel, the mesh to place it over
-    or None for a plain call).
-
-    The flash path serves every multi-token forward: training loss, the
-    logprob/value scoring passes, AND generation prefill. With a cache present,
-    a non-None kv_valid IS the prefill-from-zero marker: TransformerLM only
-    passes it when the cache index was a concrete 0 at trace time (checked
-    there, outside the remat wrapper — inside a block cache["index"] may be a
-    remat tracer even at prefill)."""
-    if kv_valid is None or T <= 1:
-        return False, None
-    return _kernel_placement(c, B, heads, kv_heads)
-
-
-def _kernel_target(mesh) -> str:
-    """The platform a kernel is compiled for: interpret (XLA-emulated) mode iff
-    it is the CPU. The ambient mesh's devices name the target; default_backend
-    alone is wrong under deviceless TPU AOT compilation
-    (scripts/scale_proof.py runs with a CPU host backend but lowers for a TPU
-    topology, where interpret mode would re-materialize the score matrices the
-    kernel exists to avoid)."""
-    return mesh.devices.flat[0].platform if mesh is not None else jax.default_backend()
-
-
-def _flash(q, kh, vh, kv_valid, scale: float, flash_mesh):
-    """q, kh, vh [B, heads, T, D] through the flash kernel, plainly or placed
-    over ``flash_mesh``; the kernel maps query head h -> kv head h // rep
-    natively, so grouped K/V are never materialized at full head count."""
-    from trlx_tpu.ops.attention import flash_attention, flash_attention_sharded
-
-    target = _kernel_target(flash_mesh)
-    if flash_mesh is not None:
-        return flash_attention_sharded(
-            q, kh, vh, kv_valid, True, scale, target == "cpu", flash_mesh, BATCH_AXES, MODEL_AXIS,
-        )
-    return flash_attention(q, kh, vh, kv_valid, True, scale, target == "cpu")
-
-
-def decode_kernel_placement(c: TransformerConfig, B: int):
-    """(whether a single-token step of ``B`` rows over the contiguous cache
-    takes the Pallas decode kernel, the mesh to place it over or None): the
-    flash kernels' rule, and per-head float rows in the cache. Everything else
-    — ``attention_impl="xla"``, the int8 cache, alibi, prefix tuning, latent
-    attention (its own absorbed decode), a mesh the call cannot be placed over
-    — keeps the einsum path."""
-    if c.attention_kind == "mla" or c.kv_cache_quant:
-        return False, None
-    return _kernel_placement(c, B, c.num_heads, c.kv_heads)
-
-
-def decode_cache_read_share(c: TransformerConfig, B: int, prompt_len: int, new_tokens: int, steps: int) -> float:
-    """Cache slots the decode steps of one rollout visited over the slots the
-    cache holds (``rollout/cache_read_share``): host arithmetic from the
-    prompt's bucket, the ``steps`` the decode loop ran and the kernel's block;
-    1.0 where the steps took the einsum path. Call under the trainer's mesh."""
-    from trlx_tpu.ops.attention import cache_read_share, choose_decode_tiles
-
-    use, mesh = decode_kernel_placement(c, B)
-    if not use:
-        return 1.0
-    n_batch, n_model = _kernel_shards(mesh)
-    virtual = c.num_virtual_tokens if c.peft_type == "prompt" else 0  # they live in the cache too
-    cache_len = prompt_len + new_tokens + virtual
-    tiles = choose_decode_tiles(
-        B // n_batch, c.kv_heads // n_model, c.num_heads // c.kv_heads, cache_len, c.dim_per_head, c.compute_dtype
-    )
-    return cache_read_share(prompt_len + virtual, steps, cache_len, tiles.block)
-
-
-def _decode(q, ck, cv, mask_bias, index, scale: float, mesh):
-    """q [B, heads, D] over the cache ck, cv [B, kv heads, S, D] up to slot
-    ``index`` through the decode kernel, plainly or placed over ``mesh``."""
-    from trlx_tpu.ops.attention import decode_attention, decode_attention_sharded
-
-    interpret = _kernel_target(mesh) == "cpu"
-    if mesh is not None:
-        return decode_attention_sharded(
-            q, ck, cv, mask_bias, index, scale, interpret, mesh, BATCH_AXES, MODEL_AXIS
-        )
-    return decode_attention(q, ck, cv, mask_bias, index, scale, interpret)
-
-
 class Attention(nn.Module):
     config: TransformerConfig
 
@@ -564,11 +406,10 @@ class Attention(nn.Module):
         kv_valid: Optional[jnp.ndarray] = None,
     ) -> Tuple[jnp.ndarray, Optional[Dict[str, jnp.ndarray]]]:
         """x: [B,T,Hid]; mask_bias additive [B,1,T,S]; cache holds this layer's k/v
-        [B,Hkv,S,D] plus the global write index. ``kv_valid`` [B,T] enables the
-        Pallas flash path on any multi-token forward — cache-free (training /
-        scoring) or generation prefill (cache written from slot 0, attention over
-        the prefix k/v only); single-token decode steps take the Pallas decode
-        kernel over the cache up to the write index."""
+        [B,Hkv,S,D] plus the global write index. ``kv_valid`` [B,T] marks a
+        multi-token forward whose keys are its own tokens — cache-free (training /
+        scoring) or generation prefill (cache written from slot 0). Which path the
+        attention takes is ``ops.attention.attend``'s to decide."""
         c = self.config
         B, T, _ = x.shape
         dense = lambda feats, name, bias, std=c.initializer_range: LoraDense(
@@ -589,7 +430,7 @@ class Attention(nn.Module):
             q = apply_rotary(q, cos, sin, c.rope_style)
             k = apply_rotary(k, cos, sin, c.rope_style)
 
-        if cache is not None and "block_tables" in cache:
+        if cache is not None and kv_cache.is_paged(cache):
             # Paged step (serving engine) against the block-pool cache. T == 1
             # is the steady-state decode: the new row lands at position
             # context_lens (its block is always exclusively owned — the
@@ -633,158 +474,25 @@ class Attention(nn.Module):
             out = dense(c.hidden_size, "o_proj", c.attn_bias, res_std)(out)
             return out, new_cache
 
+        new_cache = index = None
         if cache is not None:
-            idx = cache["index"]
-            # cache layout [B, Hkv, S, D]: per-(b,h) keys are contiguous along S,
-            # so the decode matvec streams them sequentially. The former
-            # [B, S, Hkv, D] layout made XLA materialize a transposed copy of
-            # every layer's cache every decode step (profiled on one v5e chip:
-            # ~60us copy + ~60us strided reduce per layer per step).
-            new_cache = write_kv_cache(
-                cache, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), idx
-            )
-            ck, cv = new_cache["k"], new_cache["v"]
-        else:
-            new_cache = None
-
-        # A single-token step over the contiguous cache takes the Pallas decode
-        # kernel, which reads the cache up to the write index only; who takes
-        # it is decided by what is visible here (decode_kernel_placement), and
-        # appends of several tokens keep the einsum path below like everything
-        # that rule leaves out. (A kernel with a grid of (B, Hkv) programs of
-        # 32 KB each lost to the einsum by 1.3x at B=32 S=256 before PR 1;
-        # this one, 2026-10-02, the batch on the lanes and 1.5 MB an operand a
-        # program: 2.38 against 4.13 ms a 12-layer step at B=128 S=512 on one
-        # v5e, PERF.md §6, PR 30.)
-        if cache is not None and T == 1 and "k_scale" not in new_cache:
-            use_kernel, kernel_mesh = decode_kernel_placement(c, B)
-            if use_kernel:
-                out = _decode(q[:, 0], ck, cv, mask_bias, idx, 1.0 / math.sqrt(c.dim_per_head), kernel_mesh)
-                out = out.reshape(B, T, c.num_heads * c.dim_per_head).astype(c.compute_dtype)
-                out = dense(c.hidden_size, "o_proj", c.attn_bias, res_std)(out)
-                return out, new_cache
-
-        # For prefill (cache present, T > 1, writes starting at slot 0) attention
-        # over the just-computed prefix k/v is exactly attention over the cache,
-        # since all cache slots >= T are still empty; k/v are written to the
-        # cache above regardless. The slot-0 requirement is enforced
-        # structurally: the cache index must be a concrete 0 at trace time (true
-        # for generate()'s prefill, never true inside the decode while_loop or
-        # for chunked appends, which fall back to attending over the full cache
-        # via XLA).
-        use_flash, flash_mesh = _flash_placement(c, B, T, kv_valid, c.num_heads, c.kv_heads)
-        # kh/vh [B, Hkv, S, D]: the layout attention consumes (and the cache layout)
-        k_row_scale = v_row_scale = None
-        if cache is not None and not use_flash:
-            # attend over the cache (decode step / XLA prefill)
-            if "k_scale" in new_cache and c.peft_type != "prefix":
-                # int8 cache: bare dtype convert only — the per-row scales fold
-                # into the scores (k) and the softmax weights (v) below, which
-                # is algebraically identical to dequantizing the operands but
-                # leaves the big K/V streams a pure int8->bf16 cast XLA fuses
-                # into the dot (the dequant multiply on the operand blocked
-                # that fusion: int8 decode measured only 1.16x plain bf16 at
-                # B=256 despite moving half the bytes). int8 values are exact
-                # in bf16, and the scale multiply happens in f32 on the small
-                # score/prob tensors — strictly less rounding than the old
-                # per-element dequant-to-bf16. (Prefix tuning prepends
-                # scale-less rows, so it keeps the dequant-on-read path.)
-                kh = new_cache["k"].astype(c.compute_dtype)
-                vh = new_cache["v"].astype(c.compute_dtype)
-                k_row_scale = new_cache["k_scale"]  # [B, Hkv, S, 1] f32
-                v_row_scale = new_cache["v_scale"]
-            else:
-                kh, vh = read_kv_cache(new_cache, c.compute_dtype)
-        else:
-            kh = k.transpose(0, 2, 1, 3)
-            vh = v.transpose(0, 2, 1, 3)
-
-        # prefix tuning: learned per-layer K/V prepended to whatever we attend
-        # over (never cached — they are static), visible to every query (zero
-        # bias). No positions are consumed and no rotary is applied to them
-        # (parity: peft PREFIX_TUNING past_key_values, modeling_base.py:162-240).
+            index = cache["index"]
+            new_cache = kv_cache.write_kv_cache(cache, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), index)
+        # prefix tuning: learned per-layer K/V (never cached — they are static),
+        # joined by attend in front of whatever it attends over
+        prefix = None
         if c.peft_type == "prefix" and c.num_virtual_tokens > 0:
-            nv = c.num_virtual_tokens
-            pk = self.param(
-                "prefix_k", nn.initializers.normal(c.initializer_range),
-                (nv, c.kv_heads, c.dim_per_head), c.param_dtype,
+            prefix = tuple(
+                self.param(
+                    name, nn.initializers.normal(c.initializer_range),
+                    (c.num_virtual_tokens, c.kv_heads, c.dim_per_head), c.param_dtype,
+                )
+                for name in ("prefix_k", "prefix_v")
             )
-            pv = self.param(
-                "prefix_v", nn.initializers.normal(c.initializer_range),
-                (nv, c.kv_heads, c.dim_per_head), c.param_dtype,
-            )
-            shape = (B, c.kv_heads, nv, c.dim_per_head)
-            kh = jnp.concatenate(
-                [jnp.broadcast_to(pk.astype(kh.dtype).transpose(1, 0, 2)[None], shape), kh], axis=2
-            )
-            vh = jnp.concatenate(
-                [jnp.broadcast_to(pv.astype(vh.dtype).transpose(1, 0, 2)[None], shape), vh], axis=2
-            )
-            mask_bias = jnp.concatenate(
-                [jnp.zeros(mask_bias.shape[:-1] + (nv,), mask_bias.dtype), mask_bias], axis=-1
-            )
-
-        scale = 1.0 / math.sqrt(c.dim_per_head)
-
-        if (
-            c.attention_impl == "ring"
-            and cache is None
-            and kv_valid is not None
-            and c.pos_embedding != "alibi"
-            and c.peft_type != "prefix"
-        ):
-            from trlx_tpu.ops.ring_attention import ring_attention
-
-            mesh = ambient_mesh()
-            n = mesh.shape.get(MODEL_AXIS, 1) if mesh is not None else 1
-            if mesh is not None and n > 1 and T % n == 0 and batch_divisible(mesh, B):
-                # grouped K/V ride the ring at native head count (no repeat)
-                out = ring_attention(
-                    q.transpose(0, 2, 1, 3), kh, vh,
-                    mesh, axis_name=MODEL_AXIS, causal=True, scale=scale,
-                    kv_valid=kv_valid, batch_axes=BATCH_AXES,
-                ).transpose(0, 2, 1, 3).astype(c.compute_dtype)
-                out = out.reshape(B, T, c.num_heads * c.dim_per_head)
-                out = dense(c.hidden_size, "o_proj", c.attn_bias, res_std)(out)
-                return out, new_cache
-            # fall through to XLA when the mesh/shape can't ring
-
-        if use_flash:
-            out = _flash(q.transpose(0, 2, 1, 3), kh, vh, kv_valid, scale, flash_mesh)
-            out = out.transpose(0, 2, 1, 3).astype(c.compute_dtype)
-        elif c.kv_heads != c.num_heads:
-            # grouped-query einsum: batch scores over kv heads with the group as
-            # a free axis — the old jnp.repeat path copied the whole K/V cache to
-            # full head count every decode step, multiplying HBM traffic by
-            # num_heads/kv_heads on exactly the GQA models it targets
-            rep = c.num_heads // c.kv_heads
-            qg = q.reshape(B, T, c.kv_heads, rep, c.dim_per_head)
-            scores = jnp.einsum("btkrd,bksd->bkrts", qg, kh).astype(jnp.float32) * scale
-            if k_row_scale is not None:
-                scores = scores * k_row_scale[..., 0][:, :, None, None, :]
-            bias = (
-                mask_bias[:, :, None]
-                if mask_bias.shape[1] == 1
-                else mask_bias.reshape(B, c.kv_heads, rep, *mask_bias.shape[2:])
-            )
-            probs = jax.nn.softmax(scores + bias, axis=-1)
-            if v_row_scale is not None:
-                probs = probs * v_row_scale[..., 0][:, :, None, None, :]
-            probs = probs.astype(c.compute_dtype)
-            # btkrd order flattens to head h = k*rep + r, matching the q reshape
-            out = jnp.einsum("bkrts,bksd->btkrd", probs, vh)
-        else:
-            # [B,H,T,S]
-            scores = jnp.einsum("bthd,bhsd->bhts", q, kh).astype(jnp.float32) * scale
-            if k_row_scale is not None:
-                scores = scores * k_row_scale[..., 0][:, :, None, :]
-            scores = scores + mask_bias
-            probs = jax.nn.softmax(scores, axis=-1)
-            if v_row_scale is not None:
-                probs = probs * v_row_scale[..., 0][:, :, None, :]
-            probs = probs.astype(c.compute_dtype)
-            out = jnp.einsum("bhts,bhsd->bthd", probs, vh)
-        out = out.reshape(B, T, c.num_heads * c.dim_per_head)
+        out = attend(
+            q, k, v, new_cache, mask_bias, kv_valid, index,
+            1.0 / math.sqrt(c.dim_per_head), c.attention_impl, c.biased_attention, prefix,
+        )
         out = dense(c.hidden_size, "o_proj", c.attn_bias, res_std)(out)
         return out, new_cache
 
@@ -800,15 +508,6 @@ class _Kernel(nn.Module):
     @nn.compact
     def __call__(self) -> jnp.ndarray:
         return self.param("kernel", nn.initializers.normal(self.std), self.shape, self.param_dtype)
-
-
-def latent_cache_layout(batch_size: int, max_length: int, config: TransformerConfig, dtype) -> Dict[str, Tuple]:
-    """A latent-attention layer's cache buffers as {key: (shape, dtype)}: the
-    normed latent and the rotated shared key of every token."""
-    return {
-        "c": ((batch_size, max_length, config.kv_lora_rank), dtype),
-        "k_rope": ((batch_size, max_length, config.qk_rope_head_dim), dtype),
-    }
 
 
 _MLA_REFUSALS = {
@@ -842,7 +541,7 @@ class LatentAttention(nn.Module):
         c = self.config
         if c.peft_type == "prefix":
             raise ValueError("prefix tuning prepends per-head keys and values; latent attention has none")
-        if cache is not None and "block_tables" in cache:
+        if cache is not None and kv_cache.is_paged(cache):
             raise ValueError(_MLA_REFUSALS["paged"])
         B, T, _ = x.shape
         H, nope, rope, vdim, rank = c.num_heads, c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim, c.kv_lora_rank
@@ -867,30 +566,17 @@ class LatentAttention(nn.Module):
 
             new_cache = None
             if cache is not None:
-                at = (0, cache["index"], 0)
-                new_cache = {
-                    "c": jax.lax.dynamic_update_slice(cache["c"], latent.astype(cache["c"].dtype), at),
-                    "k_rope": jax.lax.dynamic_update_slice(
-                        cache["k_rope"], k_rope[:, :, 0].astype(cache["k_rope"].dtype), at),
-                }
-            use_flash, flash_mesh = _flash_placement(c, B, T, kv_valid, H, H)
-            expanded = cache is None or use_flash  # per-head keys and values of this forward's own tokens
+                new_cache = kv_cache.write_latent_cache(cache, latent, k_rope, cache["index"])
+            # per-head keys and values of this forward's own tokens: cache-free, or a prefill the flash kernel takes
+            expanded = cache is None or flash_placement(c.attention_impl, c.biased_attention, B, T, kv_valid, H, H)[0]
             if expanded:
                 kv = jnp.einsum("btl,lhm->bthm", latent, w_kvb)
                 k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(k_rope, (B, T, H, rope))], axis=-1)
                 q = jnp.concatenate([q_nope, q_rope], axis=-1)
                 v = kv[..., nope:]
         if expanded:
-            if use_flash:
-                # outside the "mla" scope: the kernels keep the module's name, %attn.N
-                out = _flash(
-                    q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
-                    kv_valid, scale, flash_mesh,
-                ).transpose(0, 2, 1, 3)
-            else:
-                scores = jnp.einsum("bthd,bshd->bhts", q, k).astype(jnp.float32) * scale + mask_bias
-                probs = jax.nn.softmax(scores, axis=-1).astype(c.compute_dtype)
-                out = jnp.einsum("bhts,bshd->bthd", probs, v)
+            # outside the "mla" scope: the kernels keep the module's name, %attn.N
+            out = attend(q, k, v, None, mask_bias, kv_valid, None, scale, c.attention_impl, c.biased_attention, None)
         else:
             with jax.named_scope("mla"):  # absorbed, over the cache
                 ck, kr = new_cache["c"].astype(c.compute_dtype), new_cache["k_rope"].astype(c.compute_dtype)
@@ -1369,18 +1055,11 @@ class TransformerLM(nn.Module):
 
     def init_cache(self, batch_size: int, max_length: int, dtype=None) -> KVCache:
         c = self.config
-        dtype = dtype or c.compute_dtype
-        if c.peft_type == "prompt":
-            max_length += c.num_virtual_tokens  # virtual rows live in the cache too
+        per_layer = c.cache_layout(batch_size, max_length, dtype)
         if c.attention_kind == "mla":
             from trlx_tpu.utils.metrics import gauges
 
-            per_layer = latent_cache_layout(batch_size, max_length, c, dtype)
-            gauges.set("mla/cache_bytes_per_token", c.num_layers * sum(
-                shp[-1] * jnp.dtype(dt).itemsize for shp, dt in per_layer.values()))
-        else:
-            shape = (batch_size, c.kv_heads, max_length, c.dim_per_head)
-            per_layer = kv_cache_layout(shape, dtype, c.kv_cache_quant)
+            gauges.set("mla/cache_bytes_per_token", c.num_layers * kv_cache.bytes_per_token(per_layer))
         if c.stacked:
             # nn.scan layout needs one [L, ...] array per k/v
             out = {

@@ -5,8 +5,8 @@ this is the TPU-native equivalent. Forward is an online-softmax (FlashAttention-
 Pallas kernel; backward is the standard recompute scheme (two kernels, as in the
 in-tree TPU flash attention): the forward saves only O and the per-row logsumexp and
 the backward recomputes P = exp(S - L) tile by tile, so training memory is O(T·tile)
-rather than the O(T·S) score matrix. The XLA fallback is kept behind
-``BACKWARD_IMPL`` and used for grad-parity tests.
+rather than the O(T·S) score matrix. :func:`xla_attention` is the plain reference
+the kernels' outputs and gradients are tested against.
 
 How the work is cut into programs is decided from the shape by one pure function,
 :func:`choose_tiles`. Where the whole padded sequence fits VMEM — every length RL
@@ -49,38 +49,23 @@ along the lanes) and subtracts the rows as they are. The key mask travels as
 
 import functools
 import math
-import os
-from typing import NamedTuple, Optional
+from typing import NamedTuple, Optional, Tuple
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from trlx_tpu.ops import kv_cache
+from trlx_tpu.parallel.mesh import BATCH_AXES, MODEL_AXIS
+from trlx_tpu.parallel.sharding import ambient_mesh, batch_divisible
 from trlx_tpu.utils import logging
 
 logger = logging.get_logger(__name__)
 
 NEG_INF = -1e30
-
-# "pallas" (default) or "xla": which backward the flash custom_vjp traces.
-# Pallas recomputes attention per tile from the saved logsumexp — O(T·tile)
-# memory, mandatory at long context. The XLA O(T·S) recompute is kept for
-# grad-parity tests and as `learner_overlap.flash_bwd`; it has not been timed
-# against the kernels since their tiles come from the shape (ROADMAP D5). Pick
-# via set_flash_backward / TRLX_FLASH_BWD.
-BACKWARD_IMPL = os.environ.get("TRLX_FLASH_BWD", "pallas")
-
-
-def set_flash_backward(impl: str) -> str:
-    """Select the flash-attention backward ("pallas" | "xla") for subsequent
-    traces; returns the previous value. The choice is captured at trace time,
-    so set it before the train step is first jitted."""
-    global BACKWARD_IMPL
-    if impl not in ("pallas", "xla"):
-        raise ValueError(f"flash backward must be 'pallas' or 'xla', got {impl!r}")
-    prev, BACKWARD_IMPL = BACKWARD_IMPL, impl
-    return prev
 
 LANE = 128  # lanes of a vector register; the lane side of every score tile is a multiple
 # What a program costs to start, in score elements (0.4 us of a v5e program against
@@ -769,16 +754,7 @@ def _fwd(q, k, v, kv_valid, causal, scale, interpret):
 def _bwd(causal, scale, interpret, res, g):
     q, k, v, kv_valid, out, lse = res
     scale_ = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-
-    if BACKWARD_IMPL == "pallas":
-        dq, dk, dv = _flash_backward(q, k, v, kv_valid, out, lse, g, causal, scale_, interpret)
-        return dq, dk, dv, None
-
-    def ref(q, k, v):
-        return xla_attention(q, k, v, kv_valid, causal, scale_)
-
-    _, vjp = jax.vjp(ref, q, k, v)
-    dq, dk, dv = vjp(g)
+    dq, dk, dv = _flash_backward(q, k, v, kv_valid, out, lse, g, causal, scale_, interpret)
     return dq, dk, dv, None
 
 
@@ -1010,11 +986,14 @@ def decode_attention(
     return out.transpose(3, 0, 1, 2).reshape(B, H, Dv)
 
 
-def _placed(local, mesh, batch_axes, head_axis, operands, out_ndim: int):
-    """SPMD placement for a Pallas attention call: Mosaic kernels cannot be
-    auto-partitioned by XLA's SPMD pass (it raises at compile time on any
-    multi-device mesh), so shard the embarrassingly-parallel grid axes
-    explicitly — batch over ``batch_axes``, heads over ``head_axis`` — and run
+# ---- dispatch: a model states what it attends over (:func:`attend`); which path, over which mesh, is decided here
+
+
+def _placed(local, mesh, operands, out_ndim: int):
+    """``local(*arrays)`` plainly where ``mesh`` is None, else its SPMD
+    placement over ``mesh``: Mosaic kernels cannot be auto-partitioned by XLA's
+    SPMD pass (it raises at compile time on any multi-device mesh), so shard
+    the embarrassingly-parallel grid axes explicitly — batch over ``BATCH_AXES``, heads over ``MODEL_AXIS`` — and run
     the kernel per shard inside a ``shard_map``. No cross-shard terms exist:
     each (batch, head) pair's softmax is independent, and the grouped-KV head
     map stays consistent because H_local/Hkv_local equals the global ratio
@@ -1024,6 +1003,8 @@ def _placed(local, mesh, batch_axes, head_axis, operands, out_ndim: int):
     ``operands``: ``(array, has_heads)`` pairs — dimension 0 of an array is the
     batch, dimension 1 the heads where ``has_heads``; a scalar is replicated.
     The result has ``out_ndim`` dimensions, batch then heads first."""
+    if mesh is None:
+        return local(*(x for x, _ in operands))
     from jax.sharding import PartitionSpec as P
 
     # The map must be manual over EVERY mesh axis the SPMD partitioner would
@@ -1050,9 +1031,8 @@ def _placed(local, mesh, batch_axes, head_axis, operands, out_ndim: int):
     # over the pipe-sharded stacked layer params ("slice dim size 4096 greater
     # than dynamic slice dimension: 2048", v5e compiler, scripts/scale_proof.py)
     # — and prefill under a pipe mesh is a once-per-generation cost.
-    batch_entry = tuple(batch_axes) if isinstance(batch_axes, tuple) else (batch_axes,)
-    batch_entry = tuple(a for a in batch_entry if a in axes)
-    head_entry = head_axis if head_axis in axes else None
+    batch_entry = tuple(a for a in BATCH_AXES if a in axes)
+    head_entry = MODEL_AXIS if MODEL_AXIS in axes else None
 
     def spec(ndim, has_heads):
         entries = [batch_entry or None, head_entry if has_heads else None] + [None] * (ndim - 2)
@@ -1064,28 +1044,227 @@ def _placed(local, mesh, batch_axes, head_axis, operands, out_ndim: int):
     )(*(x for x, _ in operands))
 
 
-def flash_attention_sharded(
-    q, k, v, kv_valid, causal: bool, scale: Optional[float], interpret: bool,
-    mesh, batch_axes, head_axis,
-):
-    """:func:`flash_attention` placed over a multi-device mesh (:func:`_placed`)."""
-
-    def local(q, k, v, kv_valid):
-        return flash_attention(q, k, v, kv_valid, causal, scale, interpret)
-
-    return _placed(
-        local, mesh, batch_axes, head_axis, [(q, True), (k, True), (v, True), (kv_valid, False)], out_ndim=4
-    )
+def _kernel_shards(mesh) -> Tuple[int, int]:
+    """(shards of the batch, shards of the heads) a kernel placed over ``mesh``
+    (None: a plain call) is cut into."""
+    if mesh is None:
+        return 1, 1
+    return int(np.prod([mesh.shape.get(a, 1) for a in BATCH_AXES])), mesh.shape.get(MODEL_AXIS, 1)
 
 
-def decode_attention_sharded(
-    q, k, v, mask_bias, index, scale: Optional[float], interpret: bool, mesh, batch_axes, head_axis,
-):
-    """:func:`decode_attention` placed over a multi-device mesh (:func:`_placed`):
-    every shard reads its rows' and heads' part of the cache up to the same index."""
+def _kernel_placement(impl: str, biased: bool, B: int, heads: int, kv_heads: int):
+    """(whether a Pallas attention kernel may run for these operands, the mesh
+    to place it over or None for a plain call). ``biased``: the scores carry an
+    additive bias of their own (alibi) or rows are prepended to the keys
+    (prefix tuning) — the kernels take neither."""
+    use = impl == "flash" and not biased
+    # On a multi-device mesh the call must be placed explicitly (_placed), and
+    # a shape that cannot divide its axes falls back to the einsum paths.
+    mesh = None
+    if use:
+        mesh = ambient_mesh()
+        if mesh is not None:
+            n_batch, n_model = _kernel_shards(mesh)
+            if mesh.size == 1:
+                # single device: plain call. (Any larger mesh goes via the
+                # shard_map even when batch/model axes are trivial — a pipe-only
+                # mesh still has an auto axis the Mosaic kernel cannot sit under.)
+                mesh = None
+            elif B % n_batch or heads % n_model or kv_heads % n_model:
+                use = False  # kernel cannot place; XLA attention
+    return use, mesh
 
-    def local(q, k, v, mask_bias, index):
-        return decode_attention(q, k, v, mask_bias, index, scale, interpret)
 
-    operands = [(q, True), (k, True), (v, True), (mask_bias, False), (jnp.asarray(index, jnp.int32), False)]
-    return _placed(local, mesh, batch_axes, head_axis, operands, out_ndim=3)
+def flash_placement(impl: str, biased: bool, B: int, T: int, kv_valid, heads: int, kv_heads: int):
+    """(whether this forward takes the flash kernel, the mesh to place it over
+    or None for a plain call): every multi-token forward whose keys are its own
+    tokens (:func:`attend` on ``kv_valid``) — training loss, the logprob/value
+    scoring passes and generation prefill."""
+    if kv_valid is None or T <= 1:
+        return False, None
+    return _kernel_placement(impl, biased, B, heads, kv_heads)
+
+
+def decode_kernel_placement(impl: str, biased: bool, layer, heads: int):
+    """(whether a single-token step over a layer's contiguous cache — ``layer``
+    holds its arrays, concrete or abstract — takes the Pallas decode kernel,
+    the mesh to place it over or None): the flash kernels' rule, and per-head
+    float rows in the cache. Everything else — ``impl="xla"``, the int8 cache,
+    alibi, prefix tuning, a latent cache (its own absorbed decode), a mesh the
+    call cannot be placed over — keeps the einsum path."""
+    if kv_cache.is_latent(layer) or kv_cache.has_row_scales(layer):
+        return False, None
+    B, kv_heads = layer["k"].shape[:2]
+    return _kernel_placement(impl, biased, B, heads, kv_heads)
+
+
+def decode_cache_read_share(impl: str, biased: bool, heads: int, layout, new_tokens: int, steps: int) -> float:
+    """Cache slots the decode steps of one rollout visited over the slots the
+    cache holds (``rollout/cache_read_share``): host arithmetic from a layer's
+    ``layout`` (``ops/kv_cache.py``; what is not for ``new_tokens`` was
+    prefilled), the ``steps`` the decode loop ran and the kernel's block; 1.0
+    where the steps took the einsum path. Call under the trainer's mesh."""
+    layer = {key: jax.ShapeDtypeStruct(shape, dtype) for key, (shape, dtype) in layout.items()}
+    use, mesh = decode_kernel_placement(impl, biased, layer, heads)
+    if not use:
+        return 1.0
+    B, kv_heads, cache_len, D = layer["k"].shape
+    n_batch, n_model = _kernel_shards(mesh)
+    tiles = choose_decode_tiles(B // n_batch, kv_heads // n_model, heads // kv_heads, cache_len, D, layer["k"].dtype)
+    return cache_read_share(cache_len - new_tokens, steps, cache_len, tiles.block)
+
+
+def _interpret(mesh) -> bool:
+    """Interpret (XLA-emulated) mode iff a kernel is compiled for the CPU. The
+    ambient mesh's devices name the target; default_backend alone is wrong
+    under deviceless TPU AOT compilation (scripts/scale_proof.py lowers for a
+    TPU topology from a CPU host, where interpret mode would re-materialize
+    the score matrices the kernel exists to avoid)."""
+    return (mesh.devices.flat[0].platform if mesh is not None else jax.default_backend()) == "cpu"
+
+
+def attend(q, k, v, cache, mask_bias, kv_valid, index, scale: float, impl: str, biased: bool, prefix):
+    """Every attention that is not paged: ``q`` ``[B, T, H, D]`` against this
+    forward's own ``k`` ``[B, T, Hkv, D]`` / ``v`` ``[B, T, Hkv, Dv]`` or, where
+    a layer's ``cache`` (``ops/kv_cache.py``; already holding this forward's
+    rows from slot ``index`` on) is given, against the cache. Returns
+    ``[B, T, H * Dv]`` in q's dtype, heads flattened as an output projection
+    takes them.
+
+    ``mask_bias`` is additive, ``[B, 1 | H, T, S]``; ``kv_valid`` ``[B, T]``
+    marks a multi-token forward whose keys are its own tokens — cache-free
+    (training, scoring) or a prefill from slot 0, where attention over the
+    just-computed k / v is exactly attention over the cache since every later
+    slot is still empty (TransformerLM passes it only when the write index was
+    a concrete 0 at trace time, checked outside the remat wrapper). ``impl`` is
+    the configured ``"xla" | "flash" | "ring"``; ``biased`` says that
+    ``mask_bias`` carries more than the mask (alibi) or that ``prefix`` rows
+    are prepended, which no kernel takes. ``prefix``: None or learned ``(k, v)``
+    rows ``[nv, Hkv, D]`` every query sees (zero bias), joined in front of
+    whatever is attended over.
+
+    What is chosen, in this order: the Pallas decode kernel for a single-token
+    step over a per-head float cache (it reads the cache up to the write index
+    only; appends of several tokens keep the einsum); the ring for a cache-free
+    forward under ``impl="ring"`` on a mesh that can ring; the flash kernel for
+    a ``kv_valid`` forward of more than one token; else the einsum — grouped
+    (the group a free axis, K/V never repeated to full head count) or
+    multi-head, with an int8 cache's row scales folded into the scores and the
+    probabilities. The einsum paths are the reference the kernels are tested
+    against."""
+    B, T, H, _ = q.shape
+    kv_heads = k.shape[2]
+    dtype = q.dtype
+
+    if cache is not None and T == 1:
+        use_kernel, mesh = decode_kernel_placement(impl, biased, cache, H)
+        if use_kernel:
+            interpret = _interpret(mesh)
+
+            def decode(q, k, v, mask_bias, index):
+                return decode_attention(q, k, v, mask_bias, index, scale, interpret)
+
+            if mesh is not None:
+                index = jnp.asarray(index, jnp.int32)  # an operand of the shard_map, replicated
+            operands = [(q[:, 0], True), (cache["k"], True), (cache["v"], True), (mask_bias, False), (index, False)]
+            return _placed(decode, mesh, operands, out_ndim=3).reshape(B, T, -1).astype(dtype)
+
+    use_flash, flash_mesh = flash_placement(impl, biased, B, T, kv_valid, H, kv_heads)
+    # kh/vh [B, Hkv, S, D]: the layout attention consumes (and the cache layout)
+    k_row_scale = v_row_scale = None
+    if cache is not None and not use_flash:
+        # attend over the cache (decode step / XLA prefill)
+        if kv_cache.has_row_scales(cache) and prefix is None:
+            # int8 cache: bare dtype convert only — the per-row scales fold
+            # into the scores (k) and the softmax weights (v) below, which is
+            # algebraically dequantizing the operands but leaves the big K/V
+            # streams a pure int8->bf16 cast XLA fuses into the dot (a multiply
+            # on the operand blocks that fusion). int8 values are exact in
+            # bf16 and the scales multiply in f32 on the small score/prob
+            # tensors. (Prefix tuning prepends scale-less rows, so it keeps
+            # the dequant-on-read path.)
+            kh = cache["k"].astype(dtype)
+            vh = cache["v"].astype(dtype)
+            k_row_scale = cache["k_scale"]  # [B, Hkv, S, 1] f32
+            v_row_scale = cache["v_scale"]
+        else:
+            kh, vh = kv_cache.read_kv_cache(cache, dtype)
+    else:
+        kh = k.transpose(0, 2, 1, 3)
+        vh = v.transpose(0, 2, 1, 3)
+
+    # prefix tuning: learned rows prepended to whatever we attend over, visible
+    # to every query (zero bias); no positions are consumed, no rotary applied
+    # (parity: peft PREFIX_TUNING past_key_values, modeling_base.py:162-240).
+    if prefix is not None:
+        pk, pv = prefix
+        nv = pk.shape[0]
+        shape = (B, kv_heads, nv, pk.shape[-1])
+        kh = jnp.concatenate(
+            [jnp.broadcast_to(pk.astype(kh.dtype).transpose(1, 0, 2)[None], shape), kh], axis=2
+        )
+        vh = jnp.concatenate(
+            [jnp.broadcast_to(pv.astype(vh.dtype).transpose(1, 0, 2)[None], shape), vh], axis=2
+        )
+        mask_bias = jnp.concatenate(
+            [jnp.zeros(mask_bias.shape[:-1] + (nv,), mask_bias.dtype), mask_bias], axis=-1
+        )
+
+    if impl == "ring" and cache is None and kv_valid is not None and not biased:
+        from trlx_tpu.ops.ring_attention import ring_attention
+
+        mesh = ambient_mesh()
+        n = mesh.shape.get(MODEL_AXIS, 1) if mesh is not None else 1
+        if mesh is not None and n > 1 and T % n == 0 and batch_divisible(mesh, B):
+            # grouped K/V ride the ring at native head count (no repeat)
+            out = ring_attention(
+                q.transpose(0, 2, 1, 3), kh, vh,
+                mesh, axis_name=MODEL_AXIS, causal=True, scale=scale,
+                kv_valid=kv_valid, batch_axes=BATCH_AXES,
+            ).transpose(0, 2, 1, 3).astype(dtype)
+            return out.reshape(B, T, -1)
+        # fall through to XLA when the mesh/shape can't ring
+
+    if use_flash:
+        # the kernel maps query head h -> kv head h // rep natively, so grouped
+        # K/V are never materialized at full head count
+        interpret = _interpret(flash_mesh)
+
+        def flash(q, k, v, kv_valid):
+            return flash_attention(q, k, v, kv_valid, True, scale, interpret)
+
+        operands = [(q.transpose(0, 2, 1, 3), True), (kh, True), (vh, True), (kv_valid, False)]
+        out = _placed(flash, flash_mesh, operands, out_ndim=4).transpose(0, 2, 1, 3).astype(dtype)
+    elif kv_heads != H:
+        # grouped-query einsum: batch scores over kv heads with the group as
+        # a free axis — the old jnp.repeat path copied the whole K/V cache to
+        # full head count every decode step, multiplying HBM traffic by
+        # num_heads/kv_heads on exactly the GQA models it targets
+        rep = H // kv_heads
+        qg = q.reshape(B, T, kv_heads, rep, q.shape[-1])
+        scores = jnp.einsum("btkrd,bksd->bkrts", qg, kh).astype(jnp.float32) * scale
+        if k_row_scale is not None:
+            scores = scores * k_row_scale[..., 0][:, :, None, None, :]
+        bias = (
+            mask_bias[:, :, None]
+            if mask_bias.shape[1] == 1
+            else mask_bias.reshape(B, kv_heads, rep, *mask_bias.shape[2:])
+        )
+        probs = jax.nn.softmax(scores + bias, axis=-1)
+        if v_row_scale is not None:
+            probs = probs * v_row_scale[..., 0][:, :, None, None, :]
+        probs = probs.astype(dtype)
+        # btkrd order flattens to head h = k*rep + r, matching the q reshape
+        out = jnp.einsum("bkrts,bksd->btkrd", probs, vh)
+    else:
+        # [B,H,T,S]
+        scores = jnp.einsum("bthd,bhsd->bhts", q, kh).astype(jnp.float32) * scale
+        if k_row_scale is not None:
+            scores = scores * k_row_scale[..., 0][:, :, None, :]
+        scores = scores + mask_bias
+        probs = jax.nn.softmax(scores, axis=-1)
+        if v_row_scale is not None:
+            probs = probs * v_row_scale[..., 0][:, :, None, :]
+        probs = probs.astype(dtype)
+        out = jnp.einsum("bhts,bhsd->bthd", probs, vh)
+    return out.reshape(B, T, -1)

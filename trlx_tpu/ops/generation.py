@@ -28,6 +28,11 @@ from trlx_tpu.ops.sampling import sample_token
 StepFn = Callable[..., Tuple[jnp.ndarray, jnp.ndarray, Any]]
 
 
+#: the padding ladder (8 .. 8192): prompt, response and sequence lengths are padded up to the next
+#: of these, so that a run compiles one program a bucket and not one a length
+LENGTH_BUCKETS = tuple(2 ** i for i in range(3, 14))
+
+
 def pad_to_bucket(length: int, buckets: Sequence[int]) -> int:
     """Smallest bucket >= length (limits recompilation across prompt lengths;
     parity concern: reference pads to multiples of 8, SURVEY.md §7 hard-part 3)."""

@@ -1,0 +1,120 @@
+"""What one layer of the contiguous (one-shot generator's) cache holds, and how it is written and read.
+
+A layer's cache is a plain dict of arrays — the decode ``while_loop`` carries it, so its pytree
+structure is part of the compiled program — in one of three formats:
+
+- per-head rows: ``k``, ``v`` ``[B, Hkv, S, D]`` in the compute dtype, contiguous along S per (b, h) so
+  that the decode matvec streams them (``[B, S, Hkv, D]`` cost a transposed copy of every layer a step);
+- per-head int8 rows (``kv_cache_quant``): ``k``, ``v`` int8 plus ``k_scale``, ``v_scale``
+  ``[B, Hkv, S, 1]`` float32, one symmetric scale a row (the paged pool quantizes its rows the same way);
+- latent: ``c`` ``[B, S, rank]``, the normed latent, and ``k_rope`` ``[B, S, rope]``, the rotated shared key.
+
+A layout is the same dict with ``(shape, dtype)`` in place of each array; how many layers there are and
+whether they are stacked is the model's. The serving engine's paged cache is laid out in
+``ops/paged_attention.py`` and only recognised here (:func:`is_paged`).
+"""
+
+import math
+from typing import Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+
+Layout = Dict[str, Tuple[Tuple[int, ...], jnp.dtype]]
+
+
+def kv_cache_layout(shape: Tuple[int, ...], dtype, quant: bool) -> Layout:
+    """Per-head buffers of one layer, ``shape`` ``[B, Hkv, S, D]``: int8 values + one f32
+    scale per row when ``quant``."""
+    if quant:
+        return {
+            "k": (shape, jnp.int8), "v": (shape, jnp.int8),
+            "k_scale": (shape[:-1] + (1,), jnp.float32),
+            "v_scale": (shape[:-1] + (1,), jnp.float32),
+        }
+    return {"k": (shape, dtype), "v": (shape, dtype)}
+
+
+def latent_cache_layout(batch_size: int, max_length: int, rank: int, rope_dim: int, dtype) -> Layout:
+    """A latent-attention layer's buffers: the normed latent and the rotated shared key of every token."""
+    return {
+        "c": ((batch_size, max_length, rank), dtype),
+        "k_rope": ((batch_size, max_length, rope_dim), dtype),
+    }
+
+
+def has_row_scales(layer) -> bool:
+    """Whether a layer's cache (or layout) holds int8 rows with a scale each."""
+    return "k_scale" in layer
+
+
+def is_latent(layer) -> bool:
+    """Whether a layer's cache (or layout) holds latents, not per-head keys and values."""
+    return "c" in layer
+
+
+def is_paged(layer) -> bool:
+    """Whether a layer's cache is the serving engine's block pool, read through block tables."""
+    return "block_tables" in layer
+
+
+def bytes_per_token(layout: Layout) -> int:
+    """Bytes one token's slot takes in one layer of ``layout``, every buffer counted."""
+    slot_axis = 1 if is_latent(layout) else 2
+    return sum(
+        math.prod(shape) // (shape[0] * shape[slot_axis]) * jnp.dtype(dtype).itemsize
+        for shape, dtype in layout.values()
+    )
+
+
+def quantize_kv_rows(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Symmetric per-row int8 quantization over the trailing (head) dim:
+    x [..., D] -> (int8 values [..., D], f32 scales [..., 1])."""
+    amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1, keepdims=True)
+    scale = jnp.maximum(amax, 1e-8) / 127.0
+    q = jnp.clip(jnp.round(x.astype(jnp.float32) / scale), -127, 127).astype(jnp.int8)
+    return q, scale
+
+
+def write_kv_cache(cache: Dict[str, jnp.ndarray], kT: jnp.ndarray, vT: jnp.ndarray, idx):
+    """Append [B,H,T,D] rows at slot ``idx``; quantizes when the cache carries
+    scale planes (kv_cache_quant layout). Shared by the causal and T5 decoders —
+    the quant scheme must stay identical between them."""
+    at = (0, 0, idx, 0)
+    if has_row_scales(cache):
+        kq, ks = quantize_kv_rows(kT)
+        vq, vs = quantize_kv_rows(vT)
+        return {
+            "k": jax.lax.dynamic_update_slice(cache["k"], kq, at),
+            "v": jax.lax.dynamic_update_slice(cache["v"], vq, at),
+            "k_scale": jax.lax.dynamic_update_slice(cache["k_scale"], ks, at),
+            "v_scale": jax.lax.dynamic_update_slice(cache["v_scale"], vs, at),
+        }
+    return {
+        "k": jax.lax.dynamic_update_slice(cache["k"], kT.astype(cache["k"].dtype), at),
+        "v": jax.lax.dynamic_update_slice(cache["v"], vT.astype(cache["v"].dtype), at),
+    }
+
+
+def read_kv_cache(cache: Dict[str, jnp.ndarray], compute_dtype):
+    """(kh, vh) to attend over; int8 caches dequantize on read — XLA fuses the
+    convert+scale into the score einsum's operand stream, so HBM moves int8."""
+    if has_row_scales(cache):
+        # multiply int8 values by the f32 scale at full precision, THEN cast:
+        # casting the scale to bf16 first would truncate it to 8 mantissa bits
+        # and stack avoidable error on top of the int8 quantization
+        return (
+            (cache["k"].astype(jnp.float32) * cache["k_scale"]).astype(compute_dtype),
+            (cache["v"].astype(jnp.float32) * cache["v_scale"]).astype(compute_dtype),
+        )
+    return cache["k"], cache["v"]
+
+
+def write_latent_cache(cache: Dict[str, jnp.ndarray], latent: jnp.ndarray, k_rope: jnp.ndarray, idx):
+    """Append ``latent`` [B,T,rank] and ``k_rope`` [B,T,1,rope], the one rotated
+    head all share, at slot ``idx``."""
+    at = (0, idx, 0)
+    return {
+        "c": jax.lax.dynamic_update_slice(cache["c"], latent.astype(cache["c"].dtype), at),
+        "k_rope": jax.lax.dynamic_update_slice(cache["k_rope"], k_rope[:, :, 0].astype(cache["k_rope"].dtype), at),
+    }

@@ -53,6 +53,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from trlx_tpu.analysis.ir.entrypoints import EntryArtifacts, register_entrypoint
+from trlx_tpu.ops.kv_cache import has_row_scales, quantize_kv_rows
 
 NEG_INF = -1e30  # kernel-internal mask value (f32 exact, like ops/attention.py)
 
@@ -429,8 +430,6 @@ def write_paged_kv_multi(
     real blocks for, and any position is rewritten before the attention mask
     can expose it, so overflow writes are harmless garbage.
     """
-    from trlx_tpu.models.transformer import quantize_kv_rows
-
     NB, Hkv, BS, D = cache["k"].shape
     B, Q = k_new.shape[:2]
     lens = cache["context_lens"]
@@ -439,7 +438,7 @@ def write_paged_kv_multi(
 
     out = dict(cache)
     new = {"k": k_new, "v": v_new}
-    if "k_scale" in cache:
+    if has_row_scales(cache):
         for key, rows in (("k", k_new), ("v", v_new)):
             quantized, row_scale = quantize_kv_rows(rows.reshape(B * Q, Hkv, D))
             new[key] = quantized.reshape(B, Q, Hkv, D)
@@ -460,7 +459,7 @@ def paged_pool_layout(
     dtype, quant: bool,
 ) -> dict:
     """Per-layer pool buffers as ``{key: (shape, dtype)}`` (mirror of the
-    contiguous ``kv_cache_layout``)."""
+    contiguous ``ops.kv_cache.kv_cache_layout``)."""
     shape = (num_blocks, kv_heads, block_size, dim_per_head)
     if quant:
         return {

@@ -140,9 +140,8 @@ def sample_token(
     values, draw categorical over k, gather the token id. With exact selection
     this is distribution-identical to masking the full-V logits and sampling
     (softmax is invariant to the NEG_INF entries) but removes every full-vocab
-    pass after the selection itself — on chip the old full-V path cost 4.4x
-    decode throughput at B=256/k=50 (bench `gpt2_rollout_new_tok_s_topk50_topp95`
-    11.5k vs 51.0k tok/s plain).
+    pass after the selection itself (no benchmark cell samples with top-k, so
+    what the full-V path costs on the chip is not measured: ROADMAP S5).
 
     ``top_k_impl``: "approx" (default) selects candidates with
     ``jax.lax.approx_max_k`` — the TPU-native binned selection (per-candidate

@@ -24,8 +24,8 @@ from typing import Callable, Dict, Iterator, List, Optional, Sequence, Tuple
 import numpy as np
 
 from trlx_tpu.obs.flight import flight
-from trlx_tpu.ops.generation import pad_to_bucket
-from trlx_tpu.serving.engine import PREFILL_LEN_BUCKETS, ServingEngine
+from trlx_tpu.ops.generation import LENGTH_BUCKETS, pad_to_bucket
+from trlx_tpu.serving.engine import ServingEngine
 from trlx_tpu.serving.policy import (
     EngineStoppedError,
     RequestExpiredError,
@@ -159,7 +159,7 @@ class GenerationClient:
         whatever outcome the engine produced, unchanged."""
         engine = self.engine
         N = int(max_new_tokens)
-        P = pad_to_bucket(max((len(p) for p in prompts), default=1), PREFILL_LEN_BUCKETS)
+        P = pad_to_bucket(max((len(p) for p in prompts), default=1), LENGTH_BUCKETS)
         with self._step_lock:
             uids = [
                 engine.submit(
@@ -230,7 +230,7 @@ class GenerationClient:
         """
         engine = self.engine
         N = int(max_new_tokens)
-        P = pad_to_bucket(max((len(p) for p in prompts), default=1), PREFILL_LEN_BUCKETS)
+        P = pad_to_bucket(max((len(p) for p in prompts), default=1), LENGTH_BUCKETS)
         done: Dict[int, Request] = {}
 
         with self._step_lock:

@@ -70,7 +70,7 @@ import jax.numpy as jnp
 
 from trlx_tpu.obs import compile_log
 from trlx_tpu.obs.flight import flight
-from trlx_tpu.ops.generation import left_pad_batch, pad_to_bucket
+from trlx_tpu.ops.generation import LENGTH_BUCKETS, left_pad_batch, pad_to_bucket
 from trlx_tpu.ops.paged_attention import paged_slots, scatter_paged_rows
 from trlx_tpu.ops.sampling import count_accepted_drafts, sample_token
 from trlx_tpu.resilience.chaos import chaos
@@ -87,9 +87,6 @@ from trlx_tpu.utils import logging
 from trlx_tpu.utils.metrics import gauges, nearest_rank
 
 logger = logging.get_logger(__name__)
-
-# prompt-length buckets for prefill (same family the one-shot path uses)
-PREFILL_LEN_BUCKETS = tuple(2 ** i for i in range(3, 14))
 
 
 def _pow2_at_least(n: int, cap: int) -> int:
@@ -542,7 +539,7 @@ class ServingEngine:
             else:
                 first = ids_full
             by_bucket.setdefault(
-                pad_to_bucket(len(first), PREFILL_LEN_BUCKETS), []
+                pad_to_bucket(len(first), LENGTH_BUCKETS), []
             ).append((slot, req, first))
         for P_b, group in sorted(by_bucket.items()):
             n_b = _pow2_at_least(len(group), self.num_slots)

@@ -18,7 +18,7 @@ invariants that matter:
 - **census integrity** — the allocator's block + owner census balances after
   every restart and at the end.
 
-Usage (tests/test_serving_tenants.py soak, bench.py ``serving_tenants`` leg)::
+Usage (tests/test_serving_tenants.py soak)::
 
     registry = TenantRegistry()
     registry.register("free", slo_class=0, kv_block_quota=6)

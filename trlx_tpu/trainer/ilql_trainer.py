@@ -18,7 +18,7 @@ from trlx_tpu.models.hf_loading import load_pretrained
 from trlx_tpu.models.heads import sync_target_q_heads as _sync_heads
 from trlx_tpu.models.policy import CausalLMWithILQLHeads
 from trlx_tpu.models.transformer import TransformerLM
-from trlx_tpu.ops.generation import pad_to_bucket
+from trlx_tpu.ops.generation import LENGTH_BUCKETS, pad_to_bucket
 from trlx_tpu.parallel import mesh as mesh_lib
 from trlx_tpu.parallel.sharding import make_param_shardings
 from trlx_tpu.pipeline.offline_pipeline import ILQLRolloutStorage, tokenize_dialogue
@@ -29,7 +29,7 @@ from trlx_tpu.utils.modeling import flatten_dict
 
 logger = logging.get_logger(__name__)
 
-BUCKETS = [2 ** i for i in range(2, 14)]
+BUCKETS = (4,) + LENGTH_BUCKETS  # an offline sample may be a token or two
 
 
 def make_experience(samples, rewards, tokenizer=None, max_length: int = 2048,

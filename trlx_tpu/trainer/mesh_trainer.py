@@ -25,10 +25,11 @@ import jax.numpy as jnp
 import optax
 
 from trlx_tpu.data.configs import TRLConfig
-from trlx_tpu.models.transformer import TransformerConfig, decode_cache_read_share
+from trlx_tpu.models.transformer import TransformerConfig
 from trlx_tpu.obs import Observability, batch_token_count, compile_log
+from trlx_tpu.ops.attention import decode_cache_read_share
 from trlx_tpu.ops.generation import generate as generate_op
-from trlx_tpu.ops.generation import generate_seq2seq, left_pad_batch, pad_to_bucket
+from trlx_tpu.ops.generation import LENGTH_BUCKETS, generate_seq2seq, left_pad_batch, pad_to_bucket
 from trlx_tpu.parallel import mesh as mesh_lib
 from trlx_tpu.pipeline.tokenization import load_tokenizer
 from trlx_tpu.resilience import Resilience, chaos_poison_batch, find_latest_committed
@@ -387,7 +388,7 @@ class MeshRLTrainer(BaseRLTrainer):
                 "learner_overlap: overlapped FSDP step active "
                 f"(fsdp={self.mesh.shape['fsdp']}, num_microbatches={num_mb}, "
                 f"int8_opt_state={lov.int8_opt_state}, remat={lov.remat}, "
-                f"flash_bwd={lov.flash_bwd}, max_grad_norm={self._overlap_max_grad_norm})"
+                f"max_grad_norm={self._overlap_max_grad_norm})"
             )
             return fsdp_lib.make_overlapped_grad_accum_step(
                 loss_fn,
@@ -529,8 +530,7 @@ class MeshRLTrainer(BaseRLTrainer):
         proc_kwargs = self.pop_gen_processor_kwargs(gen_kwargs)
 
         max_len = max(len(p) for p in prompts_ids)
-        buckets = [2 ** i for i in range(3, 14)]
-        P = pad_to_bucket(max_len, buckets)
+        P = pad_to_bucket(max_len, LENGTH_BUCKETS)
         ids, mask = left_pad_batch(prompts_ids, gen_kwargs["pad_token_id"], P)
 
         is_seq2seq = getattr(self, "is_seq2seq", False)
@@ -589,9 +589,11 @@ class MeshRLTrainer(BaseRLTrainer):
         if isinstance(getattr(self, "model_config", None), TransformerConfig):
             # the loop ran until the longest row ended; its first token came from the prefill
             steps = int(response_mask.sum(axis=1).max()) - 1
+            c = self.model_config
             with self.mesh:
                 gauges.set("rollout/cache_read_share", decode_cache_read_share(
-                    self.model_config, ids.shape[0], P, max_new, steps))
+                    c.attention_impl, c.biased_attention, c.num_heads,
+                    c.cache_layout(ids.shape[0], P + max_new), max_new, steps))
         # seq2seq sequences are [decoder_start] + response: pad_len for decode() is 1
         return sequences, response_mask, 1 if is_seq2seq else P
 

@@ -31,6 +31,7 @@ from trlx_tpu.models.policy import (
 from trlx_tpu.models.transformer import TransformerLM, moe_counters
 from trlx_tpu.obs import compile_log, span
 from trlx_tpu.obs.flight import flight
+from trlx_tpu.ops.generation import LENGTH_BUCKETS, left_pad_batch, pad_to_bucket
 from trlx_tpu.parallel import mesh as mesh_lib
 from trlx_tpu.parallel.sharding import make_param_shardings
 from trlx_tpu.pipeline.ppo_pipeline import PPORolloutStorage
@@ -55,9 +56,6 @@ logger = logging.get_logger(__name__)
 #: sanction list, and the CompileWatcher probe all share one number.
 _STREAM_MAX_R_BUCKETS = rt_contracts.get("stream_score_ladder").max_shapes
 
-#: the shared pow2 padding ladder (8 .. 8192) every bucketing path draws from
-_POW2_BUCKETS = [2 ** i for i in range(3, 14)]
-
 
 @jax.jit
 def copy_params(tree):
@@ -70,12 +68,10 @@ def overlap_r_buckets(max_new: int) -> List[int]:
     """The quantized response-length ladder for streaming microbuckets:
     ≤ :data:`_STREAM_MAX_R_BUCKETS` pow2 shapes covering up to
     ``max_new + 1`` (decode may re-append eos)."""
-    from trlx_tpu.ops.generation import pad_to_bucket
-
     top = max(1, max_new + 1)
     # ceil(top / d) for d in 8,4,2,1 — dedup after pow2 padding keeps the
     # ladder at <= 4 entries with the full shape always present
-    return sorted({pad_to_bucket(max(1, -(-top // d)), _POW2_BUCKETS) for d in (8, 4, 2, 1)})
+    return sorted({pad_to_bucket(max(1, -(-top // d)), LENGTH_BUCKETS) for d in (8, 4, 2, 1)})
 
 
 def quantize_stream_response(r: int, ladder: List[int]) -> int:
@@ -86,14 +82,12 @@ def quantize_stream_response(r: int, ladder: List[int]) -> int:
     ``TRLX_RT_SEED_REGRESSION=shape_churn`` makes this return the raw length
     — the unbucketed-shape defect the compile gate must catch (ci.sh proves
     the gate fails closed; see trlx_tpu/analysis/rt/seeds.py)."""
-    from trlx_tpu.ops.generation import pad_to_bucket
-
     if rt_seeds.shape_churn():
         return r
     for cand in ladder:
         if r <= cand:
             return cand
-    return pad_to_bucket(r, _POW2_BUCKETS)  # defensive; the ladder covers max_new+1
+    return pad_to_bucket(r, LENGTH_BUCKETS)  # defensive; the ladder covers max_new+1
 
 
 def check_stream_bucket_family(families, B: int, P: int, R: int, limit: int = _STREAM_MAX_R_BUCKETS):
@@ -210,11 +204,6 @@ class PPOTrainer(MeshRLTrainer):
         lov = getattr(self.config.train, "learner_overlap", None)
         if lov is not None and lov.enabled and lov.remat is not None:
             overrides.setdefault("remat", lov.remat)
-        if lov is not None and lov.enabled and lov.flash_bwd is not None:
-            # captured at trace time, so set before the step is first jitted
-            from trlx_tpu.ops.attention import set_flash_backward
-
-            set_flash_backward(lov.flash_bwd)
         overrides.setdefault("remat", self.config.mesh.remat)
         overrides.setdefault("sequence_sharding", self.config.mesh.sequence_shard)
         from trlx_tpu.models.hf_loading import merge_loaded_params, peft_overrides
@@ -829,7 +818,6 @@ class PPOTrainer(MeshRLTrainer):
         from concurrent.futures import ThreadPoolExecutor
 
         from trlx_tpu.obs.overlap import OverlapWindow
-        from trlx_tpu.ops.generation import left_pad_batch, pad_to_bucket
         from trlx_tpu.pipeline.ppo_pipeline import ppo_collate_fn
         from trlx_tpu.resilience.chaos import chaos
         from trlx_tpu.rollout.reorder import ReorderBuffer
@@ -838,7 +826,6 @@ class PPOTrainer(MeshRLTrainer):
         serialize = os.environ.get("TRLX_OVERLAP_SEED_REGRESSION", "") == "serialize"
         mb = int(cfg.overlap_microbucket or self.method.chunk_size)
         pad_id = self.tokenizer.pad_token_id
-        pow2 = _POW2_BUCKETS
         r_ladder = self._overlap_r_buckets()
         # the reward worker threads must not share the main thread's HF fast
         # tokenizer (not re-entrant — same reasoning as overlap_reward_scoring)
@@ -1055,7 +1042,7 @@ class PPOTrainer(MeshRLTrainer):
                     base = generated
                     generated += len(prompts)
                     cur["P"] = pad_to_bucket(
-                        max((len(p) for p in prompts), default=1), pow2
+                        max((len(p) for p in prompts), default=1), LENGTH_BUCKETS
                     )
 
                     def on_finish(i, req, _base=base, _prompts=prompts, _meta=metadata):
@@ -1275,10 +1262,8 @@ class PPOTrainer(MeshRLTrainer):
         # fixed-shape scoring forward
         P = max(len(p) for p in prompts)
         R = max(len(o) for o in out_ids)
-        from trlx_tpu.ops.generation import left_pad_batch, pad_to_bucket
-
-        P = pad_to_bucket(P, [2 ** i for i in range(3, 14)])
-        R = pad_to_bucket(R, [2 ** i for i in range(3, 14)])
+        P = pad_to_bucket(P, LENGTH_BUCKETS)
+        R = pad_to_bucket(R, LENGTH_BUCKETS)
         q_ids, q_mask = left_pad_batch(prompts, self.tokenizer.pad_token_id, P)
         r_ids = np.full((len(out_ids), R), self.tokenizer.pad_token_id, np.int32)
         r_mask = np.zeros((len(out_ids), R), np.int32)

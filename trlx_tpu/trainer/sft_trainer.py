@@ -13,7 +13,7 @@ from trlx_tpu.data.configs import TRLConfig
 from trlx_tpu.methods.sft import SFTConfig
 from trlx_tpu.models.hf_loading import load_pretrained
 from trlx_tpu.models.transformer import TransformerLM
-from trlx_tpu.ops.generation import pad_to_bucket
+from trlx_tpu.ops.generation import LENGTH_BUCKETS, pad_to_bucket
 from trlx_tpu.parallel import mesh as mesh_lib
 from trlx_tpu.parallel.sharding import make_param_shardings
 from trlx_tpu.pipeline.offline_pipeline import DialogStore, tokenize_dialogue
@@ -22,8 +22,6 @@ from trlx_tpu.trainer.mesh_trainer import MeshRLTrainer
 from trlx_tpu.utils import logging
 
 logger = logging.get_logger(__name__)
-
-BUCKETS = [2 ** i for i in range(3, 14)]
 
 
 def _resolve_pad_id(tokenizer):
@@ -265,7 +263,7 @@ class SFTTrainer(MeshRLTrainer):
         if self.is_seq2seq:
             return self._train_step_s2s(batch)
         B, T = batch["input_ids"].shape
-        Tb = pad_to_bucket(T, BUCKETS)
+        Tb = pad_to_bucket(T, LENGTH_BUCKETS)
         # pad rows to a num_mb multiple (fully-masked rows contribute zero loss)
         Bp = ((B + self.num_mb - 1) // self.num_mb) * self.num_mb
         pad = ((0, Bp - B), (0, Tb - T))
@@ -284,7 +282,7 @@ class SFTTrainer(MeshRLTrainer):
     def _train_step_s2s(self, batch) -> Dict[str, float]:
         B, Te = batch["input_ids"].shape
         Td = batch["labels"].shape[1]
-        Teb, Tdb = pad_to_bucket(Te, BUCKETS), pad_to_bucket(Td, BUCKETS)
+        Teb, Tdb = pad_to_bucket(Te, LENGTH_BUCKETS), pad_to_bucket(Td, LENGTH_BUCKETS)
         Bp = ((B + self.num_mb - 1) // self.num_mb) * self.num_mb
         padded = {
             "input_ids": np.pad(

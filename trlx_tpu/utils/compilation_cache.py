@@ -6,7 +6,7 @@ compile of the process, so configuring the cache after anything has compiled
 (even a ``jax.random.PRNGKey``) silently disables it for the whole process.
 Callers therefore invoke :func:`configure_compilation_cache` as the first
 jax-touching act: ``MeshRLTrainer.__init__`` before it derives its RNG key,
-``bench.py`` and ``chip_smoke.py`` before their first phase, and
+``benchmark/run.py`` and ``chip_smoke.py`` before their first phase, and
 ``python -m trlx_tpu.analysis.ir`` before lowering.
 
 Where the cache lives, in order:
