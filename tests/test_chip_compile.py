@@ -12,9 +12,11 @@ fixture: only one process may load the TPU library, so a call made while any
 module is imported would break the other xdist workers' collection.
 """
 
+import dataclasses
 import functools
 import re
 
+import numpy as np
 import pytest
 
 import jax
@@ -250,3 +252,82 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_persistent_cache, monkeypatc
         names = _attention_instructions(text)
         assert len(names) == 6, names  # forward, dkv and dq of two layers
         assert all(re.match(r"^%attn[.0-9]* custom-call$", name) for name in names), names
+
+
+def _sharded_ppo_learner(topo, fsdp=4, layers=2):
+    """``PPOTrainer``'s own train step over a ``data=1, fsdp=4`` mesh of the described chips, as
+    ``chip_smoke.py --chips 4`` runs it (gpt2's widths and vocabulary, 64 + 65 tokens, batch 32)
+    at two layers: the trainer's programs without its state, since a described device holds no
+    array. Returns (the jitted step, its abstract arguments, the mesh, (B, P, R))."""
+    import optax
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    import chip_smoke
+    from trlx_tpu.data.ppo_types import PPORLBatch
+    from trlx_tpu.models.hf_loading import load_pretrained
+    from trlx_tpu.models.policy import CausalLMWithValueHead
+    from trlx_tpu.parallel import mesh as mesh_lib
+    from trlx_tpu.parallel.sharding import make_param_shardings, make_state_shardings
+    from trlx_tpu.trainer.ppo_trainer import PPOTrainer
+    from trlx_tpu.utils import get_optimizer_class, get_scheduler_class
+
+    sizes = dataclasses.replace(chip_smoke.FULL, model_overrides=dict(num_layers=layers))
+    config = chip_smoke.ppo_config(sizes, "unused", fsdp=fsdp, trainer="PPOTrainer")
+    mesh = Mesh(np.array(topo.devices[:fsdp]).reshape(1, fsdp, 1, 1), mesh_lib.MESH_AXES)
+
+    t = object.__new__(PPOTrainer)  # setup_model and setup_optimizer place arrays; the step needs none
+    t.config, t.method, t.mesh, t.health, t._engine, t._train_steps = config, config.method, mesh, None, None, {}
+    t.is_seq2seq, t.num_mb = False, 1
+    dtypes = dict(param_dtype=jnp.dtype(config.mesh.param_dtype), compute_dtype=jnp.dtype(config.mesh.compute_dtype))
+    t.model_config, _, _ = load_pretrained(
+        config.model.model_path, {**config.model.model_overrides, **dtypes, "remat": config.mesh.remat}, mesh=None)
+    t.module = CausalLMWithValueHead(t.model_config)
+    shapes = jax.eval_shape(
+        lambda: t.module.init(jax.random.PRNGKey(0), jnp.zeros((1, 2), jnp.int32), jnp.ones((1, 2), jnp.int32))
+    )["params"]
+    params = jax.tree.map(
+        lambda leaf, sharding: jax.ShapeDtypeStruct(leaf.shape, dtypes["param_dtype"], sharding=sharding),
+        shapes, make_param_shardings(shapes, mesh))
+    kwargs = dict(config.optimizer.kwargs)
+    schedule = get_scheduler_class(config.scheduler.name)
+    t.lr_schedule = schedule(learning_rate=kwargs.pop("lr"), **config.scheduler.kwargs)
+    tx = get_optimizer_class(config.optimizer.name)(learning_rate=t.lr_schedule, **kwargs)
+    t.tx = optax.multi_transform({"train": tx, "freeze": optax.set_to_zero()}, t._trainable_labels(params))
+    state = jax.eval_shape(t.tx.init, params)
+    state = jax.tree.map(
+        lambda leaf, sharding: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype, sharding=sharding),
+        state, make_state_shardings(state, mesh))
+
+    B, P, R = sizes.batch, sizes.prompt_len, sizes.new_tokens + 1  # the trainer re-appends eos
+    rows = NamedSharding(mesh, PartitionSpec(mesh_lib.BATCH_AXES, None))
+
+    def of(width, dtype):
+        return jax.ShapeDtypeStruct((B, width), dtype, sharding=rows)
+
+    batch = PPORLBatch(
+        query_tensors=of(P, jnp.int32), response_tensors=of(R, jnp.int32), logprobs=of(R, jnp.float32),
+        values=of(R, jnp.float32), rewards=of(R, jnp.float32), attention_mask=of(P, jnp.int32),
+        response_mask=of(R, jnp.int32),
+    )
+    return t._get_train_step(B, P, R), (params, state, batch), mesh, (B, P, R)
+
+
+def test_sharded_ppo_train_step_compiles_with_the_response_window(topo, no_persistent_cache, monkeypatch):
+    """The fsdp=4 PPO train step at gpt2's vocabulary of 50257: the shape on which the chip's
+    compiler failed (PR 22: "Bitcast cannot have different shape sizes of output and operand")
+    when ``[B, T, V]`` logits were sliced and the backward padded them. The head now runs over
+    the response window's rows of the hidden states, and the backward pads ``[B, R, d]``."""
+    if len(topo.devices) < 4:
+        pytest.skip("the described topology has fewer than four chips")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    step, args, mesh, (B, P, R) = _sharded_ppo_learner(topo)
+    with mesh:
+        compiled = step.lower(*args).compile()  # raises what the chip's compiler would
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text  # the flash kernels, placed over the mesh
+    assert "all-gather" in text or "all-reduce" in text  # the parameters are sharded over the four chips
+    V = 50257
+    assert re.search(rf"bf16\[{B // 4},{R},{V}\]", text), "a device's share of the window's logits"
+    # no array over every position and the vocabulary, in any dtype, whole or a device's share
+    assert not re.search(rf"\[\d+,(?:{P + R}|{P + R - 1}),{V}\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2 ** 30

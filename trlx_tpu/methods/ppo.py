@@ -266,11 +266,11 @@ def _build_train_step(spec: str, mesh, method) -> EntryArtifacts:
     from jax.sharding import NamedSharding, PartitionSpec
 
     from trlx_tpu.data.ppo_types import PPORLBatch
-    from trlx_tpu.models.policy import CausalLMWithValueHead
+    from trlx_tpu.models.policy import CausalLMWithValueHead, head_of
     from trlx_tpu.models.presets import PRESETS
     from trlx_tpu.parallel.mesh import BATCH_AXES
     from trlx_tpu.parallel.sharding import make_param_shardings, make_state_shardings
-    from trlx_tpu.utils.modeling import next_token_logprobs
+    from trlx_tpu.utils.modeling import response_logprobs
 
     dims = {"small": dict(hidden=64, layers=2, heads=4, vocab=256, B=8, P=24, R=8)}[spec]
     model_config = PRESETS["gpt2"].replace(
@@ -318,16 +318,15 @@ def _build_train_step(spec: str, mesh, method) -> EntryArtifacts:
     def loss_fn(params, mb):
         seq = jnp.concatenate([mb.query_tensors, mb.response_tensors], axis=1)
         mask = jnp.concatenate([mb.attention_mask, mb.response_mask], axis=1)
-        logits, values_pred, _, _ = module.apply({"params": params}, seq, mask)
+        hidden, values_pred, _, _ = module.apply({"params": params}, seq, mask, with_head=False)
         if seed_regression == "allgather":
-            # audit seed: replicating the sharded logits forces an all-gather
-            # the committed budget does not contain
-            logits = jax.lax.with_sharding_constraint(
-                logits, NamedSharding(mesh, PartitionSpec())
+            # audit seed: replicating the sharded hidden states forces an
+            # all-gather the committed budget does not contain
+            hidden = jax.lax.with_sharding_constraint(
+                hidden, NamedSharding(mesh, PartitionSpec())
             )
-        logprobs = next_token_logprobs(logits, seq)
         start = mb.query_tensors.shape[1] - 1
-        logprobs = logprobs[:, start:start + R]
+        logprobs = response_logprobs(hidden, head_of(module, params), seq, start, R)
         values_pred = values_pred[:, start:start + R].astype(jnp.float32)
         advantages, returns = method.get_advantages_and_returns(
             mb.values, mb.rewards, mb.response_mask
@@ -338,8 +337,8 @@ def _build_train_step(spec: str, mesh, method) -> EntryArtifacts:
         )
         if seed_regression == "f32_upcast":
             # audit seed: a heavy f32 matmul inside the bf16-declared step
-            logits32 = logits.astype(jnp.float32)
-            probe = jnp.einsum("btv,bsv->ts", logits32, logits32)
+            hidden32 = hidden.astype(jnp.float32)
+            probe = jnp.einsum("btd,bsd->ts", hidden32, hidden32)
             loss = loss + 0.0 * jnp.sum(probe, dtype=jnp.float32)
         return loss
 
@@ -373,15 +372,15 @@ def _ppo_audit_loss_fn(module, method, mesh, R: int):
     """The audit-shape PPO loss shared by the overlap entrypoints: same
     construction as ``build_ppo_train_step``'s, minus the seeds (the overlap
     seed lives in ``parallel/fsdp.py``'s step builder, not the loss)."""
-    from trlx_tpu.utils.modeling import next_token_logprobs
+    from trlx_tpu.models.policy import head_of
+    from trlx_tpu.utils.modeling import response_logprobs
 
     def loss_fn(params, mb):
         seq = jnp.concatenate([mb.query_tensors, mb.response_tensors], axis=1)
         mask = jnp.concatenate([mb.attention_mask, mb.response_mask], axis=1)
-        logits, values_pred, _, _ = module.apply({"params": params}, seq, mask)
-        logprobs = next_token_logprobs(logits, seq)
+        hidden, values_pred, _, _ = module.apply({"params": params}, seq, mask, with_head=False)
         start = mb.query_tensors.shape[1] - 1
-        logprobs = logprobs[:, start:start + R]
+        logprobs = response_logprobs(hidden, head_of(module, params), seq, start, R)
         values_pred = values_pred[:, start:start + R].astype(jnp.float32)
         advantages, returns = method.get_advantages_and_returns(
             mb.values, mb.rewards, mb.response_mask

@@ -24,7 +24,10 @@ from trlx_tpu.models.transformer import KVCache, TransformerConfig, TransformerL
 
 class CausalLMWithValueHead(nn.Module):
     """Trunk LM + scalar value head. ``branch_layer`` (when set in a call) returns the
-    activation entering that layer, for the hydra reference branch.
+    activation entering that layer, for the hydra reference branch. A call with
+    ``with_head=False`` returns the post-norm hidden states where the logits
+    would stand and takes no vocabulary head: the caller applies :meth:`head`
+    to the rows it reads (``utils.modeling.response_logprobs``).
 
     ``num_value_layers`` > 0 gives the value function its own trainable *branch* of
     top layers fed from the trunk activation ``num_value_layers`` from the top
@@ -70,6 +73,7 @@ class CausalLMWithValueHead(nn.Module):
         positions: Optional[jnp.ndarray] = None,
         cache: Optional[KVCache] = None,
         branch_layer: Optional[int] = None,
+        with_head: bool = True,
     ):
         if self.num_value_layers > 0:
             if cache is not None:
@@ -82,16 +86,20 @@ class CausalLMWithValueHead(nn.Module):
             value_start = self.config.num_layers - self.num_value_layers
             capture = sorted({value_start, *(() if branch_layer is None else (branch_layer,))})
             logits, hidden, captures, new_cache = self.transformer(
-                input_ids, attention_mask, positions, cache, tuple(capture)
+                input_ids, attention_mask, positions, cache, tuple(capture), with_head
             )
             values = self._value_branch(captures[value_start], attention_mask, positions)
             branch_hidden = None if branch_layer is None else captures[branch_layer]
-            return logits, values, branch_hidden, new_cache
+            return (logits if with_head else hidden), values, branch_hidden, new_cache
         logits, hidden, branch_hidden, new_cache = self.transformer(
-            input_ids, attention_mask, positions, cache, branch_layer
+            input_ids, attention_mask, positions, cache, branch_layer, with_head
         )
         values = self.v_head(hidden)
-        return logits, values, branch_hidden, new_cache
+        return (logits if with_head else hidden), values, branch_hidden, new_cache
+
+    def head(self, rows: jnp.ndarray) -> jnp.ndarray:
+        """The trunk's vocabulary head over post-norm rows (``TransformerLM.head``)."""
+        return self.transformer.head(rows)
 
     def lm_only(
         self,
@@ -110,10 +118,12 @@ class CausalLMWithValueHead(nn.Module):
         attention_mask: Optional[jnp.ndarray],
         positions: Optional[jnp.ndarray],
         start_layer: int,
+        with_head: bool = True,
     ):
         """Frozen-branch forward (hydra): run layers[start_layer:] + head from a
-        cached activation. Call with the frozen param subtree."""
-        return self.transformer.forward_from(hidden, attention_mask, positions, start_layer)
+        cached activation (``with_head=False``: the post-norm hidden states
+        instead of the logits). Call with the frozen param subtree."""
+        return self.transformer.forward_from(hidden, attention_mask, positions, start_layer, with_head)
 
     def init_cache(self, batch_size: int, max_length: int) -> KVCache:
         return self.transformer_init_cache(batch_size, max_length)
@@ -121,6 +131,14 @@ class CausalLMWithValueHead(nn.Module):
     def transformer_init_cache(self, batch_size: int, max_length: int) -> KVCache:
         # plain helper (not a module method) — cache needs no params
         return TransformerLM(self.config).init_cache(batch_size, max_length)
+
+
+def head_of(module, params: Dict[str, Any]):
+    """The vocabulary head of ``module`` (a ``TransformerLM`` or a
+    ``CausalLMWithValueHead``) with ``params``, as ``response_logprobs`` takes
+    it: rows [..., d] -> logits [..., V]. Of ``params`` it reads the tied
+    embedding or ``lm_head`` alone, so a hydra branch's subtree will do."""
+    return lambda rows: module.apply({"params": params}, rows, method=module.head)
 
 
 class CausalLMWithILQLHeads(nn.Module):

@@ -791,16 +791,21 @@ class TransformerLM(nn.Module):
                 kernel_init=nn.initializers.normal(c.initializer_range),
             )
 
-    def _final(self, x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        """(logits, post-norm hidden)."""
-        if self.config.final_norm:
-            x = self.ln_f(x)
+    def head(self, x: jnp.ndarray) -> jnp.ndarray:
+        """The vocabulary head over post-norm rows [..., d] -> logits [..., V]
+        in the compute dtype. A caller that reads a window of the positions
+        takes hidden states from a forward with ``with_head=False`` and applies
+        this to the window's rows (``utils.modeling.response_logprobs``)."""
         if self.config.tie_word_embeddings:
             emb = self.embed_tokens.embedding.astype(self.config.compute_dtype)
-            logits = x @ emb.T
-        else:
-            logits = self.lm_head(x)
-        return logits, x
+            return x @ emb.T
+        return self.lm_head(x)
+
+    def _final(self, x: jnp.ndarray, with_head: bool = True) -> Tuple[Optional[jnp.ndarray], jnp.ndarray]:
+        """(logits, or None without the head; post-norm hidden)."""
+        if self.config.final_norm:
+            x = self.ln_f(x)
+        return (self.head(x) if with_head else None), x
 
     def embed(self, input_ids: jnp.ndarray, positions: jnp.ndarray) -> jnp.ndarray:
         x = self.embed_tokens(input_ids)
@@ -817,12 +822,15 @@ class TransformerLM(nn.Module):
         positions: Optional[jnp.ndarray] = None,
         cache: Optional[KVCache] = None,
         branch_layer: Optional[int] = None,
+        with_head: bool = True,
     ):
         """input_ids [B,T]; attention_mask [B,T] (1=real token). With ``cache``,
         T may be 1 (decode step) and the mask must cover the cache length [B,S].
         Returns (logits [B,T,V], hidden [B,T,Hid] post-norm, branch_hidden or None,
         new cache or None). ``branch_layer`` = index of the first *unfrozen* layer;
-        its input activation is returned for the hydra reference branch."""
+        its input activation is returned for the hydra reference branch.
+        ``with_head=False`` leaves the vocabulary head out (logits is None): the
+        caller applies :meth:`head` to the rows it reads."""
         c = self.config
         B, T = input_ids.shape
         nv = c.num_virtual_tokens if c.peft_type == "prompt" else 0
@@ -957,9 +965,9 @@ class TransformerLM(nn.Module):
             # gather the sequence dim before heads (Megatron's
             # gather_from_sequence_parallel_region analogue)
             x = constrain_gathered(x)
-        logits, hidden = self._final(x)
+        logits, hidden = self._final(x, with_head)
         if nv_rows:  # drop virtual rows: external output shape is [B, T, ...]
-            logits = logits[:, nv_rows:]
+            logits = None if logits is None else logits[:, nv_rows:]
             hidden = hidden[:, nv_rows:]
         new_cache = None
         if cache is not None:
@@ -1011,11 +1019,13 @@ class TransformerLM(nn.Module):
         attention_mask: Optional[jnp.ndarray],
         positions: Optional[jnp.ndarray],
         start_layer: int,
+        with_head: bool = True,
     ):
         """Run layers[start_layer:] + final norm + lm head from a branch activation.
         This is the hydra frozen-branch forward (reference ``forward_hydra``,
         modeling_ppo.py:410-453) — called with the frozen param subtree via
-        ``apply({"params": frozen}, ..., method="forward_from")``."""
+        ``apply({"params": frozen}, ..., method="forward_from")``. Returns the
+        logits, or with ``with_head=False`` the post-norm hidden states."""
         if self.config.stacked:
             raise NotImplementedError(
                 "hydra branch forwards need per-layer params; stacked models "
@@ -1028,8 +1038,8 @@ class TransformerLM(nn.Module):
         x = hidden
         for layer in self.layers[start_layer:]:
             x, _ = layer(x, mask_bias, positions, None, attention_mask)
-        logits, _ = self._final(x)
-        return logits
+        logits, hidden = self._final(x, with_head)
+        return logits if with_head else hidden
 
     def _constrain_cache_leaf(self, x: jnp.ndarray, stacked: bool) -> jnp.ndarray:
         """Pin the KV-cache layout over the mesh. Stacked decode ([L, B, H, ...]

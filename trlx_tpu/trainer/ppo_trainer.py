@@ -27,6 +27,7 @@ from trlx_tpu.models.hf_loading import load_pretrained
 from trlx_tpu.models.policy import (
     CausalLMWithValueHead,
     branch_param_subtree,
+    head_of,
 )
 from trlx_tpu.models.transformer import TransformerLM, moe_counters
 from trlx_tpu.obs import compile_log, span
@@ -44,7 +45,7 @@ from trlx_tpu.utils.modeling import (
     RunningMoments,
     flatten_dict,
     logprobs_of_labels,
-    next_token_logprobs,
+    response_logprobs,
 )
 
 logger = logging.get_logger(__name__)
@@ -522,6 +523,9 @@ class PPOTrainer(MeshRLTrainer):
         key = (B, P, R)
         if key in self._score_fns:
             return self._score_fns[key]
+        # rows the vocabulary head is taken over / rows the forward runs; a
+        # seq2seq decoder's positions are the response already
+        gauges.set("score/head_rows_share", 1.0 if self.is_seq2seq else R / (P + R))
 
         if self.is_seq2seq:
             module, t5 = self.module, self._t5_module()
@@ -573,35 +577,35 @@ class PPOTrainer(MeshRLTrainer):
         peft_base_ref = self.peft_base_ref
         base_trunk = getattr(self, "base_trunk_module", None)
 
+        start = P - 1  # the row whose successor is the first response token
+
         def ppo_score(params, ref_params, frozen_branch, seq, mask):
             with jax.named_scope("policy_forward"):
-                logits, values, branch_hidden, _ = module.apply(
-                    {"params": params}, seq, mask, branch_layer=branch_start
+                hidden, values, branch_hidden, _ = module.apply(
+                    {"params": params}, seq, mask, branch_layer=branch_start, with_head=False
                 )
             with jax.named_scope("logprobs"):
-                logprobs = next_token_logprobs(logits, seq)
+                logprobs = response_logprobs(hidden, head_of(module, params), seq, start, R)
             with jax.named_scope("reference_forward"):
                 if peft_base_ref:
                     # same (frozen) trunk params, adapters structurally disabled
-                    ref_logits, _, _, _ = base_trunk.apply(
-                        {"params": params["transformer"]}, seq, mask
+                    ref_head = head_of(base_trunk, params["transformer"])
+                    _, ref_hidden, _, _ = base_trunk.apply(
+                        {"params": params["transformer"]}, seq, mask, with_head=False
                     )
                 elif branch_start is not None:
-                    ref_logits = module.apply(
+                    ref_head = head_of(trunk, frozen_branch)
+                    ref_hidden = module.apply(
                         {"params": {"transformer": frozen_branch}},
-                        branch_hidden, mask, None, branch_start,
+                        branch_hidden, mask, None, branch_start, with_head=False,
                         method=module.forward_branch,
                     )
                 else:
-                    ref_logits, _, _, _ = trunk.apply({"params": ref_params}, seq, mask)
+                    ref_head = head_of(trunk, ref_params)
+                    _, ref_hidden, _, _ = trunk.apply({"params": ref_params}, seq, mask, with_head=False)
             with jax.named_scope("logprobs"):
-                ref_logprobs = next_token_logprobs(ref_logits, seq)
-            start = P - 1
-            return (
-                logprobs[:, start : start + R],
-                values[:, start : start + R].astype(jnp.float32),
-                ref_logprobs[:, start : start + R],
-            )
+                ref_logprobs = response_logprobs(ref_hidden, ref_head, seq, start, R)
+            return logprobs, values[:, start : start + R].astype(jnp.float32), ref_logprobs
 
         self._score_fns[key] = jax.jit(
             ppo_score, out_shardings=mesh_lib.replicated(self.mesh)
@@ -1556,6 +1560,7 @@ class PPOTrainer(MeshRLTrainer):
         key = (B, P, R)
         if key in self._train_steps:
             return self._train_steps[key]
+        gauges.set("learn/head_rows_share", 1.0 if self.is_seq2seq else R / (P + R))  # as in _get_score_fn
         module, method = self.module, self.method
 
         # staleness-aware IS correction (async engine only): the mode is fixed
@@ -1606,15 +1611,14 @@ class PPOTrainer(MeshRLTrainer):
             seq = jnp.concatenate([mb.query_tensors, mb.response_tensors], axis=1)
             mask = jnp.concatenate([mb.attention_mask, mb.response_mask], axis=1)
             if counts_experts:  # the expert layers' loads come out beside the forward's answers
-                (logits, values_pred, _, _), sown = module.apply(
-                    {"params": params}, seq, mask, mutable=["moe_stats"]
+                (hidden, values_pred, _, _), sown = module.apply(
+                    {"params": params}, seq, mask, with_head=False, mutable=["moe_stats"]
                 )
             else:
-                logits, values_pred, _, _ = module.apply({"params": params}, seq, mask)
-            logprobs = next_token_logprobs(logits, seq)
+                hidden, values_pred, _, _ = module.apply({"params": params}, seq, mask, with_head=False)
             start = mb.query_tensors.shape[1] - 1
             Rr = mb.response_tensors.shape[1]
-            logprobs = logprobs[:, start : start + Rr]
+            logprobs = response_logprobs(hidden, head_of(module, params), seq, start, Rr)
             values_pred = values_pred[:, start : start + Rr].astype(jnp.float32)
             advantages, returns = method.get_advantages_and_returns(
                 mb.values, mb.rewards, mb.response_mask

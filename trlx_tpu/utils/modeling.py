@@ -8,12 +8,17 @@ reference's ``torch.distributed.all_reduce`` plumbing disappears. Explicit named
 variants are provided for use inside ``shard_map`` regions.
 """
 
+import functools
 from typing import Any, Dict, MutableMapping, Optional, Tuple
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
+
+from trlx_tpu.utils import logging
+
+logger = logging.get_logger(__name__)
 
 
 def make_head_init(scale: float = 0.02):
@@ -35,19 +40,77 @@ def logprobs_of_labels(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
     return jnp.take_along_axis(logprobs, labels[..., None], axis=-1)[..., 0]
 
 
-def next_token_logprobs(logits: jnp.ndarray, tokens: jnp.ndarray) -> jnp.ndarray:
-    """Log-probability of each token's successor: logits [B, T, V], tokens
-    [B, T] -> [B, T-1].
+def response_logprobs(
+    hidden: jnp.ndarray, head, tokens: jnp.ndarray, start: int, length: int
+) -> jnp.ndarray:
+    """Log-probabilities of the response tokens from the final hidden states:
+    ``hidden`` [B, T, d] post-norm, ``head`` the vocabulary head (rows
+    [B, R, d] -> logits [B, R, V] in the compute dtype: ``TransformerLM.head``
+    applied with the head's weights), ``tokens`` [B, T] -> [B, R], the
+    log-probability of tokens ``start+1 … start+length`` given what precedes
+    each (a PPO caller passes ``start = P - 1``, ``length = R``).
 
-    The labels are aligned at every position and the [B, T] result is sliced,
-    never the [B, T, V] logits: a slice there puts a vocab-sized ``pad`` into
-    the backward, and the TPU compiler failed on exactly that in the sharded
-    (fsdp=4) PPO step at gpt2's vocab of 50257 ("INTERNAL: ... Bitcast cannot
-    have different shape sizes of output and operand", PR 22). The values are
-    those of ``logprobs_of_labels(logits[:, :-1], tokens[:, 1:])``.
+    The head is taken over the window's rows alone: the *hidden states* are
+    sliced, never ``[B, T, V]`` logits. Besides the rows nobody reads, a slice
+    of the logits puts a vocab-sized ``pad`` into the backward, and the TPU
+    compiler failed on exactly that in the sharded (fsdp=4) PPO step at gpt2's
+    vocab of 50257 ("INTERNAL: ... Bitcast cannot have different shape sizes
+    of output and operand", PR 22); the backward here pads ``[B, R, d]`` to
+    ``[B, T, d]``. The values are those of
+    ``logprobs_of_labels(head(hidden)[:, :-1], tokens[:, 1:])[:, start:start+length]``
+    row for row: same operands, same dtypes, the same float32 reduction.
     """
-    labels = jnp.concatenate([tokens[:, 1:], tokens[:, :1]], axis=1)  # the last is never read
-    return logprobs_of_labels(logits, labels)[:, :-1]
+    rows = jax.lax.slice_in_dim(hidden, start, start + length, axis=1)
+    labels = jax.lax.slice_in_dim(tokens, start + 1, start + 1 + length, axis=1)
+    logits = head(rows)
+    _log_head_rows(hidden.shape, length, logits.shape[-1], str(logits.dtype))
+    return _logprobs_lean(logits, labels)
+
+
+@functools.lru_cache(maxsize=None)
+def _log_head_rows(hidden_shape, rows, vocab, dtype):
+    """The window taken, once per traced shape."""
+    B, T, d = hidden_shape
+    row_mib = B * vocab * jnp.dtype(dtype).itemsize / 2**20  # one position's logits over the batch
+    logger.info(  # graftcheck: noqa[JX003] — once per traced shape is the point
+        f"vocabulary head h[{B},{T},{d}] → rows {rows} of {T}, V {vocab} {dtype}:"
+        f" logits {rows * row_mib:.1f} MiB where every row would take {T * row_mib:.1f}"
+    )
+
+
+@jax.custom_vjp
+def _logprobs_lean(logits: jnp.ndarray, labels: jnp.ndarray) -> jnp.ndarray:
+    """``logprobs_of_labels`` that keeps no float32 ``[rows, V]`` tensor between
+    forward and backward: the residuals are the logits as they arrive (bf16 on
+    TPU) and one float32 log-sum-exp a row."""
+    return _logprobs_lean_fwd(logits, labels)[0]
+
+
+def _at_label(labels, shape):
+    """[..., V] mask of each row's label."""
+    return jax.lax.broadcasted_iota(labels.dtype, shape, len(shape) - 1) == labels[..., None]
+
+
+def _logprobs_lean_fwd(logits, labels):
+    x = logits.astype(jnp.float32)
+    top = x.max(axis=-1, keepdims=True)
+    shifted = x - top
+    log_sum = jnp.log(jnp.exp(shifted).sum(axis=-1, keepdims=True))
+    # the label's logit by a masked sum (one term is not zero, so it is exact),
+    # which fuses into the pass that sums the exponentials; a gather does not
+    picked = jnp.where(_at_label(labels, x.shape), shifted, 0.0).sum(axis=-1)
+    return picked - log_sum[..., 0], (logits, top + log_sum, labels)
+
+
+def _logprobs_lean_bwd(residuals, g):
+    logits, lse, labels = residuals
+    x = logits.astype(jnp.float32)
+    # g · (onehot − softmax) in float32, cast where it enters the head's products
+    d_logits = g[..., None] * (_at_label(labels, x.shape).astype(jnp.float32) - jnp.exp(x - lse))
+    return d_logits.astype(logits.dtype), None
+
+
+_logprobs_lean.defvjp(_logprobs_lean_fwd, _logprobs_lean_bwd)
 
 
 def masked_mean(x: jnp.ndarray, mask: jnp.ndarray, axis=None) -> jnp.ndarray:
