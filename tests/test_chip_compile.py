@@ -196,6 +196,12 @@ for _shape, _kv_heads in [(s, None) for s in CELL_SHAPES] + LONG_SHAPES:
 for _T in (513, 576, 640):
     CASES[f"flash_fwd-4x16x{_T}x192-v128"] = functools.partial(_flash, False, (4, 16, _T, 192), None, jnp.bfloat16, 128)
     CASES[f"flash_grad-4x16x{_T}x192-v128"] = functools.partial(_flash, True, (4, 16, _T, 192), None, jnp.bfloat16, 128)
+# D = 128 at the ouro-2.6b cell's shapes: a learner microbatch (8 x 257), a scoring chunk at its buckets (64 + 256),
+# the prefill of 128 prompts; the (pass, layer) loop calls them 20 times a forward at one shape
+for _shape in ((8, 16, 257, 128), (32, 16, 320, 128), (128, 16, 64, 128)):
+    _name = "x".join(map(str, _shape))
+    CASES[f"flash_fwd-{_name}"] = functools.partial(_flash, False, _shape)
+    CASES[f"flash_grad-{_name}"] = functools.partial(_flash, True, _shape)
 # the grouped expert products: a learner microbatch (4 x 513 tokens) and a decode step (128)
 for _tokens in (2052, 128):
     CASES[f"grouped_products_fwd-{_tokens}"] = functools.partial(_grouped_products, False, _tokens)
@@ -204,6 +210,8 @@ for _tokens in (2052, 128):
 CASES["decode-128x12x512x64"] = functools.partial(_decode, (128, 12, 512, 64))
 CASES["decode-64x16x576x64"] = functools.partial(_decode, (64, 16, 576, 64))
 CASES["decode-32x32x1024x128-hkv8"] = functools.partial(_decode, (32, 32, 1024, 128), 8)
+# the ouro-2.6b cell's decode step: 16 kv heads of 128, a kv slot of one operand 512 KiB against gpt2's 196 KB
+CASES["decode-128x16x256x128"] = functools.partial(_decode, (128, 16, 256, 128))
 for _preset in ("gpt2", "gpt_bigcode"):  # Hkv=12 rep=1 D=64; Hkv=1 rep=16 D=128
     for _pool, _quant in (("bf16", False), ("int8", True)):
         CASES[f"paged_decode-{_pool}-{_preset}"] = functools.partial(

@@ -546,6 +546,54 @@ def _kimi_to_params(sd, c):
     return p
 
 
+#: Ouro's published names of a block's norms and projections -> this program's
+_OURO_NORMS = (
+    ("ln_1", "input_layernorm"), ("ln_1_post", "input_layernorm_2"),
+    ("ln_2", "post_attention_layernorm"), ("ln_2_post", "post_attention_layernorm_2"),
+)
+_OURO_LINEARS = (
+    *((("attn", f"{n}_proj"), f"self_attn.{n}_proj") for n in "qkvo"),
+    *((("mlp", f"{n}_proj"), f"mlp.{n}_proj") for n in ("gate", "up", "down")),
+)
+
+
+def _ouro_to_params(sd, c):
+    """Ouro (a looped model: one stack of layers, so one set of names): four
+    norms a block, no biases but the exit gate's."""
+    p = {
+        "embed_tokens": {"embedding": sd["model.embed_tokens.weight"]},
+        "ln_f": {"scale": sd["model.norm.weight"]},
+        "exit_gate": {"kernel": sd["model.early_exit_gate.weight"].T, "bias": sd["model.early_exit_gate.bias"]},
+    }
+    if not c.tie_word_embeddings:
+        p["lm_head"] = {"kernel": sd["lm_head.weight"].T}
+    for i in range(c.num_layers):
+        pre = f"model.layers.{i}"
+        layer = {ours: {"scale": sd[f"{pre}.{theirs}.weight"]} for ours, theirs in _OURO_NORMS}
+        for (module, ours), theirs in _OURO_LINEARS:
+            layer.setdefault(module, {})[ours] = _linear(sd, f"{pre}.{theirs}")
+        p[f"layers_{i}"] = layer
+    return p
+
+
+def _ouro_from_params(p, c):
+    sd = {
+        "model.embed_tokens.weight": p["embed_tokens"]["embedding"],
+        "model.norm.weight": p["ln_f"]["scale"],
+        "model.early_exit_gate.weight": p["exit_gate"]["kernel"].T,
+        "model.early_exit_gate.bias": p["exit_gate"]["bias"],
+    }
+    if "lm_head" in p:
+        sd["lm_head.weight"] = p["lm_head"]["kernel"].T
+    for i in range(c.num_layers):
+        L, pre = p[f"layers_{i}"], f"model.layers.{i}"
+        for ours, theirs in _OURO_NORMS:
+            sd[f"{pre}.{theirs}.weight"] = L[ours]["scale"]
+        for (module, ours), theirs in _OURO_LINEARS:
+            sd[f"{pre}.{theirs}.weight"] = L[module][ours]["kernel"].T
+    return sd
+
+
 CONVERTERS = {
     "gpt2": (_gpt2_to_params, _gpt2_from_params),
     "llama": (_llama_to_params, _llama_from_params),
@@ -556,6 +604,7 @@ CONVERTERS = {
     "gpt_bigcode": (_bigcode_to_params, _bigcode_from_params),
     # load only: a share of the experts and of the vocabulary is no whole checkpoint to export
     "kimi_vl": (_kimi_to_params, None),
+    "ouro": (_ouro_to_params, _ouro_from_params),
 }
 # "t5" is registered below once its converters are defined (seq2seq section)
 
@@ -657,7 +706,7 @@ def load_pretrained(
 
 def _family_of(name: str) -> str:
     key = name.lower().replace("-", "").replace("_", "")
-    for family in ("gptbigcode", "gptneox", "gptj", "gpt2", "llama", "opt", "bloom", "kimivl"):
+    for family in ("gptbigcode", "gptneox", "gptj", "gpt2", "llama", "opt", "bloom", "kimivl", "ouro"):
         if family in key:
             return {"gptneox": "gpt_neox", "gptbigcode": "gpt_bigcode", "kimivl": "kimi_vl"}.get(family, family)
     if "pythia" in key or "neox" in key:
@@ -737,6 +786,17 @@ def make_hf_config(model_type: str, c: TransformerConfig):
             n_head=c.num_heads, n_positions=c.max_position_embeddings,
             n_inner=c.ffn_dim, layer_norm_epsilon=c.norm_eps,
             multi_query=c.kv_heads == 1, activation_function="gelu_pytorch_tanh",
+        )
+    if model_type == "ouro":
+        # transformers has no class for it (the published model brings its own code): the published keys
+        published = type("OuroConfig", (transformers.PretrainedConfig,), {"model_type": "ouro"})
+        return published(
+            vocab_size=c.vocab_size, hidden_size=c.hidden_size, num_hidden_layers=c.num_layers,
+            num_attention_heads=c.num_heads, num_key_value_heads=c.kv_heads, head_dim=c.dim_per_head,
+            intermediate_size=c.ffn_dim, max_position_embeddings=c.max_position_embeddings,
+            rms_norm_eps=c.norm_eps, rope_theta=c.rope_theta, hidden_act="silu",
+            tie_word_embeddings=c.tie_word_embeddings, total_ut_steps=c.loop_steps,
+            early_exit_threshold=c.early_exit_threshold,
         )
     if model_type == "t5":
         return transformers.T5Config(

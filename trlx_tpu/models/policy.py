@@ -37,8 +37,10 @@ class CausalLMWithValueHead(nn.Module):
     num_value_layers: int = 0
 
     def setup(self):
-        from trlx_tpu.models.transformer import Block, _norm_module
+        from trlx_tpu.models.transformer import _LOOP_REFUSALS, Block, _norm_module
 
+        if self.num_value_layers > 0 and self.config.loop_steps > 1:
+            raise ValueError(_LOOP_REFUSALS["branch"])
         if self.num_value_layers > self.config.num_layers:
             raise ValueError(
                 f"num_value_layers_unfrozen={self.num_value_layers} exceeds "
@@ -201,6 +203,10 @@ def branch_param_subtree(trunk_params: Dict[str, Any], start_layer: int, config:
     """Extract the frozen reference-branch params: top layers + final norm + output
     head (+ tied embedding). This is the JAX analogue of the reference's
     ``deepcopy`` of unfrozen blocks into ``frozen_head`` (modeling_ppo.py:385-410)."""
+    if config.loop_steps > 1:
+        from trlx_tpu.models.transformer import _LOOP_REFUSALS
+
+        raise ValueError(_LOOP_REFUSALS["branch"])
     t = unfreeze(trunk_params) if hasattr(trunk_params, "unfreeze") else dict(trunk_params)
     sub: Dict[str, Any] = {}
     for i in range(start_layer, config.num_layers):

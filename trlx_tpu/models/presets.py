@@ -72,6 +72,17 @@ PRESETS: Dict[str, TransformerConfig] = {
         moe_intermediate_size=1408, first_dense_layers=1, routed_scaling_factor=2.446,
         norm_topk_prob=True,
     ),
+    # Ouro-2.6B, a looped language model: the 48 layers are applied total_ut_steps = 4
+    # times over the same weights, the final norm after every pass; sandwich norms
+    # (four RMSNorms a block); a per-pass exit gate whose published threshold of 1
+    # lets no token leave early.
+    "ouro": TransformerConfig(
+        vocab_size=49152, hidden_size=2048, num_layers=48, num_heads=16, head_dim=128,
+        intermediate_size=5632, max_position_embeddings=65536, pos_embedding="rotary",
+        rope_style="neox", rope_theta=1000000.0, norm="rmsnorm", norm_eps=1e-6,
+        activation="silu", glu=True, attn_bias=False, mlp_bias=False, tie_word_embeddings=False,
+        loop_steps=4, sandwich_norms=True, exit_gate=True, early_exit_threshold=1.0,
+    ),
 }
 
 
@@ -82,7 +93,7 @@ def get_preset(name: str, overrides: Optional[Dict[str, Any]] = None) -> Transfo
     if key in PRESETS:
         config = PRESETS[key]
     else:
-        for family in ("gpt_bigcode", "gpt_neox", "gptj", "gpt2", "llama", "opt", "bloom", "kimi_vl"):
+        for family in ("gpt_bigcode", "gpt_neox", "gptj", "gpt2", "llama", "opt", "bloom", "kimi_vl", "ouro"):
             if family.replace("_", "") in key.replace("_", "").replace("-", ""):
                 config = PRESETS[family]
                 break
@@ -185,6 +196,25 @@ def from_hf_config(hf_config, overrides: Optional[Dict[str, Any]] = None) -> Tra
             num_shared_experts=text.n_shared_experts, moe_intermediate_size=text.moe_intermediate_size,
             first_dense_layers=text.first_k_dense_replace,
             routed_scaling_factor=text.routed_scaling_factor, norm_topk_prob=text.norm_topk_prob,
+        )
+    elif mt == "ouro":
+        unsupported = {
+            "rope_scaling": getattr(hf_config, "rope_scaling", None) is not None,
+            "use_sliding_window": bool(getattr(hf_config, "use_sliding_window", False)),
+            "hidden_act other than silu": getattr(hf_config, "hidden_act", "silu") != "silu",
+        }
+        if any(unsupported.values()):
+            raise ValueError(f"{mt}: not supported: {[k for k, v in unsupported.items() if v]}")
+        config = PRESETS["ouro"].replace(
+            vocab_size=hf_config.vocab_size, hidden_size=hf_config.hidden_size,
+            num_layers=hf_config.num_hidden_layers, num_heads=hf_config.num_attention_heads,
+            num_kv_heads=getattr(hf_config, "num_key_value_heads", None),
+            head_dim=getattr(hf_config, "head_dim", None), intermediate_size=hf_config.intermediate_size,
+            max_position_embeddings=hf_config.max_position_embeddings,
+            rope_theta=float(getattr(hf_config, "rope_theta", 10000.0)), norm_eps=hf_config.rms_norm_eps,
+            tie_word_embeddings=getattr(hf_config, "tie_word_embeddings", False),
+            loop_steps=hf_config.total_ut_steps,
+            early_exit_threshold=float(getattr(hf_config, "early_exit_threshold", 1.0)),
         )
     else:
         raise ValueError(f"Unsupported HF model_type {mt!r}")

@@ -16,6 +16,7 @@ generation jits to a single XLA while-loop; activations can be sequence-sharded 
 ``with_sharding_constraint`` hooks (Megatron-SP analogue).
 """
 
+import contextlib
 import math
 from dataclasses import dataclass, replace
 from typing import Any, Dict, Optional, Tuple
@@ -146,6 +147,18 @@ class TransformerConfig:
     first_dense_layers: int = 0
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = True
+    # Looped layers: the whole stack is applied loop_steps times over the same
+    # parameters, the final norm at the end of every pass and its output fed to
+    # the next; the cache holds keys and values of every (pass, layer), entry
+    # pass * num_layers + layer, read only by that pass of later tokens.
+    # sandwich_norms puts a norm after each sub-layer as well as before it
+    # (ln_1_post, ln_2_post). exit_gate holds the leaf of a per-pass exit gate
+    # (hidden -> 1, with a bias); at early_exit_threshold >= 1 no token leaves
+    # early, every pass runs and the gate is read for the loop/ counters alone.
+    loop_steps: int = 1
+    sandwich_norms: bool = False
+    exit_gate: bool = False
+    early_exit_threshold: float = 1.0
 
     @property
     def stacked(self) -> bool:
@@ -158,6 +171,11 @@ class TransformerConfig:
     @property
     def held_experts(self) -> int:
         return self.num_experts if self.experts_held is None else self.experts_held
+
+    @property
+    def cache_entries(self) -> int:
+        """Layer caches a forward writes: one for every (pass, layer)."""
+        return self.loop_steps * self.num_layers
     # Megatron-SP analogue: shard the residual stream's sequence dim over the
     # `model` axis between blocks (reference sequence_parallel cfg,
     # modeling_nemo_ppo.py:160-164). Applied on cache-free forwards.
@@ -519,6 +537,24 @@ _MLA_REFUSALS = {
 }
 
 
+_LOOP_REFUSALS = {
+    "stacked": "scan_layers / pipeline_stages > 1 scan one stack of layers over one cache array [L, ...] once; "
+               "looped layers (loop_steps > 1) walk the stack loop_steps times over a cache entry for every "
+               "(pass, layer), which the stage scan does not carry",
+    "kv_cache_quant": "kv_cache_quant has not been held against a reference through looped layers (loop_steps > 1), "
+                      "whose later passes read what the earlier ones rounded",
+    "paged": "the paged block pool holds num_layers pools a token; looped layers (loop_steps > 1) need one for "
+             "every (pass, layer), which the allocator and the paged kernels' callers do not lay out",
+    "branch": "a looped model (loop_steps > 1) has no frozen trunk under unfrozen top layers: the top layers feed "
+              "the bottom ones of the next pass, so there is no layer from which a hydra or value branch could "
+              "start; use a full reference copy (num_layers_unfrozen=-1) and num_value_layers_unfrozen=0",
+    "early_exit": "early_exit_threshold < 1 lets the rows of one fixed-shape batch leave at different passes "
+                  "(and leaves the later passes' cache entries of a token unwritten), which this forward does "
+                  "not do: every pass runs for every token",
+    "final_norm": "looped layers (loop_steps > 1) feed each pass the final norm's output; final_norm=False has none",
+}
+
+
 class LatentAttention(nn.Module):
     """Multi-head latent attention (``attention_kind="mla"``).
 
@@ -680,6 +716,14 @@ def moe_counters(moe_stats) -> Dict[str, jnp.ndarray]:
     }
 
 
+def loop_counters(loop_stats) -> Dict[str, jnp.ndarray]:
+    """The ``loop_stats`` collection of one forward as counters (float32
+    scalars): ``loop/hidden_delta_<t>`` for every pass after the first and,
+    with an exit gate, ``loop/exit_pass_expected``."""
+    # sow keeps a tuple under each name: a leaf's path ends (..., name, index in the tuple)
+    return {f"loop/{path[-2].key}": value for path, value in jax.tree_util.tree_leaves_with_path(loop_stats)}
+
+
 class Block(nn.Module):
     config: TransformerConfig
     expert_layer: bool = False  # this layer's FFN is the sparse one (config.is_expert_layer)
@@ -690,6 +734,8 @@ class Block(nn.Module):
         attention = LatentAttention if c.attention_kind == "mla" else Attention
         mlp = SparseMLP if self.expert_layer else MLP
         if c.parallel_residual:
+            if c.sandwich_norms:
+                raise ValueError("sandwich_norms norm each sub-layer's output on its own; parallel_residual sums them")
             h1 = _norm_module(c, "ln_1")(x)
             h2 = h1 if c.shared_parallel_ln else _norm_module(c, "ln_2")(x)
             attn_out, new_cache = attention(c, name="attn")(h1, mask_bias, positions, cache, kv_valid)
@@ -701,8 +747,13 @@ class Block(nn.Module):
         attn_out, new_cache = attention(c, name="attn")(
             _norm_module(c, "ln_1")(x), mask_bias, positions, cache, kv_valid
         )
+        if c.sandwich_norms:
+            attn_out = _norm_module(c, "ln_1_post")(attn_out)
         x = x + attn_out
-        x = x + mlp(c, name="mlp")(_norm_module(c, "ln_2")(x))
+        mlp_out = mlp(c, name="mlp")(_norm_module(c, "ln_2")(x))
+        if c.sandwich_norms:
+            mlp_out = _norm_module(c, "ln_2_post")(mlp_out)
+        x = x + mlp_out
         # per-layer Megatron-SP residual constraint lives HERE (not in the caller's
         # layer loop) so every path — listed loop, nn.scan stack, value branch,
         # forward_from — gets it identically
@@ -751,6 +802,19 @@ class TransformerLM(nn.Module):
             for refused, why in (("stacked", c.stacked), ("kv_cache_quant", c.kv_cache_quant)):
                 if why:
                     raise ValueError(_MLA_REFUSALS[refused])
+        if c.early_exit_threshold < 1:
+            raise ValueError(_LOOP_REFUSALS["early_exit"])
+        if c.loop_steps > 1:
+            for refused, why in (
+                ("stacked", c.stacked), ("kv_cache_quant", c.kv_cache_quant), ("final_norm", not c.final_norm),
+            ):
+                if why:
+                    raise ValueError(_LOOP_REFUSALS[refused])
+        if c.exit_gate:
+            self.exit_gate = nn.Dense(
+                1, use_bias=True, dtype=c.compute_dtype, param_dtype=c.param_dtype,
+                kernel_init=nn.initializers.normal(c.initializer_range),
+            )
         if c.stacked:
             if c.pipeline_stages > 1:
                 if c.num_layers % c.pipeline_stages != 0:
@@ -832,6 +896,8 @@ class TransformerLM(nn.Module):
         ``with_head=False`` leaves the vocabulary head out (logits is None): the
         caller applies :meth:`head` to the rows it reads."""
         c = self.config
+        if branch_layer is not None and c.loop_steps > 1:
+            raise NotImplementedError(_LOOP_REFUSALS["branch"])
         B, T = input_ids.shape
         nv = c.num_virtual_tokens if c.peft_type == "prompt" else 0
         # prompt tuning prepends nv virtual rows internally; the external
@@ -940,22 +1006,33 @@ class TransformerLM(nn.Module):
                 )
             x, stacked_kv = self._apply_stacked(x, mask_bias, layer_positions, cache, kv_valid)
         else:
-            new_layer_caches = []
-            for i, layer in enumerate(self.layers):
-                if i in capture_set:
-                    captures[i] = x
-                layer_cache = None
-                if cache is not None:
-                    layer_cache = {
-                        key: cache[key][i] for key in cache if key != "index"
-                    }
-                    layer_cache["index"] = cache["index"]
-                x, new_lc = layer(x, mask_bias, layer_positions, layer_cache, kv_valid)
-                if cache is not None:
-                    new_layer_caches.append(new_lc)
+            # the loop/ counters are computed where a caller asks for the collection, and at init (the gate's leaf)
+            counting = (c.loop_steps > 1 or c.exit_gate) and (
+                self.is_initializing() or self.is_mutable_collection("loop_stats"))
+            new_layer_caches, states = [], []
+            for step in range(c.loop_steps):
+                # one pass of the stack; a model that does not loop traces what it traced before the loop was here
+                with jax.named_scope("loop.pass") if c.loop_steps > 1 else contextlib.nullcontext():
+                    for i, layer in enumerate(self.layers):
+                        if i in capture_set:
+                            captures[i] = x
+                        layer_cache = None
+                        if cache is not None:
+                            entry = step * c.num_layers + i  # keys and values of this pass, read by this pass alone
+                            layer_cache = {
+                                key: cache[key][entry] for key in cache if key != "index"
+                            }
+                            layer_cache["index"] = cache["index"]
+                        x, new_lc = layer(x, mask_bias, layer_positions, layer_cache, kv_valid)
+                        if cache is not None:
+                            new_layer_caches.append(new_lc)
+                    if step < c.loop_steps - 1:  # the last pass's norm is _final's, below
+                        x = self.ln_f(x)
+                        if counting:
+                            states.append(x[:, nv_rows:])
             stacked_kv = None
             if cache is not None:
-                # keep the per-layer list layout (no jnp.stack: restacking would
+                # keep the per-entry list layout (no jnp.stack: restacking would
                 # copy the full cache every decode step)
                 stacked_kv = {
                     key: [lc[key] for lc in new_layer_caches]
@@ -969,6 +1046,9 @@ class TransformerLM(nn.Module):
         if nv_rows:  # drop virtual rows: external output shape is [B, T, ...]
             logits = None if logits is None else logits[:, nv_rows:]
             hidden = hidden[:, nv_rows:]
+        if not c.stacked and counting:
+            real = attention_mask if cache is None and attention_mask is not None else jnp.ones((B, T), jnp.int32)
+            self._sow_loop_stats(states + [hidden], real.astype(jnp.float32))
         new_cache = None
         if cache is not None:
             if c.stacked:
@@ -986,6 +1066,26 @@ class TransformerLM(nn.Module):
         else:
             branch_out = captures if isinstance(branch_layer, tuple) else None
         return logits, hidden, branch_out, new_cache
+
+    def _sow_loop_stats(self, states, real) -> None:
+        """The ``loop_stats`` collection of one forward (:func:`loop_counters`),
+        means over the real tokens ``real`` [B, T] marks, from the normed state
+        after each pass: how far each further pass still moves the state, and
+        the pass the gate's exit distribution expects to leave at
+        (``p_t = lambda_t prod_{j<t} (1 - lambda_j)``, the rest at the last)."""
+        states = [h.astype(jnp.float32) for h in states]
+        mean = lambda v: (v * real).sum() / jnp.maximum(real.sum(), 1.0)
+        norm = lambda h: jnp.sqrt((h * h).sum(-1))
+        for t in range(1, len(states)):
+            moved = norm(states[t] - states[t - 1]) / jnp.maximum(norm(states[t - 1]), 1e-30)
+            self.sow("loop_stats", f"hidden_delta_{t + 1}", mean(moved))
+        if self.config.exit_gate:
+            stay, expected = 1.0, 0.0
+            for t, h in enumerate(states):
+                leave = jax.nn.sigmoid(self.exit_gate(h).astype(jnp.float32)[..., 0])
+                expected = expected + (t + 1) * (stay if t == len(states) - 1 else leave * stay)
+                stay = stay * (1.0 - leave)
+            self.sow("loop_stats", "exit_pass_expected", mean(expected))
 
     def _apply_stacked(self, x, mask_bias, positions, cache, kv_valid):
         """Run the stacked block stack (``pipeline_stages > 1`` or ``scan_layers`` layout).
@@ -1031,6 +1131,8 @@ class TransformerLM(nn.Module):
                 "hydra branch forwards need per-layer params; stacked models "
                 "use a separate reference model (num_layers_unfrozen=-1)"
             )
+        if self.config.loop_steps > 1:
+            raise NotImplementedError(_LOOP_REFUSALS["branch"])
         B, T, _ = hidden.shape
         default_positions, mask_bias = make_attn_bias(self.config, attention_mask, B, T)
         if positions is None:
@@ -1070,6 +1172,11 @@ class TransformerLM(nn.Module):
             from trlx_tpu.utils.metrics import gauges
 
             gauges.set("mla/cache_bytes_per_token", c.num_layers * kv_cache.bytes_per_token(per_layer))
+        if c.loop_steps > 1:
+            from trlx_tpu.utils.metrics import gauges
+
+            gauges.set("loop/passes", c.loop_steps)
+            gauges.set("loop/cache_bytes_per_token", c.cache_entries * kv_cache.bytes_per_token(per_layer))
         if c.stacked:
             # nn.scan layout needs one [L, ...] array per k/v
             out = {
@@ -1085,9 +1192,10 @@ class TransformerLM(nn.Module):
         # true in-place single-token write. A single stacked [L, ...] array forces
         # XLA to slice out every layer and re-stack the WHOLE cache each step —
         # profiled at 3.6ms of a 4.65ms gpt2-124M decode step on one v5e chip
-        # (~15x the HBM bound for this model).
+        # (~15x the HBM bound for this model). Looped layers hold an entry for
+        # every (pass, layer) in the same list, entry pass * num_layers + layer.
         out = {
-            key: [jnp.zeros(shp, dt) for _ in range(c.num_layers)]
+            key: [jnp.zeros(shp, dt) for _ in range(c.cache_entries)]
             for key, (shp, dt) in per_layer.items()
         }
         out["index"] = jnp.array(0, jnp.int32)
@@ -1107,6 +1215,8 @@ class TransformerLM(nn.Module):
         c = self.config
         if c.attention_kind == "mla":
             raise ValueError(_MLA_REFUSALS["paged"])
+        if c.loop_steps > 1:
+            raise ValueError(_LOOP_REFUSALS["paged"])
         layout = paged_pool_layout(
             num_blocks, block_size, c.kv_heads, c.dim_per_head,
             dtype or c.compute_dtype, c.kv_cache_quant,
@@ -1137,6 +1247,8 @@ class TransformerLM(nn.Module):
         null block table row) still produce finite output — the engine
         discards it."""
         c = self.config
+        if c.loop_steps > 1:
+            raise ValueError(_LOOP_REFUSALS["paged"])
         if c.stacked:
             raise NotImplementedError("paged decode: per-layer list layout only")
         if c.peft_type in ("prompt", "prefix"):
@@ -1179,6 +1291,8 @@ class TransformerLM(nn.Module):
         list layout and the stacked ``scan_layers`` layout (pools ``[L, ...]``,
         walked by the layer scan with the table/lens broadcast across L)."""
         c = self.config
+        if c.loop_steps > 1:
+            raise ValueError(_LOOP_REFUSALS["paged"])
         if c.peft_type in ("prompt", "prefix"):
             raise NotImplementedError("paged verify does not support peft prompt/prefix")
         B, Q = input_ids.shape

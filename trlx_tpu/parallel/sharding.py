@@ -68,6 +68,11 @@ def default_lm_rules() -> List[Rule]:
         (r".*experts/(gate|up)$", PartitionSpec(None, FSDP_AXIS, MODEL_AXIS)),
         (r".*experts/down$", PartitionSpec(None, MODEL_AXIS, FSDP_AXIS)),
         (r".*router/kernel$", PartitionSpec(FSDP_AXIS, None)),
+        # looped layers: the exit gate [hidden, 1] over fsdp; its bias and the sandwich
+        # norms' scales (ln_1_post, ln_2_post) are replicated like every norm
+        (r".*exit_gate/kernel$", PartitionSpec(FSDP_AXIS, None)),
+        (r".*exit_gate/bias$", PartitionSpec()),
+        (r".*ln_[12]_post/scale$", PartitionSpec()),
         # mlp: up/gate column-parallel; down row-parallel
         (r".*(up_proj|gate_proj)/kernel$", PartitionSpec(FSDP_AXIS, MODEL_AXIS)),
         (r".*(up_proj|gate_proj)/bias$", PartitionSpec(MODEL_AXIS)),

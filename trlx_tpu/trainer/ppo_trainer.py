@@ -29,7 +29,7 @@ from trlx_tpu.models.policy import (
     branch_param_subtree,
     head_of,
 )
-from trlx_tpu.models.transformer import TransformerLM, moe_counters
+from trlx_tpu.models.transformer import TransformerLM, loop_counters, moe_counters
 from trlx_tpu.obs import compile_log, span
 from trlx_tpu.obs.flight import flight
 from trlx_tpu.ops.generation import LENGTH_BUCKETS, left_pad_batch, pad_to_bucket
@@ -1606,13 +1606,17 @@ class PPOTrainer(MeshRLTrainer):
             return self._train_steps[key]
 
         counts_experts = self.model_config.num_experts > 0
+        counts_loops = self.model_config.loop_steps > 1
+        sown_collections = [
+            name for name, counted in (("moe_stats", counts_experts), ("loop_stats", counts_loops)) if counted
+        ]
 
         def loss_fn(params, mb: PPORLBatch):
             seq = jnp.concatenate([mb.query_tensors, mb.response_tensors], axis=1)
             mask = jnp.concatenate([mb.attention_mask, mb.response_mask], axis=1)
-            if counts_experts:  # the expert layers' loads come out beside the forward's answers
+            if sown_collections:  # the expert layers' loads, the loop's counters: out beside the forward's answers
                 (hidden, values_pred, _, _), sown = module.apply(
-                    {"params": params}, seq, mask, with_head=False, mutable=["moe_stats"]
+                    {"params": params}, seq, mask, with_head=False, mutable=sown_collections
                 )
             else:
                 hidden, values_pred, _, _ = module.apply({"params": params}, seq, mask, with_head=False)
@@ -1634,6 +1638,8 @@ class PPOTrainer(MeshRLTrainer):
                     seq.size * self.model_config.experts_per_token
                     * sum(map(self.model_config.is_expert_layer, range(self.model_config.num_layers)))
                 )
+            if counts_loops:
+                stats.update(loop_counters(sown["loop_stats"]))
             return loss, stats
 
         self._train_steps[key] = self.make_grad_accum_step(
@@ -1669,7 +1675,8 @@ class PPOTrainer(MeshRLTrainer):
         with span("learn.sync"):  # the host waiting for the device
             out = {k: float(v) for k, v in jax.device_get(stats).items()}
         for name, value in out.items():
-            if name.startswith("moe/"):  # a microbatch's counters, summed over the expert layers
+            # a microbatch's counters: the experts' summed over the expert layers, the loop's per pass
+            if name.startswith(("moe/", "loop/")):
                 gauges.set(name, value)
         if self._island is not None:
             # device_get above synced the step; the interval is real compute
