@@ -136,8 +136,9 @@ def phase_kernels(sizes: Sizes) -> None:
 
     # flash attention: forward, and the Pallas backward kernels
     B, T = sizes.flash_batch, sizes.flash_len
+    # [B, T, heads, D]: the layout a model's projections leave q, k, v in, and the kernels take
     q, k, v, g = (
-        jax.random.normal(next(keys), (B, n, T, D), jnp.float32).astype(dtype)
+        jax.random.normal(next(keys), (B, T, n, D), jnp.float32).astype(dtype)
         for n in (H, Hkv, Hkv, H)
     )
     # ragged right padding, as the scoring forward sees it
@@ -149,8 +150,9 @@ def phase_kernels(sizes: Sizes) -> None:
             q, k, v, kv_valid, True, scale, sizes.interpret
         )
 
-    def plain(q, k, v):
-        return attention.xla_attention(q, k, v, kv_valid, True, scale)
+    def plain(q, k, v):  # the plain reference holds the heads before the rows
+        q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+        return attention.xla_attention(q, k, v, kv_valid, True, scale).transpose(0, 2, 1, 3)
 
     def out_and_grads(fn):
         def run(q, k, v, g):

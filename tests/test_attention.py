@@ -9,14 +9,25 @@ import jax
 import jax.numpy as jnp
 
 import trlx_tpu.ops.attention as attn
-from trlx_tpu.ops.attention import choose_tiles, flash_attention, xla_attention
+from trlx_tpu.ops.attention import choose_tiles, flash_attention
+
+
+def _heads_first(x):
+    """``[B, T, H, D]``, the kernels' layout (a model's projections'), to the plain
+    reference's ``[B, H, T, D]``; and back."""
+    return x.transpose(0, 2, 1, 3)
+
+
+def xla_attention(q, k, v, kv_valid, causal, scale):
+    """The plain reference on operands in the kernels' layout."""
+    return _heads_first(attn.xla_attention(*map(_heads_first, (q, k, v)), kv_valid, causal, scale))
 
 
 def make_inputs(B=2, H=2, T=64, S=64, D=16, seed=0):
     rng = np.random.default_rng(seed)
-    q = jnp.asarray(rng.normal(size=(B, H, T, D)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(B, H, S, D)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(B, H, S, D)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(B, T, H, D)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(B, S, H, D)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(B, S, H, D)), jnp.float32)
     return q, k, v
 
 
@@ -57,8 +68,8 @@ def test_flash_gradients_match_xla():
 
 @pytest.mark.parametrize("T,S", [(24, 24), (144, 144), (10, 10), (72, 136)])
 def test_flash_non_block_multiple_shapes(T, S):
-    """The kernel pads T/S to block multiples internally, so mixed P+R shapes
-    (e.g. 16+128=144) and odd prefill lengths take the flash path."""
+    """The kernel's blocks overhang ragged T/S, so mixed P+R shapes (e.g.
+    16+128=144) and odd prefill lengths take the flash path."""
     q, k, v = make_inputs(T=T, S=S, seed=3)
     kv_valid = np.ones((2, S), np.int32)
     kv_valid[0, : S // 4] = 0
@@ -174,9 +185,9 @@ def test_gqa_decode_generation_matches_xla():
 
 def make_gqa_inputs(B=2, H=4, Hkv=2, T=48, S=48, D=16, seed=5):
     rng = np.random.default_rng(seed)
-    q = jnp.asarray(rng.normal(size=(B, H, T, D)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(B, Hkv, S, D)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(B, Hkv, S, D)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(B, T, H, D)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(B, S, Hkv, D)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(B, S, Hkv, D)), jnp.float32)
     return q, k, v
 
 
@@ -196,7 +207,7 @@ def test_flash_gqa_kernel_matches_xla():
     "B,H,Hkv,T,S,maskfrac",
     [
         (2, 2, 2, 64, 64, 0.0),
-        (2, 2, 2, 40, 72, 0.25),  # ragged: internal padding in both T and S
+        (2, 2, 2, 40, 72, 0.25),  # ragged: the blocks overhang both T and S
         (1, 4, 2, 48, 48, 0.3),  # GQA: dk/dv sum over the query-head group
         (2, 4, 1, 33, 62, 0.2),  # MQA + ragged
     ],
@@ -287,29 +298,35 @@ def test_model_gqa_grouped_einsum_matches_repeat():
 # ------------------------------------------------------------ the tile chooser
 
 CELL_LENGTHS = (64, 512, 513, 576, 640)  # prefill, learner and scoring lengths of the benchmark's cells
+# (T, S, D, rep, dtype, H): H None where a model has heads enough for any count a program may want
 CHOOSER_SHAPES = [
-    (T, T, 64, 1, jnp.bfloat16) for T in CELL_LENGTHS + (8, 96, 104, 130, 1024, 1100, 4096)
+    (T, T, 64, 1, jnp.bfloat16, None) for T in CELL_LENGTHS + (8, 96, 104, 130, 1024, 1100, 4096)
 ] + [
-    (T, T, 128, rep, jnp.bfloat16) for T in (2048, 8192, 131072) for rep in (1, 4, 16)
-] + [(72, 136, 16, 1, jnp.float32), (33, 62, 16, 4, jnp.float32), (513, 513, 128, 8, jnp.float32)]
+    (T, T, 128, rep, jnp.bfloat16, None) for T in (2048, 8192, 131072) for rep in (1, 4, 16)
+] + [(72, 136, 16, 1, jnp.float32, 2), (33, 62, 16, 4, jnp.float32, 4), (513, 513, 128, 8, jnp.float32, None)]
 
 
-@pytest.mark.parametrize("T,S,D,rep,dtype", CHOOSER_SHAPES)
-def test_chooser_tiles_fit_the_chip(T, S, D, rep, dtype):
+@pytest.mark.parametrize("T,S,D,rep,dtype,H", CHOOSER_SHAPES)
+def test_chooser_tiles_fit_the_chip(T, S, D, rep, dtype, H):
     """Lane side in multiples of 128, rows in the dtype's sublane multiple,
-    padding only up to the tile, the reckoned VMEM under the budget."""
-    tiles = choose_tiles(T, S, D, rep, dtype)
+    overhang only up to the tile, a program's heads whole lane tiles of its
+    blocks or all there are, the reckoned VMEM under the budget."""
+    tiles = choose_tiles(T, S, D, rep, dtype, H=H)
     sublane = 32 // jnp.dtype(dtype).itemsize
     assert tiles.block_q % 128 == 0 and tiles.block_k % 128 == 0
     assert tiles.block_q not in (96, 104) and tiles.block_k not in (96, 104)
+    assert (tiles.T, tiles.S) == (T, S)
     assert tiles.Tp % tiles.block_q == 0 and tiles.Sp % tiles.block_k == 0
     assert T <= tiles.Tp < T + tiles.block_q and S <= tiles.Sp < S + tiles.block_k
     assert tiles.Tr % sublane == 0 and T <= tiles.Tr <= tiles.Tp
     assert tiles.Sr % sublane == 0 and S <= tiles.Sr <= tiles.Sp
     assert tiles.sub in (128, 256, 512) and max(tiles.block_q, tiles.block_k) <= 8 * tiles.sub
-    assert 1 <= tiles.heads <= 8 and (tiles.whole or tiles.heads == 1)
+    least = 128 // np.gcd(128, D)  # heads to a whole lane tile
+    assert 1 <= tiles.heads <= 8 and (tiles.whole or tiles.heads == (H or least))
+    kv_heads = max(1, tiles.heads // rep)
+    assert tiles.heads == H or ((tiles.heads * D) % 128 == 0 and (kv_heads * D) % 128 == 0)
     assert 0 < tiles.vmem_bytes <= 12 * 2**20
-    assert tiles == choose_tiles(T, S, D, rep, dtype)  # a pure function of the shape
+    assert tiles == choose_tiles(T, S, D, rep, dtype, H=H)  # a pure function of the shape
 
 
 @pytest.mark.parametrize("T", CELL_LENGTHS)
@@ -325,7 +342,7 @@ def test_chooser_walks_long_sequences_in_large_tiles(T, rep):
     tiles = choose_tiles(T, T, 128, rep, jnp.bfloat16)
     assert not tiles.whole and tiles.heads == 1
     assert 128 <= tiles.block_q <= 512 and 128 <= tiles.block_k <= 512
-    assert tiles.Tp == T and tiles.Sp == T  # no padding where the tile divides the length
+    assert tiles.Tp == T and tiles.Sp == T  # no overhang where the tile divides the length
 
 
 def test_chooser_budget_decides_whole_or_walked():
@@ -345,15 +362,56 @@ def test_heads_per_program_divides_heads_and_groups(H, rep, most, want):
     assert attn._heads_per_program(H, rep, most) == want
 
 
+@pytest.mark.parametrize(
+    "H,rep,most,lane,want",
+    [
+        (12, 1, 4, 2, 4), (12, 1, 8, 2, 6), (16, 1, 8, 2, 8),  # D = 64: an even number of heads
+        (16, 4, 8, 2, 8),  # two kv heads to the program's eight query heads
+        (16, 4, 6, 2, 8),  # four query heads would bring one kv head, half a lane tile: the fewest that may
+        (7, 1, 4, 2, 7), (25, 1, 8, 2, 25),  # an odd head count: all the heads, the whole extent
+        (4, 4, 8, 2, 4), (16, 16, 4, 2, 4),  # multi-query: the one kv head the whole extent, a part of the group even
+        (16, 16, 4, 1, 4), (12, 1, 8, 1, 6),  # D = 128: any count
+        (3, 1, 8, 16, 3), (4, 2, 8, 8, 4),  # the tests' small widths: all the heads
+    ],
+)
+def test_heads_per_program_keeps_the_lane_rule(H, rep, most, lane, want):
+    """A block's last dimension is a multiple of 128 or the array's whole
+    extent: the program's query heads, and their kv heads, are a multiple of
+    ``lane`` heads or all of them."""
+    got = attn._heads_per_program(H, rep, most, lane)
+    assert got == want
+    kv_heads, kv_total = max(1, got // rep), H // rep
+    assert H % got == 0 and (got % lane == 0 or got == H) and (kv_heads % lane == 0 or kv_heads == kv_total)
+
+
+@pytest.mark.parametrize(
+    "T,D,Dv,H,rep,lane,heads",
+    [
+        (513, 64, 64, 12, 1, 2, 4), (513, 64, 64, 16, 1, 2, 4), (64, 64, 64, 12, 1, 2, 6),  # the gpt2 cells
+        (513, 192, 128, 16, 1, 2, 2),  # kimi-vl-a3b: 2 x 192 = 384 lanes
+        (257, 128, 128, 16, 1, 1, 8),  # ouro-2.6b
+        (513, 64, 64, 25, 1, 2, 25),  # gpt2-xl's 25 heads of 64: no even count divides them
+        (513, 64, 64, 32, 4, 2, 8),  # grouped at D = 64: eight query heads bring two kv heads
+        (4096, 64, 64, 16, 1, 2, 2),  # walked: the fewest heads the lanes allow
+        (2048, 128, 128, 16, 4, 1, 1),
+    ],
+)
+def test_chooser_learns_the_lane_rule_from_the_shape(T, D, Dv, H, rep, lane, heads):
+    assert attn._lane_heads(D, Dv) == lane
+    tiles = choose_tiles(T, T, D, rep, jnp.bfloat16, Dv=Dv, H=H)
+    assert tiles.heads == heads and tiles.heads in attn._head_counts(H, rep, lane)
+    assert tiles.vmem_bytes <= 12 * 2**20
+
+
 # ------------------------------------- parity on both sides of the chooser's threshold
 
 
-def _walked(T, S, D, rep, dtype, Dv=None):
+def _walked(T, S, D, rep, dtype, Dv=None, H=None):
     """Tiles of 128 walked over the grid, as a sequence too long for VMEM gets:
     the chooser with its budget shrunk to the least that still holds a tile."""
-    for mib in (1, 1.5, 2, 3):
+    for mib in (1, 1.5, 2, 3, 4, 6):
         try:
-            tiles = choose_tiles(T, S, D, rep, dtype, vmem_budget=int(mib * 2**20), Dv=Dv)
+            tiles = choose_tiles(T, S, D, rep, dtype, vmem_budget=int(mib * 2**20), Dv=Dv, H=H)
         except ValueError:
             continue
         assert not tiles.whole and min(tiles.block_q, tiles.block_k) == 128
@@ -385,8 +443,8 @@ def test_forward_and_gradient_match_xla_in_both_regimes(case, regime):
     B, H, Hkv, T, S, dtype, masking = PARITY_CASES[case][:7]
     D, Dv = PARITY_CASES[case][7:] or (16, 16)
     rng = np.random.default_rng(11)
-    q, k = (jnp.asarray(rng.normal(size=(B, heads, n, D)), dtype) for heads, n in ((H, T), (Hkv, S)))
-    g, v = (jnp.asarray(rng.normal(size=(B, heads, n, Dv)), dtype) for heads, n in ((H, T), (Hkv, S)))
+    q, k = (jnp.asarray(rng.normal(size=(B, n, heads, D)), dtype) for heads, n in ((H, T), (Hkv, S)))
+    g, v = (jnp.asarray(rng.normal(size=(B, n, heads, Dv)), dtype) for heads, n in ((H, T), (Hkv, S)))
     kv_valid = np.ones((B, S), np.int32)
     kv_valid[0, : S // 3] = 0  # left padding: sample 0's first queries see no key at all
     if masking == "none-valid":
@@ -394,7 +452,7 @@ def test_forward_and_gradient_match_xla_in_both_regimes(case, regime):
     kv_valid = jnp.asarray(kv_valid)
     scale = D ** -0.5
     rep = H // Hkv
-    tiles = choose_tiles(T, S, D, rep, dtype, Dv=Dv) if regime == "whole" else _walked(T, S, D, rep, dtype, Dv)
+    tiles = choose_tiles(T, S, D, rep, dtype, Dv=Dv, H=H) if regime == "whole" else _walked(T, S, D, rep, dtype, Dv, H)
     assert tiles.whole == (regime == "whole")
 
     out, lse = attn._flash_forward(q, k, v, kv_valid, True, scale, True, with_lse=True, tiles=tiles)
@@ -413,10 +471,117 @@ def test_forward_and_gradient_match_xla_in_both_regimes(case, regime):
             np.testing.assert_array_equal(np.asarray(a[-1], np.float32), 0.0)
 
 
+def _operands(B, T, S, H, Hkv, D, Dv, dtype, seed=17):
+    """q, k, v, dO in the kernels' layout and a left-padded key mask."""
+    rng = np.random.default_rng(seed)
+    q, k = (jnp.asarray(rng.normal(size=(B, n, heads, D)), dtype) for heads, n in ((H, T), (Hkv, S)))
+    g, v = (jnp.asarray(rng.normal(size=(B, n, heads, Dv)), dtype) for heads, n in ((H, T), (Hkv, S)))
+    kv_valid = np.ones((B, S), np.int32)
+    kv_valid[0, : S // 5] = 0
+    return q, k, v, g, jnp.asarray(kv_valid)
+
+
+def _assert_forward_and_gradients_match(q, k, v, g, kv_valid, got, dtype, causal=True):
+    scale = q.shape[-1] ** -0.5
+    want_out, vjp = jax.vjp(lambda q, k, v: xla_attention(q, k, v, kv_valid, causal, scale), q, k, v)
+    tol = dict(atol=2e-4, rtol=2e-4) if dtype == jnp.float32 else dict(atol=6e-2, rtol=3e-2)
+    for a, b, name in zip(got, (want_out,) + vjp(g), ("out", "dq", "dk", "dv")):
+        assert a.shape == b.shape and a.dtype == dtype, name
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.all(np.isfinite(a)), name
+        np.testing.assert_allclose(a, b, err_msg=name, **tol)
+
+
+@pytest.mark.parametrize("regime", ["whole", "walked"])
+@pytest.mark.parametrize(
+    "T,S,H,Hkv,D,Dv",
+    [(140, 140, 2, 2, 16, 16), (33, 190, 4, 2, 16, 16), (130, 72, 4, 1, 24, 16), (200, 200, 2, 2, 64, 64)],
+)
+def test_nothing_past_the_operands_end_reaches_an_output(T, S, H, Hkv, D, Dv, regime):
+    """Ragged T and S, the blocks overhanging both: q, k, v and dO are sliced,
+    under jit, from buffers with rows to spare that hold NaN past the end, so
+    whatever an overhanging block finds there (on the chip the buffer's own
+    rows, NaN in interpret mode either way) is NaN. The forward and all three
+    gradients are finite and equal the plain reference's on the clean rows."""
+    dtype = jnp.float32
+    q, k, v, g, kv_valid = _operands(2, T, S, H, Hkv, D, Dv, dtype)
+    rep = H // Hkv
+    tiles = choose_tiles(T, S, D, rep, dtype, Dv=Dv, H=H) if regime == "whole" else _walked(T, S, D, rep, dtype, Dv, H)
+    assert tiles.whole == (regime == "whole") and tiles.Tp > T and tiles.Sp > S
+
+    def with_rows_to_spare(x, spare=200):
+        return jnp.concatenate([x, jnp.full((x.shape[0], spare) + x.shape[2:], jnp.nan, x.dtype)], axis=1)
+
+    @jax.jit
+    def run(q_buf, k_buf, v_buf, g_buf):
+        q, k, v, g = q_buf[:, :T], k_buf[:, :S], v_buf[:, :S], g_buf[:, :T]
+        scale = D ** -0.5
+        out, lse = attn._flash_forward(q, k, v, kv_valid, True, scale, True, with_lse=True, tiles=tiles)
+        return (out, lse) + attn._flash_backward(q, k, v, kv_valid, out, lse, g, True, scale, True, tiles=tiles)
+
+    out, lse, dq, dk, dv = run(*map(with_rows_to_spare, (q, k, v, g)))
+    _assert_forward_and_gradients_match(q, k, v, g, kv_valid, (out, dq, dk, dv), dtype)
+    # the row statistics are as long as the tiles: past the data they hold the constant the backward expects
+    assert np.all(np.isfinite(np.asarray(lse)))
+    if regime == "whole":  # rows past the data are not computed at all
+        np.testing.assert_array_equal(np.asarray(lse[..., tiles.Tr:]), np.float32(attn.NEG_INF))
+
+
+@pytest.mark.parametrize("D,Dv", [(64, 64), (128, 128), (192, 128)])
+@pytest.mark.parametrize("T", [513, 257, 65])
+def test_the_cells_ragged_lengths_at_their_widths(T, D, Dv):
+    """The learner's lengths (the store's 513, ouro's 257, a short 65) at the
+    cells' head widths, two heads as the lane rule wants at D = 64 and 192:
+    forward and the three gradients through the public function."""
+    dtype = jnp.float32
+    q, k, v, g, kv_valid = _operands(1, T, T, 2, 2, D, Dv, dtype, seed=T + D)
+    tiles = choose_tiles(T, T, D, 1, dtype, Dv=Dv, H=2)
+    assert tiles.Tp - T == -T % tiles.block_q > 0 and (tiles.heads * D) % 128 == 0
+    out, vjp = jax.vjp(lambda q, k, v: flash_attention(q, k, v, kv_valid, True, None, True), q, k, v)
+    _assert_forward_and_gradients_match(q, k, v, g, kv_valid, (out,) + vjp(g), dtype)
+
+
+@pytest.mark.parametrize(
+    "H,Hkv,D,heads",
+    [(3, 3, 64, 3), (5, 5, 64, 5), (6, 3, 64, 6), (3, 1, 64, 3), (6, 6, 64, 6), (3, 3, 128, 3), (5, 1, 128, 5)],
+)
+def test_head_counts_the_lane_rule_cannot_halve(H, Hkv, D, heads):
+    """Odd head counts at D = 64: no even number of heads divides them, so a
+    program takes them all (the whole extent of the last dimension) — also
+    where the kv heads are odd though the query heads are not. At D = 128 every
+    count is whole lane tiles."""
+    dtype = jnp.float32
+    T = 72
+    tiles = choose_tiles(T, T, D, H // Hkv, dtype, H=H)
+    assert tiles.heads == heads
+    q, k, v, g, kv_valid = _operands(2, T, T, H, Hkv, D, D, dtype, seed=H)
+    out, vjp = jax.vjp(lambda q, k, v: flash_attention(q, k, v, kv_valid, True, None, True), q, k, v)
+    _assert_forward_and_gradients_match(q, k, v, g, kv_valid, (out,) + vjp(g), dtype)
+
+
+@pytest.mark.parametrize("regime", ["whole", "walked"])
+@pytest.mark.parametrize("T,S,H,Hkv,D,Dv,dtype", [
+    (140, 140, 2, 2, 16, 16, jnp.float32), (150, 150, 4, 2, 24, 16, jnp.float32),
+    (200, 200, 2, 2, 16, 16, jnp.bfloat16),
+])
+def test_delta_is_the_row_sum_of_dO_times_O(T, S, H, Hkv, D, Dv, dtype, regime):
+    """``delta`` as the dq kernel forms it and the dkv kernel reads it: float32
+    ``sum(dO * O)`` over the value width, a ``[B, H, 1, Tp]`` row, 0 past the data."""
+    q, k, v, g, kv_valid = _operands(2, T, S, H, Hkv, D, Dv, dtype)
+    rep, scale = H // Hkv, D ** -0.5
+    tiles = choose_tiles(T, S, D, rep, dtype, Dv=Dv, H=H) if regime == "whole" else _walked(T, S, D, rep, dtype, Dv, H)
+    out, lse = attn._flash_forward(q, k, v, kv_valid, True, scale, True, with_lse=True, tiles=tiles)
+    _, delta = attn._flash_dq(q, k, v, kv_valid, out, lse, g, True, scale, True, tiles)
+    assert delta.shape == (2, H, 1, tiles.Tp) and delta.dtype == jnp.float32
+    want = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1).transpose(0, 2, 1)  # [B, H, T]
+    np.testing.assert_allclose(np.asarray(delta[:, :, 0, :T]), np.asarray(want), atol=1e-5, rtol=1e-5)
+    np.testing.assert_array_equal(np.asarray(delta[:, :, 0, T:]), 0.0)
+
+
 def test_chooser_choice_is_logged_once_per_shape(caplog):
     import logging
 
-    q, k, v = make_inputs(B=1, H=3, T=40, S=40, D=8, seed=4)
+    q, k, v = make_inputs(B=1, H=3, T=40, S=40, D=8, seed=4)  # [1, 40, 3, 8]
     kv_valid = jnp.ones((1, 40), jnp.int32)
     attn._log_tiles.cache_clear()
     root = logging.getLogger("trlx_tpu")
@@ -427,9 +592,12 @@ def test_chooser_choice_is_logged_once_per_shape(caplog):
                 jax.grad(lambda q: flash_attention(q, k, v, kv_valid, True, None, True).sum())(q)
     finally:
         root.removeHandler(caplog.handler)
-    lines = [r.getMessage() for r in caplog.records if "flash attention q[1,3,40,8]" in r.getMessage()]
+    lines = [r.getMessage() for r in caplog.records if "flash attention q[1,40,3,8]" in r.getMessage()]
     assert len(lines) == 1, lines
     assert "tiles 128x128" in lines[0] and "3 head(s) a program" in lines[0] and "grid (1, 1, 1, 1)" in lines[0]
+    # what crosses the kernels' boundary: the operands' layout, the rows that overhang, where delta is formed
+    assert "blocks (1, rows, heads*D)" in lines[0] and "overhanging by 88x88 rows" in lines[0]
+    assert "delta formed in the dq kernel" in lines[0]
 
 
 # ------------------------------------------------------------ the decode kernel
@@ -595,7 +763,7 @@ def _attend_case(case, seed=11):
         seen = jnp.asarray(valid) * (jnp.arange(S)[None, :] <= index)
         mask_bias = jnp.where(seen[:, None, None, :] > 0, 0.0, -1e9).astype(jnp.float32)
         kh, vh = kv_cache.read_kv_cache(cache, jnp.float32)  # the int8 rows as they read back
-        want = xla_attention(q.transpose(0, 2, 1, 3), kh, vh, seen, False, scale)
+        want = attn.xla_attention(q.transpose(0, 2, 1, 3), kh, vh, seen, False, scale)
         return dict(q=q, k=k, v=v, cache=cache, mask_bias=mask_bias, kv_valid=None, index=jnp.int32(index),
                     scale=scale, impl=impl), want.transpose(0, 2, 1, 3).reshape(B, 1, H * D)
     q = jnp.asarray(rng.normal(size=(B, T, H, D)), jnp.float32)
@@ -603,7 +771,7 @@ def _attend_case(case, seed=11):
     kv_valid = jnp.asarray(valid)
     causal = jnp.tril(jnp.ones((T, T), bool))[None, None] & (kv_valid[:, None, None, :] > 0)
     mask_bias = jnp.where(causal, 0.0, -1e9).astype(jnp.float32)
-    want = xla_attention(*(x.transpose(0, 2, 1, 3) for x in (q, k, v)), kv_valid, True, scale)
+    want = attn.xla_attention(*(x.transpose(0, 2, 1, 3) for x in (q, k, v)), kv_valid, True, scale)
     return dict(q=q, k=k, v=v, cache=None, mask_bias=mask_bias, kv_valid=kv_valid, index=None, scale=scale,
                 impl=impl), want.transpose(0, 2, 1, 3).reshape(B, T, H * D)
 

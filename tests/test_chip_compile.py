@@ -69,7 +69,7 @@ def no_persistent_cache():
 def _flash(grad, shape=None, kv_heads=None, dtype=jnp.bfloat16, v_width=None):
     c = PRESETS["gpt2"]
     B, H, T, D = shape or (FLASH_B, c.num_heads, FLASH_T, c.dim_per_head)
-    kv_shape = (B, kv_heads or H, T, D)
+    kv_shape = (B, T, kv_heads or H, D)  # the kernels' layout: rows, then heads, as a model's projections leave them
     v_shape = kv_shape[:3] + (v_width or D,)
 
     def fwd(q, k, v, kv_valid):
@@ -79,7 +79,7 @@ def _flash(grad, shape=None, kv_heads=None, dtype=jnp.bfloat16, v_width=None):
         return fwd(q, k, v, kv_valid).astype(jnp.float32).sum()
 
     fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else fwd
-    return fn, [((B, H, T, D), dtype), (kv_shape, dtype), (v_shape, dtype), ((B, T), jnp.int32)]
+    return fn, [((B, T, H, D), dtype), (kv_shape, dtype), (v_shape, dtype), ((B, T), jnp.int32)]
 
 
 def _grouped_products(grad, tokens):
@@ -151,6 +151,96 @@ def _model_grad():
         return jax.grad(loss)(jax.tree_util.tree_unflatten(treedef, leaves), ids, mask)
 
     return fn, [((B, T), jnp.int32)] * 2 + [(x.shape, x.dtype) for x in leaves]
+
+
+# the four benchmark cells' trunks at their published widths, a layer or two (ouro: one layer, two passes):
+# (config, learner [B, T], scorer [B, T] at its buckets)
+def _cell_models():
+    gpt2 = PRESETS["gpt2"]
+    return {
+        "gpt2": (gpt2.replace(num_layers=2), (32, 513), (32, 576)),
+        "gpt2-medium": (gpt2.replace(num_layers=1, hidden_size=1024, num_heads=16), (2, 513), (32, 640)),
+        # the leading dense layer: latent attention, keys 192 wide and values 128
+        "kimi-vl-a3b": (PRESETS["kimi_vl"].replace(num_layers=1, vocab_size=20480), (4, 513), (32, 576)),
+        "ouro-2.6b": (PRESETS["ouro"].replace(num_layers=1, loop_steps=2), (8, 257), (32, 320)),
+    }
+
+
+def _cell_trunk(cell, learner):
+    """A cell's trunk as the learner (the gradient of its hidden states) or the
+    scorer (the forward) runs it: what lies between the projections' products
+    and the flash calls is the compiled program's to show."""
+    config, learn_shape, score_shape = _cell_models()[cell]
+    model = TransformerLM(config.replace(attention_impl="flash", compute_dtype=jnp.bfloat16))
+    B, T = learn_shape if learner else score_shape
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32), jnp.ones((1, 8), jnp.int32))["params"]
+    )
+    leaves, treedef = jax.tree_util.tree_flatten(params)
+
+    def hidden(leaves, ids, mask):
+        params = jax.tree_util.tree_unflatten(treedef, leaves)
+        return model.apply({"params": params}, ids, mask, with_head=False)[1]
+
+    def fn(ids, mask, *leaves):
+        if not learner:
+            return hidden(leaves, ids, mask)
+        return jax.grad(lambda leaves: hidden(leaves, ids, mask).astype(jnp.float32).sum())(list(leaves))
+
+    return fn, [((B, T), jnp.int32)] * 2 + [(x.shape, x.dtype) for x in leaves], model.config
+
+
+@pytest.mark.parametrize("program", ["learner", "scorer"])
+@pytest.mark.parametrize("cell", ["gpt2", "gpt2-medium", "kimi-vl-a3b", "ouro-2.6b"])
+def test_nothing_runs_between_the_projections_and_the_flash_calls(cell, program, one_chip, no_persistent_cache,
+                                                                  monkeypatch):
+    """At the cells' learner and scorer shapes, compiled for the chip: a layer's
+    flash calls keep the names the benchmark finds them by, and XLA runs no op of
+    its own on a ``[B, ., T, .]``-sized array around them — no ``pad`` of an
+    operand to the tiles' extent, no ``copy`` / ``transpose`` to or from a
+    heads-first ``[B, H, T, D]`` array (none exists any more), no reduce forming
+    ``delta``. (The decode case's "no copy of the cache" is the pattern.) Where
+    the projections' products are the operands (gpt2's family) every operand
+    of a flash call comes straight from the fusion that computed it. A model
+    that forms q and k heads-split after the projection (rotary pairs in ouro,
+    the nope / rope concatenation of latent attention in kimi-vl) still pays
+    XLA's relayout from ``[B, T, H, D]`` tiled over (H, D) to the rows the
+    kernels read, where it paid the transpose before: PERF.md section 7."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fn, shapes, config = _cell_trunk(cell, program == "learner")
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    B, T = shapes[0][0]
+    Tp = -(-T // 128) * 128
+    H, widths = config.num_heads, {config.dim_per_head}
+    if config.attention_kind == "mla":
+        widths = {config.qk_nope_head_dim + config.qk_rope_head_dim, config.v_head_dim}
+    calls = config.num_layers * config.loop_steps * (3 if program == "learner" else 1)
+    names = _attention_instructions(text)
+    assert len(names) == calls and all(re.match(r"^%attn[.0-9]* custom-call$", name) for name in names), names
+
+    rows, width = f"(?:{T}|{Tp})", "(?:" + "|".join(map(str, sorted(widths))) + ")"
+    heads_first = rf"\[{B},{H},{rows},{width}\]"
+    found = [
+        line.strip()[:200] for line in text.splitlines()
+        # an operand padded to the tiles' extent, heads-first or as the kernels now take it
+        if re.search(rf"= (?:bf16|f32)(?:{heads_first}|\[{B},{Tp},\d+\])\S* pad\(", line)
+        or re.search(rf"= \w+{heads_first}\S* (?:copy|transpose)\(", line)  # a transpose to or from heads-first
+        or re.search(rf"= f32\[{B},{H},(?:1,)?{rows}\]\S* reduce\(", line)  # delta = sum(dO * O) over the value width
+    ]
+    assert not found, found
+    if T != Tp:  # the one array still padded in HBM is the key mask, kilobytes
+        assert re.search(rf"= s32\[{B},{Tp}\]\S* pad\(", text)
+
+    if cell.startswith("gpt2"):
+        relayouts = []
+        for call in re.finditer(r"^\s*%attn[.0-9]* = .*? custom-call\((.*?)\), custom_call_target", text, re.M):
+            for operand in re.findall(r"%[\w.\-]+", call.group(1))[1:]:  # but the key mask, kilobytes
+                producer = re.search(rf"^\s*{re.escape(operand)} = \S+ ([\w\-]+)\(", text, re.M).group(1)
+                if producer in ("copy", "transpose", "reshape", "pad") or re.search(
+                        r"copy|transpose|pad", operand.replace("copy-done", "")):  # copy-done: a move between memories
+                    relayouts.append(f"{operand} {producer}")
+        assert not relayouts, relayouts
 
 
 def _paged(preset, quant, q_len):

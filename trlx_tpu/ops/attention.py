@@ -8,22 +8,35 @@ the backward recomputes P = exp(S - L) tile by tile, so training memory is O(T·
 rather than the O(T·S) score matrix. :func:`xla_attention` is the plain reference
 the kernels' outputs and gradients are tested against.
 
+The kernels read and write the model's own arrays: q ``[B, T, H, D]``, k ``[B, S, Hkv,
+D]``, v ``[B, S, Hkv, Dv]`` as the projections produce them, viewed ``[B, T, H·D]`` (a
+bitcast), and O, dq, dk, dv the same way. A program's block is ``(1, rows, heads·D)``
+and a head is a lane window of it, so no transpose to ``[B, H, T, D]`` runs around a
+call. Any T and S: the grid's blocks overhang the arrays' ends, nothing is padded or
+sliced in HBM. What an overhanging block holds past the end is garbage (NaN in
+interpret mode); every load that can overhang replaces it by zeros with a select,
+the overhanging part of a store is clipped, and the per-row statistics past the
+data are written as constants. The backward's ``delta = rowsum(dO · O)`` is formed
+inside the ``dq`` kernel, in float32 on the tiles it holds, and handed to ``dkv`` as a row.
+
 How the work is cut into programs is decided from the shape by one pure function,
-:func:`choose_tiles`. Where the whole padded sequence fits VMEM — every length RL
-runs at gpt2 widths: 64 to 1024 — a program takes the whole sequence of one or more
-heads: grid (batch, heads / heads-per-program, 1, 1), nothing carried across grid
-steps, and the body walks the score matrix in row tiles whose causal extents are
+:func:`choose_tiles`. Where the whole sequence (rounded up to 128) fits VMEM — every
+length RL runs at gpt2 widths: 64 to 1024 — a program takes the whole sequence of
+several heads: grid (batch, heads / heads-per-program, 1, 1), nothing carried across
+grid steps, and the body walks the score matrix in row tiles whose causal extents are
 static, so nothing above the diagonal is computed. Where it does not (long contexts
 at D = 128), the kv side is walked on the last grid axis in tiles of 128-512 —
 TPU grids execute sequentially, so running max / denominator / accumulator live in
 VMEM scratch across kv steps — and blocks above the diagonal are skipped with
-``pl.when``. One kernel body per pass serves both.
+``pl.when``. One kernel body per pass serves both. The heads of a program are whole
+lane tiles of its blocks (an even number at D = 64; 2, 4 or 8 at D = 192) or all the
+heads there are, which the chooser sees to.
 
 Operands are multiplied in the dtype they arrive in (bfloat16 on the MXU in one
 pass, float32 in full) and accumulated in float32; softmax statistics, the mask
-arithmetic and every accumulator are float32, and P and dS are cast to the operand
-dtype for their second matmuls, as the XLA path does. The backward writes dq, dk, dv
-in the dtype of q, k, v.
+arithmetic, ``delta`` and every accumulator are float32, and P and dS are cast to the
+operand dtype for their second matmuls, as the XLA path does. The backward writes dq,
+dk, dv in the dtype of q, k, v.
 
 Grouped-query attention is native: K/V arrive with their own head count ``Hkv`` and
 the BlockSpec index maps hand a program its query heads' kv heads, so grouped K/V
@@ -34,17 +47,18 @@ Masking model matches :mod:`trlx_tpu.models.transformer`: slot-based causality p
 [B, S] key-validity mask (left-padded prompts). Engaged on every multi-token forward:
 the training loss, the logprob/value scoring passes, and generation *prefill* (which
 attends over the just-computed prefix k/v while the cache write happens separately).
-Arbitrary T/S are supported via internal padding (see ``_flash_forward``). Single-token
-decode steps over the contiguous cache have a kernel of their own, :func:`decode_attention`
-(the section "decode" below), which reads the cache only up to the write index.
+Single-token decode steps over the contiguous cache have a kernel of their own,
+:func:`decode_attention` (the section "decode" below), which reads the cache only up
+to the write index.
 
 Layout note: per-row statistics (logsumexp, delta) travel between the kernels as
-``[B, H, 1, T]`` float32 rows with T on the lanes — B·H·T·4 bytes in HBM, where a
-trailing dimension of 1 or 8 would be padded to 128 lanes. The forward turns its
-column of row statistics into such a row with one transpose per head; ``dq`` turns
-it back; ``dkv`` works on transposed score tiles (keys down the sublanes, queries
-along the lanes) and subtracts the rows as they are. The key mask travels as
-``[B, 1, S]``.
+``[B, H, 1, Tp]`` float32 rows with T on the lanes — B·H·Tp·4 bytes in HBM, where a
+trailing dimension of 1 or 8 would be padded to 128 lanes; they and the ``[B, 1, Sp]``
+key mask, kilobytes each, are the only arrays as long as the tiles rather than the
+data. The forward turns its column of row statistics into such a row with one
+transpose per head, ``dq`` does the same for ``delta`` and turns the logsumexp back;
+``dkv`` works on transposed score tiles (keys down the sublanes, queries along the
+lanes) and subtracts the rows as they are.
 """
 
 import functools
@@ -69,7 +83,7 @@ NEG_INF = -1e30
 
 LANE = 128  # lanes of a vector register; the lane side of every score tile is a multiple
 # What a program costs to start, in score elements (0.4 us of a v5e program against
-# some 60 k score elements a microsecond): the chooser trades it against padding.
+# some 60 k score elements a microsecond): the chooser trades it against overhang.
 _PROGRAM_COST = 24 * 1024
 # Score elements a program should not exceed when it takes several heads.
 _PROGRAM_AREA = 2 * 1024 * 1024
@@ -85,74 +99,123 @@ class FlashTiles(NamedTuple):
     block_q: int  # query rows a program holds; Tp where the whole sequence is one tile
     block_k: int  # key rows a grid step holds; Sp where the whole sequence is one tile
     sub: int  # rows of a score tile the body works on at a time
-    Tp: int  # padded query length, a multiple of block_q
-    Sp: int  # padded key length, a multiple of block_k
+    T: int  # query rows there are: the last q block overhangs the arrays by Tp - T rows
+    S: int  # key rows there are
+    Tp: int  # the q blocks' extent, a multiple of block_q; the length of no array but the row statistics
+    Sp: int  # the kv blocks' extent, a multiple of block_k; the key mask's length
     Tr: int  # query rows that hold data, rounded up to the dtype's sublane tile
     Sr: int  # key rows that hold data, the same
-    heads: int  # most query heads one program takes
-    vmem_bytes: int  # reckoned VMEM of the largest pass (the dkv backward)
+    heads: int  # query heads one program takes, a lane window of its blocks each
+    vmem_bytes: int  # reckoned VMEM of the largest pass
 
     @property
     def whole(self) -> bool:
-        """One program sees the whole padded sequence: nothing is carried
-        across grid steps and causal extents are static."""
+        """One program sees the whole sequence: nothing is carried across grid
+        steps and causal extents are static."""
         return self.block_q == self.Tp and self.block_k == self.Sp
 
 
 def _reckon_vmem(block_q, block_k, sub, heads, D, rep, itemsize, whole, Dv=None) -> int:
     """VMEM bytes of the pass that holds most. Each pass: its operands twice
-    (Pallas double-buffers them), score-shaped float32 temporaries of ``sub``
-    rows (scores, probabilities and the mask bias in the forward; dP and dS
-    besides in the two backward passes), the mask bias kept for every row tile
-    where extents are static, and what it keeps in scratch. The forward and dq
-    passes take ``heads`` query heads, the dkv pass their kv heads' whole groups.
-    ``Dv`` is the width of v, o and dO where it is not q's and k's ``D``."""
-    d_lanes, dv_lanes = _round_up(D, LANE), _round_up(Dv or D, LANE)
+    (Pallas double-buffers them), a head's operands once more as the values the
+    body works on, score-shaped float32 temporaries of ``sub`` rows (scores,
+    probabilities and the mask bias in the forward; dP and dS besides in the two
+    backward passes), the mask bias kept for every row tile where extents are
+    static, and what it keeps in scratch. The forward and dq passes take
+    ``heads`` query heads, the dkv pass their kv heads' whole groups. A block's
+    heads lie side by side on the lanes. ``Dv`` is the width of v, o and dO where
+    it is not q's and k's ``D``."""
+    Dv = Dv or D
     kv_heads = max(1, heads // rep)
     group = kv_heads * rep
-    # q, k, dq, dk are D wide; v, o, dO, dv are Dv wide
-    q_tile, k_tile = block_q * d_lanes * itemsize, block_k * d_lanes * itemsize
-    o_tile, v_tile = block_q * dv_lanes * itemsize, block_k * dv_lanes * itemsize
+
+    def tile(rows, n, width):  # a block of n heads' columns
+        return rows * _round_up(n * width, LANE) * itemsize
+
+    q_tile, o_tile = tile(block_q, heads, D), tile(block_q, heads, Dv)
+    k_tile, v_tile = tile(block_k, kv_heads, D), tile(block_k, kv_heads, Dv)
+    group_q, group_o = tile(block_q, group, D), tile(block_q, group, Dv)
+    head = tile(block_q + block_k, 1, D) + tile(block_q + block_k, 1, Dv)
     row = 8 * block_q * 4  # a [1, block_q] float32 row takes a sublane tile
     mask = 8 * block_k * 4
     bias = block_q * block_k * 4 if whole else 0
     carried = 0 if whole else 4  # bytes of float32 scratch per carried element
     forward = (
-        2 * (heads * (q_tile + o_tile) + kv_heads * (k_tile + v_tile) + heads * row + mask)
+        2 * (q_tile + o_tile + k_tile + v_tile + heads * row + mask) + head
         + 3 * sub * block_k * 4 + bias
-        + block_q * LANE * 4 + carried * heads * block_q * (dv_lanes + 2 * LANE)
+        + block_q * LANE * 4 + carried * block_q * (_round_up(heads * Dv, LANE) + 2 * heads * LANE)
     )
     dq = (
-        2 * (heads * (2 * q_tile + o_tile) + kv_heads * (k_tile + v_tile) + 2 * heads * row + mask)
+        2 * (2 * q_tile + 2 * o_tile + k_tile + v_tile + 2 * heads * row + mask) + head
         + 5 * sub * block_k * 4 + bias
-        + 2 * block_q * LANE * 4 + carried * heads * block_q * d_lanes
+        + 2 * block_q * LANE * 4 + carried * block_q * _round_up(heads * D, LANE)
     )
     dkv = (
-        2 * (group * (q_tile + o_tile) + 2 * kv_heads * (k_tile + v_tile) + 2 * group * row + mask)
+        2 * (group_q + group_o + 2 * (k_tile + v_tile) + 2 * group * row + mask) + head
         + 5 * sub * block_q * 4 + bias
-        + block_k * LANE * 4 + carried * kv_heads * block_k * (d_lanes + dv_lanes)
+        + block_k * LANE * 4 + carried * block_k * (_round_up(kv_heads * D, LANE) + _round_up(kv_heads * Dv, LANE))
     )
     return max(forward, dq, dkv)
 
 
-def choose_tiles(
-    T: int, S: int, D: int, rep: int, dtype, vmem_budget: int = 12 * 2**20, Dv: Optional[int] = None
-) -> FlashTiles:
-    """Tiles for q ``[.., T, D]`` against k ``[.., S, D]`` and v ``[.., S, Dv]``
-    (``Dv`` = ``D`` where it is not given) with ``rep`` query heads to a kv
-    head. A pure function of the shape; the one place tiles are chosen.
+def _lane_heads(D: int, Dv: int) -> int:
+    """The fewest heads whose columns, ``D`` and ``Dv`` wide, fill whole lane
+    tiles: 1 at 128, 2 at 64 and at 192 / 128."""
+    return math.lcm(LANE // math.gcd(LANE, D), LANE // math.gcd(LANE, Dv))
 
-    Lengths are padded to multiples of 128 (the lane side of a score tile: keys
-    in the forward and dq passes, queries in the transposed dkv pass); rows are
-    worked on up to the dtype's sublane multiple only. Where the whole padded
-    sequence fits ``vmem_budget`` a program takes it whole, for one or more
-    heads; where it does not, the kv side is walked on the last grid axis with
-    tiles of 128-512. Among the tilings that fit, the cheapest by padded score
-    area plus a fixed cost per program wins."""
+
+def _head_counts(H: int, rep: int, lane: int = 1):
+    """The counts of query heads a program may take, ascending: programs share
+    the heads out evenly with their kv heads (a divisor of the group, or whole
+    groups dividing Hkv), and a block's columns — the program's query heads', its
+    kv heads' — are a multiple of ``lane`` heads or all the heads there are
+    (the chip's rule for the last dimension of a block: a multiple of 128 or the
+    whole extent). All H always may."""
+    kv_total = H // rep
+
+    def may(g):
+        kv = max(1, g // rep)
+        shares = rep % g == 0 or (g % rep == 0 and kv_total % kv == 0)
+        return shares and (g % lane == 0 or g == H) and (kv % lane == 0 or kv == kv_total)
+
+    return [g for g in range(1, H + 1) if may(g)]
+
+
+def _heads_per_program(H: int, rep: int, most: int, lane: int = 1) -> int:
+    """The most query heads, at most ``most``, that :func:`_head_counts` allows;
+    the fewest it allows where that is more than ``most``."""
+    counts = _head_counts(H, rep, lane)
+    return max((g for g in counts if g <= most), default=counts[0])
+
+
+def choose_tiles(
+    T: int, S: int, D: int, rep: int, dtype, vmem_budget: int = 12 * 2**20, Dv: Optional[int] = None,
+    H: Optional[int] = None,
+) -> FlashTiles:
+    """Tiles for q ``[B, T, H·D]`` against k ``[B, S, Hkv·D]`` and v ``[B, S,
+    Hkv·Dv]`` (``Dv`` = ``D`` where it is not given) with ``rep`` query heads to a
+    kv head (``H`` not given: heads enough for any count a program may want). A
+    pure function of the shape; the one place tiles and the heads of a program
+    are chosen.
+
+    Blocks are multiples of 128 rows (the lane side of a score tile: keys in the
+    forward and dq passes, queries in the transposed dkv pass) and overhang the
+    arrays' ends; rows are worked on up to the dtype's sublane multiple only. A
+    program's heads are lane windows of its blocks, so their columns must fill
+    whole lane tiles (:func:`_head_counts`). Where the whole sequence fits
+    ``vmem_budget`` a program takes it whole, for as many heads as the budget and
+    the program's area allow; where it does not, the kv side is walked on the
+    last grid axis with tiles of 128-512, for the fewest heads the lanes allow.
+    Among the tilings that fit, the cheapest by score area plus a fixed cost per
+    program wins."""
+    Dv = Dv or D
     itemsize = jnp.dtype(dtype).itemsize
     sublane = 32 // itemsize  # 8 rows of float32, 16 of bfloat16 to a tile
     Tr, Sr = _round_up(T, sublane), _round_up(S, sublane)
     T128, S128 = _round_up(T, LANE), _round_up(S, LANE)
+    lane = _lane_heads(D, Dv)
+    H = H or 8 * lane * rep
+    least = _heads_per_program(H, rep, 1, lane)  # the fewest heads the lanes allow a program
 
     best = None
     walked = [(bq, bk) for bq in (512, 256, 128) for bk in (512, 256, 128) if bq < T128 or bk < S128]
@@ -162,7 +225,9 @@ def choose_tiles(
         whole = block_q == Tp and block_k == Sp
         # the smallest row tile that leaves the body at most eight to unroll
         sub = next((n for n in (128, 256, 512) if max(block_q, block_k) <= 8 * n), None)
-        fits = sub is not None and _reckon_vmem(block_q, block_k, sub, 1, D, rep, itemsize, whole, Dv) <= vmem_budget
+        fits = sub is not None and (
+            _reckon_vmem(block_q, block_k, sub, least, D, rep, itemsize, whole, Dv) <= vmem_budget
+        )
         if not fits:  # graftcheck: noqa[JX004] — static shape/int, not traced
             continue
         cost = Tp * Sp + (Tp // block_q) * (Sp // block_k) * _PROGRAM_COST
@@ -170,11 +235,12 @@ def choose_tiles(
             best = (cost, block_q, block_k, sub, Tp, Sp, whole)
     if best is None:
         raise ValueError(
-            f"no flash-attention tiling of T={T} S={S} D={D} Dv={Dv or D} rep={rep} fits {vmem_budget} bytes of VMEM"
+            f"no flash-attention tiling of T={T} S={S} D={D} Dv={Dv} rep={rep} heads={least} fits"
+            f" {vmem_budget} bytes of VMEM"
         )
     _, block_q, block_k, sub, Tp, Sp, whole = best
 
-    def takes(heads):  # several heads to a program: only whole sequences, within the area and the budget
+    def takes(heads):  # more heads than the lanes ask for: only whole sequences, within the area and the budget
         return (
             whole
             and heads <= 8
@@ -182,52 +248,82 @@ def choose_tiles(
             and _reckon_vmem(block_q, block_k, sub, heads, D, rep, itemsize, whole, Dv) <= vmem_budget
         )
 
-    heads = max(h for h in (1, 2, 4, 8) if h == 1 or takes(h))
+    heads = _heads_per_program(H, rep, max((h for h in range(1, 9) if takes(h)), default=1), lane)
     return FlashTiles(
-        block_q, block_k, sub, Tp, Sp, min(Tr, Tp), min(Sr, Sp), heads,
+        block_q, block_k, sub, T, S, Tp, Sp, min(Tr, Tp), min(Sr, Sp), heads,
         _reckon_vmem(block_q, block_k, sub, heads, D, rep, itemsize, whole, Dv),
     )
 
 
-def _heads_per_program(H: int, rep: int, most: int) -> int:
-    """The most query heads, at most ``most``, that programs can share out evenly
-    with their kv heads: a divisor of the group, or whole groups dividing Hkv."""
-    fits = [
-        g for g in range(1, min(most, H) + 1)
-        if (rep % g == 0) or (g % rep == 0 and (H // rep) % (g // rep) == 0)
-    ]
-    return max(fits)
-
-
 @functools.lru_cache(maxsize=None)
-def _log_tiles(B, H, Hkv, T, S, D, Dv, dtype, tiles, heads):
+def _log_tiles(B, H, Hkv, D, Dv, dtype, tiles):
     """The chooser's choice, once per traced shape."""
+    T, S, heads = tiles.T, tiles.S, tiles.heads
     kv_heads = max(1, heads // (H // Hkv))
     steps = (tiles.Tp // tiles.block_q, tiles.Sp // tiles.block_k)
-    values = "" if Dv == D else f" v[{B},{Hkv},{S},{Dv}]"
+    values = "" if Dv == D else f" v[{B},{S},{Hkv},{Dv}]"
     logger.info(  # graftcheck: noqa[JX003] — once per traced shape is the point
-        f"flash attention q[{B},{H},{T},{D}] kv[{B},{Hkv},{S},{D}]{values} {dtype}:"
-        f" tiles {tiles.block_q}x{tiles.block_k}"
-        f" in rows of {tiles.sub}, padded {tiles.Tp}x{tiles.Sp}, {heads} head(s) a program, grid"
-        f" {(B, H // heads) + steps} (dkv {(B, Hkv // kv_heads) + steps[::-1]}), VMEM reckoned"
-        f" {tiles.vmem_bytes / 2**20:.1f} MiB"
+        f"flash attention q[{B},{T},{H},{D}] kv[{B},{S},{Hkv},{D}]{values} {dtype}:"
+        f" operands as the model holds them, blocks (1, rows, heads*D); tiles {tiles.block_q}x{tiles.block_k}"
+        f" in rows of {tiles.sub}, overhanging by {tiles.Tp - T}x{tiles.Sp - S} rows (nothing padded in HBM),"
+        f" {heads} head(s) a program, grid"
+        f" {(B, H // heads) + steps} (dkv {(B, Hkv // kv_heads) + steps[::-1]}), delta formed in the dq kernel,"
+        f" VMEM reckoned {tiles.vmem_bytes / 2**20:.1f} MiB"
     )
 
 
-def _loop(body, *, count: int) -> None:
-    """``body(i)`` for i < count; no loop around a single pass."""
-    if count == 1:
-        body(0)
+def _loop(body, *, count: int, unrolled: bool = False) -> None:
+    """``body(i)`` for i < count; no loop around a single pass, none in the
+    program where it is ``unrolled`` (``i`` is then a Python int)."""
+    if unrolled or count == 1:
+        for i in range(count):
+            body(i)
     else:
         jax.lax.fori_loop(0, count, lambda i, c: (body(i), c)[1], 0)
 
 
-def _for_each_head(heads: int, rep: int, fn) -> None:
+def _for_each_head(heads: int, rep: int, fn, *, unrolled: bool) -> None:
     """``fn(h, kh)`` for a program's query heads h with their local kv head kh:
     whole groups of ``rep`` where the program holds several kv heads, else a
     part of one group."""
     group = min(heads, rep)
-    _loop(lambda kh: _loop(lambda r: fn(kh * group + r, kh), count=group), count=heads // group)
+    _loop(
+        lambda kh: _loop(lambda r: fn(kh * group + r, kh), count=group, unrolled=unrolled),
+        count=heads // group, unrolled=unrolled,
+    )
+
+
+def _window(h, width: int):
+    """Head h's lane window of a block ``[.., heads * width]``: a static slice
+    for a Python ``h``, else a dynamic one, which the chip takes on the lanes at
+    multiples of 128 only: where a head's columns are not whole lane tiles
+    (:func:`_lane_heads` > 1) the loops over heads are unrolled."""
+    if isinstance(h, int):
+        return slice(h * width, (h + 1) * width)
+    return pl.ds(pl.multiple_of(h * width, LANE), width)
+
+
+def _held(x, first, limit: int, extent: int):
+    """``x`` ``[rows, width]``, rows ``first ..`` of an array with ``limit``
+    rows under blocks that reach to ``extent``, with zeros for what lies past
+    the array's end: there an overhanging block holds garbage, which a select
+    keeps out of every product (a multiply by zero would let a NaN through).
+    Nothing to do where the blocks do not overhang, or ``first`` is a Python int
+    and these rows end before the array does."""
+    if extent == limit or (isinstance(first, int) and first + x.shape[0] <= limit):
+        return x
+    rows = first + jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    return jnp.where(rows < limit, x, jnp.zeros_like(x))
+
+
+def _block_offsets(tiles: "FlashTiles", qi, kj):
+    """First row of this grid step's q block and of its kv block: Python ints
+    where a side is one block, so that :func:`_held` can tell statically which
+    rows overhang."""
+    return (
+        qi * tiles.block_q if tiles.Tp > tiles.block_q else 0,
+        kj * tiles.block_k if tiles.Sp > tiles.block_k else 0,
+    )
 
 
 def _row_tiles(rows: int, sub: int):
@@ -289,7 +385,8 @@ def _visit_below_diagonal(visit, qi, kj, *, causal: bool, tiles: FlashTiles) -> 
 def _mask_biases(kv_valid, q0, k0, *, row_tiles, causal: bool):
     """Per row tile ``(first row, rows, keys)`` of a [queries, keys] score
     block at (q0, k0): 0 where a query may see a key, NEG_INF elsewhere. Added
-    to the scores; the heads of a program share it."""
+    to the scores, which are finite (:func:`_held`); the heads of a program
+    share it. Keys past the data are invalid in the mask as it arrives."""
     valid = jnp.where(kv_valid > 0, 0.0, NEG_INF)  # [1, block_k]
     biases = []
     for r0, n, keys in row_tiles:
@@ -304,30 +401,34 @@ def _mask_biases(kv_valid, q0, k0, *, row_tiles, causal: bool):
 
 def _flash_kernel(
     kv_valid_ref,  # [1, 1, block_k] int32
-    q_ref,  # [1, heads, block_q, D]
-    k_ref,  # [1, kv heads, block_k, D]
-    v_ref,  # [1, kv heads, block_k, Dv]
-    o_ref,  # [1, heads, block_q, Dv]
+    q_ref,  # [1, block_q, heads * D]
+    k_ref,  # [1, block_k, kv heads * D]
+    v_ref,  # [1, block_k, kv heads * Dv]
+    o_ref,  # [1, block_q, heads * Dv]
     *rest,  # lse_ref [1, heads, 1, block_q] f32 and its [block_q, LANE] scratch (with_lse);
-    # m, l [heads, block_q, 1] and acc [heads, block_q, Dv] f32 scratch (kv walked)
+    # m, l [heads, block_q, 1] and acc [block_q, heads * Dv] f32 scratch (kv walked)
     causal: bool,
     scale: float,
     tiles: FlashTiles,
     rep: int,
+    widths: Tuple[int, int],
     with_lse: bool,
 ):
     rest = list(rest)
     lse_ref, lse_cols = (rest.pop(0), rest.pop(-1)) if with_lse else (None, None)
-    heads = q_ref.shape[1]
+    D, Dv = widths
+    heads = q_ref.shape[2] // D
+    static = _lane_heads(D, Dv) > 1  # lane windows at static offsets: the loops over heads unrolled
     block_q, block_k = tiles.block_q, tiles.block_k
     kv_steps = tiles.Sp // block_k
     carried = kv_steps > 1  # running max / sum / accumulator live in scratch across kv steps
     qi, kj = pl.program_id(2), pl.program_id(3)
+    q0, k0 = _block_offsets(tiles, qi, kj)
     row_tiles = _query_row_tiles(tiles, causal)
 
     def finish(h, r0, n, m, l, acc):
         seen = m > NEG_INF / 2  # rows with no valid key give 0, not NaN
-        o_ref[0, h, r0:r0 + n, :] = (acc * jnp.where(seen, 1.0 / l, 0.0)).astype(o_ref.dtype)
+        o_ref[0, r0:r0 + n, _window(h, Dv)] = (acc * jnp.where(seen, 1.0 / l, 0.0)).astype(o_ref.dtype)
         if with_lse:
             lse = jnp.where(seen, m + jnp.log(l), NEG_INF)
             lse_cols[r0:r0 + n, :] = jnp.broadcast_to(lse, (n, LANE))
@@ -342,16 +443,18 @@ def _flash_kernel(
             acc_scratch[...] = jnp.zeros_like(acc_scratch)
 
     data_rows = row_tiles[-1][0] + row_tiles[-1][1]
-    if with_lse and data_rows < block_q:  # the backward must find no NaN past the data
+    if with_lse and data_rows < block_q:  # the backward must find no garbage past the data
         lse_cols[data_rows:, :] = jnp.full((block_q - data_rows, LANE), NEG_INF, jnp.float32)
 
     def visit():
-        biases = _mask_biases(kv_valid_ref[0], qi * block_q, kj * block_k, row_tiles=row_tiles, causal=causal)
+        biases = _mask_biases(kv_valid_ref[0], q0, k0, row_tiles=row_tiles, causal=causal)
 
         def head(h, kh):
-            k, v = k_ref[0, kh], v_ref[0, kh]  # loaded once; the row tiles slice the values
+            # loaded once; the row tiles slice the values
+            k = _held(k_ref[0, :, _window(kh, D)], k0, tiles.S, tiles.Sp)
+            v = _held(v_ref[0, :, _window(kh, Dv)], k0, tiles.S, tiles.Sp)
             for (r0, n, keys), bias in zip(row_tiles, biases):
-                q = q_ref[0, h, r0:r0 + n, :]
+                q = _held(q_ref[0, r0:r0 + n, _window(h, D)], q0 + r0, tiles.T, tiles.Tp)
                 s = _nt_dot(q, k[:keys]) * scale + bias  # [n, keys]
                 m = jnp.max(s, axis=1, keepdims=True)
                 if carried:
@@ -365,14 +468,14 @@ def _flash_kernel(
                 if carried:
                     alpha = jnp.exp(m_prev - m)
                     l_scratch[h, r0:r0 + n] = alpha * l_scratch[h, r0:r0 + n] + l
-                    acc_scratch[h, r0:r0 + n] = alpha * acc_scratch[h, r0:r0 + n] + acc
+                    acc_scratch[r0:r0 + n, _window(h, Dv)] = alpha * acc_scratch[r0:r0 + n, _window(h, Dv)] + acc
                     m_scratch[h, r0:r0 + n] = m
                 else:
                     finish(h, r0, n, m, l, acc)
             if with_lse and not carried:
                 lse_ref[0, h] = _to_rows(lse_cols[...])
 
-        _for_each_head(heads, rep, head)
+        _for_each_head(heads, rep, head, unrolled=static)
 
     _visit_below_diagonal(visit, qi, kj, causal=causal, tiles=tiles)
 
@@ -382,11 +485,12 @@ def _flash_kernel(
         def _finalize():
             def head(h):
                 for r0, n, _ in row_tiles:
-                    finish(h, r0, n, m_scratch[h, r0:r0 + n], l_scratch[h, r0:r0 + n], acc_scratch[h, r0:r0 + n])
+                    finish(h, r0, n, m_scratch[h, r0:r0 + n], l_scratch[h, r0:r0 + n],
+                           acc_scratch[r0:r0 + n, _window(h, Dv)])
                 if with_lse:
                     lse_ref[0, h] = _to_rows(lse_cols[...])
 
-            _loop(head, count=heads)
+            _loop(head, count=heads, unrolled=static)
 
 
 def _kv_block_map(heads: int, rep: int):
@@ -400,25 +504,43 @@ def _grid_semantics():
     return pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
 
 
-def _pad_to(x, *, axis: int, n: int):
-    """Zero-pad ``axis`` of x up to length n."""
-    if x.shape[axis] == n:
-        return x
-    widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, n - x.shape[axis])
-    return jnp.pad(x, widths)
-
-
 def _key_mask(kv_valid, Sp: int):
     """[B, S] -> [B, 1, Sp] int32, the layout the kernels take a row of it in;
-    padded keys are invalid."""
-    return _pad_to(kv_valid.astype(jnp.int32), axis=1, n=Sp)[:, None, :]
+    keys past the data are invalid. Kilobytes: the one operand padded in HBM."""
+    kv_valid = kv_valid.astype(jnp.int32)
+    return jnp.pad(kv_valid, ((0, 0), (0, Sp - kv_valid.shape[1])))[:, None, :]
 
 
+def _flat(x):
+    """``[B, T, heads, width]`` as the kernels take it, ``[B, T, heads * width]``: a bitcast."""
+    return x.reshape(x.shape[:2] + (-1,))
+
+
+def _shared_by_layers(*static_argnames):
+    """jit a function that makes one of the three flash calls, so that a model's
+    layers, which make it at one shape, share one trace and one lowering of the
+    kernel: the heads of a program are unrolled in the kernels' bodies, and
+    traced a layer that adds half a second a layer to the lowering of every
+    program (set-up time, warm or cold). Jitted under the name ``attn``, which is
+    what the model's scope names the unjitted calls: the chip shows ``%attn.N
+    custom-call`` either way, and the benchmark finds the kernels by that."""
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def attn(*args, **kwargs):
+            return fn(*args, **kwargs)
+
+        attn.__name__ = attn.__qualname__ = "attn"
+        return jax.jit(attn, static_argnames=static_argnames)
+
+    return wrap
+
+
+@_shared_by_layers("causal", "scale", "interpret", "with_lse", "tiles")
 def _flash_forward(
-    q: jnp.ndarray,  # [B, H, T, D]
-    k: jnp.ndarray,  # [B, Hkv, S, D]
-    v: jnp.ndarray,  # [B, Hkv, S, Dv]
+    q: jnp.ndarray,  # [B, T, H, D]
+    k: jnp.ndarray,  # [B, S, Hkv, D]
+    v: jnp.ndarray,  # [B, S, Hkv, Dv]
     kv_valid: jnp.ndarray,  # [B, S] int32
     causal: bool,
     scale: float,
@@ -426,31 +548,28 @@ def _flash_forward(
     with_lse: bool = False,
     tiles: Optional[FlashTiles] = None,
 ):
-    """Pad to the chooser's tiles, run the forward kernel, slice the padding
-    off. Any T/S: padded keys are masked through ``kv_valid``, padded query
-    rows are sliced off. With ``with_lse`` also returns the per-row logsumexp
-    as the backward takes it: ``[B, H, 1, Tp]`` float32, T on the lanes."""
-    B, H, T, D = q.shape
-    Hkv, S, Dv = k.shape[1], k.shape[2], v.shape[3]
+    """Run the forward kernel over the operands as they are: any T/S, the last
+    blocks overhang, keys past S are invalid in the mask and zeros in the
+    products, query rows past T are never stored. Returns O ``[B, T, H, Dv]``;
+    with ``with_lse`` also the per-row logsumexp as the backward takes it:
+    ``[B, H, 1, Tp]`` float32, T on the lanes, NEG_INF past the data."""
+    B, T, H, D = q.shape
+    S, Hkv, Dv = k.shape[1], k.shape[2], v.shape[3]
     assert H % Hkv == 0, (H, Hkv)
     rep = H // Hkv
     if tiles is None:
-        tiles = choose_tiles(T, S, D, rep, q.dtype, Dv=Dv)
-    heads = _heads_per_program(H, rep, tiles.heads)
-    _log_tiles(B, H, Hkv, T, S, D, Dv, jnp.dtype(q.dtype).name, tiles, heads)
+        tiles = choose_tiles(T, S, D, rep, q.dtype, Dv=Dv, H=H)
+    _log_tiles(B, H, Hkv, D, Dv, jnp.dtype(q.dtype).name, tiles)
+    heads = tiles.heads
     kv_heads = max(1, heads // rep)
     block_q, block_k = tiles.block_q, tiles.block_k
     kvh = _kv_block_map(heads, rep)
 
-    q = _pad_to(q, axis=2, n=tiles.Tp)
-    k, v = _pad_to(k, axis=2, n=tiles.Sp), _pad_to(v, axis=2, n=tiles.Sp)
-    kv_valid = _key_mask(kv_valid, tiles.Sp)
-
-    q_spec, o_spec = (pl.BlockSpec((1, heads, block_q, w), lambda b, h, i, j: (b, h, i, 0)) for w in (D, Dv))
+    q_spec, o_spec = (pl.BlockSpec((1, block_q, heads * w), lambda b, h, i, j: (b, i, h)) for w in (D, Dv))
     k_spec, v_spec = (
-        pl.BlockSpec((1, kv_heads, block_k, w), lambda b, h, i, j: (b, kvh(h), j, 0)) for w in (D, Dv)
+        pl.BlockSpec((1, block_k, kv_heads * w), lambda b, h, i, j: (b, j, kvh(h))) for w in (D, Dv)
     )
-    out_shape = [jax.ShapeDtypeStruct((B, H, tiles.Tp, Dv), q.dtype)]
+    out_shape = [jax.ShapeDtypeStruct((B, T, H * Dv), q.dtype)]
     out_specs = [o_spec]
     scratch = []
     if with_lse:
@@ -460,14 +579,14 @@ def _flash_forward(
         scratch += [
             pltpu.VMEM((heads, block_q, 1), jnp.float32),
             pltpu.VMEM((heads, block_q, 1), jnp.float32),
-            pltpu.VMEM((heads, block_q, Dv), jnp.float32),
+            pltpu.VMEM((block_q, heads * Dv), jnp.float32),
         ]
     if with_lse:
         scratch.append(pltpu.VMEM((block_q, LANE), jnp.float32))
 
     res = pl.pallas_call(
         functools.partial(
-            _flash_kernel, causal=causal, scale=scale, tiles=tiles, rep=rep, with_lse=with_lse
+            _flash_kernel, causal=causal, scale=scale, tiles=tiles, rep=rep, widths=(D, Dv), with_lse=with_lse
         ),
         grid=(B, H // heads, tiles.Tp // block_q, tiles.Sp // block_k),
         in_specs=[
@@ -479,22 +598,104 @@ def _flash_forward(
         scratch_shapes=scratch,
         interpret=interpret,
         compiler_params=_grid_semantics(),
-    )(kv_valid, q, k, v)
-    out = res[0][:, :, :T, :]
+    )(_key_mask(kv_valid, tiles.Sp), _flat(q), _flat(k), _flat(v))
+    out = res[0].reshape(B, T, H, Dv)
     return (out, res[1]) if with_lse else out
 
 
 # ----------------------------------------------------------------- backward
 
 
+def _flash_bwd_dq_kernel(
+    kv_valid_ref,  # [1, 1, block_k]
+    q_ref,  # [1, block_q, heads * D]
+    k_ref,  # [1, block_k, kv heads * D]
+    v_ref,  # [1, block_k, kv heads * Dv]
+    o_ref,  # [1, block_q, heads * Dv]
+    do_ref,  # as o_ref
+    lse_ref,  # [1, heads, 1, block_q] f32
+    dq_ref,  # as q_ref, out
+    delta_ref,  # as lse_ref, out: rowsum(dO * O), which the dkv pass subtracts
+    delta_cols,  # [block_q, LANE] f32 scratch
+    *scratch,  # dq [block_q, heads * D] f32 where the kv side is walked
+    causal: bool,
+    scale: float,
+    tiles: FlashTiles,
+    rep: int,
+    widths: Tuple[int, int],
+):
+    D, Dv = widths
+    heads = q_ref.shape[2] // D
+    static = _lane_heads(D, Dv) > 1  # lane windows at static offsets: the loops over heads unrolled
+    block_q, block_k = tiles.block_q, tiles.block_k
+    kv_steps = tiles.Sp // block_k
+    carried = kv_steps > 1
+    qi, kj = pl.program_id(2), pl.program_id(3)
+    q0, k0 = _block_offsets(tiles, qi, kj)
+    row_tiles = _query_row_tiles(tiles, causal)
+
+    if carried:
+        (dq_scratch,) = scratch
+
+        @pl.when(kj == 0)
+        def _init():
+            dq_scratch[...] = jnp.zeros_like(dq_scratch)
+
+    data_rows = row_tiles[-1][0] + row_tiles[-1][1]
+    if data_rows < block_q:  # rows past the data take no part: their delta is 0, not garbage
+        delta_cols[data_rows:, :] = jnp.zeros((block_q - data_rows, LANE), jnp.float32)
+
+    def visit():
+        biases = _mask_biases(kv_valid_ref[0], q0, k0, row_tiles=row_tiles, causal=causal)
+
+        def head(h, kh):
+            lse = _to_cols(lse_ref[0, h])  # [block_q, 1]
+            # fully-masked rows have lse == NEG_INF; guard the exp against inf * 0
+            lse = jnp.where(lse > NEG_INF / 2, lse, 0.0)
+            k = _held(k_ref[0, :, _window(kh, D)], k0, tiles.S, tiles.Sp)
+            v = _held(v_ref[0, :, _window(kh, Dv)], k0, tiles.S, tiles.Sp)
+            for (r0, n, keys), bias in zip(row_tiles, biases):
+                q = _held(q_ref[0, r0:r0 + n, _window(h, D)], q0 + r0, tiles.T, tiles.Tp)
+                do = _held(do_ref[0, r0:r0 + n, _window(h, Dv)], q0 + r0, tiles.T, tiles.Tp)
+                o = _held(o_ref[0, r0:r0 + n, _window(h, Dv)], q0 + r0, tiles.T, tiles.Tp)
+                delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=1, keepdims=True)  # [n, 1]
+                delta_cols[r0:r0 + n, :] = jnp.broadcast_to(delta, (n, LANE))
+                p = jnp.exp(_nt_dot(q, k[:keys]) * scale + bias - lse[r0:r0 + n])  # [n, keys]
+                dp = _nt_dot(do, v[:keys])
+                ds = p * (dp - delta) * scale
+                dq = _dot(ds.astype(k.dtype), k[:keys])  # [n, D]
+                if carried:
+                    dq_scratch[r0:r0 + n, _window(h, D)] += dq
+                else:
+                    dq_ref[0, r0:r0 + n, _window(h, D)] = dq.astype(dq_ref.dtype)
+
+            def write_delta():
+                delta_ref[0, h] = _to_rows(delta_cols[...])
+
+            if carried:  # the first kv block is visited for every q block, and once is enough
+                pl.when(kj == 0)(write_delta)
+            else:
+                write_delta()
+
+        _for_each_head(heads, rep, head, unrolled=static)
+
+    _visit_below_diagonal(visit, qi, kj, causal=causal, tiles=tiles)
+
+    if carried:
+
+        @pl.when(kj == kv_steps - 1)
+        def _finalize():
+            dq_ref[0] = dq_scratch[...].astype(dq_ref.dtype)
+
+
 def _flash_bwd_dkv_kernel(
     kv_valid_ref,  # [1, 1, block_k]
-    q_ref,  # [1, kv heads * rep, block_q, D]: each kv head's whole query-head group
-    k_ref,  # [1, kv heads, block_k, D]
-    v_ref,  # [1, kv heads, block_k, Dv]
+    q_ref,  # [1, block_q, kv heads * rep * D]: each kv head's whole query-head group
+    k_ref,  # [1, block_k, kv heads * D]
+    v_ref,  # [1, block_k, kv heads * Dv]
     do_ref,  # as q_ref, Dv wide
     lse_ref,  # [1, kv heads * rep, 1, block_q] f32
-    delta_ref,
+    delta_ref,  # as lse_ref: the dq pass's
     dk_ref,  # as k_ref, out
     dv_ref,  # as v_ref, out
     *scratch,  # dk, dv as their blocks, f32, where the q side is walked
@@ -502,15 +703,19 @@ def _flash_bwd_dkv_kernel(
     scale: float,
     tiles: FlashTiles,
     rep: int,
+    widths: Tuple[int, int],
 ):
     """Works on transposed score tiles, keys down the sublanes and queries along
     the lanes: lse and delta are subtracted as the rows they arrive as, and all
     four matmuls (K Q^T, V dO^T, P^T dO, dS^T Q) contract without a transpose."""
-    kv_heads = k_ref.shape[1]
+    D, Dv = widths
+    kv_heads = k_ref.shape[2] // D
+    static = _lane_heads(D, Dv) > 1  # lane windows at static offsets: the loops over heads unrolled
     block_q, block_k = tiles.block_q, tiles.block_k
     q_steps = tiles.Tp // block_q
     carried = q_steps > 1
     kj, qi = pl.program_id(2), pl.program_id(3)
+    q0, k0 = _block_offsets(tiles, qi, kj)
     # (first key row, rows, first query needed): key rows past the data are left out where
     # the kv side is one tile, queries before the diagonal where causal extents are static
     row_tiles = [
@@ -533,18 +738,21 @@ def _flash_bwd_dkv_kernel(
             bias = valid[c0:c0 + n]
             if causal:
                 shape = (n, block_q - first)
-                k_pos = kj * block_k + c0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-                q_pos = qi * block_q + first + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+                k_pos = k0 + c0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+                q_pos = q0 + first + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
                 bias = jnp.where(k_pos <= q_pos, bias, NEG_INF)
             biases.append(bias)
 
         def kv_head(kh):
-            k, v = k_ref[0, kh], v_ref[0, kh]
+            k = _held(k_ref[0, :, _window(kh, D)], k0, tiles.S, tiles.Sp)
+            v = _held(v_ref[0, :, _window(kh, Dv)], k0, tiles.S, tiles.Sp)
 
-            # dK/dV of a kv head sum over its query-head group
+            # dK/dV of a kv head sum over its query-head group. Query rows past the data: q and dO are
+            # zeros there and delta is 0, so dS is 0 and P meets a zero dO — they add nothing
             def group(r, sums):
                 h = kh * rep + r
-                q, do = q_ref[0, h], do_ref[0, h]
+                q = _held(q_ref[0, :, _window(h, D)], q0, tiles.T, tiles.Tp)
+                do = _held(do_ref[0, :, _window(h, Dv)], q0, tiles.T, tiles.Tp)
                 out = []
                 for (c0, n, first), bias, (dk, dv) in zip(row_tiles, biases, sums):
                     lse = lse_ref[0, h, :, first:]  # [1, queries]
@@ -558,22 +766,30 @@ def _flash_bwd_dkv_kernel(
                     out.append((dk, dv))
                 return out
 
-            sums = [
-                (jnp.zeros((n, k.shape[1]), jnp.float32), jnp.zeros((n, v.shape[1]), jnp.float32))
-                for _, n, _ in row_tiles
-            ]
-            sums = group(0, sums) if rep == 1 else jax.lax.fori_loop(0, rep, group, sums)
+            sums = [(jnp.zeros((n, D), jnp.float32), jnp.zeros((n, Dv), jnp.float32)) for _, n, _ in row_tiles]
+            if static or rep == 1:
+                for r in range(rep):
+                    sums = group(r, sums)
+            else:
+                sums = jax.lax.fori_loop(0, rep, group, sums)
             for (c0, n, _), (dk, dv) in zip(row_tiles, sums):
                 if carried:
-                    dk_scratch[kh, c0:c0 + n] += dk
-                    dv_scratch[kh, c0:c0 + n] += dv
+                    dk_scratch[c0:c0 + n, _window(kh, D)] += dk
+                    dv_scratch[c0:c0 + n, _window(kh, Dv)] += dv
                 else:
-                    dk_ref[0, kh, c0:c0 + n, :] = dk.astype(dk_ref.dtype)
-                    dv_ref[0, kh, c0:c0 + n, :] = dv.astype(dv_ref.dtype)
+                    dk_ref[0, c0:c0 + n, _window(kh, D)] = dk.astype(dk_ref.dtype)
+                    dv_ref[0, c0:c0 + n, _window(kh, Dv)] = dv.astype(dv_ref.dtype)
 
-        _loop(kv_head, count=kv_heads)
+        _loop(kv_head, count=kv_heads, unrolled=static)
 
     _visit_below_diagonal(visit, qi, kj, causal=causal, tiles=tiles)
+
+    if causal and not tiles.whole and not carried:
+
+        @pl.when(kj * block_k > block_q - 1)  # the one q block ends above these keys: no query sees them
+        def _unseen():
+            dk_ref[...] = jnp.zeros_like(dk_ref)
+            dv_ref[...] = jnp.zeros_like(dv_ref)
 
     if carried:
 
@@ -583,132 +799,88 @@ def _flash_bwd_dkv_kernel(
             dv_ref[0] = dv_scratch[...].astype(dv_ref.dtype)
 
 
-def _flash_bwd_dq_kernel(
-    kv_valid_ref,  # [1, 1, block_k]
-    q_ref,  # [1, heads, block_q, D]
-    k_ref,  # [1, kv heads, block_k, D]
-    v_ref,  # [1, kv heads, block_k, Dv]
-    do_ref,  # [1, heads, block_q, Dv]
-    lse_ref,  # [1, heads, 1, block_q] f32
-    delta_ref,
-    dq_ref,  # [1, heads, block_q, D] out
-    *scratch,  # dq [heads, block_q, D] f32 where the kv side is walked
-    causal: bool,
-    scale: float,
-    tiles: FlashTiles,
-    rep: int,
-):
-    heads = q_ref.shape[1]
-    block_q, block_k = tiles.block_q, tiles.block_k
-    kv_steps = tiles.Sp // block_k
-    carried = kv_steps > 1
-    qi, kj = pl.program_id(2), pl.program_id(3)
-    row_tiles = _query_row_tiles(tiles, causal)
-
-    if carried:
-        (dq_scratch,) = scratch
-
-        @pl.when(kj == 0)
-        def _init():
-            dq_scratch[...] = jnp.zeros_like(dq_scratch)
-
-    def visit():
-        biases = _mask_biases(kv_valid_ref[0], qi * block_q, kj * block_k, row_tiles=row_tiles, causal=causal)
-
-        def head(h, kh):
-            lse = _to_cols(lse_ref[0, h])  # [block_q, 1]
-            lse = jnp.where(lse > NEG_INF / 2, lse, 0.0)
-            delta = _to_cols(delta_ref[0, h])
-            k, v = k_ref[0, kh], v_ref[0, kh]
-            for (r0, n, keys), bias in zip(row_tiles, biases):
-                q = q_ref[0, h, r0:r0 + n, :]
-                do = do_ref[0, h, r0:r0 + n, :]
-                p = jnp.exp(_nt_dot(q, k[:keys]) * scale + bias - lse[r0:r0 + n])  # [n, keys]
-                dp = _nt_dot(do, v[:keys])
-                ds = p * (dp - delta[r0:r0 + n]) * scale
-                dq = _dot(ds.astype(k.dtype), k[:keys])  # [n, D]
-                if carried:
-                    dq_scratch[h, r0:r0 + n] += dq
-                else:
-                    dq_ref[0, h, r0:r0 + n, :] = dq.astype(dq_ref.dtype)
-
-        _for_each_head(heads, rep, head)
-
-    _visit_below_diagonal(visit, qi, kj, causal=causal, tiles=tiles)
-
-    if carried:
-
-        @pl.when(kj == kv_steps - 1)
-        def _finalize():
-            dq_ref[0] = dq_scratch[...].astype(dq_ref.dtype)
-
-
-def _flash_backward(q, k, v, kv_valid, out, lse, g, causal, scale, interpret, tiles=None):
-    """Pallas backward: recompute P per tile from the saved logsumexp (``lse`` as
-    ``_flash_forward`` returns it, ``[B, H, 1, Tp]``). Returns dq, dk, dv in the
-    dtype of q, k, v.
-
-    Two kernels: ``dkv`` runs grid (B, kv-head blocks, kv steps, q steps), a
-    program taking whole query-head groups so dK/dV sum over the group without
-    output-block write conflicts; ``dq`` runs the forward's grid. Where the whole
-    sequence is one tile neither carries anything across grid steps."""
-    B, H, T, D = q.shape
-    Hkv, S, Dv = k.shape[1], k.shape[2], v.shape[3]
+@_shared_by_layers("causal", "scale", "interpret", "tiles")
+def _flash_dq(q, k, v, kv_valid, out, lse, g, causal, scale, interpret, tiles):
+    """The backward's first kernel, on the forward's grid: dq ``[B, T, H, D]``
+    and ``delta = rowsum(g * out)`` as the second kernel takes it, ``[B, H, 1,
+    Tp]`` float32 (0 past the data), formed in float32 on the tiles of ``g``
+    and ``out`` the kernel holds."""
+    B, T, H, D = q.shape
+    Hkv, Dv = k.shape[2], v.shape[3]
     rep = H // Hkv
-    if tiles is None:
-        tiles = choose_tiles(T, S, D, rep, q.dtype, Dv=Dv)
-    heads = _heads_per_program(H, rep, tiles.heads)
+    heads, block_q, block_k = tiles.heads, tiles.block_q, tiles.block_k
     kv_heads = max(1, heads // rep)
-    block_q, block_k = tiles.block_q, tiles.block_k
-    q_steps, kv_steps = tiles.Tp // block_q, tiles.Sp // block_k
+    kv_steps = tiles.Sp // block_k
     kvh = _kv_block_map(heads, rep)
+    q_spec, do_spec = (pl.BlockSpec((1, block_q, heads * w), lambda b, h, qi, kj: (b, qi, h)) for w in (D, Dv))
+    row_spec = pl.BlockSpec((1, heads, 1, block_q), lambda b, h, qi, kj: (b, h, 0, qi))
+    k_spec, v_spec = (pl.BlockSpec((1, block_k, kv_heads * w), lambda b, h, qi, kj: (b, kj, kvh(h))) for w in (D, Dv))
+    dq, delta = pl.pallas_call(
+        functools.partial(_flash_bwd_dq_kernel, causal=causal, scale=scale, tiles=tiles, rep=rep, widths=(D, Dv)),
+        grid=(B, H // heads, tiles.Tp // block_q, kv_steps),
+        in_specs=[
+            pl.BlockSpec((1, 1, block_k), lambda b, h, qi, kj: (b, 0, kj)),
+            q_spec, k_spec, v_spec, do_spec, do_spec, row_spec,
+        ],
+        out_specs=[q_spec, row_spec],
+        out_shape=[jax.ShapeDtypeStruct((B, T, H * D), q.dtype), jax.ShapeDtypeStruct(lse.shape, jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, LANE), jnp.float32)]
+        + ([pltpu.VMEM((block_q, heads * D), jnp.float32)] if kv_steps > 1 else []),
+        interpret=interpret,
+        compiler_params=_grid_semantics(),
+    )(_key_mask(kv_valid, tiles.Sp), _flat(q), _flat(k), _flat(v), _flat(out), _flat(g), lse)
+    return dq.reshape(q.shape), delta
 
-    # padded query rows: dO == 0 and delta == 0 there, so they add nothing
-    q, g, out = (_pad_to(x, axis=2, n=tiles.Tp) for x in (q, g, out))
-    k, v = _pad_to(k, axis=2, n=tiles.Sp), _pad_to(v, axis=2, n=tiles.Sp)
-    kv_valid = _key_mask(kv_valid, tiles.Sp)
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)[:, :, None, :]  # [B, H, 1, Tp]
 
+@_shared_by_layers("causal", "scale", "interpret", "tiles")
+def _flash_dkv(q, k, v, kv_valid, lse, delta, g, causal, scale, interpret, tiles):
+    """The backward's second kernel: grid (B, kv-head blocks, kv steps, q
+    steps), a program taking whole query-head groups so dK/dV sum over the group
+    without output-block write conflicts; ``delta`` is the first kernel's."""
+    B, _, H, D = q.shape
+    S, Hkv, Dv = k.shape[1], k.shape[2], v.shape[3]
+    rep = H // Hkv
+    kv_heads = max(1, tiles.heads // rep)
+    block_q, block_k = tiles.block_q, tiles.block_k
+    q_steps = tiles.Tp // block_q
     # a block of kv_heads * rep query heads at block index hk is kv-head block hk's groups
     group_spec, group_do_spec = (
-        pl.BlockSpec((1, kv_heads * rep, block_q, w), lambda b, hk, kj, qi: (b, hk, qi, 0)) for w in (D, Dv)
+        pl.BlockSpec((1, block_q, kv_heads * rep * w), lambda b, hk, kj, qi: (b, qi, hk)) for w in (D, Dv)
     )
     group_row_spec = pl.BlockSpec((1, kv_heads * rep, 1, block_q), lambda b, hk, kj, qi: (b, hk, 0, qi))
-    k_spec, v_spec = (pl.BlockSpec((1, kv_heads, block_k, w), lambda b, hk, kj, qi: (b, hk, kj, 0)) for w in (D, Dv))
+    k_spec, v_spec = (pl.BlockSpec((1, block_k, kv_heads * w), lambda b, hk, kj, qi: (b, kj, hk)) for w in (D, Dv))
     dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, causal=causal, scale=scale, tiles=tiles, rep=rep),
-        grid=(B, Hkv // kv_heads, kv_steps, q_steps),
+        functools.partial(_flash_bwd_dkv_kernel, causal=causal, scale=scale, tiles=tiles, rep=rep, widths=(D, Dv)),
+        grid=(B, Hkv // kv_heads, tiles.Sp // block_k, q_steps),
         in_specs=[
             pl.BlockSpec((1, 1, block_k), lambda b, hk, kj, qi: (b, 0, kj)),
             group_spec, k_spec, v_spec, group_do_spec, group_row_spec, group_row_spec,
         ],
         out_specs=[k_spec, v_spec],
-        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype), jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        scratch_shapes=[pltpu.VMEM((kv_heads, block_k, w), jnp.float32) for w in (D, Dv)] if q_steps > 1 else [],
+        out_shape=[jax.ShapeDtypeStruct((B, S, Hkv * D), k.dtype), jax.ShapeDtypeStruct((B, S, Hkv * Dv), v.dtype)],
+        scratch_shapes=[pltpu.VMEM((block_k, kv_heads * w), jnp.float32) for w in (D, Dv)] if q_steps > 1 else [],
         interpret=interpret,
         compiler_params=_grid_semantics(),
-    )(kv_valid, q, k, v, g, lse, delta)
+    )(_key_mask(kv_valid, tiles.Sp), _flat(q), _flat(k), _flat(v), _flat(g), lse, delta)
+    return dk.reshape(k.shape), dv.reshape(v.shape)
 
-    q_spec, do_spec = (pl.BlockSpec((1, heads, block_q, w), lambda b, h, qi, kj: (b, h, qi, 0)) for w in (D, Dv))
-    row_spec = pl.BlockSpec((1, heads, 1, block_q), lambda b, h, qi, kj: (b, h, 0, qi))
-    dq_k_spec, dq_v_spec = (
-        pl.BlockSpec((1, kv_heads, block_k, w), lambda b, h, qi, kj: (b, kvh(h), kj, 0)) for w in (D, Dv)
-    )
-    dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, causal=causal, scale=scale, tiles=tiles, rep=rep),
-        grid=(B, H // heads, q_steps, kv_steps),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_k), lambda b, h, qi, kj: (b, 0, kj)),
-            q_spec, dq_k_spec, dq_v_spec, do_spec, row_spec, row_spec,
-        ],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((heads, block_q, D), jnp.float32)] if kv_steps > 1 else [],
-        interpret=interpret,
-        compiler_params=_grid_semantics(),
-    )(kv_valid, q, k, v, g, lse, delta)
 
-    return dq[:, :, :T, :], dk[:, :, :S, :], dv[:, :, :S, :]
+def _flash_backward(q, k, v, kv_valid, out, lse, g, causal, scale, interpret, tiles=None):
+    """Pallas backward over the operands as they are (q, out, g ``[B, T, H, ·]``,
+    k, v ``[B, S, Hkv, ·]``): recompute P per tile from the saved logsumexp
+    (``lse`` as ``_flash_forward`` returns it, ``[B, H, 1, Tp]``). Returns dq, dk,
+    dv shaped and typed as q, k, v.
+
+    Two kernels: ``dq`` takes ``out`` beside ``g`` and forms ``delta`` on the way
+    (:func:`_flash_dq`); ``dkv`` reads that row and therefore runs second
+    (:func:`_flash_dkv`). Where the whole sequence is one tile neither carries
+    anything across grid steps."""
+    if tiles is None:
+        B, T, H, D = q.shape
+        tiles = choose_tiles(T, k.shape[1], D, H // k.shape[2], q.dtype, Dv=v.shape[3], H=H)
+    dq, delta = _flash_dq(q, k, v, kv_valid, out, lse, g, causal, scale, interpret, tiles)
+    dk, dv = _flash_dkv(q, k, v, kv_valid, lse, delta, g, causal, scale, interpret, tiles)
+    return dq, dk, dv
 
 
 def xla_attention(q, k, v, kv_valid, causal: bool, scale: float) -> jnp.ndarray:
@@ -735,8 +907,9 @@ def xla_attention(q, k, v, kv_valid, causal: bool, scale: float) -> jnp.ndarray:
 def flash_attention(
     q, k, v, kv_valid, causal: bool = True, scale: Optional[float] = None, interpret: bool = False,
 ):
-    """Flash attention, [B,H,T,D] layout; K/V may carry fewer (grouped) heads, and
-    V (with it the output) a width of its own, ``[B,Hkv,S,Dv]``.
+    """Flash attention over q ``[B,T,H,D]``, k ``[B,S,Hkv,D]``, v ``[B,S,Hkv,Dv]``,
+    the layout a model's projections leave them in; K/V may carry fewer (grouped)
+    heads, and V (with it the output, ``[B,T,H,Dv]``) a width of its own.
     Tiles come from the shape (:func:`choose_tiles`). Differentiable: backward
     runs Pallas dq/dkv kernels recomputing attention per tile from the saved
     logsumexp (O(T·tile) memory, matching the memory model of the reference's
@@ -759,6 +932,7 @@ def _bwd(causal, scale, interpret, res, g):
 
 
 flash_attention.defvjp(_fwd, _bwd)
+
 
 
 # ----------------------------------------------------------------- decode
@@ -989,7 +1163,7 @@ def decode_attention(
 # ---- dispatch: a model states what it attends over (:func:`attend`); which path, over which mesh, is decided here
 
 
-def _placed(local, mesh, operands, out_ndim: int):
+def _placed(local, mesh, operands, out: Tuple[int, int]):
     """``local(*arrays)`` plainly where ``mesh`` is None, else its SPMD
     placement over ``mesh``: Mosaic kernels cannot be auto-partitioned by XLA's
     SPMD pass (it raises at compile time on any multi-device mesh), so shard
@@ -1000,9 +1174,9 @@ def _placed(local, mesh, operands, out_ndim: int):
     when both divide the axis. Differentiable: autodiff enters the shard_map
     and applies the kernel's custom VJP per shard.
 
-    ``operands``: ``(array, has_heads)`` pairs — dimension 0 of an array is the
-    batch, dimension 1 the heads where ``has_heads``; a scalar is replicated.
-    The result has ``out_ndim`` dimensions, batch then heads first."""
+    ``operands``: ``(array, heads_dim)`` pairs — dimension 0 of an array is the
+    batch, dimension ``heads_dim`` the heads (None: it has none); a scalar is
+    replicated. ``out``: the result's ``(ndim, heads_dim)``."""
     if mesh is None:
         return local(*(x for x, _ in operands))
     from jax.sharding import PartitionSpec as P
@@ -1034,13 +1208,15 @@ def _placed(local, mesh, operands, out_ndim: int):
     batch_entry = tuple(a for a in BATCH_AXES if a in axes)
     head_entry = MODEL_AXIS if MODEL_AXIS in axes else None
 
-    def spec(ndim, has_heads):
-        entries = [batch_entry or None, head_entry if has_heads else None] + [None] * (ndim - 2)
+    def spec(ndim, heads_dim):
+        entries = [batch_entry or None] + [None] * (ndim - 1)
+        if heads_dim is not None:
+            entries[heads_dim] = head_entry
         return P(*entries[:ndim])
 
     return jax.shard_map(
-        local, mesh=mesh, in_specs=tuple(spec(jnp.ndim(x), heads) for x, heads in operands),
-        out_specs=spec(out_ndim, True), check_vma=False, axis_names=axes,
+        local, mesh=mesh, in_specs=tuple(spec(jnp.ndim(x), heads_dim) for x, heads_dim in operands),
+        out_specs=spec(*out), check_vma=False, axis_names=axes,
     )(*(x for x, _ in operands))
 
 
@@ -1145,9 +1321,10 @@ def attend(q, k, v, cache, mask_bias, kv_valid, index, scale: float, impl: str, 
 
     What is chosen, in this order: the Pallas decode kernel for a single-token
     step over a per-head float cache (it reads the cache up to the write index
-    only; appends of several tokens keep the einsum); the ring for a cache-free
-    forward under ``impl="ring"`` on a mesh that can ring; the flash kernel for
-    a ``kv_valid`` forward of more than one token; else the einsum — grouped
+    only; appends of several tokens keep the einsum); the flash kernels for
+    a ``kv_valid`` forward of more than one token under ``impl="flash"``, which
+    take q, k, v in the layout they arrive in; the ring for a cache-free
+    forward under ``impl="ring"`` on a mesh that can ring; else the einsum — grouped
     (the group a free axis, K/V never repeated to full head count) or
     multi-head, with an int8 cache's row scales folded into the scores and the
     probabilities. The einsum paths are the reference the kernels are tested
@@ -1166,13 +1343,25 @@ def attend(q, k, v, cache, mask_bias, kv_valid, index, scale: float, impl: str, 
 
             if mesh is not None:
                 index = jnp.asarray(index, jnp.int32)  # an operand of the shard_map, replicated
-            operands = [(q[:, 0], True), (cache["k"], True), (cache["v"], True), (mask_bias, False), (index, False)]
-            return _placed(decode, mesh, operands, out_ndim=3).reshape(B, T, -1).astype(dtype)
+            operands = [(q[:, 0], 1), (cache["k"], 1), (cache["v"], 1), (mask_bias, None), (index, None)]
+            return _placed(decode, mesh, operands, out=(3, 1)).reshape(B, T, -1).astype(dtype)
 
     use_flash, flash_mesh = flash_placement(impl, biased, B, T, kv_valid, H, kv_heads)
+    if use_flash:
+        # the kernels take q, k, v as the projections left them and hand the output back the same way: no
+        # transpose on either side; query head h maps to kv head h // rep natively, so grouped K/V are
+        # never materialized at full head count
+        interpret = _interpret(flash_mesh)
+
+        def flash(q, k, v, kv_valid):
+            return flash_attention(q, k, v, kv_valid, True, scale, interpret)
+
+        operands = [(q, 2), (k, 2), (v, 2), (kv_valid, None)]
+        return _placed(flash, flash_mesh, operands, out=(4, 2)).astype(dtype).reshape(B, T, -1)
+
     # kh/vh [B, Hkv, S, D]: the layout attention consumes (and the cache layout)
     k_row_scale = v_row_scale = None
-    if cache is not None and not use_flash:
+    if cache is not None:
         # attend over the cache (decode step / XLA prefill)
         if kv_cache.has_row_scales(cache) and prefix is None:
             # int8 cache: bare dtype convert only — the per-row scales fold
@@ -1225,17 +1414,7 @@ def attend(q, k, v, cache, mask_bias, kv_valid, index, scale: float, impl: str, 
             return out.reshape(B, T, -1)
         # fall through to XLA when the mesh/shape can't ring
 
-    if use_flash:
-        # the kernel maps query head h -> kv head h // rep natively, so grouped
-        # K/V are never materialized at full head count
-        interpret = _interpret(flash_mesh)
-
-        def flash(q, k, v, kv_valid):
-            return flash_attention(q, k, v, kv_valid, True, scale, interpret)
-
-        operands = [(q.transpose(0, 2, 1, 3), True), (kh, True), (vh, True), (kv_valid, False)]
-        out = _placed(flash, flash_mesh, operands, out_ndim=4).transpose(0, 2, 1, 3).astype(dtype)
-    elif kv_heads != H:
+    if kv_heads != H:
         # grouped-query einsum: batch scores over kv heads with the group as
         # a free axis — the old jnp.repeat path copied the whole K/V cache to
         # full head count every decode step, multiplying HBM traffic by
