@@ -82,13 +82,14 @@ def _flash(grad, shape=None, kv_heads=None, dtype=jnp.bfloat16, v_width=None):
     return fn, [((B, T, H, D), dtype), (kv_shape, dtype), (v_shape, dtype), ((B, T), jnp.int32)]
 
 
-def _grouped_products(grad, tokens):
+def _grouped_products(grad, tokens, f=1408, k=6):
     """The expert layer's sorted grouped products (``ops/moe.py``) at the
     kimi-vl-a3b cell's shapes: 8 experts of 2048 x 1408 held, 6 assignments a
-    token, a row for every assignment."""
+    token, a row for every assignment; at the lfm2-24b-a2b cell's the experts
+    are 1536 wide and a token has 4."""
     from trlx_tpu.ops import moe
 
-    E, d, f, k = 8, 2048, 1408, 6
+    E, d = 8, 2048
 
     def fwd(x, chosen, weights, gate, up, down):
         return moe.expert_ffn(x, chosen, weights, gate, up, down, expert_offset=0)[0]
@@ -163,6 +164,12 @@ def _cell_models():
         # the leading dense layer: latent attention, keys 192 wide and values 128
         "kimi-vl-a3b": (PRESETS["kimi_vl"].replace(num_layers=1, vocab_size=20480), (4, 513), (32, 576)),
         "ouro-2.6b": (PRESETS["ouro"].replace(num_layers=1, loop_steps=2), (8, 257), (32, 320)),
+        # a convolution layer and the attention layer (32 / 8 heads of 64, a norm on each head), both over the
+        # dense FFN: the experts' grouped products have cases of their own
+        "lfm2-24b-a2b": (
+            PRESETS["lfm2_moe"].replace(
+                num_layers=2, layer_kinds=("conv", "attention"), first_dense_layers=2, vocab_size=8192),
+            (8, 513), (32, 576)),
     }
 
 
@@ -191,7 +198,7 @@ def _cell_trunk(cell, learner):
 
 
 @pytest.mark.parametrize("program", ["learner", "scorer"])
-@pytest.mark.parametrize("cell", ["gpt2", "gpt2-medium", "kimi-vl-a3b", "ouro-2.6b"])
+@pytest.mark.parametrize("cell", ["gpt2", "gpt2-medium", "kimi-vl-a3b", "ouro-2.6b", "lfm2-24b-a2b"])
 def test_nothing_runs_between_the_projections_and_the_flash_calls(cell, program, one_chip, no_persistent_cache,
                                                                   monkeypatch):
     """At the cells' learner and scorer shapes, compiled for the chip: a layer's
@@ -215,7 +222,7 @@ def test_nothing_runs_between_the_projections_and_the_flash_calls(cell, program,
     H, widths = config.num_heads, {config.dim_per_head}
     if config.attention_kind == "mla":
         widths = {config.qk_nope_head_dim + config.qk_rope_head_dim, config.v_head_dim}
-    calls = config.num_layers * config.loop_steps * (3 if program == "learner" else 1)
+    calls = config.attention_layers * config.loop_steps * (3 if program == "learner" else 1)
     names = _attention_instructions(text)
     assert len(names) == calls and all(re.match(r"^%attn[.0-9]* custom-call$", name) for name in names), names
 
@@ -292,16 +299,28 @@ for _shape in ((8, 16, 257, 128), (32, 16, 320, 128), (128, 16, 64, 128)):
     _name = "x".join(map(str, _shape))
     CASES[f"flash_fwd-{_name}"] = functools.partial(_flash, False, _shape)
     CASES[f"flash_grad-{_name}"] = functools.partial(_flash, True, _shape)
+# 32 query heads over 8 key/value heads of 64 at the lfm2-24b-a2b cell's shapes: a learner microbatch
+# (8 x 513), a scoring chunk at its buckets (64 + 512), the prefill of 128 prompts
+for _shape in ((8, 32, 513, 64), (32, 32, 576, 64), (128, 32, 64, 64)):
+    _name = "x".join(map(str, _shape)) + "-hkv8"
+    CASES[f"flash_fwd-{_name}"] = functools.partial(_flash, False, _shape, 8)
+    CASES[f"flash_grad-{_name}"] = functools.partial(_flash, True, _shape, 8)
 # the grouped expert products: a learner microbatch (4 x 513 tokens) and a decode step (128)
 for _tokens in (2052, 128):
     CASES[f"grouped_products_fwd-{_tokens}"] = functools.partial(_grouped_products, False, _tokens)
     CASES[f"grouped_products_grad-{_tokens}"] = functools.partial(_grouped_products, True, _tokens)
+# and at the lfm2-24b-a2b cell's: experts 1536 wide, 4 a token, a microbatch of 8 x 513 tokens, a decode step
+for _tokens in (4104, 128):
+    CASES[f"grouped_products_fwd-{_tokens}-f1536-k4"] = functools.partial(_grouped_products, False, _tokens, 1536, 4)
+    CASES[f"grouped_products_grad-{_tokens}-f1536-k4"] = functools.partial(_grouped_products, True, _tokens, 1536, 4)
 # the decode kernel at the cells' decode steps ([B, H, S, D]; cell 2's cache is 576 slots), and a grouped model's
 CASES["decode-128x12x512x64"] = functools.partial(_decode, (128, 12, 512, 64))
 CASES["decode-64x16x576x64"] = functools.partial(_decode, (64, 16, 576, 64))
 CASES["decode-32x32x1024x128-hkv8"] = functools.partial(_decode, (32, 32, 1024, 128), 8)
 # the ouro-2.6b cell's decode step: 16 kv heads of 128, a kv slot of one operand 512 KiB against gpt2's 196 KB
 CASES["decode-128x16x256x128"] = functools.partial(_decode, (128, 16, 256, 128))
+# the lfm2-24b-a2b cell's: the one attention layer's cache, 8 kv heads of 64 under 32 query heads
+CASES["decode-128x32x512x64-hkv8"] = functools.partial(_decode, (128, 32, 512, 64), 8)
 for _preset in ("gpt2", "gpt_bigcode"):  # Hkv=12 rep=1 D=64; Hkv=1 rep=16 D=128
     for _pool, _quant in (("bf16", False), ("int8", True)):
         CASES[f"paged_decode-{_pool}-{_preset}"] = functools.partial(

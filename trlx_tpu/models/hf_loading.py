@@ -594,6 +594,81 @@ def _ouro_from_params(p, c):
     return sd
 
 
+#: LFM2's published names of a layer's projections -> this program's (module, name)
+_LFM2_MIXERS = {
+    True: ((("conv", "in_proj"), "conv.in_proj"), (("conv", "out_proj"), "conv.out_proj")),
+    False: tuple((("attn", ours), f"self_attn.{theirs}") for ours, theirs in (
+        ("q_proj", "q_proj"), ("k_proj", "k_proj"), ("v_proj", "v_proj"), ("o_proj", "out_proj"))),
+}
+_LFM2_FFN = (("gate", "w1"), ("up", "w3"), ("down", "w2"))
+
+
+def _lfm2_to_params(sd, c):
+    """LFM2-MoE (``lfm2_moe``): a convolution or an attention mixer a layer under
+    its own names, the depthwise filter ``[d, 1, taps]`` there and ``[d, taps]``
+    here, the head tied. Only the experts held (``experts_held`` from
+    ``expert_offset``) and the first ``vocab_size`` rows of the vocabulary are taken."""
+    p = {
+        "embed_tokens": {"embedding": sd["model.embed_tokens.weight"][: c.vocab_size]},
+        "ln_f": {"scale": sd["model.embedding_norm.weight"]},
+    }
+    if not c.tie_word_embeddings:
+        p["lm_head"] = {"kernel": sd["lm_head.weight"][: c.vocab_size].T}
+    for i in range(c.num_layers):
+        pre, conv = f"model.layers.{i}", c.is_conv_layer(i)
+        layer = {"ln_1": {"scale": sd[f"{pre}.operator_norm.weight"]}, "ln_2": {"scale": sd[f"{pre}.ffn_norm.weight"]}}
+        for (module, ours), theirs in _LFM2_MIXERS[conv]:
+            layer.setdefault(module, {})[ours] = _linear(sd, f"{pre}.{theirs}")
+        if conv:
+            layer["conv"]["conv"] = {"kernel": sd[f"{pre}.conv.conv.weight"][:, 0, :]}
+        else:
+            layer["attn"]["q_norm"] = {"scale": sd[f"{pre}.self_attn.q_layernorm.weight"]}
+            layer["attn"]["k_norm"] = {"scale": sd[f"{pre}.self_attn.k_layernorm.weight"]}
+        if c.is_expert_layer(i):
+            held = range(c.expert_offset, c.expert_offset + c.held_experts)
+            layer["mlp"] = {
+                "router": {"kernel": sd[f"{pre}.feed_forward.gate.weight"].T,
+                           "bias": sd[f"{pre}.feed_forward.expert_bias"]},
+                "experts": {ours: np.stack([sd[f"{pre}.feed_forward.experts.{e}.{theirs}.weight"].T for e in held])
+                            for ours, theirs in _LFM2_FFN},
+            }
+        else:
+            layer["mlp"] = {f"{ours}_proj": _linear(sd, f"{pre}.feed_forward.{theirs}") for ours, theirs in _LFM2_FFN}
+        p[f"layers_{i}"] = layer
+    return p
+
+
+def _lfm2_from_params(p, c):
+    """The held experts go out under their published indices, from ``expert_offset`` on."""
+    sd = {
+        "model.embed_tokens.weight": p["embed_tokens"]["embedding"],
+        "model.embedding_norm.weight": p["ln_f"]["scale"],
+    }
+    if "lm_head" in p:
+        sd["lm_head.weight"] = p["lm_head"]["kernel"].T
+    for i in range(c.num_layers):
+        L, pre, conv = p[f"layers_{i}"], f"model.layers.{i}", c.is_conv_layer(i)
+        sd[f"{pre}.operator_norm.weight"] = L["ln_1"]["scale"]
+        sd[f"{pre}.ffn_norm.weight"] = L["ln_2"]["scale"]
+        for (module, ours), theirs in _LFM2_MIXERS[conv]:
+            sd[f"{pre}.{theirs}.weight"] = L[module][ours]["kernel"].T
+        if conv:
+            sd[f"{pre}.conv.conv.weight"] = L["conv"]["conv"]["kernel"][:, None, :]
+        else:
+            sd[f"{pre}.self_attn.q_layernorm.weight"] = L["attn"]["q_norm"]["scale"]
+            sd[f"{pre}.self_attn.k_layernorm.weight"] = L["attn"]["k_norm"]["scale"]
+        if c.is_expert_layer(i):
+            sd[f"{pre}.feed_forward.gate.weight"] = L["mlp"]["router"]["kernel"].T
+            sd[f"{pre}.feed_forward.expert_bias"] = L["mlp"]["router"]["bias"]
+            for ours, theirs in _LFM2_FFN:
+                for e, kernel in enumerate(L["mlp"]["experts"][ours], start=c.expert_offset):
+                    sd[f"{pre}.feed_forward.experts.{e}.{theirs}.weight"] = kernel.T
+        else:
+            for ours, theirs in _LFM2_FFN:
+                sd[f"{pre}.feed_forward.{theirs}.weight"] = L["mlp"][f"{ours}_proj"]["kernel"].T
+    return sd
+
+
 CONVERTERS = {
     "gpt2": (_gpt2_to_params, _gpt2_from_params),
     "llama": (_llama_to_params, _llama_from_params),
@@ -605,6 +680,7 @@ CONVERTERS = {
     # load only: a share of the experts and of the vocabulary is no whole checkpoint to export
     "kimi_vl": (_kimi_to_params, None),
     "ouro": (_ouro_to_params, _ouro_from_params),
+    "lfm2_moe": (_lfm2_to_params, _lfm2_from_params),
 }
 # "t5" is registered below once its converters are defined (seq2seq section)
 
@@ -706,9 +782,10 @@ def load_pretrained(
 
 def _family_of(name: str) -> str:
     key = name.lower().replace("-", "").replace("_", "")
-    for family in ("gptbigcode", "gptneox", "gptj", "gpt2", "llama", "opt", "bloom", "kimivl", "ouro"):
+    for family in ("gptbigcode", "gptneox", "gptj", "gpt2", "llama", "opt", "bloom", "kimivl", "ouro", "lfm2moe"):
         if family in key:
-            return {"gptneox": "gpt_neox", "gptbigcode": "gpt_bigcode", "kimivl": "kimi_vl"}.get(family, family)
+            return {"gptneox": "gpt_neox", "gptbigcode": "gpt_bigcode", "kimivl": "kimi_vl",
+                    "lfm2moe": "lfm2_moe"}.get(family, family)
     if "pythia" in key or "neox" in key:
         return "gpt_neox"
     if "starcoder" in key or "santacoder" in key:
@@ -797,6 +874,20 @@ def make_hf_config(model_type: str, c: TransformerConfig):
             rms_norm_eps=c.norm_eps, rope_theta=c.rope_theta, hidden_act="silu",
             tie_word_embeddings=c.tie_word_embeddings, total_ut_steps=c.loop_steps,
             early_exit_threshold=c.early_exit_threshold,
+        )
+    if model_type == "lfm2_moe":
+        # the published keys, whether or not this transformers has the class
+        published = type("Lfm2MoeConfig", (transformers.PretrainedConfig,), {"model_type": "lfm2_moe"})
+        return published(
+            vocab_size=c.vocab_size, hidden_size=c.hidden_size, num_hidden_layers=c.num_layers,
+            num_attention_heads=c.num_heads, num_key_value_heads=c.kv_heads, intermediate_size=c.ffn_dim,
+            moe_intermediate_size=c.moe_intermediate_size, max_position_embeddings=c.max_position_embeddings,
+            norm_eps=c.norm_eps, rope_parameters={"rope_theta": c.rope_theta, "rope_type": "default"},
+            layer_types=["conv" if c.is_conv_layer(i) else "full_attention" for i in range(c.num_layers)],
+            conv_L_cache=c.conv_taps, conv_bias=False, num_dense_layers=c.first_dense_layers,
+            num_experts=c.num_experts, num_experts_per_tok=c.experts_per_token, norm_topk_prob=c.norm_topk_prob,
+            routed_scaling_factor=c.routed_scaling_factor, use_expert_bias=True,
+            tie_word_embeddings=c.tie_word_embeddings,
         )
     if model_type == "t5":
         return transformers.T5Config(

@@ -83,7 +83,24 @@ PRESETS: Dict[str, TransformerConfig] = {
         activation="silu", glu=True, attn_bias=False, mlp_bias=False, tie_word_embeddings=False,
         loop_steps=4, sandwich_norms=True, exit_gate=True, early_exit_threshold=1.0,
     ),
+    # LFM2-24B-A2B (model_type lfm2_moe), a hybrid: 30 of the 40 layers mix tokens by a
+    # gated short convolution of 3 taps, 10 by grouped-query attention with a norm on
+    # every query and key head; two leading dense layers, then 64 sigmoid-routed experts
+    # (4 a token, weights over their sum + 1e-6) and no shared one; the head is tied.
+    "lfm2_moe": TransformerConfig(
+        vocab_size=65536, hidden_size=2048, num_layers=40, num_heads=32, num_kv_heads=8,
+        intermediate_size=11776, max_position_embeddings=128000, pos_embedding="rotary",
+        rope_style="neox", rope_theta=1000000.0, norm="rmsnorm", norm_eps=1e-5,
+        activation="silu", glu=True, attn_bias=False, mlp_bias=False, tie_word_embeddings=True,
+        layer_kinds=tuple("attention" if i % 4 == 2 else "conv" for i in range(40)),  # the published layer_types
+        conv_taps=3, qk_norm=True, num_experts=64, experts_per_token=4, num_shared_experts=0,
+        moe_intermediate_size=1536, first_dense_layers=2, routed_scaling_factor=1.0, norm_topk_prob=True,
+        router_norm_eps=1e-6,
+    ),
 }
+
+#: the published ``layer_types`` -> ``TransformerConfig.layer_kinds``
+LFM2_LAYER_KINDS = {"conv": "conv", "full_attention": "attention"}
 
 
 def get_preset(name: str, overrides: Optional[Dict[str, Any]] = None) -> TransformerConfig:
@@ -93,7 +110,8 @@ def get_preset(name: str, overrides: Optional[Dict[str, Any]] = None) -> Transfo
     if key in PRESETS:
         config = PRESETS[key]
     else:
-        for family in ("gpt_bigcode", "gpt_neox", "gptj", "gpt2", "llama", "opt", "bloom", "kimi_vl", "ouro"):
+        for family in ("gpt_bigcode", "gpt_neox", "gptj", "gpt2", "llama", "opt", "bloom", "kimi_vl", "ouro",
+                       "lfm2_moe"):
             if family.replace("_", "") in key.replace("_", "").replace("-", ""):
                 config = PRESETS[family]
                 break
@@ -215,6 +233,28 @@ def from_hf_config(hf_config, overrides: Optional[Dict[str, Any]] = None) -> Tra
             tie_word_embeddings=getattr(hf_config, "tie_word_embeddings", False),
             loop_steps=hf_config.total_ut_steps,
             early_exit_threshold=float(getattr(hf_config, "early_exit_threshold", 1.0)),
+        )
+    elif mt == "lfm2_moe":
+        unsupported = {
+            "conv_bias": bool(getattr(hf_config, "conv_bias", False)),
+            "use_expert_bias false": not getattr(hf_config, "use_expert_bias", True),
+            "rope_type other than default": (getattr(hf_config, "rope_parameters", None) or {}).get(
+                "rope_type", "default") != "default",
+        }
+        if any(unsupported.values()):
+            raise ValueError(f"{mt}: not supported: {[k for k, v in unsupported.items() if v]}")
+        config = PRESETS["lfm2_moe"].replace(
+            vocab_size=hf_config.vocab_size, hidden_size=hf_config.hidden_size,
+            num_layers=hf_config.num_hidden_layers, num_heads=hf_config.num_attention_heads,
+            num_kv_heads=hf_config.num_key_value_heads, intermediate_size=hf_config.intermediate_size,
+            max_position_embeddings=hf_config.max_position_embeddings,
+            rope_theta=float((getattr(hf_config, "rope_parameters", None) or {}).get("rope_theta", 1000000.0)),
+            norm_eps=hf_config.norm_eps, tie_word_embeddings=getattr(hf_config, "tie_word_embeddings", True),
+            layer_kinds=tuple(LFM2_LAYER_KINDS[kind] for kind in hf_config.layer_types),
+            conv_taps=hf_config.conv_L_cache, num_experts=hf_config.num_experts,
+            experts_per_token=hf_config.num_experts_per_tok, moe_intermediate_size=hf_config.moe_intermediate_size,
+            first_dense_layers=hf_config.num_dense_layers, routed_scaling_factor=hf_config.routed_scaling_factor,
+            norm_topk_prob=hf_config.norm_topk_prob,
         )
     else:
         raise ValueError(f"Unsupported HF model_type {mt!r}")

@@ -37,7 +37,8 @@ from trlx_tpu.parallel.sharding import (
 )
 
 # a layer's buffers (ops/kv_cache.py) under their keys, each a list of L arrays (per-layer carries ->
-# in-place decode writes) or one stacked [L, ...] array when config.stacked, plus "index": i32[]
+# in-place decode writes) or one stacked [L, ...] array when config.stacked, plus "index": i32[]; with
+# layer_kinds, keys and values over the attention layers alone and "conv" over the convolution layers
 KVCache = Dict[str, Any]
 
 
@@ -159,6 +160,28 @@ class TransformerConfig:
     sandwich_norms: bool = False
     exit_gate: bool = False
     early_exit_threshold: float = 1.0
+    # A mixer chosen layer by layer: layer_kinds names each layer "attention" or
+    # "conv" (empty: every layer attends; kinds past num_layers are dropped, so a
+    # depth cut keeps the leading layers). A "conv" layer mixes tokens by a gated
+    # short convolution (ShortConv): a causal depthwise filter of conv_taps taps
+    # along the sequence between two gates. Its cache entry is the row's last
+    # conv_taps - 1 gated inputs, [B, conv_taps - 1, hidden] whatever the length,
+    # beside the attention layers' keys and values (ops/kv_cache.py).
+    layer_kinds: Tuple[str, ...] = ()
+    conv_taps: int = 3
+    # RMSNorm over each query and key head's dimensions, a scale of its own for
+    # q and for k, before rotary (q_norm, k_norm)
+    qk_norm: bool = False
+    # what the router adds to the sum of the chosen scores before dividing by it
+    router_norm_eps: float = 0.0
+
+    def __post_init__(self):
+        # a list out of a json or yml file; a depth cut by num_layers alone keeps the leading layers' kinds
+        kinds = tuple(self.layer_kinds)[: self.num_layers]
+        object.__setattr__(self, "layer_kinds", kinds)
+        if set(kinds) - {"attention", "conv"} or (kinds and len(kinds) != self.num_layers):
+            raise ValueError(
+                f"layer_kinds {kinds} does not name each of {self.num_layers} layers 'attention' or 'conv'")
 
     @property
     def stacked(self) -> bool:
@@ -168,14 +191,25 @@ class TransformerConfig:
     def is_expert_layer(self, index: int) -> bool:
         return self.num_experts > 0 and index >= self.first_dense_layers
 
+    def is_conv_layer(self, index: int) -> bool:
+        return bool(self.layer_kinds) and self.layer_kinds[index] == "conv"
+
+    @property
+    def conv_layers(self) -> int:
+        return self.layer_kinds.count("conv")
+
+    @property
+    def attention_layers(self) -> int:
+        return self.num_layers - self.conv_layers
+
     @property
     def held_experts(self) -> int:
         return self.num_experts if self.experts_held is None else self.experts_held
 
     @property
     def cache_entries(self) -> int:
-        """Layer caches a forward writes: one for every (pass, layer)."""
-        return self.loop_steps * self.num_layers
+        """Attention caches a forward writes: one for every (pass, attention layer)."""
+        return self.loop_steps * self.attention_layers
     # Megatron-SP analogue: shard the residual stream's sequence dim over the
     # `model` axis between blocks (reference sequence_parallel cfg,
     # modeling_nemo_ppo.py:160-164). Applied on cache-free forwards.
@@ -211,7 +245,8 @@ class TransformerConfig:
         return self.pos_embedding == "alibi" or self.peft_type == "prefix"
 
     def cache_layout(self, batch_size: int, max_length: int, dtype=None) -> Dict[str, Tuple]:
-        """One layer of the contiguous cache for ``max_length`` tokens a row (``ops/kv_cache.py``)."""
+        """One attention layer of the contiguous cache for ``max_length`` tokens a row
+        (``ops/kv_cache.py``); a convolution layer's is :meth:`conv_state_layout`."""
         dtype = dtype or self.compute_dtype
         if self.peft_type == "prompt":
             max_length += self.num_virtual_tokens  # virtual rows live in the cache too
@@ -219,6 +254,10 @@ class TransformerConfig:
             return kv_cache.latent_cache_layout(batch_size, max_length, self.kv_lora_rank, self.qk_rope_head_dim, dtype)
         shape = (batch_size, self.kv_heads, max_length, self.dim_per_head)
         return kv_cache.kv_cache_layout(shape, dtype, self.kv_cache_quant)
+
+    def conv_state_layout(self, batch_size: int, dtype=None) -> Dict[str, Tuple]:
+        """One convolution layer of the contiguous cache: the gated inputs the next token's filter reads."""
+        return kv_cache.conv_state_layout(batch_size, self.conv_taps, self.hidden_size, dtype or self.compute_dtype)
 
     def residual_init_std(self) -> float:
         """Init std for projections writing into the residual stream
@@ -442,6 +481,10 @@ class Attention(nn.Module):
         q = q.reshape(B, T, c.num_heads, c.dim_per_head)
         k = k.reshape(B, T, c.kv_heads, c.dim_per_head)
         v = v.reshape(B, T, c.kv_heads, c.dim_per_head)
+        if c.qk_norm:
+            head_norm = lambda name: nn.RMSNorm(
+                epsilon=c.norm_eps, dtype=c.compute_dtype, param_dtype=c.param_dtype, name=name)
+            q, k = head_norm("q_norm")(q), head_norm("k_norm")(k)
 
         if c.pos_embedding == "rotary":
             cos, sin = make_rotary(c, positions)
@@ -553,6 +596,63 @@ _LOOP_REFUSALS = {
                   "not do: every pass runs for every token",
     "final_norm": "looped layers (loop_steps > 1) feed each pass the final norm's output; final_norm=False has none",
 }
+
+
+_CONV_REFUSALS = {
+    "paged": "the paged block pool holds keys and values by token; a convolution layer (layer_kinds) holds a "
+             "fixed-size state for each row, which needs a slot of its own in serving/allocator.py and a snapshot "
+             "for preemption: the serving engine does not run this model yet",
+    "stacked": "scan_layers / pipeline_stages > 1 run one scanned Block over stacked parameters and one cache array "
+               "[L, ...], and the layers of this model are not alike: layer_kinds makes some of them convolutions",
+    "kv_cache_quant": "kv_cache_quant has not been held against a reference beside a convolution's float state "
+                      "(layer_kinds)",
+    "ring": "attention_impl='ring' splits the sequence over chips, and a convolution layer (layer_kinds) reads its "
+            "left neighbours across the split, which nothing exchanges yet",
+    "sequence_sharding": "sequence_sharding splits the residual stream's sequence over the model axis, and a "
+                         "convolution layer (layer_kinds) reads its left neighbours across the split",
+    "peft": "prompt and prefix tuning prepend rows to what attention reads; a convolution layer (layer_kinds) "
+            "has no such rows",
+}
+
+
+class ShortConv(nn.Module):
+    """Gated short convolution (a ``"conv"`` layer of ``layer_kinds``).
+
+    ``[b, c, x] = z W_in`` in that order; ``u = b * x``, zero at padded
+    positions (``valid``: prompts are left-padded, and a convolution, unlike
+    masked attention, would otherwise carry the pad rows' values into the first
+    real tokens); ``v_t = sum_j w_j * u_{t - (taps - 1) + j}``, a causal
+    depthwise filter of ``conv_taps`` taps along the sequence (``w`` is
+    ``[hidden, taps]``, one filter a channel); ``y = (c * v) W_out``. Plain XLA:
+    the shifted multiply-adds fuse. With ``cache`` (this layer's state,
+    ``ops/kv_cache.py``) the filter reads the row's last ``taps - 1`` inputs
+    from it, whether the forward is the prefill (the state is zeros) or a decode
+    step, and the state after the forward is returned."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, cache=None, valid=None):
+        c = self.config
+        T, d, taps = x.shape[1], c.hidden_size, c.conv_taps
+        dense = lambda feats, name, std=c.initializer_range: LoraDense(
+            feats, use_bias=False, dtype=c.compute_dtype, param_dtype=c.param_dtype,
+            kernel_init=nn.initializers.normal(std), name=name,
+            r=c.lora_r if name in c.lora_targets else 0, alpha=c.lora_alpha,
+        )
+        with jax.named_scope("conv"):
+            b, gate, xx = jnp.split(dense(3 * d, "in_proj")(x), 3, axis=-1)
+            u = b * xx
+            if valid is not None:
+                u = u * valid[..., None].astype(u.dtype)
+            w = _Kernel((d, taps), c.initializer_range, c.param_dtype, name="conv")().astype(c.compute_dtype)
+            new_cache = None
+            if cache is None:
+                seen = jnp.pad(u, ((0, 0), (taps - 1, 0), (0, 0)))  # nothing before the first token
+            else:
+                seen, new_cache = kv_cache.roll_conv_state(cache, u)
+            v = sum(w[:, j] * seen[:, j : j + T] for j in range(taps))
+            return dense(d, "out_proj", c.residual_init_std())(gate * v), new_cache
 
 
 class LatentAttention(nn.Module):
@@ -692,7 +792,8 @@ class SparseMLP(nn.Module):
         gate, up, down = _Experts(c, name="experts")()
         flat = x.reshape(B * T, d)
         chosen, weights = moe.route(
-            flat, kernel, bias, top_k=c.experts_per_token, norm_topk=c.norm_topk_prob, scale=c.routed_scaling_factor)
+            flat, kernel, bias, top_k=c.experts_per_token, norm_topk=c.norm_topk_prob, scale=c.routed_scaling_factor,
+            norm_eps=c.router_norm_eps)
         routed, load = moe.expert_ffn(
             flat, chosen, weights, gate, up, down, expert_offset=c.expert_offset, act=_act(c.activation))
         self.sow("moe_stats", "load", load)
@@ -727,26 +828,34 @@ def loop_counters(loop_stats) -> Dict[str, jnp.ndarray]:
 class Block(nn.Module):
     config: TransformerConfig
     expert_layer: bool = False  # this layer's FFN is the sparse one (config.is_expert_layer)
+    conv_layer: bool = False  # this layer's mixer is the short convolution (config.is_conv_layer)
 
     @nn.compact
     def __call__(self, x, mask_bias, positions, cache=None, kv_valid=None):
         c = self.config
         attention = LatentAttention if c.attention_kind == "mla" else Attention
         mlp = SparseMLP if self.expert_layer else MLP
+        if self.conv_layer:
+            # a cached forward of more than one token whose mask did not come along (``kv_valid`` marks the
+            # forwards whose keys are their own tokens) would convolve over pad rows unmasked
+            if cache is not None and kv_valid is None and x.shape[1] > 1:
+                raise ValueError("a convolution layer takes a cached forward of several tokens only as the "
+                                 "prefill from slot 0 with its attention mask")
+            mix = lambda h: ShortConv(c, name="conv")(h, cache, kv_valid)
+        else:
+            mix = lambda h: attention(c, name="attn")(h, mask_bias, positions, cache, kv_valid)
         if c.parallel_residual:
             if c.sandwich_norms:
                 raise ValueError("sandwich_norms norm each sub-layer's output on its own; parallel_residual sums them")
             h1 = _norm_module(c, "ln_1")(x)
             h2 = h1 if c.shared_parallel_ln else _norm_module(c, "ln_2")(x)
-            attn_out, new_cache = attention(c, name="attn")(h1, mask_bias, positions, cache, kv_valid)
+            attn_out, new_cache = mix(h1)
             mlp_out = mlp(c, name="mlp")(h2)
             out = x + attn_out + mlp_out
             if c.sequence_sharding and cache is None:
                 out = constrain_seq(out)
             return out, new_cache
-        attn_out, new_cache = attention(c, name="attn")(
-            _norm_module(c, "ln_1")(x), mask_bias, positions, cache, kv_valid
-        )
+        attn_out, new_cache = mix(_norm_module(c, "ln_1")(x))
         if c.sandwich_norms:
             attn_out = _norm_module(c, "ln_1_post")(attn_out)
         x = x + attn_out
@@ -802,6 +911,13 @@ class TransformerLM(nn.Module):
             for refused, why in (("stacked", c.stacked), ("kv_cache_quant", c.kv_cache_quant)):
                 if why:
                     raise ValueError(_MLA_REFUSALS[refused])
+        if c.conv_layers:
+            for refused, why in (
+                ("stacked", c.stacked), ("kv_cache_quant", c.kv_cache_quant), ("ring", c.attention_impl == "ring"),
+                ("sequence_sharding", c.sequence_sharding), ("peft", c.peft_type != "none"),
+            ):
+                if why:
+                    raise ValueError(_CONV_REFUSALS[refused])
         if c.early_exit_threshold < 1:
             raise ValueError(_LOOP_REFUSALS["early_exit"])
         if c.loop_steps > 1:
@@ -846,7 +962,9 @@ class TransformerLM(nn.Module):
             )(c, name="layers_scan")
             self.layers = ()
         else:
-            self.layers = [block(c, expert_layer=c.is_expert_layer(i)) for i in range(c.num_layers)]
+            self.layers = [
+                block(c, expert_layer=c.is_expert_layer(i), conv_layer=c.is_conv_layer(i)) for i in range(c.num_layers)
+            ]
         if c.final_norm:
             self.ln_f = _norm_module(c)
         if not c.tie_word_embeddings:
@@ -908,6 +1026,8 @@ class TransformerLM(nn.Module):
         if cache is not None:
             if c.attention_kind == "mla":
                 S = cache["c"][0].shape[1]  # per-layer [B,S,rank]
+            elif not c.attention_layers:
+                S = attention_mask.shape[1]  # no layer holds slots: the mask alone says how many there are
             else:
                 ck = cache["k"]
                 # list layout: per-layer [B,H,S,D]; stacked layout: [L,B,H,S,D]
@@ -1010,6 +1130,8 @@ class TransformerLM(nn.Module):
             counting = (c.loop_steps > 1 or c.exit_gate) and (
                 self.is_initializing() or self.is_mutable_collection("loop_stats"))
             new_layer_caches, states = [], []
+            # a layer's rank among the layers of its own kind
+            rank = [sum(c.is_conv_layer(j) == c.is_conv_layer(i) for j in range(i)) for i in range(c.num_layers)]
             for step in range(c.loop_steps):
                 # one pass of the stack; a model that does not loop traces what it traced before the loop was here
                 with jax.named_scope("loop.pass") if c.loop_steps > 1 else contextlib.nullcontext():
@@ -1018,10 +1140,12 @@ class TransformerLM(nn.Module):
                             captures[i] = x
                         layer_cache = None
                         if cache is not None:
-                            entry = step * c.num_layers + i  # keys and values of this pass, read by this pass alone
-                            layer_cache = {
-                                key: cache[key][entry] for key in cache if key != "index"
-                            }
+                            # what this pass wrote, read by this pass alone; a layer's buffers stand in the lists
+                            # of its own kind (keys and values, or a convolution's state) at its rank among them
+                            conv = c.is_conv_layer(i)
+                            entry = step * (c.conv_layers if conv else c.attention_layers) + rank[i]
+                            layout = c.conv_state_layout(B) if conv else c.cache_layout(B, 1)
+                            layer_cache = {key: cache[key][entry] for key in layout}
                             layer_cache["index"] = cache["index"]
                         x, new_lc = layer(x, mask_bias, layer_positions, layer_cache, kv_valid)
                         if cache is not None:
@@ -1034,10 +1158,10 @@ class TransformerLM(nn.Module):
             if cache is not None:
                 # keep the per-entry list layout (no jnp.stack: restacking would
                 # copy the full cache every decode step)
-                stacked_kv = {
-                    key: [lc[key] for lc in new_layer_caches]
-                    for key in new_layer_caches[0]
-                }
+                stacked_kv = {}
+                for lc in new_layer_caches:  # in the order of (pass, layer): each list in its own kind's order
+                    for key, value in lc.items():
+                        stacked_kv.setdefault(key, []).append(value)
         if seq_shard:
             # gather the sequence dim before heads (Megatron's
             # gather_from_sequence_parallel_region analogue)
@@ -1177,6 +1301,15 @@ class TransformerLM(nn.Module):
 
             gauges.set("loop/passes", c.loop_steps)
             gauges.set("loop/cache_bytes_per_token", c.cache_entries * kv_cache.bytes_per_token(per_layer))
+        if c.conv_layers:
+            from trlx_tpu.utils.metrics import gauges
+
+            state = c.conv_state_layout(batch_size, dtype)
+            gauges.set("hybrid/attention_layers", c.attention_layers)
+            gauges.set("hybrid/conv_layers", c.conv_layers)
+            gauges.set("hybrid/cache_bytes_per_token", c.cache_entries * kv_cache.bytes_per_token(per_layer))
+            gauges.set("hybrid/state_bytes_per_row",
+                       c.loop_steps * c.conv_layers * kv_cache.state_bytes_per_row(state))
         if c.stacked:
             # nn.scan layout needs one [L, ...] array per k/v
             out = {
@@ -1193,11 +1326,16 @@ class TransformerLM(nn.Module):
         # XLA to slice out every layer and re-stack the WHOLE cache each step —
         # profiled at 3.6ms of a 4.65ms gpt2-124M decode step on one v5e chip
         # (~15x the HBM bound for this model). Looped layers hold an entry for
-        # every (pass, layer) in the same list, entry pass * num_layers + layer.
+        # every (pass, layer) in the same list, entry pass * num_layers + layer. Where
+        # layer_kinds makes some layers convolutions, the keys' and values' lists hold the
+        # attention layers alone and "conv" the states of the convolution layers.
         out = {
             key: [jnp.zeros(shp, dt) for _ in range(c.cache_entries)]
             for key, (shp, dt) in per_layer.items()
         }
+        if c.conv_layers:
+            (shp, dt), = state.values()
+            out["conv"] = [jnp.zeros(shp, dt) for _ in range(c.loop_steps * c.conv_layers)]
         out["index"] = jnp.array(0, jnp.int32)
         return out
 
@@ -1217,6 +1355,8 @@ class TransformerLM(nn.Module):
             raise ValueError(_MLA_REFUSALS["paged"])
         if c.loop_steps > 1:
             raise ValueError(_LOOP_REFUSALS["paged"])
+        if c.conv_layers:
+            raise ValueError(_CONV_REFUSALS["paged"])
         layout = paged_pool_layout(
             num_blocks, block_size, c.kv_heads, c.dim_per_head,
             dtype or c.compute_dtype, c.kv_cache_quant,
@@ -1249,6 +1389,8 @@ class TransformerLM(nn.Module):
         c = self.config
         if c.loop_steps > 1:
             raise ValueError(_LOOP_REFUSALS["paged"])
+        if c.conv_layers:
+            raise ValueError(_CONV_REFUSALS["paged"])
         if c.stacked:
             raise NotImplementedError("paged decode: per-layer list layout only")
         if c.peft_type in ("prompt", "prefix"):
@@ -1293,6 +1435,8 @@ class TransformerLM(nn.Module):
         c = self.config
         if c.loop_steps > 1:
             raise ValueError(_LOOP_REFUSALS["paged"])
+        if c.conv_layers:
+            raise ValueError(_CONV_REFUSALS["paged"])
         if c.peft_type in ("prompt", "prefix"):
             raise NotImplementedError("paged verify does not support peft prompt/prefix")
         B, Q = input_ids.shape

@@ -1,13 +1,15 @@
 """What one layer of the contiguous (one-shot generator's) cache holds, and how it is written and read.
 
 A layer's cache is a plain dict of arrays — the decode ``while_loop`` carries it, so its pytree
-structure is part of the compiled program — in one of three formats:
+structure is part of the compiled program — in one of four formats:
 
 - per-head rows: ``k``, ``v`` ``[B, Hkv, S, D]`` in the compute dtype, contiguous along S per (b, h) so
   that the decode matvec streams them (``[B, S, Hkv, D]`` cost a transposed copy of every layer a step);
 - per-head int8 rows (``kv_cache_quant``): ``k``, ``v`` int8 plus ``k_scale``, ``v_scale``
   ``[B, Hkv, S, 1]`` float32, one symmetric scale a row (the paged pool quantizes its rows the same way);
-- latent: ``c`` ``[B, S, rank]``, the normed latent, and ``k_rope`` ``[B, S, rope]``, the rotated shared key.
+- latent: ``c`` ``[B, S, rank]``, the normed latent, and ``k_rope`` ``[B, S, rope]``, the rotated shared key;
+- a short convolution's state: ``conv`` ``[B, taps - 1, d]``, the row's last gated inputs, the ones the next
+  token's filter reads beside its own — a fixed size whatever the length, so nothing of it is "per token".
 
 A layout is the same dict with ``(shape, dtype)`` in place of each array; how many layers there are and
 whether they are stacked is the model's. The serving engine's paged cache is laid out in
@@ -43,6 +45,11 @@ def latent_cache_layout(batch_size: int, max_length: int, rank: int, rope_dim: i
     }
 
 
+def conv_state_layout(batch_size: int, taps: int, width: int, dtype) -> Layout:
+    """A short-convolution layer's buffer: the last ``taps - 1`` gated inputs of every row."""
+    return {"conv": ((batch_size, taps - 1, width), dtype)}
+
+
 def has_row_scales(layer) -> bool:
     """Whether a layer's cache (or layout) holds int8 rows with a scale each."""
     return "k_scale" in layer
@@ -65,6 +72,12 @@ def bytes_per_token(layout: Layout) -> int:
         math.prod(shape) // (shape[0] * shape[slot_axis]) * jnp.dtype(dtype).itemsize
         for shape, dtype in layout.values()
     )
+
+
+def state_bytes_per_row(layout: Layout) -> int:
+    """Bytes one row's state takes in one convolution layer of ``layout``."""
+    (shape, dtype), = layout.values()
+    return math.prod(shape[1:]) * jnp.dtype(dtype).itemsize
 
 
 def quantize_kv_rows(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -118,3 +131,13 @@ def write_latent_cache(cache: Dict[str, jnp.ndarray], latent: jnp.ndarray, k_rop
         "c": jax.lax.dynamic_update_slice(cache["c"], latent.astype(cache["c"].dtype), at),
         "k_rope": jax.lax.dynamic_update_slice(cache["k_rope"], k_rope[:, :, 0].astype(cache["k_rope"].dtype), at),
     }
+
+
+def roll_conv_state(cache: Dict[str, jnp.ndarray], u: jnp.ndarray):
+    """``u`` [B, T, d], the gated inputs of T new tokens (zero at padded positions), behind the state:
+    (the ``taps - 1 + T`` inputs the tokens' filters read, in the order of time; the state after them,
+    the last ``taps - 1`` of those). One rule for the prefill (the state is zeros, the prompt left-padded,
+    so its last positions are real) and for a decode step (T = 1: the state rolls by one)."""
+    state = cache["conv"]
+    seen = jnp.concatenate([state.astype(u.dtype), u], axis=1)
+    return seen, {"conv": seen[:, seen.shape[1] - state.shape[1]:].astype(state.dtype)}

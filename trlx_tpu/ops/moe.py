@@ -33,20 +33,27 @@ directions: the backward of each gather is the gather the other way, never a
 scatter-add.
 """
 
+import functools
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
+from trlx_tpu.utils import logging
+
+logger = logging.get_logger(__name__)
+
 
 def route(
-    h: jnp.ndarray, kernel: jnp.ndarray, bias: jnp.ndarray, *, top_k: int, norm_topk: bool, scale: float
+    h: jnp.ndarray, kernel: jnp.ndarray, bias: jnp.ndarray, *, top_k: int, norm_topk: bool, scale: float,
+    norm_eps: float = 0.0,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """h [N, d], kernel [d, experts], bias [experts] -> (experts chosen [N, top_k]
     int32, their weights [N, top_k] float32). Scores are float32 at full
     precision whatever the compute dtype, as the published router's are: a
     rounded score flips the choice between near-equal experts. The bias only
-    chooses, so it takes no gradient."""
+    chooses, so it takes no gradient. ``norm_eps`` is what a published router
+    adds to the sum it divides by (0 adds nothing)."""
     with jax.named_scope("moe.route"):
         scores = jax.nn.sigmoid(
             jnp.dot(h.astype(jnp.float32), kernel.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST)
@@ -54,7 +61,8 @@ def route(
         _, chosen = jax.lax.top_k(jax.lax.stop_gradient(scores + bias.astype(jnp.float32)), top_k)
         weights = jnp.take_along_axis(scores, chosen, axis=-1)
         if norm_topk:
-            weights = weights / weights.sum(-1, keepdims=True)
+            total = weights.sum(-1, keepdims=True)
+            weights = weights / (total + norm_eps if norm_eps else total)
         return chosen.astype(jnp.int32), weights * scale
 
 
@@ -93,6 +101,42 @@ def _row_tile(assignments: int) -> int:
     return 128 if assignments <= 2048 else 512
 
 
+def choose_product_tiles(row_tile: int, k: int, n: int, vmem_budget: int = 14 * 2**20) -> Tuple[int, int, int]:
+    """(row, inner, column) tiles of a grouped product ``[M, k] x [E, k, n]`` whose
+    rows are laid in tiles of ``row_tile``. A pure function of the shape, as the
+    attention kernels' choosers are.
+
+    The inner tile is 1024 beside a row tile of 128 and halves where the row
+    tile is 512. The column tile is the largest multiple of 128 that divides
+    ``n`` and fits ``vmem_budget`` of the chip's 16 MiB beside them, in the
+    product and in its weights' gradient, which jax's ``gmm`` runs with the
+    same tiles: both buffers of each operand tile, both of the output tile, and
+    its float32 accumulator. It divides ``n`` because the kernel runs a whole
+    program for a last tile that overhangs (at 1,408 a width of 1,536 ran 1,408
+    columns for its last 128). 1,408 and 1,536 are taken whole; 2,048 is 17 MiB
+    whole and goes as two tiles of 1,024. A width that no multiple of 128
+    divides (the tests' small ones) is one tile."""
+    inner = min(k, 1024 if row_tile == 128 else 512)
+
+    def fits(column):
+        product = 2 * 2 * (row_tile * inner + inner * column) + 3 * 4 * row_tile * column  # bf16 in, float32 out
+        # the weights' gradient [inner, column]: bf16 rows and float32 cotangents in, bf16 out, a float32 accumulator
+        gradient = 2 * row_tile * (2 * inner + 4 * column) + (2 * 2 + 4) * inner * column
+        return max(product, gradient) <= vmem_budget
+
+    column = next((tn for tn in range(n - n % 128, 0, -128) if n % tn == 0 and fits(tn)), n)
+    return row_tile, inner, column
+
+
+@functools.lru_cache(maxsize=None)
+def _log_product_tiles(M, k, n, E, tiles):
+    """The chooser's choice, once per traced shape."""
+    logger.info(  # graftcheck: noqa[JX003] — once per traced shape is the point
+        f"grouped product rows[{M},{k}] x weights[{E},{k},{n}]: tiles {tiles[0]} x {tiles[1]} x {tiles[2]},"
+        f" {-(-n // tiles[2])} column tile(s) of {tiles[2]}"
+    )
+
+
 def _grouped_product(rows: jnp.ndarray, weights: jnp.ndarray, room: jnp.ndarray, tile: int) -> jnp.ndarray:
     """rows [M, k] sorted by group, each group from a tile boundary on; weights
     [E, k, n]; room [E] int32, the groups' rows in whole tiles -> [M, n]
@@ -106,12 +150,11 @@ def _grouped_product(rows: jnp.ndarray, weights: jnp.ndarray, room: jnp.ndarray,
     if mesh is not None and mesh.size > 1:
         raise ValueError(MESH_REFUSAL)
     weights = weights.astype(rows.dtype)
-    _, k, n = weights.shape
-    # the inner tile halves where the row tile is 512: the float32 output tile and its accumulator, both
-    # buffers of each operand, must fit 16 MiB of VMEM. Interpret (XLA-emulated) mode iff the compile
-    # target is the CPU, as the flash kernels decide it
-    return gmm(rows, weights, room, jnp.float32, (tile, min(k, 1024 if tile == 128 else 512), min(n, 1408)),
-               interpret=jax.default_backend() == "cpu")
+    E, k, n = weights.shape
+    tiles = choose_product_tiles(tile, k, n)
+    _log_product_tiles(rows.shape[0], k, n, E, tiles)
+    # interpret (XLA-emulated) mode iff the compile target is the CPU, as the flash kernels decide it
+    return gmm(rows, weights, room, jnp.float32, tiles, interpret=jax.default_backend() == "cpu")
 
 
 def expert_ffn(

@@ -73,6 +73,13 @@ def default_lm_rules() -> List[Rule]:
         (r".*exit_gate/kernel$", PartitionSpec(FSDP_AXIS, None)),
         (r".*exit_gate/bias$", PartitionSpec()),
         (r".*ln_[12]_post/scale$", PartitionSpec()),
+        # gated short convolution: the product to [b, c, x] column-parallel, the product back
+        # row-parallel, the depthwise filter [hidden, taps] by channel; the query / key head
+        # norms' scales [head_dim] are replicated like every norm
+        (r".*in_proj/kernel$", PartitionSpec(FSDP_AXIS, MODEL_AXIS)),
+        (r".*out_proj/kernel$", PartitionSpec(MODEL_AXIS, FSDP_AXIS)),
+        (r".*conv/kernel$", PartitionSpec(MODEL_AXIS, None)),
+        (r".*(q_norm|k_norm)/scale$", PartitionSpec()),
         # mlp: up/gate column-parallel; down row-parallel
         (r".*(up_proj|gate_proj)/kernel$", PartitionSpec(FSDP_AXIS, MODEL_AXIS)),
         (r".*(up_proj|gate_proj)/bias$", PartitionSpec(MODEL_AXIS)),

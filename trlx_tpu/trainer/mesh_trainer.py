@@ -586,10 +586,11 @@ class MeshRLTrainer(BaseRLTrainer):
                 )
             sequences = np.asarray(jax.device_get(out["sequences"]))
             response_mask = np.asarray(jax.device_get(out["response_mask"]))
-        if isinstance(getattr(self, "model_config", None), TransformerConfig):
+        model_config = getattr(self, "model_config", None)
+        if isinstance(model_config, TransformerConfig) and model_config.attention_layers:
             # the loop ran until the longest row ended; its first token came from the prefill
             steps = int(response_mask.sum(axis=1).max()) - 1
-            c = self.model_config
+            c = model_config
             with self.mesh:
                 gauges.set("rollout/cache_read_share", decode_cache_read_share(
                     c.attention_impl, c.biased_attention, c.num_heads,
