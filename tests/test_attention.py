@@ -712,11 +712,20 @@ def test_cache_read_share_counts_visited_slots(prompt, steps, cache, block, want
         assert 0.56 < got < 0.58  # cell 1: 56 % of the slots hold a token on average
 
 
-def test_decode_chooser_choice_is_logged_once_per_shape(caplog):
+@pytest.mark.parametrize(
+    "fold,said",
+    [(1, "rows 2 block 16, fold 1, lanes filled 2/128, grid (1, 2), VMEM reckoned"),
+     (3, "rows 6 block 16, fold 3, lanes filled 6/128, grid (1, 2), VMEM reckoned")],
+)
+def test_decode_chooser_choice_is_logged_once_per_shape(caplog, fold, said):
+    """One line a traced shape, in the model's shapes whatever the cache's fold, with the fold and
+    the lanes it fills."""
     import logging
 
+    from trlx_tpu.ops import kv_cache
+
     q = jnp.ones((2, 3, 8), jnp.float32)
-    k = v = jnp.ones((2, 3, 24, 8), jnp.float32)
+    k = v = kv_cache.fold_heads(jnp.ones((2, 3, 24, 8), jnp.float32), fold)
     bias = jnp.zeros((2, 1, 1, 24), jnp.float32)
     attn._log_decode_tiles.cache_clear()
     root = logging.getLogger("trlx_tpu")
@@ -729,7 +738,29 @@ def test_decode_chooser_choice_is_logged_once_per_shape(caplog):
         root.removeHandler(caplog.handler)
     lines = [r.getMessage() for r in caplog.records if "decode attention q[2,3,8]" in r.getMessage()]
     assert len(lines) == 1, lines
-    assert "cache[2,3,24,8] float32: rows 2 block 16, grid (1, 2), VMEM reckoned" in lines[0]
+    assert f"cache[2,3,24,8] float32: {said}" in lines[0]
+
+
+# (B, Hkv) -> kv heads beside each row: the largest divisor of Hkv that fits the 128 lanes, 1 from 128 rows on
+DECODE_FOLDS = {
+    (8, 1): 1, (8, 8): 8, (8, 12): 12, (8, 16): 16,  # 8 and 12 kv heads cap the fold: 64 and 96 lanes filled
+    (16, 1): 1, (16, 8): 8, (16, 12): 6, (16, 16): 8,
+    (32, 1): 1, (32, 8): 4, (32, 12): 4, (32, 16): 4,
+    (64, 1): 1, (64, 8): 2, (64, 12): 2, (64, 16): 2,
+    (96, 1): 1, (96, 8): 1, (96, 12): 1, (96, 16): 1,  # no second head fits beside 96 rows
+    (128, 1): 1, (128, 8): 1, (128, 12): 1, (128, 16): 1,
+    (256, 1): 1, (256, 8): 1, (256, 12): 1, (256, 16): 1,
+}
+
+
+@pytest.mark.parametrize("B,Hkv", sorted(DECODE_FOLDS))
+def test_decode_fold_chooser_fills_the_lanes_with_whole_kv_heads(B, Hkv):
+    fold = attn.choose_decode_fold(B, Hkv)
+    assert fold == DECODE_FOLDS[B, Hkv]
+    assert Hkv % fold == 0 and (fold == 1 or fold * B <= 128)
+    # the programs then take the folded rows, and what they fill is what the gauge reports
+    tiles = attn.choose_decode_tiles(B * fold, Hkv // fold, 1, 576, 64, jnp.bfloat16)
+    assert attn._lane_fill(tiles.rows) == (min(B * fold, 128), 128)
 
 
 # ------------------------------------------------------- attend: the dispatch
@@ -803,6 +834,72 @@ def test_attend_reaches_each_path_and_agrees_with_the_plain_reference(case, kern
     # rows with no key to see (a padded query) are the reference's zeros and anyone's guess elsewhere
     rows = np.asarray(operands["kv_valid"] if operands["kv_valid"] is not None else np.ones(got.shape[:2]), bool)
     np.testing.assert_allclose(np.asarray(got)[rows], np.asarray(want)[rows], atol=3e-5, rtol=1e-5)
+
+
+# name: (B, H, Hkv, the fold the chooser gives)
+FOLDED_CASES = {"64-rows-16-heads": (64, 16, 16, 2), "16-rows-32-over-8-heads": (16, 32, 8, 8),
+                "8-rows-the-heads-cap-the-fold": (8, 6, 3, 3)}
+
+
+@pytest.mark.parametrize("where", ["inside", "ragged-last-block"])
+@pytest.mark.parametrize("impl", ["flash", "xla"])
+@pytest.mark.parametrize("case", sorted(FOLDED_CASES))
+def test_attend_over_a_folded_cache_equals_the_einsum_over_the_unfolded_one(case, impl, where, monkeypatch):
+    """A single-token step over a cache written through ``kv_cache.write_kv_cache`` with kv heads
+    beside the rows — the decode kernel under ``"flash"``; under ``"xla"`` the einsum, which
+    unfolds what it is handed — against the einsum over the same rows unfolded: left padding in
+    the mask, the write index inside the cache and in a last block that ends with the cache
+    (41 slots in blocks of 8: interpret mode fills what overhangs with NaN)."""
+    from trlx_tpu.ops import kv_cache
+
+    B, H, Hkv, fold = FOLDED_CASES[case]
+    assert attn.choose_decode_fold(B, Hkv) == fold
+    S, D = 41, 8
+    index = {"inside": 19, "ragged-last-block": S - 1}[where]
+    rng = np.random.default_rng(index + B)
+    q = jnp.asarray(rng.normal(size=(B, 1, H, D)), jnp.float32)
+    rows = [jnp.asarray(rng.normal(size=(B, Hkv, index + 1, D)), jnp.float32) for _ in range(2)]
+    pads = rng.integers(0, index, size=B)  # row b's first slots are padding; the index never is
+    seen = (np.arange(S)[None, :] >= pads[:, None]) & (np.arange(S)[None, :] <= index)
+    mask_bias = jnp.where(jnp.asarray(seen)[:, None, None, :], 0.0, -1e9).astype(jnp.float32)
+
+    def step(fold, impl):
+        layout = kv_cache.kv_cache_layout((B, Hkv, S, D), jnp.float32, False, fold)
+        # what no token was written to must not reach a result
+        unwritten = jnp.nan if impl == "flash" else 0.0
+        cache = {key: jnp.full(shape, unwritten, dtype) for key, (shape, dtype) in layout.items()}
+        cache = kv_cache.write_kv_cache(cache, rows[0], rows[1], 0)
+        k = v = jnp.zeros((B, 1, Hkv, D), jnp.float32)  # this step's rows are in the cache already
+        return attn.attend(q, k, v, cache, mask_bias, None, jnp.int32(index), 1.0 / np.sqrt(D), impl, False, None)
+
+    want = step(1, "xla")
+    taken = _spy_on_kernels(monkeypatch)
+    got = step(fold, impl)
+    assert taken == (["decode_attention"] if impl == "flash" else [])
+    assert got.shape == (B, 1, H * D) and np.all(np.isfinite(np.asarray(got)))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("case", sorted(FOLDED_CASES))
+def test_read_share_and_lane_fill_of_a_folded_layer(case):
+    """The slots visited follow the block the chooser gives the shape the kernel sees — at 64 rows
+    of 16 heads the same 8 slots folded or not, so the same share; a slot of fewer heads makes a
+    longer block — and the lanes filled are what the fold is for: 0.5 -> 1.0 at 64 rows, 1.0
+    where no kernel runs."""
+    from trlx_tpu.ops import kv_cache
+
+    B, H, Hkv, fold = FOLDED_CASES[case]
+    shape, new_tokens, steps = (B, Hkv, 576, 64), 64, 63
+    flat, folded = (kv_cache.kv_cache_layout(shape, jnp.bfloat16, False, g) for g in (1, fold))
+    share = [attn.decode_cache_read_share("flash", False, H, layout, B, new_tokens, steps) for layout in (flat, folded)]
+    blocks = [attn.choose_decode_tiles(B * g, Hkv // g, H // Hkv, 576, 64, jnp.bfloat16).block for g in (1, fold)]
+    assert share == [attn.cache_read_share(512, steps, 576, block) for block in blocks]
+    if fold == 2:
+        assert blocks == [8, 8] and share[0] == share[1]
+    assert attn.decode_cache_lane_fill("flash", False, H, flat, B) == B / 128
+    assert attn.decode_cache_lane_fill("flash", False, H, folded, B) == B * fold / 128
+    assert attn.decode_cache_lane_fill("xla", False, H, flat, B) == 1.0
+    assert attn.decode_cache_read_share("xla", False, H, flat, B, new_tokens, steps) == 1.0
 
 
 @pytest.mark.parametrize("what", ["alibi", "prefix"])

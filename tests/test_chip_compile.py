@@ -105,23 +105,35 @@ def _grouped_products(grad, tokens, f=1408, k=6):
 
 
 def _decode(shape, kv_heads=None, dtype=jnp.bfloat16):
-    """The decode kernel as the generator's loop calls it: inside a ``while_loop``,
-    over a cache ``[B, Hkv, S, D]`` with this step's token just written, the index traced."""
+    """The decode kernel as the generator's loop calls it: inside a ``while_loop``, over a cache the
+    loop carries in the shape ``ops/kv_cache.py`` lays it out in (``fold`` kv heads beside each row where
+    fewer than 128 rows decode: ``[B * fold, Hkv / fold, S, D]``), this step's token just written by
+    ``kv_cache``'s own write, the index traced."""
+    from trlx_tpu.ops import kv_cache
+
     B, H, S, D = shape
     Hkv = kv_heads or H
+    layout = kv_cache.kv_cache_layout((B, Hkv, S, D), dtype, False, attention.choose_decode_fold(B, Hkv))
 
-    def fn(q, k, v, mask_bias, k_new, steps):
+    def fn(q, k, v, mask_bias, k_new, v_new, steps):
         def body(carry):
-            index, q, k = carry
-            k = jax.lax.dynamic_update_slice(k, k_new, (0, 0, index, 0))
-            return index + 1, attention.decode_attention(q, k, v, mask_bias, index), k
+            index, q, cache = carry
+            cache = kv_cache.write_kv_cache(cache, k_new, v_new, index)
+            return index + 1, attention.decode_attention(q, cache["k"], cache["v"], mask_bias, index), cache
 
-        return jax.lax.while_loop(lambda carry: carry[0] < steps, body, (jnp.int32(1), q, k))
+        return jax.lax.while_loop(lambda carry: carry[0] < steps, body, (jnp.int32(1), q, {"k": k, "v": v}))
 
     return fn, [
-        ((B, H, D), dtype), ((B, Hkv, S, D), dtype), ((B, Hkv, S, D), dtype), ((B, 1, 1, S), jnp.float32),
-        ((B, Hkv, 1, D), dtype), ((), jnp.int32),
+        ((B, H, D), dtype), layout["k"], layout["v"], ((B, 1, 1, S), jnp.float32),
+        ((B, Hkv, 1, D), dtype), ((B, Hkv, 1, D), dtype), ((), jnp.int32),
     ]
+
+
+def _while_body(text):
+    """The compiled text of the first ``while`` loop's body."""
+    name = re.search(r"\bwhile\(.*?body=(%[\w.\-]+)", text).group(1)
+    body = text[text.index(f"\n{name} ("):]
+    return body[:body.index("\n}\n")]
 
 
 def _attention_instructions(text):
@@ -358,13 +370,21 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_persistent_cache, monkeypatc
         assert names and all(re.match(r"^%decode_attn[.0-9]* custom-call$", name) for name in names), names
         # the cache reaches the kernel slots outermost, batch on the lanes, without a copy: the
         # transposes around the call are bitcasts of the layout the loop carries it in
-        B, Hkv, S, D = shapes[1][0]
+        B, Hkv, S, D = shapes[1][0]  # as the loop carries it: rows x kv heads beside them, kv heads at a row
         cache = f"(?:{B},{Hkv},{S},{D}|{S},{Hkv},{D},{B})"
-        body = re.search(r"\bwhile\(.*?body=(%[\w.\-]+)", text).group(1)
-        body = text[text.index(f"\n{body} ("):]
-        body = body[:body.index("\n}\n")]
+        body = _while_body(text)
         assert "tpu_custom_call" in body and re.search(rf"= bf16\[{cache}\]\S* bitcast\(", body)
         assert not re.search(rf"= bf16\[{cache}\]\S* (?:copy|transpose)\(", body), "the cache is copied every step"
+        rows = shapes[0][0][0]
+        if rows < 128:
+            # kv heads stand beside the rows: the loop carries the folded cache batch-minor, as it carries a
+            # batch of 128, and no operand the kernel is handed leaves lanes empty
+            assert B == 128 and B * Hkv == rows * shapes[4][0][1], shapes
+            assert re.search(rf"bf16\[{B},{Hkv},{S},{D}\]{{0,3,1,2", body), "the carried cache is not batch-minor"
+            call = next(line for line in body.splitlines() if "tpu_custom_call" in line)
+            handed = re.search(r"operand_layout_constraints={(.*?)}, \w+=", call).group(1)
+            lanes = [int(n) for n in re.findall(r"bf16\[\d+,\d+,\d+,(\d+)\]{3,2,1,0}", handed)]
+            assert len(lanes) == 3 and min(lanes) >= 128, handed  # q, k, v
     if case.endswith("attn_names"):
         names = _attention_instructions(text)
         assert len(names) == 6, names  # forward, dkv and dq of two layers
@@ -448,3 +468,57 @@ def test_sharded_ppo_train_step_compiles_with_the_response_window(topo, no_persi
     # no array over every position and the vocabulary, in any dtype, whole or a device's share
     assert not re.search(rf"\[\d+,(?:{P + R}|{P + R - 1}),{V}\]", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2 ** 30
+
+
+@pytest.mark.parametrize("axes", [(1, 4, 1, 1), (2, 1, 1, 2)], ids=["fsdp4", "data2-model2"])
+def test_folded_decode_step_places_over_four_chips(axes, topo, no_persistent_cache, monkeypatch):
+    """gpt2-medium's decode step (64 rows, 16 heads, 576 slots) through ``attend`` over a mesh of the
+    four described chips: the fold is taken from a shard's rows and kv heads (16 rows of 16 heads:
+    8 a row; 32 rows of 8: 4 a row), the rows-major fold keeps a shard's rows and heads on its chip,
+    so the kernel is placed as it stands, on 128 full lanes a shard, with no collective and no
+    cache-sized copy in the loop."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from trlx_tpu.ops import kv_cache
+    from trlx_tpu.parallel import mesh as mesh_lib
+
+    if len(topo.devices) < 4:
+        pytest.skip("the described topology has fewer than four chips")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(axes), mesh_lib.MESH_AXES)
+    B, H, S, D = 64, 16, 576, 64
+    n_batch, n_model = axes[0] * axes[1], axes[3]
+    with mesh:
+        fold = attention.decode_cache_fold("flash", False, B, H, H)
+    assert fold == attention.choose_decode_fold(B // n_batch, H // n_model) and fold * B // n_batch == 128
+    layout = kv_cache.kv_cache_layout((B, H, S, D), jnp.bfloat16, False, fold)
+    attend = attention.attend  # the dispatch a model calls, which chooses the kernel and places it over the mesh
+
+    def fn(q, k, v, mask_bias, k_new, v_new, steps):
+        def body(carry):
+            index, q, cache = carry
+            cache = kv_cache.write_kv_cache(cache, k_new, v_new, index)
+            step = k_new.transpose(0, 2, 1, 3)  # this step's rows as the model forms them; the cache has them already
+            out = attend(q, step, step, cache, mask_bias, None, index, D ** -0.5, "flash", False, None)
+            return index + 1, out.reshape(q.shape), cache
+
+        return jax.lax.while_loop(lambda carry: carry[0] < steps, body, (jnp.int32(1), q, {"k": k, "v": v}))
+
+    def placed(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, PartitionSpec(*spec)))
+
+    rows, heads = mesh_lib.BATCH_AXES, mesh_lib.MODEL_AXIS
+    args = [
+        placed((B, 1, H, D), jnp.bfloat16, rows, None, heads), placed(*layout["k"], rows, heads),
+        placed(*layout["v"], rows, heads), placed((B, 1, 1, S), jnp.float32, rows),
+        placed((B, H, 1, D), jnp.bfloat16, rows, heads), placed((B, H, 1, D), jnp.bfloat16, rows, heads),
+        placed((), jnp.int32),
+    ]
+    with mesh:
+        compiled = jax.jit(fn).lower(*args).compile()  # raises what the chip's compiler would
+    text = compiled.as_text()
+    body = _while_body(text)
+    held = H // n_model // fold
+    assert "tpu_custom_call" in body and re.search(rf"bf16\[{S},{held},{D},128\]\S* bitcast\(", body), "a shard's cache"
+    assert not re.search(r"all-gather|all-reduce|all-to-all|collective-permute", body)
+    assert not re.search(rf"= bf16\[(?:128,{held},{S},{D}|{S},{held},{D},128)\]\S* (?:copy|transpose)\(", body)

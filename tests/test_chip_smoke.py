@@ -49,6 +49,8 @@ def test_ppo_phase_at_tiny_widths(tmp_path, capsys, serving):
         from trlx_tpu.utils.metrics import gauges
 
         assert gauges.snapshot("rollout/").get("rollout/cache_read_share") == 1.0
+        # 4 rows with their 2 kv heads beside them: 8 of a program's 128 lanes hold a (row, kv head) pair
+        assert gauges.snapshot("rollout/").get("rollout/cache_lane_fill") == 8 / 128
     out = capsys.readouterr().out
     assert "0 after it (CompileWatcher)" in out
     assert f"step {TINY.steps}/{TINY.steps}:" in out

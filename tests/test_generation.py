@@ -358,8 +358,59 @@ def test_generate_is_the_same_with_the_decode_kernel_and_the_einsum(case):
     with mesh or contextlib.nullcontext():
         cache = jax.eval_shape(lambda: TransformerLM(base).init_cache(len(prompts), 8 + n_new))
         placed = attention.decode_kernel_placement(
-            "flash", base.biased_attention, {"k": cache["k"][0], "v": cache["v"][0]}, base.num_heads)[0]
+            "flash", base.biased_attention, {"k": cache["k"][0], "v": cache["v"][0]}, base.num_heads, len(prompts))[0]
     assert placed == (case != "mesh-the-heads-do-not-divide")
+
+
+# name: (config overrides, mesh axes or None, rows, the cache's (rows, kv heads) once folded)
+FOLDED_GENERATE_CASES = {
+    "64-rows-2-heads": (dict(), None, 64, (128, 1)),
+    "16-rows-grouped-the-heads-cap-the-fold": (dict(num_heads=8, num_kv_heads=4, hidden_size=32), None, 16, (64, 1)),
+    "placed-over-a-mesh": (dict(num_heads=4, hidden_size=32), dict(data=2, fsdp=2, model=2), 16, (32, 2)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FOLDED_GENERATE_CASES))
+def test_generate_gives_the_same_tokens_with_kv_heads_folded_beside_the_rows(case, monkeypatch):
+    """``generate`` end to end through the decode kernel over a cache that holds kv heads beside
+    the rows (what a batch under 128 rows gets) against the same kernel over the cache unfolded
+    (the chooser made to say 1): the same tokens, the same ``response_mask``, rows ending on an
+    eos at different steps, left-padded prompts of different lengths."""
+    import contextlib
+
+    from trlx_tpu.ops import attention
+    from trlx_tpu.parallel.mesh import make_mesh
+
+    overrides, axes, rows, folded_shape = FOLDED_GENERATE_CASES[case]
+    config = PRESETS["gpt2"].replace(**{**TINY, **overrides, "attention_impl": "flash"})
+    model = TransformerLM(config)
+    rng = np.random.default_rng(rows)
+    prompts = [rng.integers(1, 37, size=rng.integers(1, 9)).astype(np.int32) for _ in range(rows)]
+    ids, mask = left_pad_batch(prompts, pad_token_id=0, target_len=8)
+    ids, mask = jnp.asarray(ids), jnp.asarray(mask)
+    params = model.init(jax.random.PRNGKey(0), ids[:2], mask[:2])["params"]
+    n_new = 10
+    mesh = make_mesh(**axes) if axes else None
+
+    def run(eos):
+        fn = jax.jit(lambda params, ids, mask: generate(
+            model_step_fn(model), params, lambda b, s: model.init_cache(b, s, jnp.float32), ids, mask,
+            jax.random.PRNGKey(0), max_new_tokens=n_new, do_sample=False, pad_token_id=0, eos_token_id=eos,
+        ))
+        with mesh or contextlib.nullcontext():
+            layout = config.cache_layout(rows, 8 + n_new)
+            return jax.tree.map(np.asarray, fn(params, ids, mask)), layout["k"][0][:2]
+
+    eos = int(run(None)[0]["sequences"][0, 8 + 3])  # a token that some row emits inside the run
+    got, shape = run(eos)
+    assert shape == folded_shape
+    monkeypatch.setattr(attention, "choose_decode_fold", lambda B, Hkv: 1)
+    want, shape = run(eos)
+    assert shape == (rows, config.kv_heads)
+    np.testing.assert_array_equal(got["sequences"], want["sequences"])
+    np.testing.assert_array_equal(got["response_mask"], want["response_mask"])
+    lengths = want["response_mask"].sum(axis=1)
+    assert lengths.min() < n_new < lengths.max() + 1, lengths
 
 
 CELL_1 = dict(B=128, prompt_len=64, new_tokens=448, steps=447)  # gpt2.ppo-long-response's rollout
@@ -375,7 +426,8 @@ CELL_1 = dict(B=128, prompt_len=64, new_tokens=448, steps=447)  # gpt2.ppo-long-
         (dict(attention_impl="flash", peft_type="prefix", num_virtual_tokens=4), None, 1.0, 1.0),
         (dict(attention_impl="flash", attention_kind="mla"), None, 1.0, 1.0),  # its own absorbed decode
         (dict(attention_impl="flash", peft_type="prompt", num_virtual_tokens=8), None, 0.57, 0.60),  # in the cache too
-        (dict(attention_impl="flash"), dict(data=4, model=2), 0.56, 0.59),  # a shard's rows and heads
+        # a shard's rows and heads: 32 rows of 6 kv heads, folded to 96 of 2, whose smaller slots make blocks of 32
+        (dict(attention_impl="flash"), dict(data=4, model=2), 0.56, 0.60),
         (dict(attention_impl="flash"), dict(data=1, model=8), 1.0, 1.0),  # 12 heads over 8: the einsum
     ],
 )
@@ -390,7 +442,7 @@ def test_cache_read_share_follows_who_takes_the_decode_kernel(overrides, axes, l
     def share(B, prompt_len, new_tokens, steps):  # as MeshRLTrainer.generate asks
         return decode_cache_read_share(
             config.attention_impl, config.biased_attention, config.num_heads,
-            config.cache_layout(B, prompt_len + new_tokens), new_tokens, steps,
+            config.cache_layout(B, prompt_len + new_tokens), B, new_tokens, steps,
         )
 
     with make_mesh(**axes) if axes else contextlib.nullcontext():

@@ -193,7 +193,9 @@ def _cached_decode(config, attention_impl, P=20, N=6, pads=(4, 0, 9)):
     cache = trunk.init_cache(3, P + N)
     # keys and values over the one attention layer, a state for each of the three convolution layers
     assert set(cache) == {"k", "v", "conv", "index"} and len(cache["k"]) == len(cache["v"]) == 1
-    assert cache["k"][0].shape == (3, 2, P + N, 16) and len(cache["conv"]) == 3
+    # (the decode kernel's cache holds the 2 kv heads beside each of the 3 rows)
+    assert cache["k"][0].shape == ((6, 1) if attention_impl == "flash" else (3, 2)) + (P + N, 16)
+    assert len(cache["conv"]) == 3
     assert cache["conv"][2].shape == (3, 2, 64)  # the two gated inputs the next token's three taps read
     seen = mask.at[:, P:].set(0)
     positions = jnp.clip(jnp.cumsum(mask, axis=1) - 1, 0, None)

@@ -116,7 +116,8 @@ def _cached_decode(config, attention_impl, scramble=None, P=20, N=6):
     cache = trunk.init_cache(3, P + N)
     # an entry for every (pass, layer): 4 passes of 3 layers, per-head keys and values
     assert set(cache) == {"k", "v", "index"} and len(cache["k"]) == len(cache["v"]) == 12
-    assert cache["k"][11].shape == (3, 4, P + N, 16)
+    # (the decode kernel's cache holds the 4 kv heads beside each of the 3 rows)
+    assert cache["k"][11].shape == ((12, 1) if attention_impl == "flash" else (3, 4)) + (P + N, 16)
     seen = mask.at[:, P:].set(0)
     positions = jnp.clip(jnp.cumsum(mask, axis=1) - 1, 0, None)
     prefill = jax.jit(lambda p, i, m, pos, c: trunk.apply({"params": p}, i, m, pos, {**c, "index": 0}))

@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from trlx_tpu.ops import kv_cache
-from trlx_tpu.ops.attention import attend, flash_placement
+from trlx_tpu.ops.attention import attend, decode_cache_fold, flash_placement
 from trlx_tpu.parallel.mesh import BATCH_AXES, MODEL_AXIS, PIPE_AXIS
 from trlx_tpu.parallel.sharding import (
     ambient_mesh,
@@ -253,7 +253,10 @@ class TransformerConfig:
         if self.attention_kind == "mla":
             return kv_cache.latent_cache_layout(batch_size, max_length, self.kv_lora_rank, self.qk_rope_head_dim, dtype)
         shape = (batch_size, self.kv_heads, max_length, self.dim_per_head)
-        return kv_cache.kv_cache_layout(shape, dtype, self.kv_cache_quant)
+        # kv heads beside the rows where single-token steps take the decode kernel and fewer than 128 rows decode
+        fold = 1 if self.kv_cache_quant else decode_cache_fold(
+            self.attention_impl, self.biased_attention, batch_size, self.num_heads, self.kv_heads)
+        return kv_cache.kv_cache_layout(shape, dtype, self.kv_cache_quant, fold)
 
     def conv_state_layout(self, batch_size: int, dtype=None) -> Dict[str, Tuple]:
         """One convolution layer of the contiguous cache: the gated inputs the next token's filter reads."""
@@ -1292,22 +1295,23 @@ class TransformerLM(nn.Module):
     def init_cache(self, batch_size: int, max_length: int, dtype=None) -> KVCache:
         c = self.config
         per_layer = c.cache_layout(batch_size, max_length, dtype)
+        entry_bytes = kv_cache.bytes_per_token(per_layer, batch_size)  # one layer's, for the gauges below
         if c.attention_kind == "mla":
             from trlx_tpu.utils.metrics import gauges
 
-            gauges.set("mla/cache_bytes_per_token", c.num_layers * kv_cache.bytes_per_token(per_layer))
+            gauges.set("mla/cache_bytes_per_token", c.num_layers * entry_bytes)
         if c.loop_steps > 1:
             from trlx_tpu.utils.metrics import gauges
 
             gauges.set("loop/passes", c.loop_steps)
-            gauges.set("loop/cache_bytes_per_token", c.cache_entries * kv_cache.bytes_per_token(per_layer))
+            gauges.set("loop/cache_bytes_per_token", c.cache_entries * entry_bytes)
         if c.conv_layers:
             from trlx_tpu.utils.metrics import gauges
 
             state = c.conv_state_layout(batch_size, dtype)
             gauges.set("hybrid/attention_layers", c.attention_layers)
             gauges.set("hybrid/conv_layers", c.conv_layers)
-            gauges.set("hybrid/cache_bytes_per_token", c.cache_entries * kv_cache.bytes_per_token(per_layer))
+            gauges.set("hybrid/cache_bytes_per_token", c.cache_entries * entry_bytes)
             gauges.set("hybrid/state_bytes_per_row",
                        c.loop_steps * c.conv_layers * kv_cache.state_bytes_per_row(state))
         if c.stacked:

@@ -950,6 +950,10 @@ flash_attention.defvjp(_fwd, _bwd)
 # prefix of the buffer: a grid step holds ``block`` slots, a block past the
 # write index does nothing, and its index map is clamped to the last block that
 # holds a token, so the pipeline sees the same block again and moves no bytes.
+# Fewer than 128 rows would leave lanes empty, in HBM and in every DMA: there
+# the cache holds ``fold`` kv heads beside each row (``ops/kv_cache.py``), the
+# kernel sees ``B * fold`` rows of ``Hkv / fold`` kv heads, and only q, the mask
+# bias and the output — a token a row each — are folded and unfolded around it.
 
 
 class DecodeTiles(NamedTuple):
@@ -990,12 +994,30 @@ def choose_decode_tiles(
     return DecodeTiles(rows, block, reckon(block))
 
 
+def choose_decode_fold(B: int, Hkv: int) -> int:
+    """kv heads that stand beside each of ``B`` rows on the kernel's 128 lanes (per
+    shard): the largest divisor of ``Hkv`` that still fits them where ``B`` rows
+    leave lanes empty, else 1. A pure function of the shape, beside
+    :func:`choose_decode_tiles`: 64 rows of 16 kv heads fold 2, 16 of 8 fold 8,
+    96 and 128 rows fold nothing."""
+    if B >= LANE:
+        return 1
+    return max(g for g in range(1, Hkv + 1) if Hkv % g == 0 and g * B <= LANE)
+
+
+def _lane_fill(rows: int) -> Tuple[int, int]:
+    """(lanes that hold a row, lanes held) of a program that takes ``rows`` rows."""
+    return rows, _round_up(rows, LANE)
+
+
 @functools.lru_cache(maxsize=None)
-def _log_decode_tiles(B, H, Hkv, S, D, dtype, tiles):
-    """The chooser's choice, once per traced shape."""
+def _log_decode_tiles(B, H, Hkv, S, D, dtype, tiles, fold):
+    """The choosers' choice, once per traced shape (``B``, ``Hkv``: the model's, before the fold)."""
+    filled, lanes = _lane_fill(tiles.rows)
     logger.info(  # graftcheck: noqa[JX003] — once per traced shape is the point
         f"decode attention q[{B},{H},{D}] cache[{B},{Hkv},{S},{D}] {dtype}: rows {tiles.rows} block {tiles.block},"
-        f" grid {(B // tiles.rows, -(-S // tiles.block))}, VMEM reckoned {tiles.vmem_bytes / 2**20:.1f} MiB"
+        f" fold {fold}, lanes filled {filled}/{lanes},"
+        f" grid {(B * fold // tiles.rows, -(-S // tiles.block))}, VMEM reckoned {tiles.vmem_bytes / 2**20:.1f} MiB"
     )
 
 
@@ -1092,8 +1114,8 @@ def _decode_kernel(
 @functools.partial(jax.jit, static_argnames=("scale", "interpret", "tiles"))
 def decode_attention(
     q: jnp.ndarray,  # [B, H, D]: one new token a row
-    k: jnp.ndarray,  # [B, Hkv, S, D]: the cache, this step's token written at slot ``index``
-    v: jnp.ndarray,  # [B, Hkv, S, Dv]
+    k: jnp.ndarray,  # [B * fold, Hkv / fold, S, D]: the cache, this step's token written at slot ``index``
+    v: jnp.ndarray,  # [B * fold, Hkv / fold, S, Dv]
     mask_bias: jnp.ndarray,  # additive, B * S elements ([B, 1, 1, S]): 0 where a row may see a slot
     index,  # scalar int32: the last slot that holds a token, the same for every row
     scale: Optional[float] = None,
@@ -1105,21 +1127,26 @@ def decode_attention(
     running max and sum) and the weighted sum. Slots past ``index`` are neither
     computed on nor, beyond the block ``index`` lies in, moved. ``mask_bias``
     is the einsum path's (left padding; its causal part is implied by
-    ``index``). Grouped K/V map h -> h // rep. Returns ``[B, H, Dv]`` in q's dtype."""
+    ``index``). Grouped K/V map h -> h // rep. A cache of more rows than q is
+    folded (``kv_cache.fold_heads``): q and the mask bias are folded to meet it
+    and the output unfolded, a token a row each. Returns ``[B, H, Dv]`` in q's dtype."""
     B, H, D = q.shape
-    Hkv, S, Dv = k.shape[1], k.shape[2], v.shape[3]
-    assert H % Hkv == 0, (H, Hkv)
-    rep = H // Hkv
+    fold = k.shape[0] // B  # kv heads the cache holds beside each row
+    Hkv, S, Dv = k.shape[1], k.shape[2], v.shape[3]  # Hkv: kv heads at one row of the cache
+    assert k.shape[0] == B * fold and H % (Hkv * fold) == 0, (q.shape, k.shape)
+    rep = H // (Hkv * fold)
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
     if tiles is None:
-        tiles = choose_decode_tiles(B, Hkv, rep, S, D, k.dtype, Dv=Dv)
-    _log_decode_tiles(B, H, Hkv, S, D, jnp.dtype(k.dtype).name, tiles)
+        tiles = choose_decode_tiles(B * fold, Hkv, rep, S, D, k.dtype, Dv=Dv)
+    _log_decode_tiles(B, H, Hkv * fold, S, D, jnp.dtype(k.dtype).name, tiles, fold)
     rows, block = tiles.rows, tiles.block
 
-    # slots outermost, the batch on the lanes: bitcasts of the cache as the chip's compiler lays it out
-    q_t = q.reshape(B, Hkv, rep, D).transpose(1, 2, 3, 0)
+    # slots outermost, the batch on the lanes: bitcasts of the cache as the chip's compiler lays it out;
+    # a kv head's ``rep`` query heads go to the row that holds its slots, and the row's bias with them
+    q_t = kv_cache.fold_heads(q.reshape(B, Hkv * fold, rep, D), fold).transpose(1, 2, 3, 0)
     k_t, v_t = k.transpose(2, 1, 3, 0), v.transpose(2, 1, 3, 0)
-    bias = mask_bias.reshape(B, S).astype(jnp.float32).T
+    bias = mask_bias.reshape(B, S).astype(jnp.float32)
+    bias = (jnp.repeat(bias, fold, axis=0) if fold > 1 else bias).T
     index = jnp.asarray(index, jnp.int32).reshape(1)
 
     def last_held(j, idx):  # a block past the write index is the last held one again: no new DMA
@@ -1133,21 +1160,21 @@ def decode_attention(
         functools.partial(_decode_kernel, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(B // rows, -(-S // block)),
+            grid=(B * fold // rows, -(-S // block)),
             in_specs=[
                 pl.BlockSpec((block, rows), lambda i, j, idx: (last_held(j, idx), i)),
                 q_spec, k_spec, v_spec,
             ],
             out_specs=o_spec,
             scratch_shapes=[
-                pltpu.VMEM((H, 1, rows), jnp.float32),
-                pltpu.VMEM((H, 1, rows), jnp.float32),
-                pltpu.VMEM((H, Dv, rows), jnp.float32),
+                pltpu.VMEM((Hkv * rep, 1, rows), jnp.float32),
+                pltpu.VMEM((Hkv * rep, 1, rows), jnp.float32),
+                pltpu.VMEM((Hkv * rep, Dv, rows), jnp.float32),
                 pltpu.VMEM((rep, block, rows), jnp.float32),
                 pltpu.VMEM((rep, block, rows), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((Hkv, rep, Dv, B), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((Hkv, rep, Dv, B * fold), q.dtype),
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
@@ -1157,7 +1184,7 @@ def decode_attention(
         # on the chip, which is how the benchmark finds the flash calls
         name="decode_attn",
     )(index, bias, q_t, k_t, v_t)
-    return out.transpose(3, 0, 1, 2).reshape(B, H, Dv)
+    return kv_cache.unfold_heads(out.transpose(3, 0, 1, 2), fold).reshape(B, H, Dv)
 
 
 # ---- dispatch: a model states what it attends over (:func:`attend`); which path, over which mesh, is decided here
@@ -1261,33 +1288,74 @@ def flash_placement(impl: str, biased: bool, B: int, T: int, kv_valid, heads: in
     return _kernel_placement(impl, biased, B, heads, kv_heads)
 
 
-def decode_kernel_placement(impl: str, biased: bool, layer, heads: int):
-    """(whether a single-token step over a layer's contiguous cache — ``layer``
-    holds its arrays, concrete or abstract — takes the Pallas decode kernel,
-    the mesh to place it over or None): the flash kernels' rule, and per-head
-    float rows in the cache. Everything else — ``impl="xla"``, the int8 cache,
-    alibi, prefix tuning, a latent cache (its own absorbed decode), a mesh the
-    call cannot be placed over — keeps the einsum path."""
+def decode_kernel_placement(impl: str, biased: bool, layer, heads: int, batch_size: int):
+    """(whether a single-token step of ``batch_size`` rows over a layer's
+    contiguous cache — ``layer`` holds its arrays, concrete or abstract, folded
+    or not — takes the Pallas decode kernel, the mesh to place it over or
+    None): the flash kernels' rule, and per-head float rows in the cache.
+    Everything else — ``impl="xla"``, the int8 cache, alibi, prefix tuning, a
+    latent cache (its own absorbed decode), a mesh the call cannot be placed
+    over — keeps the einsum path."""
     if kv_cache.is_latent(layer) or kv_cache.has_row_scales(layer):
         return False, None
-    B, kv_heads = layer["k"].shape[:2]
-    return _kernel_placement(impl, biased, B, heads, kv_heads)
+    rows, held = layer["k"].shape[:2]
+    return _kernel_placement(impl, biased, batch_size, heads, held * (rows // batch_size))
 
 
-def decode_cache_read_share(impl: str, biased: bool, heads: int, layout, new_tokens: int, steps: int) -> float:
+def decode_cache_fold(impl: str, biased: bool, batch_size: int, heads: int, kv_heads: int) -> int:
+    """kv heads a per-head float layer of the contiguous cache holds beside each
+    row (``kv_cache.kv_cache_layout``'s ``fold``): :func:`choose_decode_fold`
+    of a shard's rows and kv heads where its single-token steps will take the
+    decode kernel (:func:`decode_kernel_placement`'s rule; a shard of the mesh
+    keeps whole rows and a run of neighbouring kv heads, so the fold places
+    under :func:`_placed` as it stands), else 1. Call under the mesh the steps
+    will run under."""
+    use, mesh = _kernel_placement(impl, biased, batch_size, heads, kv_heads)
+    if not use:
+        return 1
+    n_batch, n_model = _kernel_shards(mesh)
+    return choose_decode_fold(batch_size // n_batch, kv_heads // n_model)
+
+
+def _decode_shard_tiles(impl: str, biased: bool, heads: int, layout, batch_size: int) -> Optional[DecodeTiles]:
+    """The programs a shard's decode kernel is cut into for a layer's
+    ``layout`` (``ops/kv_cache.py``) of ``batch_size`` rows under the ambient
+    mesh, or None where the steps take the einsum path."""
+    layer = {key: jax.ShapeDtypeStruct(shape, dtype) for key, (shape, dtype) in layout.items()}
+    use, mesh = decode_kernel_placement(impl, biased, layer, heads, batch_size)
+    if not use:
+        return None
+    rows, held, cache_len, D = layer["k"].shape  # batch_size * fold rows of kv_heads / fold
+    n_batch, n_model = _kernel_shards(mesh)
+    rep = heads // (held * (rows // batch_size))
+    return choose_decode_tiles(rows // n_batch, held // n_model, rep, cache_len, D, layer["k"].dtype)
+
+
+def decode_cache_read_share(
+    impl: str, biased: bool, heads: int, layout, batch_size: int, new_tokens: int, steps: int
+) -> float:
     """Cache slots the decode steps of one rollout visited over the slots the
     cache holds (``rollout/cache_read_share``): host arithmetic from a layer's
-    ``layout`` (``ops/kv_cache.py``; what is not for ``new_tokens`` was
-    prefilled), the ``steps`` the decode loop ran and the kernel's block; 1.0
-    where the steps took the einsum path. Call under the trainer's mesh."""
-    layer = {key: jax.ShapeDtypeStruct(shape, dtype) for key, (shape, dtype) in layout.items()}
-    use, mesh = decode_kernel_placement(impl, biased, layer, heads)
-    if not use:
+    ``layout`` (what is not for ``new_tokens`` was prefilled), the ``steps``
+    the decode loop ran and the kernel's block; 1.0 where the steps took the
+    einsum path. Call under the trainer's mesh."""
+    tiles = _decode_shard_tiles(impl, biased, heads, layout, batch_size)
+    if tiles is None:
         return 1.0
-    B, kv_heads, cache_len, D = layer["k"].shape
-    n_batch, n_model = _kernel_shards(mesh)
-    tiles = choose_decode_tiles(B // n_batch, kv_heads // n_model, heads // kv_heads, cache_len, D, layer["k"].dtype)
+    cache_len = layout["k"][0][2]
     return cache_read_share(cache_len - new_tokens, steps, cache_len, tiles.block)
+
+
+def decode_cache_lane_fill(impl: str, biased: bool, heads: int, layout, batch_size: int) -> float:
+    """(row, kv head) pairs on the decode kernel's lanes over the lanes its
+    programs hold, a shard's (``rollout/cache_lane_fill``): what of the cache's
+    traffic is keys and values and not padding. 1.0 where the steps take the
+    einsum path, which pads nothing. Call under the trainer's mesh."""
+    tiles = _decode_shard_tiles(impl, biased, heads, layout, batch_size)
+    if tiles is None:
+        return 1.0
+    filled, lanes = _lane_fill(tiles.rows)
+    return filled / lanes
 
 
 def _interpret(mesh) -> bool:
@@ -1334,7 +1402,7 @@ def attend(q, k, v, cache, mask_bias, kv_valid, index, scale: float, impl: str, 
     dtype = q.dtype
 
     if cache is not None and T == 1:
-        use_kernel, mesh = decode_kernel_placement(impl, biased, cache, H)
+        use_kernel, mesh = decode_kernel_placement(impl, biased, cache, H, B)
         if use_kernel:
             interpret = _interpret(mesh)
 
@@ -1377,7 +1445,7 @@ def attend(q, k, v, cache, mask_bias, kv_valid, index, scale: float, impl: str, 
             k_row_scale = cache["k_scale"]  # [B, Hkv, S, 1] f32
             v_row_scale = cache["v_scale"]
         else:
-            kh, vh = kv_cache.read_kv_cache(cache, dtype)
+            kh, vh = kv_cache.read_kv_cache(cache, dtype, B)
     else:
         kh = k.transpose(0, 2, 1, 3)
         vh = v.transpose(0, 2, 1, 3)

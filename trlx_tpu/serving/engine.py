@@ -70,6 +70,7 @@ import jax.numpy as jnp
 
 from trlx_tpu.obs import compile_log
 from trlx_tpu.obs.flight import flight
+from trlx_tpu.ops.kv_cache import unfold_heads
 from trlx_tpu.ops.generation import LENGTH_BUCKETS, left_pad_batch, pad_to_bucket
 from trlx_tpu.ops.paged_attention import paged_slots, scatter_paged_rows
 from trlx_tpu.ops.sampling import count_accepted_drafts, sample_token
@@ -419,6 +420,7 @@ class ServingEngine:
         Rewriting a shared prefix block stores the identical values it
         already holds (same tokens, same params) — benign by construction."""
         P = cont["k"][0].shape[2]
+        n = rows.shape[0]
         s = jnp.arange(P)[None, :]  # source slot in the left-padded cache
         pos = s - (P - lens[:, None])  # logical token position, <0 on padding: dropped
         block, offset = paged_slots(rows, pos, self.num_blocks, self.block_size)
@@ -428,9 +430,10 @@ class ServingEngine:
             cl = cont[key]
             if key.endswith("_scale"):
                 cl = [x[..., 0] for x in cl]  # [n,Hkv,P,1] -> [n,Hkv,P]
-            # cont [n, Hkv, P, ...] -> rows [n, P, Hkv, ...]
+            # cont [n, Hkv, P, ...] (kv heads beside the rows where the prefill's cache is folded)
+            # -> rows [n, P, Hkv, ...]
             out[key] = [
-                scatter_paged_rows(p, block, offset, jnp.moveaxis(c, 2, 1))
+                scatter_paged_rows(p, block, offset, jnp.moveaxis(unfold_heads(c, c.shape[0] // n), 2, 1))
                 for p, c in zip(pools[key], cl)
             ]
         return out

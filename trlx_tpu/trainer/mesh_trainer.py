@@ -27,7 +27,7 @@ import optax
 from trlx_tpu.data.configs import TRLConfig
 from trlx_tpu.models.transformer import TransformerConfig
 from trlx_tpu.obs import Observability, batch_token_count, compile_log
-from trlx_tpu.ops.attention import decode_cache_read_share
+from trlx_tpu.ops.attention import decode_cache_lane_fill, decode_cache_read_share
 from trlx_tpu.ops.generation import generate as generate_op
 from trlx_tpu.ops.generation import LENGTH_BUCKETS, generate_seq2seq, left_pad_batch, pad_to_bucket
 from trlx_tpu.parallel import mesh as mesh_lib
@@ -590,11 +590,11 @@ class MeshRLTrainer(BaseRLTrainer):
         if isinstance(model_config, TransformerConfig) and model_config.attention_layers:
             # the loop ran until the longest row ended; its first token came from the prefill
             steps = int(response_mask.sum(axis=1).max()) - 1
-            c = model_config
+            c, rows = model_config, ids.shape[0]
             with self.mesh:
-                gauges.set("rollout/cache_read_share", decode_cache_read_share(
-                    c.attention_impl, c.biased_attention, c.num_heads,
-                    c.cache_layout(ids.shape[0], P + max_new), max_new, steps))
+                decode = (c.attention_impl, c.biased_attention, c.num_heads, c.cache_layout(rows, P + max_new), rows)
+                gauges.set("rollout/cache_read_share", decode_cache_read_share(*decode, max_new, steps))
+                gauges.set("rollout/cache_lane_fill", decode_cache_lane_fill(*decode))
         # seq2seq sequences are [decoder_start] + response: pad_len for decode() is 1
         return sequences, response_mask, 1 if is_seq2seq else P
 
