@@ -449,17 +449,24 @@ def _sharded_ppo_learner(topo, fsdp=4, layers=2):
     return t._get_train_step(B, P, R), (params, state, batch), mesh, (B, P, R)
 
 
-def test_sharded_ppo_train_step_compiles_with_the_response_window(topo, no_persistent_cache, monkeypatch):
+@pytest.fixture(scope="module")
+def sharded_train_step(topo, no_persistent_cache):
+    """(the fsdp=4 PPO train step compiled for the described chips, (B, P, R)): one compile, read by two tests."""
+    if len(topo.devices) < 4:
+        pytest.skip("the described topology has fewer than four chips")
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "default_backend", lambda: "tpu")
+        step, args, mesh, sizes = _sharded_ppo_learner(topo)
+        with mesh:
+            return step.lower(*args).compile(), sizes  # raises what the chip's compiler would
+
+
+def test_sharded_ppo_train_step_compiles_with_the_response_window(sharded_train_step):
     """The fsdp=4 PPO train step at gpt2's vocabulary of 50257: the shape on which the chip's
     compiler failed (PR 22: "Bitcast cannot have different shape sizes of output and operand")
     when ``[B, T, V]`` logits were sliced and the backward padded them. The head now runs over
     the response window's rows of the hidden states, and the backward pads ``[B, R, d]``."""
-    if len(topo.devices) < 4:
-        pytest.skip("the described topology has fewer than four chips")
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    step, args, mesh, (B, P, R) = _sharded_ppo_learner(topo)
-    with mesh:
-        compiled = step.lower(*args).compile()  # raises what the chip's compiler would
+    compiled, (B, P, R) = sharded_train_step
     text = compiled.as_text()
     assert "tpu_custom_call" in text  # the flash kernels, placed over the mesh
     assert "all-gather" in text or "all-reduce" in text  # the parameters are sharded over the four chips
@@ -468,6 +475,29 @@ def test_sharded_ppo_train_step_compiles_with_the_response_window(topo, no_persi
     # no array over every position and the vocabulary, in any dtype, whole or a device's share
     assert not re.search(rf"\[\d+,(?:{P + R}|{P + R - 1}),{V}\]", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 2 ** 30
+
+
+def test_the_chips_program_carries_the_programs_scopes(sharded_train_step):
+    """The table ``obs/op_scopes.py`` walks out of the TPU program's text: of the instructions
+    that can be device events at least 95 % stand under a scope of the vocabulary (the
+    compiler's own moves between memory spaces carry no ``op_name`` and take their user's), the
+    head's are told from the trunk's, and the flash calls of the two layers (forward, dq, dkv)
+    read ``kernel``, forward and backward apart."""
+    from trlx_tpu.obs import op_scopes
+
+    rows = op_scopes.walk(sharded_train_step[0].as_text())
+    events = [row for row in rows.values() if row["kind"] != "container"]
+    scoped = [row for row in events if row["scope"]]
+    assert len(events) > 1000 and len(scoped) >= 0.95 * len(events), (len(scoped), len(events))
+    assert {tuple(row["scope"]) for row in scoped} >= {("loss",), ("loss", "logprobs"), ("optimizer",)}
+    lent = [row for row in events if "via" in row]
+    moves = [row for row in lent if row["opcode"].endswith(("-start", "-done")) or row["opcode"] == "custom-call"]
+    assert len(moves) >= 0.8 * len(lent) and not [row for row in moves if row["kind"] == "kernel"]
+    kernels = {name: row for name, row in rows.items() if row["kind"] == "kernel"}
+    assert sorted(row["pass"] for row in kernels.values()) == ["backward"] * 4 + ["forward"] * 2, kernels
+    assert all(re.match(r"^%attn[.0-9]*$", name) and row["scope"] == ["loss"] for name, row in kernels.items())
+    products = [row for row in events if row["kind"] == "product"]
+    assert products and all(row["scope"] for row in products)
 
 
 @pytest.mark.parametrize("axes", [(1, 4, 1, 1), (2, 1, 1, 2)], ids=["fsdp4", "data2-model2"])

@@ -20,6 +20,9 @@ facade the trainer drives from ``TRLConfig.train.observability``:
 - :mod:`trlx_tpu.obs.compile_log` — the always-on, bounded record of the
   process's XLA compiles with the entry each is attributed to; the one
   ``jax.monitoring`` dispatcher of the process.
+- :mod:`trlx_tpu.obs.op_scopes` — every hot program's table of its own
+  instructions (name -> named scope), built lazily from the executable that
+  ran: the join between a device trace's events and the program's scopes.
 - :mod:`trlx_tpu.obs.timeseries` / :mod:`trlx_tpu.obs.export` — bounded
   gauge time-series with windowed reductions, plus atomic JSONL and
   Prometheus text exporters.

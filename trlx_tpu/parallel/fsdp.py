@@ -322,7 +322,9 @@ def make_overlapped_grad_accum_step(
             (loss, stats), g_sh = jax.value_and_grad(local_loss, has_aux=True)(
                 param_shards, mb
             )
-            return jax.tree.map(jnp.add, carry, g_sh), (loss, stats)
+            with jax.named_scope("accumulate"):  # as in make_grad_accum_step
+                carry = jax.tree.map(jnp.add, carry, g_sh)
+            return carry, (loss, stats)
 
         def accum_seeded(carry, mb):
             # the deliberate regression: full-gradient all-reduce over fsdp,
@@ -338,7 +340,9 @@ def make_overlapped_grad_accum_step(
                 lambda g, d: g if d < 0 else _slice_local(g, d, mesh),
                 g_full, shard_dims,
             )
-            return jax.tree.map(jnp.add, carry, g_sh), (loss, stats)
+            with jax.named_scope("accumulate"):
+                carry = jax.tree.map(jnp.add, carry, g_sh)
+            return carry, (loss, stats)
 
         zero = jax.tree.map(jnp.zeros_like, param_shards)
         accum = accum_seeded if seeded_allreduce else accum_good

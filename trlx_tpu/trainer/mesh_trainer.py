@@ -26,7 +26,7 @@ import optax
 
 from trlx_tpu.data.configs import TRLConfig
 from trlx_tpu.models.transformer import TransformerConfig
-from trlx_tpu.obs import Observability, batch_token_count, compile_log
+from trlx_tpu.obs import Observability, batch_token_count, compile_log, op_scopes
 from trlx_tpu.ops.attention import decode_cache_lane_fill, decode_cache_read_share
 from trlx_tpu.ops.generation import generate as generate_op
 from trlx_tpu.ops.generation import LENGTH_BUCKETS, generate_seq2seq, left_pad_batch, pad_to_bucket
@@ -407,7 +407,8 @@ class MeshRLTrainer(BaseRLTrainer):
 
             def body(grads_acc, mb):
                 (loss, stats), grads = jax.value_and_grad(loss_fn, has_aux=True)(params, mb)
-                grads_acc = jax.tree.map(jnp.add, grads_acc, grads)
+                with jax.named_scope("accumulate"):  # apart from the backward it follows
+                    grads_acc = jax.tree.map(jnp.add, grads_acc, grads)
                 return grads_acc, (loss, stats)
 
             zero_grads = jax.tree.map(jnp.zeros_like, params)
@@ -454,7 +455,9 @@ class MeshRLTrainer(BaseRLTrainer):
         jitted = jax.jit(guarded_step, donate_argnums=(0, 1) if donate else ())
 
         def run(params, opt_state, batch):
-            return jitted(params, opt_state, batch, jnp.float32(guard.grad_norm_cap()))
+            args = (params, opt_state, batch, jnp.float32(guard.grad_norm_cap()))
+            op_scopes.note(name, jitted, args, mesh=self.mesh)  # the program itself, which ``run`` hides
+            return jitted(*args)
 
         return run
 
@@ -581,9 +584,9 @@ class MeshRLTrainer(BaseRLTrainer):
         # the host fetch, so timing only the dispatch would undercount wildly
         with self.obs.span("generate"):
             with self.mesh, compile_log.attributed("generate"):
-                out = self._compiled_generate[key](
-                    gen_params, batch["ids"], batch["mask"], sub
-                )
+                args = (gen_params, batch["ids"], batch["mask"], sub)
+                op_scopes.note("generate", self._compiled_generate[key], args, mesh=self.mesh)
+                out = self._compiled_generate[key](*args)
             sequences = np.asarray(jax.device_get(out["sequences"]))
             response_mask = np.asarray(jax.device_get(out["response_mask"]))
         model_config = getattr(self, "model_config", None)
@@ -940,7 +943,7 @@ class MeshRLTrainer(BaseRLTrainer):
                             jax.profiler.start_trace(train_config.profile_dir)
                             profiling = True
                         elif self.iter_count >= train_config.profile_end_step and profiling:
-                            jax.profiler.stop_trace()
+                            self._stop_profile()
                             profiling = False
                     # chaos site "nan-loss": poison the batch to non-finite
                     # (free when unarmed) — the health guard must catch it
@@ -1034,9 +1037,21 @@ class MeshRLTrainer(BaseRLTrainer):
             # return, sweep early stop, or an exception mid-window) — otherwise
             # jax.profiler.stop_trace() is never called and the trace is lost
             if profiling:
-                jax.profiler.stop_trace()
+                self._stop_profile()
         self._report_sweep_result(results)
         return results
+
+    def _stop_profile(self):
+        """Close the ``train.profile_dir`` session and put the programs' tables
+        of their own instructions beside the trace (``op_scopes.json``: a device
+        event's instruction name -> scope; docs/observability.md)."""
+        jax.profiler.stop_trace()
+        path = os.path.join(self.config.train.profile_dir, "op_scopes.json")
+        try:
+            rows = op_scopes.write(path)
+            logger.info(f"wrote {path}: {rows} instructions by program")
+        except Exception as e:  # the trace is what the operator asked for; the key to it is extra
+            logger.warning(f"op_scopes.json not written: {e!r}")
 
     def _sweep_tick(self, results) -> bool:
         """Under a sweep: report intermediate metrics (consumed by the ASHA

@@ -30,7 +30,7 @@ from trlx_tpu.models.policy import (
     head_of,
 )
 from trlx_tpu.models.transformer import TransformerLM, loop_counters, moe_counters
-from trlx_tpu.obs import compile_log, span
+from trlx_tpu.obs import compile_log, op_scopes, span
 from trlx_tpu.obs.flight import flight
 from trlx_tpu.ops.generation import LENGTH_BUCKETS, left_pad_batch, pad_to_bucket
 from trlx_tpu.parallel import mesh as mesh_lib
@@ -911,10 +911,10 @@ class PPOTrainer(MeshRLTrainer):
                 smask = np.concatenate([q_mask, r_mask], axis=1)
                 dbatch = mesh_lib.put_batch(self.mesh, {"seq": seq, "mask": smask})
                 with self.mesh, compile_log.attributed("ppo_score"):
-                    logprobs, values, ref_logprobs = score_fn(
-                        self.params, self._ref_scoring_params(), self.frozen_branch_params,
-                        dbatch["seq"], dbatch["mask"],
-                    )
+                    args = (self.params, self._ref_scoring_params(), self.frozen_branch_params,
+                            dbatch["seq"], dbatch["mask"])
+                    op_scopes.note("ppo_score", score_fn, args, mesh=self.mesh)
+                    logprobs, values, ref_logprobs = score_fn(*args)
             window.note_work(t0, time.perf_counter())
             inflight[0] = (items, scores, dense_scores, r_mask, logprobs, values, ref_logprobs)
             if serialize:
@@ -1283,19 +1283,19 @@ class PPOTrainer(MeshRLTrainer):
                     self.mesh, {"q": q_ids, "qm": q_mask, "r": r_ids, "rm": r_mask}
                 )
                 with self.mesh, compile_log.attributed("ppo_score"):
-                    logprobs, values, ref_logprobs = score_fn(
-                        policy_params, self._ref_scoring_params(), self.frozen_branch_params,
-                        dbatch["q"], dbatch["qm"], dbatch["r"], dbatch["rm"],
-                    )
+                    args = (policy_params, self._ref_scoring_params(), self.frozen_branch_params,
+                            dbatch["q"], dbatch["qm"], dbatch["r"], dbatch["rm"])
+                    op_scopes.note("ppo_score", score_fn, args, mesh=self.mesh)
+                    logprobs, values, ref_logprobs = score_fn(*args)
             else:
                 seq = np.concatenate([q_ids, r_ids], axis=1)
                 mask = np.concatenate([q_mask, r_mask], axis=1)
                 dbatch = mesh_lib.put_batch(self.mesh, {"seq": seq, "mask": mask})
                 with self.mesh, compile_log.attributed("ppo_score"):
-                    logprobs, values, ref_logprobs = score_fn(
-                        policy_params, self._ref_scoring_params(), self.frozen_branch_params,
-                        dbatch["seq"], dbatch["mask"],
-                    )
+                    args = (policy_params, self._ref_scoring_params(), self.frozen_branch_params,
+                            dbatch["seq"], dbatch["mask"])
+                    op_scopes.note("ppo_score", score_fn, args, mesh=self.mesh)
+                    logprobs, values, ref_logprobs = score_fn(*args)
             logprobs = np.asarray(jax.device_get(logprobs))
             values = np.asarray(jax.device_get(values))
             ref_logprobs = np.asarray(jax.device_get(ref_logprobs))
@@ -1589,7 +1589,8 @@ class PPOTrainer(MeshRLTrainer):
                 logits, values_pred, _ = module.apply(
                     {"params": params}, mb.query_tensors, mb.attention_mask, dec_in, dec_mask
                 )
-                logprobs = logprobs_of_labels(logits, mb.response_tensors)
+                with jax.named_scope("logprobs"):
+                    logprobs = logprobs_of_labels(logits, mb.response_tensors)
                 values_pred = values_pred.astype(jnp.float32)
                 advantages, returns = method.get_advantages_and_returns(
                     mb.values, mb.rewards, mb.response_mask
@@ -1622,7 +1623,8 @@ class PPOTrainer(MeshRLTrainer):
                 hidden, values_pred, _, _ = module.apply({"params": params}, seq, mask, with_head=False)
             start = mb.query_tensors.shape[1] - 1
             Rr = mb.response_tensors.shape[1]
-            logprobs = response_logprobs(hidden, head_of(module, params), seq, start, Rr)
+            with jax.named_scope("logprobs"):  # the head over the response window, as in the scorer
+                logprobs = response_logprobs(hidden, head_of(module, params), seq, start, Rr)
             values_pred = values_pred[:, start : start + Rr].astype(jnp.float32)
             advantages, returns = method.get_advantages_and_returns(
                 mb.values, mb.rewards, mb.response_mask
@@ -1671,6 +1673,8 @@ class PPOTrainer(MeshRLTrainer):
         )
         t_learn0 = time.monotonic()
         with span("learn.step"), self.mesh, compile_log.attributed(self.train_step_name):  # dispatch
+            # (under the health guard ``step`` is a plain wrapper, which notes the jitted step it calls)
+            op_scopes.note(self.train_step_name, step, (self.params, self.opt_state, dbatch), mesh=self.mesh)
             self.params, self.opt_state, stats = step(self.params, self.opt_state, dbatch)
         with span("learn.sync"):  # the host waiting for the device
             out = {k: float(v) for k, v in jax.device_get(stats).items()}
