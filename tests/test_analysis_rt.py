@@ -110,6 +110,27 @@ def test_sh001_quantized_through_ladder_is_clean(tmp_path):
     assert findings == []
 
 
+def test_sh001_quantized_through_a_capped_ladder_is_clean(tmp_path):
+    findings = check_snippet(
+        tmp_path,
+        """
+        import jax
+        import jax.numpy as jnp
+
+        from trlx_tpu.ops.generation import pad_to_bucket
+
+        step = jax.jit(lambda x: x * 2)
+
+        def feed(items, longest_allowed):
+            n = pad_to_bucket(len(items), (8, 16, 32), cap=longest_allowed)
+            buf = jnp.zeros((n, 4), jnp.float32)
+            return step(buf)
+        """,
+        select=("SH001",),
+    )
+    assert findings == []
+
+
 def test_sh001_raw_len_inline_in_ctor(tmp_path):
     findings = check_snippet(
         tmp_path,

@@ -167,21 +167,21 @@ def _model_grad():
 
 
 # the four benchmark cells' trunks at their published widths, a layer or two (ouro: one layer, two passes):
-# (config, learner [B, T], scorer [B, T] at its buckets)
+# (config, learner [B, T], scorer [B, T]: its buckets stop at the longest prompt and response the cell allows)
 def _cell_models():
     gpt2 = PRESETS["gpt2"]
     return {
-        "gpt2": (gpt2.replace(num_layers=2), (32, 513), (32, 576)),
-        "gpt2-medium": (gpt2.replace(num_layers=1, hidden_size=1024, num_heads=16), (2, 513), (32, 640)),
+        "gpt2": (gpt2.replace(num_layers=2), (32, 513), (32, 513)),
+        "gpt2-medium": (gpt2.replace(num_layers=1, hidden_size=1024, num_heads=16), (2, 513), (32, 513)),
         # the leading dense layer: latent attention, keys 192 wide and values 128
-        "kimi-vl-a3b": (PRESETS["kimi_vl"].replace(num_layers=1, vocab_size=20480), (4, 513), (32, 576)),
-        "ouro-2.6b": (PRESETS["ouro"].replace(num_layers=1, loop_steps=2), (8, 257), (32, 320)),
+        "kimi-vl-a3b": (PRESETS["kimi_vl"].replace(num_layers=1, vocab_size=20480), (4, 513), (32, 513)),
+        "ouro-2.6b": (PRESETS["ouro"].replace(num_layers=1, loop_steps=2), (8, 257), (32, 257)),
         # a convolution layer and the attention layer (32 / 8 heads of 64, a norm on each head), both over the
         # dense FFN: the experts' grouped products have cases of their own
         "lfm2-24b-a2b": (
             PRESETS["lfm2_moe"].replace(
                 num_layers=2, layer_kinds=("conv", "attention"), first_dense_layers=2, vocab_size=8192),
-            (8, 513), (32, 576)),
+            (8, 513), (32, 513)),
     }
 
 
@@ -291,9 +291,11 @@ CASES = {
     "flash_grad-transformer_lm-attn_names": _model_grad,
     "flash_grad-float32-2x12x513x64": functools.partial(_flash, True, (2, 12, 513, 64), None, jnp.float32),
 }
-# [B, H, T, D] of the benchmark's cells: learner (gpt2, gpt2-medium), scoring, prefill
+# [B, H, T, D] of the benchmark's cells: learner (gpt2, gpt2-medium), scoring at the rungs (a chunk short of
+# the caps), prefill; and scoring at the caps
 CELL_SHAPES = [
     (32, 12, 513, 64), (2, 16, 513, 64), (32, 12, 576, 64), (32, 16, 640, 64), (128, 12, 64, 64), (64, 16, 512, 64),
+    (32, 16, 513, 64),
 ]
 # no cell runs these yet: long contexts at D = 128, multi-head, grouped (rep 4) and multi-query
 LONG_SHAPES = [((1, 16, T, 128), kv_heads) for T in (2048, 8192) for kv_heads in (16, 4, 1)]
@@ -305,15 +307,15 @@ for _shape, _kv_heads in [(s, None) for s in CELL_SHAPES] + LONG_SHAPES:
 for _T in (513, 576, 640):
     CASES[f"flash_fwd-4x16x{_T}x192-v128"] = functools.partial(_flash, False, (4, 16, _T, 192), None, jnp.bfloat16, 128)
     CASES[f"flash_grad-4x16x{_T}x192-v128"] = functools.partial(_flash, True, (4, 16, _T, 192), None, jnp.bfloat16, 128)
-# D = 128 at the ouro-2.6b cell's shapes: a learner microbatch (8 x 257), a scoring chunk at its buckets (64 + 256),
-# the prefill of 128 prompts; the (pass, layer) loop calls them 20 times a forward at one shape
-for _shape in ((8, 16, 257, 128), (32, 16, 320, 128), (128, 16, 64, 128)):
+# D = 128 at the ouro-2.6b cell's shapes: a learner microbatch (8 x 257), a scoring chunk at the rungs (64 + 256)
+# and at the caps (64 + 193), the prefill of 128 prompts; the (pass, layer) loop calls them 20 times a forward
+for _shape in ((8, 16, 257, 128), (32, 16, 320, 128), (128, 16, 64, 128), (32, 16, 257, 128)):
     _name = "x".join(map(str, _shape))
     CASES[f"flash_fwd-{_name}"] = functools.partial(_flash, False, _shape)
     CASES[f"flash_grad-{_name}"] = functools.partial(_flash, True, _shape)
 # 32 query heads over 8 key/value heads of 64 at the lfm2-24b-a2b cell's shapes: a learner microbatch
-# (8 x 513), a scoring chunk at its buckets (64 + 512), the prefill of 128 prompts
-for _shape in ((8, 32, 513, 64), (32, 32, 576, 64), (128, 32, 64, 64)):
+# (8 x 513), a scoring chunk at the rungs (64 + 512) and at the caps (64 + 449), the prefill of 128 prompts
+for _shape in ((8, 32, 513, 64), (32, 32, 576, 64), (128, 32, 64, 64), (32, 32, 513, 64)):
     _name = "x".join(map(str, _shape)) + "-hkv8"
     CASES[f"flash_fwd-{_name}"] = functools.partial(_flash, False, _shape, 8)
     CASES[f"flash_grad-{_name}"] = functools.partial(_flash, True, _shape, 8)

@@ -197,6 +197,16 @@ def test_pad_to_bucket():
     assert pad_to_bucket(40, [8, 16]) == 64
 
 
+@pytest.mark.parametrize(
+    "length,cap,padded",
+    [(9, 9, 9), (5, 9, 8), (8, 8, 8), (40, 50, 50), (10, 9, 16), (9, None, 16), (1, 0, 8)],
+)
+def test_pad_to_bucket_stops_at_its_cap(length, cap, padded):
+    """A length within the cap pads to the rung or the cap, whichever is smaller; one past the
+    cap, or with no cap, pads to the rung."""
+    assert pad_to_bucket(length, [8, 16], cap=cap) == padded
+
+
 @pytest.mark.parametrize("layout", ["list", "stacked", "gqa"])
 def test_int8_kv_cache_decode_matches_fp_cache(layout):
     """kv_cache_quant=True: decode over an int8 KV cache (per-row symmetric

@@ -5,7 +5,9 @@ Parity: its values and gradients are those of the spelling it replaced,
 logits of every position. Structure: the learner's and the scorer's programs
 hold no ``[·, P+R, V]`` array, the learner's backward keeps no float32 array as
 wide as the vocabulary, and the two gauges say how much of the forward's rows
-the head was taken over."""
+the head was taken over. The scorer's shapes: each axis pads to its rung of the
+length ladder and no further than the longest length the configuration allows,
+and the positions that takes away reach no stored number."""
 
 import numpy as np
 import pytest
@@ -186,10 +188,10 @@ def test_hydra_branch_hands_over_hidden_states_and_its_own_head():
 # ------------------------------------------------------------------ structure
 
 ALPHABET = "abcdefgh "
-B_, P_, R_ = 4, 8, 8  # prompt bucket 8; 6 new tokens and the re-appended eos pad to 8
+B_, P_, R_ = 4, 8, 8  # prompt bucket 8; a response bucket of 8 for the programs lowered by hand
 
 
-def ppo_config(tmp_path, **model):
+def ppo_config(tmp_path, seq_length=16, max_new_tokens=6, compute_dtype="bfloat16", **model):
     model = model or dict(
         model_path="gpt2", num_layers_unfrozen=-1,
         model_overrides=dict(vocab_size=len(ALPHABET) + 3, hidden_size=32, num_layers=2, num_heads=2,
@@ -198,10 +200,11 @@ def ppo_config(tmp_path, **model):
     return TRLConfig(
         method=PPOConfig(
             num_rollouts=B_, chunk_size=B_, ppo_epochs=1, init_kl_coef=0.01, target=None,
-            gen_kwargs=dict(max_new_tokens=6, min_new_tokens=6, do_sample=True, top_k=0, top_p=1.0),
+            gen_kwargs=dict(max_new_tokens=max_new_tokens, min_new_tokens=max_new_tokens, do_sample=True,
+                            top_k=0, top_p=1.0),
         ),
         train=TrainConfig(
-            seq_length=16, epochs=1, total_steps=1, batch_size=B_, minibatch_size=B_ // 2,
+            seq_length=seq_length, epochs=1, total_steps=1, batch_size=B_, minibatch_size=B_ // 2,
             checkpoint_interval=10 ** 9, eval_interval=10 ** 9, checkpoint_dir=str(tmp_path / "ckpts"),
             pipeline="PromptPipeline", trainer="PPOTrainer", tracker=None, seed=2,
         ),
@@ -209,7 +212,7 @@ def ppo_config(tmp_path, **model):
         tokenizer=TokenizerConfig(tokenizer_path=f"char://{ALPHABET}"),
         optimizer=OptimizerConfig(name="adamw", kwargs=dict(lr=1e-3)),
         scheduler=SchedulerConfig(name="cosine_annealing", kwargs=dict(T_max=100, eta_min=1e-3)),
-        mesh=MeshConfig(data=1, fsdp=1, model=1, compute_dtype="bfloat16"),
+        mesh=MeshConfig(data=1, fsdp=1, model=1, compute_dtype=compute_dtype),
     )
 
 
@@ -244,11 +247,16 @@ def trained(tmp_path_factory):
 
 def test_gauges_after_a_tiny_ppo_run_give_the_window_s_share(trained):
     """Rows the head is taken over ÷ rows the forward runs, of the shapes the run compiled: the
-    scorer's responses are padded to their bucket, the store's to the longest."""
+    scorer's responses are padded to their bucket, which stops at the longest response the
+    configuration allows (6 new tokens and the re-appended eos), the store's to the longest."""
     trainer, read = trained
     ((_, P, R),), ((_, score_P, score_R),) = trainer._train_steps, trainer._score_fns
-    assert (P, score_P, score_R) == (P_, P_, R_) and 6 < R <= R_
-    assert read == {"learn/head_rows_share": R / (P + R), "score/head_rows_share": R_ / (P_ + R_)}
+    assert (P, score_P) == (P_, P_) and R == score_R == 7
+    assert read == {
+        "learn/head_rows_share": R / (P + R),
+        "score/head_rows_share": score_R / (score_P + score_R),
+        "score/padded_positions_share": 0.0,
+    }
 
 
 def test_gauges_read_one_for_seq2seq(tmp_path, one_device):
@@ -328,3 +336,91 @@ def test_learner_s_backward_keeps_no_float32_array_as_wide_as_the_vocabulary(tra
     # the window's logits as the head gave them (beside the head's weights in the compute dtype)
     assert ("bf16", f"{B_ // 2},{R_},{vocab}") in wide, wide
     assert all(dtype == "bf16" for dtype, _ in wide), wide
+
+
+# ------------------------------------------------------- the scorer's shapes
+
+SEQ, NEW = 24, 8  # prompts are cut to 16 tokens; a response is 8 tokens and the re-appended eos
+
+
+@pytest.fixture(scope="module")
+def scorer(tmp_path_factory):
+    """A float32 trainer whose scoring forward is called by hand, with a recorder of the shapes it asks for."""
+    from trlx_tpu.trainer.ppo_trainer import PPOTrainer
+
+    config = ppo_config(tmp_path_factory.mktemp("scorer"), seq_length=SEQ, max_new_tokens=NEW, compute_dtype="float32")
+    with pytest.MonkeyPatch.context() as patch:
+        _mesh_of_one_device(patch)
+        trainer = PPOTrainer(config=config, reward_fn=lambda samples, **kwargs: [0.0] * len(samples))
+    keys = []
+    build = trainer._get_score_fn
+    trainer._get_score_fn = lambda B, P, R, **kw: keys.append((B, P, R)) or build(B, P, R, **kw)
+    return trainer, keys
+
+
+def _chunk(longest_p, longest_r):
+    """Four rows of different lengths, the longest prompt and response as given (token ids drawn from the seed)."""
+    rng = np.random.default_rng(longest_p * 100 + longest_r)
+    p_lens = [longest_p, max(1, longest_p - 3), max(1, longest_p - 1), 1]
+    r_lens = [max(1, longest_r - 2), longest_r, 1, max(1, longest_r - 1)]
+    prompts = [rng.integers(3, len(ALPHABET) + 3, n).tolist() for n in p_lens]
+    responses = [rng.integers(3, len(ALPHABET) + 3, n).astype(np.int32) for n in r_lens]
+    return prompts, responses
+
+
+def _score(trainer, chunk):
+    elements = []
+    gauges.clear("score/")
+    trainer._score_and_store(chunk, [0.5, -1.0, 2.0, 0.25], elements, [], [])
+    return elements, gauges.snapshot("score/")["score/padded_positions_share"]
+
+
+@pytest.mark.parametrize(
+    "longest_p,longest_r,key",
+    [(16, NEW + 1, (16, NEW + 1)), (16, 5, (16, 8)), (20, NEW + 1, (32, NEW + 1))],
+    ids=["fixed_length_stops_at_the_caps", "response_below_the_cap_s_rung_keeps_the_rung",
+         "prompt_past_its_cap_pads_to_the_rung"],
+)
+def test_score_shape_is_the_rung_stopped_at_the_configuration_s_caps(scorer, longest_p, longest_r, key):
+    """``seq_length`` 24 and ``max_new_tokens`` 8 cap the prompt at 16 and the response at 9: a chunk that
+    reaches a cap is scored at it, not at the next rung (16); one that stops below the cap's rung keeps the
+    rung; a prompt longer than its cap (a caller that did not truncate) pads to the rung and is never cut."""
+    trainer, keys = scorer
+    chunk = _chunk(longest_p, longest_r)
+    keys.clear()
+    elements, padded_share = _score(trainer, chunk)
+    assert keys == [(4, *key)]
+    assert padded_share == pytest.approx(1.0 - (longest_p + longest_r) / sum(key))
+    for e, prompt, response in zip(elements, *chunk):
+        np.testing.assert_array_equal(e.query_tensor, prompt)
+        np.testing.assert_array_equal(e.response_tensor, response)
+        assert e.logprobs.shape == e.values.shape == e.rewards.shape == response.shape
+        assert np.isfinite(e.logprobs).all() and np.isfinite(e.values).all()
+
+
+@pytest.mark.parametrize("caps", [(16, 9), (12, 9), (20, 33), (8, 8)], ids=lambda c: f"caps{c[0]}x{c[1]}")
+def test_capped_ladder_gives_no_more_score_shapes_than_its_rungs(caps):
+    """Over every length the caps allow, the capped ladder's (P, R) keys are no more than the rungs'."""
+    from trlx_tpu.ops.generation import LENGTH_BUCKETS, pad_to_bucket
+
+    lengths = [(p, r) for p in range(1, caps[0] + 1) for r in range(1, caps[1] + 1)]
+    capped = {tuple(pad_to_bucket(n, LENGTH_BUCKETS, cap=c) for n, c in zip(pr, caps)) for pr in lengths}
+    rungs = {tuple(pad_to_bucket(n, LENGTH_BUCKETS) for n in pr) for pr in lengths}
+    assert len(capped) <= len(rungs)
+    assert all(p <= caps[0] and r <= caps[1] for p, r in capped)
+
+
+def test_stored_experience_at_the_capped_shape_is_the_rung_shape_s(scorer, monkeypatch):
+    """The positions the cap takes away hold no token: the stored log-probabilities, values and KL-penalised
+    rewards are those of today's rung shape on the same rows (float32)."""
+    trainer, keys = scorer
+    chunk = _chunk(16, NEW + 1)
+    keys.clear()
+    capped, capped_share = _score(trainer, chunk)
+    monkeypatch.setattr(trainer, "_length_caps", lambda: (None, None))
+    rung, rung_share = _score(trainer, chunk)
+    assert keys == [(4, 16, NEW + 1), (4, 16, 16)]
+    assert capped_share == 0.0 and rung_share == pytest.approx(1.0 - 25 / 32)
+    for a, b in zip(capped, rung):
+        for name in ("logprobs", "values", "rewards"):
+            np.testing.assert_allclose(getattr(a, name), getattr(b, name), rtol=1e-5, atol=1e-6, err_msg=name)

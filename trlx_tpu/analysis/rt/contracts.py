@@ -100,13 +100,18 @@ register_shape_contract(ShapeContract(
 ))
 
 #: The one-shot/serving prompt-length families: prompts pad onto the shared
-#: pow2 bucket list before any prefill or generate compile.
+#: pow2 bucket list before any prefill or generate compile. ``pad_to_bucket``'s
+#: ``cap`` (the longest length a configuration allows) only lowers a rung to
+#: that cap, so the family stays within the ladder's rungs and the one cap.
 register_shape_contract(ShapeContract(
     name="prompt_buckets",
     module="trlx_tpu.ops.generation",
     quantizers=("pad_to_bucket", "left_pad_batch"),
     max_shapes=8,
-    description="prompt lengths pad onto the shared pow2 bucket ladder",
+    description=(
+        "prompt lengths pad onto the shared pow2 bucket ladder, "
+        "no rung past the configuration's cap"
+    ),
 ))
 
 #: Serving-engine prefill waves: admission groups compile one wave program

@@ -1045,6 +1045,12 @@ class TRLConfig:
     train: TrainConfig
     mesh: MeshConfig = field(default_factory=MeshConfig)
 
+    @property
+    def max_prompt_length(self) -> int:
+        """The longest prompt a run keeps: ``trlx.train`` truncates every prompt to
+        it, so that prompt and generation fit ``seq_length``."""
+        return self.train.seq_length - self.method.gen_kwargs.get("max_new_tokens", 0)
+
     @classmethod
     def load_yaml(cls, yml_fp: str):
         with open(yml_fp) as f:

@@ -33,13 +33,16 @@ StepFn = Callable[..., Tuple[jnp.ndarray, jnp.ndarray, Any]]
 LENGTH_BUCKETS = tuple(2 ** i for i in range(3, 14))
 
 
-def pad_to_bucket(length: int, buckets: Sequence[int]) -> int:
+def pad_to_bucket(length: int, buckets: Sequence[int], cap: Optional[int] = None) -> int:
     """Smallest bucket >= length (limits recompilation across prompt lengths;
-    parity concern: reference pads to multiples of 8, SURVEY.md §7 hard-part 3)."""
-    for b in sorted(buckets):
-        if b >= length:
-            return b
-    return int(np.ceil(length / 64) * 64)
+    parity concern: reference pads to multiples of 8, SURVEY.md §7 hard-part 3).
+
+    ``cap`` is the longest length the caller's configuration allows on this
+    axis: a rung above it adds no shape that could be needed, so a length
+    within the cap pads to ``min(rung, cap)``. A length past the cap (a caller
+    that did not hold to it) pads to the rung, never below itself."""
+    rung = next((b for b in sorted(buckets) if b >= length), int(np.ceil(length / 64) * 64))
+    return min(rung, cap) if cap is not None and length <= cap else rung
 
 
 def left_pad_batch(

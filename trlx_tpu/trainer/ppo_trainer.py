@@ -1233,6 +1233,13 @@ class PPOTrainer(MeshRLTrainer):
         with span("reward"):
             return self.reward_fn(**kwargs)
 
+    def _length_caps(self):
+        """The longest prompt and response the configuration allows: ``trlx.train``
+        truncates every prompt to ``max_prompt_length``, and a response is the
+        experience generation's ``max_new_tokens`` and the re-appended eos."""
+        gen_kwargs = self.generate_experience_kwargs or self.generate_kwargs
+        return self.config.max_prompt_length, int(gen_kwargs.get("max_new_tokens", 16)) + 1
+
     def _score_and_store(
         self, chunk, scores, ppo_rl_elements, accumulated_kl, all_scores_log, params=None
     ):
@@ -1263,11 +1270,14 @@ class PPOTrainer(MeshRLTrainer):
         elif self.method.scale_reward == "ref":
             scores = scores / max(self.method.ref_std or 1.0, 1e-8)
 
-        # fixed-shape scoring forward
-        P = max(len(p) for p in prompts)
-        R = max(len(o) for o in out_ids)
-        P = pad_to_bucket(P, LENGTH_BUCKETS)
-        R = pad_to_bucket(R, LENGTH_BUCKETS)
+        # fixed-shape scoring forward: each axis pads to its rung of the ladder,
+        # and no further than the longest length the configuration allows
+        longest_p = max(len(p) for p in prompts)
+        longest_r = max(len(o) for o in out_ids)
+        p_cap, r_cap = self._length_caps()
+        P = pad_to_bucket(longest_p, LENGTH_BUCKETS, cap=p_cap)
+        R = pad_to_bucket(longest_r, LENGTH_BUCKETS, cap=r_cap)
+        gauges.set("score/padded_positions_share", 1.0 - (longest_p + longest_r) / (P + R))
         q_ids, q_mask = left_pad_batch(prompts, self.tokenizer.pad_token_id, P)
         r_ids = np.full((len(out_ids), R), self.tokenizer.pad_token_id, np.int32)
         r_mask = np.zeros((len(out_ids), R), np.int32)

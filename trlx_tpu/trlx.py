@@ -102,7 +102,7 @@ def train(
     )
 
     batch_size = config.train.batch_size
-    max_prompt_length = config.train.seq_length - config.method.gen_kwargs.get("max_new_tokens", 0)
+    max_prompt_length = config.max_prompt_length
 
     # online RL (PPO / GRPO / RFT): prompts + reward_fn (an environment was
     # adapted into reward_fn above)
