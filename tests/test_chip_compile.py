@@ -335,6 +335,38 @@ CASES["decode-32x32x1024x128-hkv8"] = functools.partial(_decode, (32, 32, 1024, 
 CASES["decode-128x16x256x128"] = functools.partial(_decode, (128, 16, 256, 128))
 # the lfm2-24b-a2b cell's: the one attention layer's cache, 8 kv heads of 64 under 32 query heads
 CASES["decode-128x32x512x64-hkv8"] = functools.partial(_decode, (128, 32, 512, 64), 8)
+
+
+def _sparse(kind, B, T):
+    """The learned sparse attention's kernels at the keye-vl-2.0-30b-a3b cell's widths (32 / 4 heads of 128, an
+    indexer of 16 heads of 64 over one key head, 2,048 kept): the selection, or attention over it with its gradient."""
+    from trlx_tpu.ops import sparse_attention as dsa
+
+    if kind == "select":
+        def fn(qi, ki, w, valid):
+            return dsa.select_keys(qi, ki, w, valid, 2048, False)
+
+        return fn, [((B, T, 16, 64), jnp.bfloat16), ((B, T, 64), jnp.bfloat16), ((B, T, 16), jnp.float32),
+                    ((B, T), jnp.int32)]
+    Tp, Sp = dsa.padded_lengths(T, T)
+
+    def fn(q, k, v, mask):
+        blocks = dsa.block_table(mask)
+        attend = lambda q, k, v: dsa.sparse_attention(q, k, v, mask, blocks, 128 ** -0.5, False)
+        if kind == "attn_fwd":
+            return attend(q, k, v)
+        return jax.grad(lambda q, k, v: attend(q, k, v).astype(jnp.float32).sum(), (0, 1, 2))(q, k, v)
+
+    return fn, [((B, T, 32, 128), jnp.bfloat16), ((B, T, 4, 128), jnp.bfloat16), ((B, T, 4, 128), jnp.bfloat16),
+                ((B, Tp, Sp), jnp.int8)]
+
+
+# learned sparse attention at the keye-vl-2.0-30b-a3b cell's shapes: a learner microbatch and a scoring chunk
+# (one row of 7,680 + 513), the generator's prefill (16 rows of 8,192: 7,680 padded to the ladder's rung)
+CASES["sparse_select-1x8193"] = functools.partial(_sparse, "select", 1, 8193)
+CASES["sparse_attn_grad-1x8193"] = functools.partial(_sparse, "attn_grad", 1, 8193)
+CASES["sparse_select-16x8192"] = functools.partial(_sparse, "select", 16, 8192)
+CASES["sparse_attn_fwd-16x8192"] = functools.partial(_sparse, "attn_fwd", 16, 8192)
 for _preset in ("gpt2", "gpt_bigcode"):  # Hkv=12 rep=1 D=64; Hkv=1 rep=16 D=128
     for _pool, _quant in (("bf16", False), ("int8", True)):
         CASES[f"paged_decode-{_pool}-{_preset}"] = functools.partial(
@@ -387,6 +419,13 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_persistent_cache, monkeypatc
             handed = re.search(r"operand_layout_constraints={(.*?)}, \w+=", call).group(1)
             lanes = [int(n) for n in re.findall(r"bf16\[\d+,\d+,\d+,(\d+)\]{3,2,1,0}", handed)]
             assert len(lanes) == 3 and min(lanes) >= 128, handed  # q, k, v
+    if case.startswith("sparse_attn"):
+        # one named family, as benchmark/metrics/sparse_attn_roofline.json matches it, and none that
+        # flash_attn_roofline's pattern would take
+        names = _attention_instructions(text)
+        ours = [n for n in names if re.match(r"^%(\w*_)?sparse_attn[_.0-9]* custom-call$", n)]
+        assert len(ours) == len(names) == (3 if "grad" in case else 1), names
+        assert not any(re.match(r"^%attn[.0-9]* custom-call$", n) for n in names), names
     if case.endswith("attn_names"):
         names = _attention_instructions(text)
         assert len(names) == 6, names  # forward, dkv and dq of two layers

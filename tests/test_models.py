@@ -25,7 +25,13 @@ TINY = dict(
 
 
 def tiny_config(family: str) -> TransformerConfig:
-    return PRESETS[family].replace(**TINY)
+    """The family at tiny sizes; a preset that fixes more kv heads than the two
+    query heads keeps one for each of them, and one with more than 8 experts
+    holds 8 of them (the router keeps its width, as a chip's share does)."""
+    preset = PRESETS[family]
+    grouped = {"num_kv_heads": TINY["num_heads"]} if (preset.num_kv_heads or 0) > TINY["num_heads"] else {}
+    held = {"experts_held": 8} if preset.num_experts > 8 else {}
+    return preset.replace(**TINY, **grouped, **held)
 
 
 @pytest.mark.parametrize("family", sorted(PRESETS))

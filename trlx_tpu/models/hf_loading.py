@@ -669,6 +669,71 @@ def _lfm2_from_params(p, c):
     return sd
 
 
+#: Keye-VL-2.0's language model (Qwen3-MoE's names): this program's attention module name -> the published one
+_KEYE_ATTENTION = tuple((ours, f"self_attn.{ours}") for ours in ("q_proj", "k_proj", "v_proj", "o_proj"))
+#: the indexer's names, which no published file of ours shows: assumed after DeepSeek-V3.2's (``wq``, ``wk``,
+#: ``k_norm``, ``weights_proj``) under ``self_attn.indexer`` (benchmark/configs/keye-vl-2.0-30b-a3b.json: assumed)
+_KEYE_INDEXER = ("wq", "wk", "weights_proj")
+_KEYE_FFN = ("gate", "up", "down")
+
+
+def _keye_to_params(sd, c):
+    """Keye-VL-2.0's language model (``KeyeVL2``) under Qwen3-MoE's names: ``model.layers.N.self_attn``
+    with q / k norms and the indexer, ``mlp.gate`` the softmax router, ``mlp.experts.E.{gate,up,down}_proj``.
+    Only the experts held (``experts_held`` from ``expert_offset``) and the first ``vocab_size`` rows of
+    the vocabulary are taken."""
+    p = {
+        "embed_tokens": {"embedding": sd["model.embed_tokens.weight"][: c.vocab_size]},
+        "ln_f": {"scale": sd["model.norm.weight"]},
+    }
+    if not c.tie_word_embeddings:
+        p["lm_head"] = {"kernel": sd["lm_head.weight"][: c.vocab_size].T}
+    held = range(c.expert_offset, c.expert_offset + c.held_experts)
+    for i in range(c.num_layers):
+        pre = f"model.layers.{i}"
+        attn = {ours: _linear(sd, f"{pre}.{theirs}") for ours, theirs in _KEYE_ATTENTION}
+        attn["q_norm"] = {"scale": sd[f"{pre}.self_attn.q_norm.weight"]}
+        attn["k_norm"] = {"scale": sd[f"{pre}.self_attn.k_norm.weight"]}
+        attn["indexer"] = {name: _linear(sd, f"{pre}.self_attn.indexer.{name}") for name in _KEYE_INDEXER}
+        attn["indexer"]["k_norm"] = {"scale": sd[f"{pre}.self_attn.indexer.k_norm.weight"],
+                                     "bias": sd[f"{pre}.self_attn.indexer.k_norm.bias"]}
+        p[f"layers_{i}"] = {
+            "ln_1": {"scale": sd[f"{pre}.input_layernorm.weight"]},
+            "ln_2": {"scale": sd[f"{pre}.post_attention_layernorm.weight"]},
+            "attn": attn,
+            "mlp": {
+                "router": {"kernel": sd[f"{pre}.mlp.gate.weight"].T},
+                "experts": {ours: np.stack([sd[f"{pre}.mlp.experts.{e}.{ours}_proj.weight"].T for e in held])
+                            for ours in _KEYE_FFN},
+            },
+        }
+    return p
+
+
+def _keye_from_params(p, c):
+    """The held experts go out under their published indices, from ``expert_offset`` on."""
+    sd = {"model.embed_tokens.weight": p["embed_tokens"]["embedding"], "model.norm.weight": p["ln_f"]["scale"]}
+    if "lm_head" in p:
+        sd["lm_head.weight"] = p["lm_head"]["kernel"].T
+    for i in range(c.num_layers):
+        L, pre = p[f"layers_{i}"], f"model.layers.{i}"
+        sd[f"{pre}.input_layernorm.weight"] = L["ln_1"]["scale"]
+        sd[f"{pre}.post_attention_layernorm.weight"] = L["ln_2"]["scale"]
+        for ours, theirs in _KEYE_ATTENTION:
+            sd[f"{pre}.{theirs}.weight"] = L["attn"][ours]["kernel"].T
+        sd[f"{pre}.self_attn.q_norm.weight"] = L["attn"]["q_norm"]["scale"]
+        sd[f"{pre}.self_attn.k_norm.weight"] = L["attn"]["k_norm"]["scale"]
+        for name in _KEYE_INDEXER:
+            sd[f"{pre}.self_attn.indexer.{name}.weight"] = L["attn"]["indexer"][name]["kernel"].T
+        sd[f"{pre}.self_attn.indexer.k_norm.weight"] = L["attn"]["indexer"]["k_norm"]["scale"]
+        sd[f"{pre}.self_attn.indexer.k_norm.bias"] = L["attn"]["indexer"]["k_norm"]["bias"]
+        sd[f"{pre}.mlp.gate.weight"] = L["mlp"]["router"]["kernel"].T
+        for ours in _KEYE_FFN:
+            for e, kernel in enumerate(L["mlp"]["experts"][ours], start=c.expert_offset):
+                sd[f"{pre}.mlp.experts.{e}.{ours}_proj.weight"] = kernel.T
+    return sd
+
+
 CONVERTERS = {
     "gpt2": (_gpt2_to_params, _gpt2_from_params),
     "llama": (_llama_to_params, _llama_from_params),
@@ -681,6 +746,9 @@ CONVERTERS = {
     "kimi_vl": (_kimi_to_params, None),
     "ouro": (_ouro_to_params, _ouro_from_params),
     "lfm2_moe": (_lfm2_to_params, _lfm2_from_params),
+    # the preset's family name and the published model_type
+    "keye_vl": (_keye_to_params, _keye_from_params),
+    "KeyeVL2": (_keye_to_params, _keye_from_params),
 }
 # "t5" is registered below once its converters are defined (seq2seq section)
 
@@ -782,10 +850,11 @@ def load_pretrained(
 
 def _family_of(name: str) -> str:
     key = name.lower().replace("-", "").replace("_", "")
-    for family in ("gptbigcode", "gptneox", "gptj", "gpt2", "llama", "opt", "bloom", "kimivl", "ouro", "lfm2moe"):
+    for family in ("gptbigcode", "gptneox", "gptj", "gpt2", "llama", "opt", "bloom", "kimivl", "ouro", "lfm2moe",
+                   "keyevl"):
         if family in key:
             return {"gptneox": "gpt_neox", "gptbigcode": "gpt_bigcode", "kimivl": "kimi_vl",
-                    "lfm2moe": "lfm2_moe"}.get(family, family)
+                    "lfm2moe": "lfm2_moe", "keyevl": "keye_vl"}.get(family, family)
     if "pythia" in key or "neox" in key:
         return "gpt_neox"
     if "starcoder" in key or "santacoder" in key:
@@ -888,6 +957,20 @@ def make_hf_config(model_type: str, c: TransformerConfig):
             num_experts=c.num_experts, num_experts_per_tok=c.experts_per_token, norm_topk_prob=c.norm_topk_prob,
             routed_scaling_factor=c.routed_scaling_factor, use_expert_bias=True,
             tie_word_embeddings=c.tie_word_embeddings,
+        )
+    if model_type in ("keye_vl", "KeyeVL2"):
+        # the published keys of the language model, whether or not this transformers has the class
+        published = type("KeyeVL2Config", (transformers.PretrainedConfig,), {"model_type": "KeyeVL2"})
+        return published(
+            vocab_size=c.vocab_size, hidden_size=c.hidden_size, num_hidden_layers=c.num_layers,
+            num_attention_heads=c.num_heads, num_key_value_heads=c.kv_heads, head_dim=c.dim_per_head,
+            intermediate_size=c.ffn_dim, moe_intermediate_size=c.moe_intermediate_size,
+            max_position_embeddings=c.max_position_embeddings, rms_norm_eps=c.norm_eps, rope_theta=c.rope_theta,
+            num_experts=c.num_experts, num_experts_per_tok=c.experts_per_token, norm_topk_prob=c.norm_topk_prob,
+            decoder_sparse_step=1, mlp_only_layers=[], use_sliding_window=False,
+            tie_word_embeddings=c.tie_word_embeddings, attention_bias=False, hidden_act="silu",
+            sa_config={"indexer_head_dim": c.index_head_dim, "indexer_num_heads": c.index_heads,
+                       "indexer_num_kv_heads": 1, "topk": c.index_topk},
         )
     if model_type == "t5":
         return transformers.T5Config(

@@ -97,6 +97,20 @@ PRESETS: Dict[str, TransformerConfig] = {
         moe_intermediate_size=1536, first_dense_layers=2, routed_scaling_factor=1.0, norm_topk_prob=True,
         router_norm_eps=1e-6,
     ),
+    # Keye-VL-2.0-30B-A3B's language model (model_type KeyeVL2; the vision tower is not run):
+    # 48 alike layers of grouped-query attention (32 query heads over 4 kv heads of 128, a norm
+    # on every query and key head) over the 2,048 earlier keys a learned indexer picks for each
+    # query (16 heads of 64 over one key head), then 128 softmax-routed experts, 8 a token,
+    # Qwen3-MoE's rule, no shared expert and no dense layer; the head is not tied.
+    "keye_vl": TransformerConfig(
+        vocab_size=151936, hidden_size=2048, num_layers=48, num_heads=32, num_kv_heads=4, head_dim=128,
+        intermediate_size=6144, max_position_embeddings=262144, pos_embedding="rotary",
+        rope_style="neox", rope_theta=10000000.0, norm="rmsnorm", norm_eps=1e-6,
+        activation="silu", glu=True, attn_bias=False, mlp_bias=False, tie_word_embeddings=False,
+        qk_norm=True, num_experts=128, experts_per_token=8, num_shared_experts=0,
+        moe_intermediate_size=768, first_dense_layers=0, routed_scaling_factor=1.0, norm_topk_prob=True,
+        router_scoring="softmax", index_topk=2048, index_heads=16, index_head_dim=64, index_rope_dim=32,
+    ),
 }
 
 #: the published ``layer_types`` -> ``TransformerConfig.layer_kinds``
@@ -111,7 +125,7 @@ def get_preset(name: str, overrides: Optional[Dict[str, Any]] = None) -> Transfo
         config = PRESETS[key]
     else:
         for family in ("gpt_bigcode", "gpt_neox", "gptj", "gpt2", "llama", "opt", "bloom", "kimi_vl", "ouro",
-                       "lfm2_moe"):
+                       "lfm2_moe", "keye_vl"):
             if family.replace("_", "") in key.replace("_", "").replace("-", ""):
                 config = PRESETS[family]
                 break
@@ -255,6 +269,29 @@ def from_hf_config(hf_config, overrides: Optional[Dict[str, Any]] = None) -> Tra
             experts_per_token=hf_config.num_experts_per_tok, moe_intermediate_size=hf_config.moe_intermediate_size,
             first_dense_layers=hf_config.num_dense_layers, routed_scaling_factor=hf_config.routed_scaling_factor,
             norm_topk_prob=hf_config.norm_topk_prob,
+        )
+    elif mt in ("KeyeVL2", "keye_vl2"):
+        text = getattr(hf_config, "text_config", hf_config)  # the language model's keys, nested or not
+        sa = dict(getattr(text, "sa_config", None) or {})
+        unsupported = {
+            "use_sliding_window": bool(getattr(text, "use_sliding_window", False)),
+            "mlp_only_layers": bool(getattr(text, "mlp_only_layers", [])),
+            "decoder_sparse_step other than 1": getattr(text, "decoder_sparse_step", 1) != 1,
+            "indexer_num_kv_heads other than 1": sa.get("indexer_num_kv_heads", 1) != 1,
+            "no sa_config": not sa,
+        }
+        if any(unsupported.values()):
+            raise ValueError(f"{mt}: not supported: {[k for k, v in unsupported.items() if v]}")
+        config = PRESETS["keye_vl"].replace(
+            vocab_size=text.vocab_size, hidden_size=text.hidden_size, num_layers=text.num_hidden_layers,
+            num_heads=text.num_attention_heads, num_kv_heads=text.num_key_value_heads, head_dim=text.head_dim,
+            intermediate_size=text.intermediate_size, max_position_embeddings=text.max_position_embeddings,
+            rope_theta=float(text.rope_theta), norm_eps=text.rms_norm_eps,
+            tie_word_embeddings=getattr(text, "tie_word_embeddings", False),
+            num_experts=text.num_experts, experts_per_token=text.num_experts_per_tok,
+            moe_intermediate_size=text.moe_intermediate_size, norm_topk_prob=text.norm_topk_prob,
+            index_topk=sa["topk"], index_heads=sa["indexer_num_heads"], index_head_dim=sa["indexer_head_dim"],
+            index_rope_dim=sa["indexer_head_dim"] // 2,
         )
     else:
         raise ValueError(f"Unsupported HF model_type {mt!r}")

@@ -174,6 +174,19 @@ class TransformerConfig:
     qk_norm: bool = False
     # what the router adds to the sum of the chosen scores before dividing by it
     router_norm_eps: float = 0.0
+    # "sigmoid" (a score an expert, chosen by score + selection bias) or "softmax" (Qwen3-MoE's rule:
+    # a softmax over all experts, no bias leaf)
+    router_scoring: str = "sigmoid"
+    # Learned sparse attention (ops/sparse_attention.py, DeepSeek's lightning indexer): index_topk > 0
+    # gives every attention layer an indexer of index_heads query heads of index_head_dim over one key
+    # head (LayerNorm on the key), rotary on the first index_rope_dim of their dimensions, per-head
+    # weights from the hidden state; each query attends over the index_topk earlier keys it scores
+    # highest. The cache holds the indexer's key beside keys and values. The indexer is held frozen:
+    # its outputs take no gradient and its leaves no update (the trainers' freeze predicate).
+    index_topk: int = 0
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_rope_dim: int = 0
 
     def __post_init__(self):
         # a list out of a json or yml file; a depth cut by num_layers alone keeps the leading layers' kinds
@@ -201,6 +214,10 @@ class TransformerConfig:
     @property
     def attention_layers(self) -> int:
         return self.num_layers - self.conv_layers
+
+    @property
+    def sparse_attention(self) -> bool:
+        return self.index_topk > 0
 
     @property
     def held_experts(self) -> int:
@@ -252,6 +269,9 @@ class TransformerConfig:
             max_length += self.num_virtual_tokens  # virtual rows live in the cache too
         if self.attention_kind == "mla":
             return kv_cache.latent_cache_layout(batch_size, max_length, self.kv_lora_rank, self.qk_rope_head_dim, dtype)
+        if self.sparse_attention:
+            return kv_cache.indexed_cache_layout(
+                batch_size, max_length, self.kv_heads, self.dim_per_head, self.index_head_dim, dtype)
         shape = (batch_size, self.kv_heads, max_length, self.dim_per_head)
         # kv heads beside the rows where single-token steps take the decode kernel and fewer than 128 rows decode
         fold = 1 if self.kv_cache_quant else decode_cache_fold(
@@ -494,6 +514,10 @@ class Attention(nn.Module):
             q = apply_rotary(q, cos, sin, c.rope_style)
             k = apply_rotary(k, cos, sin, c.rope_style)
 
+        if c.sparse_attention:
+            out, new_cache = self._selected(q, k, v, x, positions, mask_bias, cache, kv_valid)
+            return dense(c.hidden_size, "o_proj", c.attn_bias, res_std)(out), new_cache
+
         if cache is not None and kv_cache.is_paged(cache):
             # Paged step (serving engine) against the block-pool cache. T == 1
             # is the steady-state decode: the new row lands at position
@@ -560,6 +584,70 @@ class Attention(nn.Module):
         out = dense(c.hidden_size, "o_proj", c.attn_bias, res_std)(out)
         return out, new_cache
 
+    def _selected(self, q, k, v, x, positions, mask_bias, cache, kv_valid):
+        """Learned sparse attention (``config.index_topk``): the indexer, then
+        attention over the keys it selects. Multi-token forwards whose keys are
+        their own tokens take the selection and attention kernels (XLA under
+        ``attention_impl="xla"``); a single-token step over the cache scores
+        every written slot and gathers the selected ones. Sows the pairs kept and
+        the causal pairs into ``dsa_stats`` where a caller asks for them."""
+        from trlx_tpu.ops import sparse_attention as dsa
+
+        c = self.config
+        B, T = x.shape[:2]
+        if cache is not None and kv_cache.is_paged(cache):
+            raise ValueError(_DSA_REFUSALS["paged"])
+        qi, ki, w = Indexer(c, name="indexer")(x, positions)
+        scale = 1.0 / math.sqrt(c.dim_per_head)
+        new_cache = None
+        if cache is not None:
+            new_cache = kv_cache.write_indexed_cache(cache, k, v, ki, cache["index"])
+            if T == 1:
+                allowed = mask_bias[:, 0, 0, :] > -1.0  # the additive mask: 0 where a slot may be read
+                return dsa.decode_selected(q, qi, w, new_cache, allowed, topk=c.index_topk, scale=scale), new_cache
+            if kv_valid is None:
+                raise ValueError("learned sparse attention takes a cached forward of several tokens only as the "
+                                 "prefill from slot 0 with its attention mask")
+        valid = jnp.ones((B, T), jnp.int32) if kv_valid is None else kv_valid.astype(jnp.int32)
+        out, mask = dsa.attend_selected(
+            q, k, v, qi, ki, w, valid, topk=c.index_topk, scale=scale, kernels=c.attention_impl == "flash")
+        if self.is_mutable_collection("dsa_stats"):
+            kept, causal, off = dsa.pair_counts(mask, valid, c.index_topk)
+            self.sow("dsa_stats", "kept_pairs", kept)
+            self.sow("dsa_stats", "causal_pairs", causal)
+            self.sow("dsa_stats", "queries_off", off)
+        return out, new_cache
+
+
+class Indexer(nn.Module):
+    """DeepSeek-V3.2's lightning indexer over the attention's input ``x``:
+    ``qi = rope(x W_q)`` as ``index_heads`` heads of ``index_head_dim``, ``ki =
+    rope(LayerNorm(x W_k))`` one key head, ``w = (x W_w) / sqrt(heads *
+    head_dim)``; rotary (rotate-half, ``rope_theta``) on the first
+    ``index_rope_dim`` dimensions of each. Held frozen: every output is
+    stop-gradient, so the PPO loss gives the leaves nothing (top-k is discrete;
+    the published indexer's own objective is no part of PPO)."""
+
+    config: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, positions):
+        c = self.config
+        B, T, _ = x.shape
+        H, D = c.index_heads, c.index_head_dim
+        dense = lambda feats, name: nn.Dense(
+            feats, use_bias=False, dtype=c.compute_dtype, param_dtype=c.param_dtype,
+            kernel_init=nn.initializers.normal(c.initializer_range), name=name)
+        with jax.named_scope("dsa.index"):
+            qi = dense(H * D, "wq")(x).reshape(B, T, H, D)
+            ki = nn.LayerNorm(epsilon=c.norm_eps, dtype=c.compute_dtype, param_dtype=c.param_dtype,
+                              name="k_norm")(dense(D, "wk")(x))
+            cos, sin = rotary_tables(c.index_rope_dim, c.rope_theta, positions)
+            qi = apply_rotary(qi, cos, sin, "neox")
+            ki = apply_rotary(ki[:, :, None], cos, sin, "neox")[:, :, 0]
+            w = dense(H, "weights_proj")(x).astype(jnp.float32) * (1.0 / math.sqrt(H * D))
+        return jax.lax.stop_gradient(qi), jax.lax.stop_gradient(ki), jax.lax.stop_gradient(w)
+
 
 class _Kernel(nn.Module):
     """A bare ``kernel`` parameter under the module's name, for a projection
@@ -580,6 +668,25 @@ _MLA_REFUSALS = {
     "kv_cache_quant": "kv_cache_quant scales int8 rows per head; a latent row has no heads",
     "stacked": "scan_layers / pipeline_stages > 1 stack one cache array [L, B, Hkv, S, D]; latent "
                "attention caches [B, S, kv_lora_rank] + [B, S, qk_rope_head_dim] a layer",
+}
+
+
+_DSA_REFUSALS = {
+    "paged": "the paged block pool holds keys and values by token and no indexer key beside them; learned sparse "
+             "attention (index_topk) scores every written slot's indexer key a step, which serving/allocator.py does "
+             "not lay out: the serving engine does not run this model yet",
+    "stacked": "scan_layers / pipeline_stages > 1 scan one cache array [L, B, Hkv, S, D]; learned sparse attention "
+               "(index_topk) caches slot-major keys and values and an indexer key a layer",
+    "kv_cache_quant": "kv_cache_quant scales int8 rows per head of a heads-first cache; learned sparse attention "
+                      "(index_topk) gathers slot-major rows and has not been held against a reference in int8",
+    "ring": "attention_impl='ring' splits the sequence over chips, and the selection (index_topk) ranks each query's "
+            "keys over the whole sequence, which nothing exchanges yet",
+    "sequence_sharding": "sequence_sharding splits the residual stream's sequence over the model axis, and the "
+                         "selection (index_topk) ranks each query's keys over the whole sequence",
+    "peft": "prompt and prefix tuning prepend rows to what attention reads, which the indexer (index_topk) has no "
+            "keys for",
+    "mla": "learned sparse attention (index_topk) is built on per-head grouped-query attention, not on latent "
+           "attention",
 }
 
 
@@ -757,6 +864,8 @@ class _Router(nn.Module):
         c = self.config
         kernel = self.param(
             "kernel", nn.initializers.normal(c.initializer_range), (c.hidden_size, c.num_experts), c.param_dtype)
+        if c.router_scoring == "softmax":
+            return kernel, None
         # the selection bias (published ``e_score_correction_bias``): it chooses and does not weigh
         bias = self.param("bias", nn.initializers.zeros, (c.num_experts,), c.param_dtype)
         return kernel, bias
@@ -794,11 +903,25 @@ class SparseMLP(nn.Module):
         kernel, bias = _Router(c, name="router")()
         gate, up, down = _Experts(c, name="experts")()
         flat = x.reshape(B * T, d)
-        chosen, weights = moe.route(
-            flat, kernel, bias, top_k=c.experts_per_token, norm_topk=c.norm_topk_prob, scale=c.routed_scaling_factor,
-            norm_eps=c.router_norm_eps)
-        routed, load = moe.expert_ffn(
-            flat, chosen, weights, gate, up, down, expert_offset=c.expert_offset, act=_act(c.activation))
+
+        def experts(flat, real=None):
+            chosen, weights = moe.route(
+                flat, kernel, bias, top_k=c.experts_per_token, norm_topk=c.norm_topk_prob,
+                scale=c.routed_scaling_factor, norm_eps=c.router_norm_eps, scoring=c.router_scoring)
+            if real is not None:  # padding goes to no expert: no rows, no load
+                chosen = jnp.where(real[:, None], chosen, -1)
+            return moe.expert_ffn(
+                flat, chosen, weights, gate, up, down, expert_offset=c.expert_offset, act=_act(c.activation))
+
+        chunks = moe.token_chunks(B * T)
+        if chunks == 1:
+            routed, load = experts(flat)
+        else:  # a long prefill: passes of TOKEN_CHUNK tokens, one dropless buffer at a time, the last padded
+            pad = chunks * moe.TOKEN_CHUNK - B * T
+            passes = jnp.pad(flat, ((0, pad), (0, 0))).reshape(chunks, moe.TOKEN_CHUNK, d)
+            real = (jnp.arange(chunks * moe.TOKEN_CHUNK) < B * T).reshape(chunks, -1) if pad else None
+            routed, load = jax.lax.map(lambda a: experts(*a), (passes, real))
+            routed, load = routed.reshape(-1, d)[: B * T], load.sum(0)
         self.sow("moe_stats", "load", load)
         out = routed.reshape(B, T, d)
         if c.num_shared_experts:
@@ -818,6 +941,18 @@ def moe_counters(moe_stats) -> Dict[str, jnp.ndarray]:
         "moe/load_max": sum(x.max() for x in loads),
         "moe/load_mean": sum(x.mean() for x in loads),
     }
+
+
+def dsa_counters(dsa_stats) -> Dict[str, jnp.ndarray]:
+    """The ``dsa_stats`` collection of one forward as counters, summed over the
+    attention layers: the (query, key) pairs the selection kept, the causal
+    pairs there were, and the queries that kept other than ``min(topk,
+    allowed)`` keys, over real query rows (float32 scalars)."""
+    # sow keeps a tuple under each name: a leaf's path ends (..., name, index in the tuple)
+    out = {f"dsa/{name}": jnp.float32(0.0) for name in ("kept_pairs", "causal_pairs", "queries_off")}
+    for path, value in jax.tree_util.tree_leaves_with_path(dsa_stats):
+        out[f"dsa/{path[-2].key}"] = out[f"dsa/{path[-2].key}"] + value
+    return out
 
 
 def loop_counters(loop_stats) -> Dict[str, jnp.ndarray]:
@@ -921,6 +1056,14 @@ class TransformerLM(nn.Module):
             ):
                 if why:
                     raise ValueError(_CONV_REFUSALS[refused])
+        if c.sparse_attention:
+            for refused, why in (
+                ("stacked", c.stacked), ("kv_cache_quant", c.kv_cache_quant), ("ring", c.attention_impl == "ring"),
+                ("sequence_sharding", c.sequence_sharding), ("peft", c.peft_type != "none"),
+                ("mla", c.attention_kind == "mla"),
+            ):
+                if why:
+                    raise ValueError(_DSA_REFUSALS[refused])
         if c.early_exit_threshold < 1:
             raise ValueError(_LOOP_REFUSALS["early_exit"])
         if c.loop_steps > 1:
@@ -1029,6 +1172,8 @@ class TransformerLM(nn.Module):
         if cache is not None:
             if c.attention_kind == "mla":
                 S = cache["c"][0].shape[1]  # per-layer [B,S,rank]
+            elif c.sparse_attention:
+                S = cache["ki"][0].shape[1]  # per-layer [B,S,Di]
             elif not c.attention_layers:
                 S = attention_mask.shape[1]  # no layer holds slots: the mask alone says how many there are
             else:
@@ -1300,6 +1445,10 @@ class TransformerLM(nn.Module):
             from trlx_tpu.utils.metrics import gauges
 
             gauges.set("mla/cache_bytes_per_token", c.num_layers * entry_bytes)
+        if c.sparse_attention:
+            from trlx_tpu.utils.metrics import gauges
+
+            gauges.set("dsa/cache_bytes_per_token", c.cache_entries * entry_bytes)
         if c.loop_steps > 1:
             from trlx_tpu.utils.metrics import gauges
 
@@ -1361,6 +1510,8 @@ class TransformerLM(nn.Module):
             raise ValueError(_LOOP_REFUSALS["paged"])
         if c.conv_layers:
             raise ValueError(_CONV_REFUSALS["paged"])
+        if c.sparse_attention:
+            raise ValueError(_DSA_REFUSALS["paged"])
         layout = paged_pool_layout(
             num_blocks, block_size, c.kv_heads, c.dim_per_head,
             dtype or c.compute_dtype, c.kv_cache_quant,
@@ -1395,6 +1546,8 @@ class TransformerLM(nn.Module):
             raise ValueError(_LOOP_REFUSALS["paged"])
         if c.conv_layers:
             raise ValueError(_CONV_REFUSALS["paged"])
+        if c.sparse_attention:
+            raise ValueError(_DSA_REFUSALS["paged"])
         if c.stacked:
             raise NotImplementedError("paged decode: per-layer list layout only")
         if c.peft_type in ("prompt", "prefix"):
@@ -1441,6 +1594,8 @@ class TransformerLM(nn.Module):
             raise ValueError(_LOOP_REFUSALS["paged"])
         if c.conv_layers:
             raise ValueError(_CONV_REFUSALS["paged"])
+        if c.sparse_attention:
+            raise ValueError(_DSA_REFUSALS["paged"])
         if c.peft_type in ("prompt", "prefix"):
             raise NotImplementedError("paged verify does not support peft prompt/prefix")
         B, Q = input_ids.shape

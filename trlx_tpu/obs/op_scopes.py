@@ -60,6 +60,7 @@ from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 VOCABULARY = (
     "prefill", "decode", "policy_forward", "reference_forward", "logprobs", "loss", "accumulate",
     "optimizer", "mla", "moe.route", "moe.experts", "moe.shared", "loop.pass", "conv",
+    "dsa.index", "dsa.select", "dsa.attend",
 )
 CAPACITY = 64
 

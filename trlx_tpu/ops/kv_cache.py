@@ -1,7 +1,7 @@
 """What one layer of the contiguous (one-shot generator's) cache holds, and how it is written and read.
 
 A layer's cache is a plain dict of arrays — the decode ``while_loop`` carries it, so its pytree
-structure is part of the compiled program — in one of four formats:
+structure is part of the compiled program — in one of five formats:
 
 - per-head rows: ``k``, ``v`` ``[B, Hkv, S, D]`` in the compute dtype, contiguous along S per (b, h) so
   that the decode matvec streams them (``[B, S, Hkv, D]`` cost a transposed copy of every layer a step).
@@ -12,6 +12,9 @@ structure is part of the compiled program — in one of four formats:
 - per-head int8 rows (``kv_cache_quant``): ``k``, ``v`` int8 plus ``k_scale``, ``v_scale``
   ``[B, Hkv, S, 1]`` float32, one symmetric scale a row (the paged pool quantizes its rows the same way);
 - latent: ``c`` ``[B, S, rank]``, the normed latent, and ``k_rope`` ``[B, S, rope]``, the rotated shared key;
+- indexed (learned sparse attention, ``ops/sparse_attention.py``): ``k``, ``v`` ``[B, S, Hkv, D]`` slot-major,
+  so that the keys and values of a selected slot are one contiguous row to gather, and ``ki`` ``[B, S, Di]``,
+  the indexer's key of every slot, which a decode step reads whole;
 - a short convolution's state: ``conv`` ``[B, taps - 1, d]``, the row's last gated inputs, the ones the next
   token's filter reads beside its own — a fixed size whatever the length, so nothing of it is "per token".
 
@@ -71,6 +74,13 @@ def latent_cache_layout(batch_size: int, max_length: int, rank: int, rope_dim: i
     }
 
 
+def indexed_cache_layout(batch_size: int, max_length: int, kv_heads: int, head_dim: int, index_dim: int,
+                         dtype) -> Layout:
+    """A learned-sparse-attention layer's buffers: slot-major keys and values and the indexer's key."""
+    kv = ((batch_size, max_length, kv_heads, head_dim), dtype)
+    return {"k": kv, "v": kv, "ki": ((batch_size, max_length, index_dim), dtype)}
+
+
 def conv_state_layout(batch_size: int, taps: int, width: int, dtype) -> Layout:
     """A short-convolution layer's buffer: the last ``taps - 1`` gated inputs of every row."""
     return {"conv": ((batch_size, taps - 1, width), dtype)}
@@ -86,6 +96,11 @@ def is_latent(layer) -> bool:
     return "c" in layer
 
 
+def is_indexed(layer) -> bool:
+    """Whether a layer's cache (or layout) holds slot-major keys and values beside an indexer's keys."""
+    return "ki" in layer
+
+
 def is_paged(layer) -> bool:
     """Whether a layer's cache is the serving engine's block pool, read through block tables."""
     return "block_tables" in layer
@@ -94,7 +109,7 @@ def is_paged(layer) -> bool:
 def bytes_per_token(layout: Layout, batch_size: int) -> int:
     """Bytes one token's slot takes in one layer of ``layout`` for ``batch_size`` rows, every buffer
     counted (a folded layer holds ``batch_size * fold`` rows of ``Hkv / fold`` heads: the same bytes)."""
-    slot_axis = 1 if is_latent(layout) else 2
+    slot_axis = 1 if is_latent(layout) or is_indexed(layout) else 2
     return sum(
         math.prod(shape) // (batch_size * shape[slot_axis]) * jnp.dtype(dtype).itemsize
         for shape, dtype in layout.values()
@@ -162,6 +177,16 @@ def write_latent_cache(cache: Dict[str, jnp.ndarray], latent: jnp.ndarray, k_rop
     return {
         "c": jax.lax.dynamic_update_slice(cache["c"], latent.astype(cache["c"].dtype), at),
         "k_rope": jax.lax.dynamic_update_slice(cache["k_rope"], k_rope[:, :, 0].astype(cache["k_rope"].dtype), at),
+    }
+
+
+def write_indexed_cache(cache: Dict[str, jnp.ndarray], k: jnp.ndarray, v: jnp.ndarray, ki: jnp.ndarray, idx):
+    """Append ``k``, ``v`` [B,T,Hkv,D] and the indexer's ``ki`` [B,T,Di] at slot ``idx``, as the projections
+    leave them."""
+    return {
+        "k": jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype), (0, idx, 0, 0)),
+        "v": jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype), (0, idx, 0, 0)),
+        "ki": jax.lax.dynamic_update_slice(cache["ki"], ki.astype(cache["ki"].dtype), (0, idx, 0)),
     }
 
 

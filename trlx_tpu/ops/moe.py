@@ -3,7 +3,8 @@ held here, weighted combine. Dropless.
 
 The router scores every token against all ``num_experts`` published experts
 (sigmoid scores in float32, the ``top_k`` largest of score + selection bias
-chosen, weights the chosen scores normalised and scaled). The layer is told
+chosen, or a softmax over all of them and the ``top_k`` largest chosen;
+weights the chosen scores normalised and scaled). The layer is told
 which experts it holds, ``gate.shape[0]`` of them from ``expert_offset``: an
 assignment to an expert that is not held adds nothing here (another chip of the
 deployment computes it), and nothing stands in for it.
@@ -34,7 +35,7 @@ scatter-add.
 """
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -45,20 +46,26 @@ logger = logging.get_logger(__name__)
 
 
 def route(
-    h: jnp.ndarray, kernel: jnp.ndarray, bias: jnp.ndarray, *, top_k: int, norm_topk: bool, scale: float,
-    norm_eps: float = 0.0,
+    h: jnp.ndarray, kernel: jnp.ndarray, bias: Optional[jnp.ndarray], *, top_k: int, norm_topk: bool, scale: float,
+    norm_eps: float = 0.0, scoring: str = "sigmoid",
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """h [N, d], kernel [d, experts], bias [experts] -> (experts chosen [N, top_k]
     int32, their weights [N, top_k] float32). Scores are float32 at full
     precision whatever the compute dtype, as the published router's are: a
-    rounded score flips the choice between near-equal experts. The bias only
-    chooses, so it takes no gradient. ``norm_eps`` is what a published router
+    rounded score flips the choice between near-equal experts. ``scoring``
+    ``"sigmoid"``: a score an expert, the ``top_k`` largest of score + bias
+    chosen; the bias only chooses, so it takes no gradient. ``"softmax"``
+    (Qwen3-MoE's rule, no bias): the scores are a softmax over all experts and
+    the ``top_k`` largest are chosen. ``norm_eps`` is what a published router
     adds to the sum it divides by (0 adds nothing)."""
     with jax.named_scope("moe.route"):
-        scores = jax.nn.sigmoid(
-            jnp.dot(h.astype(jnp.float32), kernel.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST)
-        )
-        _, chosen = jax.lax.top_k(jax.lax.stop_gradient(scores + bias.astype(jnp.float32)), top_k)
+        logits = jnp.dot(h.astype(jnp.float32), kernel.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST)
+        if scoring == "softmax":
+            scores = jax.nn.softmax(logits, axis=-1)
+            _, chosen = jax.lax.top_k(jax.lax.stop_gradient(scores), top_k)
+        else:
+            scores = jax.nn.sigmoid(logits)
+            _, chosen = jax.lax.top_k(jax.lax.stop_gradient(scores + bias.astype(jnp.float32)), top_k)
         weights = jnp.take_along_axis(scores, chosen, axis=-1)
         if norm_topk:
             total = weights.sum(-1, keepdims=True)
@@ -92,6 +99,20 @@ MESH_REFUSAL = (
     "cannot partition, and the mesh has no expert axis and no all-to-all yet: routed experts run on a "
     "one-device mesh only"
 )
+
+
+#: tokens up to which an expert layer takes its tokens in one pass; beyond, passes of
+#: TOKEN_CHUNK tokens (a prefill of 16 rows of 7,680 would lay 983,040 assignments in one buffer)
+ONE_PASS_TOKENS = 32768
+TOKEN_CHUNK = 16384
+
+
+def token_chunks(tokens: int) -> int:
+    """How many passes an expert layer takes ``tokens`` in: 1 up to
+    ``ONE_PASS_TOKENS`` (every learner microbatch, scorer chunk and prefill the
+    other cells run), else passes of ``TOKEN_CHUNK`` tokens, the last padded.
+    A pure function of the shape."""
+    return 1 if tokens <= ONE_PASS_TOKENS else -(-tokens // TOKEN_CHUNK)
 
 
 def _row_tile(assignments: int) -> int:

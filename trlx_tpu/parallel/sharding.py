@@ -80,6 +80,9 @@ def default_lm_rules() -> List[Rule]:
         (r".*out_proj/kernel$", PartitionSpec(MODEL_AXIS, FSDP_AXIS)),
         (r".*conv/kernel$", PartitionSpec(MODEL_AXIS, None)),
         (r".*(q_norm|k_norm)/scale$", PartitionSpec()),
+        # the indexer of learned sparse attention: its narrow products out of the hidden state
+        # (query heads, the one key head, the per-head weights) over fsdp alone
+        (r".*indexer/(wq|wk|weights_proj)/kernel$", PartitionSpec(FSDP_AXIS, None)),
         # mlp: up/gate column-parallel; down row-parallel
         (r".*(up_proj|gate_proj)/kernel$", PartitionSpec(FSDP_AXIS, MODEL_AXIS)),
         (r".*(up_proj|gate_proj)/bias$", PartitionSpec(MODEL_AXIS)),

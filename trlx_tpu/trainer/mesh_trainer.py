@@ -28,6 +28,7 @@ from trlx_tpu.data.configs import TRLConfig
 from trlx_tpu.models.transformer import TransformerConfig
 from trlx_tpu.obs import Observability, batch_token_count, compile_log, op_scopes
 from trlx_tpu.ops.attention import decode_cache_lane_fill, decode_cache_read_share
+from trlx_tpu.ops.sparse_attention import decode_bytes_mean
 from trlx_tpu.ops.generation import generate as generate_op
 from trlx_tpu.ops.generation import LENGTH_BUCKETS, generate_seq2seq, left_pad_batch, pad_to_bucket
 from trlx_tpu.parallel import mesh as mesh_lib
@@ -221,7 +222,11 @@ class MeshRLTrainer(BaseRLTrainer):
     def trainable_path_predicate(self, path: str) -> bool:
         """Which params receive gradients (parity: ``freeze_bottom_causal_layers``,
         reference utils/modeling.py:22-45): with num_layers_unfrozen = N > 0, only
-        the top N transformer layers and all heads train; -1 trains everything."""
+        the top N transformer layers and all heads train; -1 trains everything.
+        The indexer of learned sparse attention never trains (its selection is
+        discrete: the loss gives it no gradient, and it takes no update)."""
+        if "indexer" in path.split("/"):
+            return False
         if self.config.model.peft_config:
             # peft mode: only adapters (LoRA / prefix K-V / prompt embeddings)
             # and heads receive gradients
@@ -590,7 +595,15 @@ class MeshRLTrainer(BaseRLTrainer):
             sequences = np.asarray(jax.device_get(out["sequences"]))
             response_mask = np.asarray(jax.device_get(out["response_mask"]))
         model_config = getattr(self, "model_config", None)
-        if isinstance(model_config, TransformerConfig) and model_config.attention_layers:
+        if isinstance(model_config, TransformerConfig) and model_config.sparse_attention:
+            # what a decode step read of the cache, by part, averaged over the steps the loop ran
+            c, steps = model_config, int(response_mask.sum(axis=1).max()) - 1
+            index, selected = decode_bytes_mean(
+                c.cache_entries, ids.shape[0], P, steps, c.index_topk, c.kv_heads, c.dim_per_head, c.index_head_dim,
+                jnp.dtype(c.compute_dtype).itemsize)
+            gauges.set("dsa/decode_index_bytes_per_step", index)
+            gauges.set("dsa/decode_kv_bytes_per_step", selected)
+        elif isinstance(model_config, TransformerConfig) and model_config.attention_layers:
             # the loop ran until the longest row ended; its first token came from the prefill
             steps = int(response_mask.sum(axis=1).max()) - 1
             c, rows = model_config, ids.shape[0]

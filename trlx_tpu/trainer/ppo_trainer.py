@@ -29,7 +29,7 @@ from trlx_tpu.models.policy import (
     branch_param_subtree,
     head_of,
 )
-from trlx_tpu.models.transformer import TransformerLM, loop_counters, moe_counters
+from trlx_tpu.models.transformer import TransformerLM, dsa_counters, loop_counters, moe_counters
 from trlx_tpu.obs import compile_log, op_scopes, span
 from trlx_tpu.obs.flight import flight
 from trlx_tpu.ops.generation import LENGTH_BUCKETS, left_pad_batch, pad_to_bucket
@@ -1618,8 +1618,10 @@ class PPOTrainer(MeshRLTrainer):
 
         counts_experts = self.model_config.num_experts > 0
         counts_loops = self.model_config.loop_steps > 1
+        counts_pairs = self.model_config.sparse_attention
         sown_collections = [
-            name for name, counted in (("moe_stats", counts_experts), ("loop_stats", counts_loops)) if counted
+            name for name, counted in (
+                ("moe_stats", counts_experts), ("loop_stats", counts_loops), ("dsa_stats", counts_pairs)) if counted
         ]
 
         def loss_fn(params, mb: PPORLBatch):
@@ -1652,6 +1654,8 @@ class PPOTrainer(MeshRLTrainer):
                 )
             if counts_loops:
                 stats.update(loop_counters(sown["loop_stats"]))
+            if counts_pairs:
+                stats.update(dsa_counters(sown["dsa_stats"]))
             return loss, stats
 
         self._train_steps[key] = self.make_grad_accum_step(
@@ -1689,8 +1693,9 @@ class PPOTrainer(MeshRLTrainer):
         with span("learn.sync"):  # the host waiting for the device
             out = {k: float(v) for k, v in jax.device_get(stats).items()}
         for name, value in out.items():
-            # a microbatch's counters: the experts' summed over the expert layers, the loop's per pass
-            if name.startswith(("moe/", "loop/")):
+            # a microbatch's counters: the experts' summed over the expert layers, the loop's per pass,
+            # the selection's pairs summed over the attention layers
+            if name.startswith(("moe/", "loop/", "dsa/")):
                 gauges.set(name, value)
         if self._island is not None:
             # device_get above synced the step; the interval is real compute
