@@ -432,6 +432,50 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_persistent_cache, monkeypatc
         assert all(re.match(r"^%attn[.0-9]* custom-call$", name) for name in names), names
 
 
+def test_the_keye_learner_selects_once_a_layer(topo, one_chip, no_persistent_cache, monkeypatch):
+    """A keye-vl-2.0-30b-a3b layer as the learner runs it (published widths, remat ``per_layer``, one row of
+    the cell's 8,193 tokens; a dense FFN in place of the experts, whose products have cases of their own),
+    its gradient compiled for the chip: the forward's packed selection is kept across the recomputation, so
+    the program holds one ``%dsa_select`` custom-call a layer, in the forward, and none in the backward —
+    and the trainer's gauge reads 1 a layer from the program's table."""
+    from jax.sharding import Mesh
+
+    from trlx_tpu.obs import op_scopes
+    from trlx_tpu.trainer.ppo_trainer import PPOTrainer
+    from trlx_tpu.utils.metrics import gauges
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    config = PRESETS["keye_vl"].replace(
+        num_layers=1, num_experts=0, vocab_size=8192, attention_impl="flash", compute_dtype=jnp.bfloat16,
+        remat="per_layer")
+    model = TransformerLM(config)
+    B, T = 1, 8193
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32), jnp.ones((1, 8), jnp.int32))["params"]
+    )
+    leaves, treedef = jax.tree_util.tree_flatten(params)
+
+    def fn(ids, mask, *leaves):
+        def hidden(leaves):
+            return model.apply({"params": jax.tree_util.tree_unflatten(treedef, leaves)}, ids, mask, with_head=False)[1]
+
+        return jax.grad(lambda leaves: hidden(leaves).astype(jnp.float32).sum())(list(leaves))
+
+    args = [jax.ShapeDtypeStruct((B, T), jnp.int32, sharding=one_chip)] * 2 + [
+        jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip) for x in leaves]
+    rows = op_scopes.walk(jax.jit(fn).lower(*args).compile().as_text())
+    selections = {name: row["pass"] for name, row in rows.items() if op_scopes.kernels_named({name: row}, "dsa_select")}
+    assert sorted(selections.values()) == ["forward"] * config.num_layers, selections
+    assert op_scopes.kernels_named(rows, "sparse_attn") == 4 * config.num_layers  # forward, again, dq, dkv
+
+    trainer = object.__new__(PPOTrainer)
+    trainer.model_config, trainer.mesh = config, Mesh(np.array(topo.devices[:1]), ("data",))
+    monkeypatch.setattr(op_scopes, "table", lambda program: rows if program == trainer.train_step_name else None)
+    gauges.clear("dsa/")
+    trainer._count_selections()
+    assert gauges.get("dsa/train_step_selections_per_layer") == 1.0
+
+
 def _sharded_ppo_learner(topo, fsdp=4, layers=2):
     """``PPOTrainer``'s own train step over a ``data=1, fsdp=4`` mesh of the described chips, as
     ``chip_smoke.py --chips 4`` runs it (gpt2's widths and vocabulary, 64 + 65 tokens, batch 32)

@@ -175,6 +175,26 @@ def test_the_selection_keeps_the_top_scores_and_ties_go_to_the_earlier_key():
     np.testing.assert_array_equal(np.asarray(reference.select(jnp.asarray(scores), jnp.asarray(allowed), topk)), want)
 
 
+def test_the_packed_selection_unpacks_to_the_kernels_mask():
+    """What a layer keeps of its selection across its recomputation: the kernel's
+    int8 mask (interpreted) at a length that is no multiple of 256, packed 32 query
+    rows to a uint32 word (bit ``t % 32`` of word ``(t // 32, s)``) and unpacked,
+    is the kernel's mask bit for bit, the padded rows' zeros included."""
+    B, T, H, D, topk = 2, 300, 2, 8, 9
+    ks = jax.random.split(jax.random.PRNGKey(8), 3)
+    qi, keys = jax.random.normal(ks[0], (B, T, H, D)), jax.random.normal(ks[1], (B, T, D))
+    w = jnp.abs(jax.random.normal(ks[2], (B, T, H)))
+    valid = jnp.ones((B, T), jnp.int32).at[1, :7].set(0)
+    mask = dsa.select_keys(qi, keys, w, valid, topk, True)
+    assert mask.shape == (B, *dsa.padded_lengths(T, T)) and mask.dtype == jnp.int8
+    words = dsa.pack_selection(mask)
+    assert words.shape == (B, mask.shape[1] // 32, mask.shape[2]) and words.dtype == jnp.uint32
+    bits = (np.asarray(words)[:, :, None, :] >> np.arange(32, dtype=np.uint32)[:, None]) & 1
+    np.testing.assert_array_equal(bits.reshape(mask.shape), np.asarray(mask))
+    np.testing.assert_array_equal(np.asarray(jax.jit(dsa.unpack_selection)(words)), np.asarray(mask))
+    assert int(np.asarray(mask)[:, T:].sum()) == 0 and int(np.asarray(mask).sum()) > 0
+
+
 def test_the_attention_kernels_match_xla_forward_and_backward():
     """The kernels over a selection (forward, dq, dkv, interpreted) against the XLA
     path's masked attention, values and gradients, with grouped heads and a query

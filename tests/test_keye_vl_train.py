@@ -77,6 +77,67 @@ def test_the_ppo_losss_gradient_matches_the_reference_leaf_by_leaf():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4 * scale, err_msg="/".join(path))
 
 
+@functools.lru_cache(maxsize=None)
+def _recomputed_layer(saves_selection: bool):
+    """One learner microbatch's loss and gradient under ``remat="per_layer"``,
+    through the kernels (interpreted), the collections the learner asks for
+    included: (the ``dsa_select`` calls of the lowered program, the gradient).
+    ``saves_selection`` False empties the names the layer's remat keeps, as the
+    program was before it kept the packed selection."""
+    from trlx_tpu.models import transformer
+
+    saved = transformer._saved_names if saves_selection else (lambda config: ())
+    module, like = program(tiny_config(topk=8), attention_impl="flash", remat="per_layer", num_experts=0)
+    weights = jax.tree.map(lambda x: jax.random.normal(jax.random.PRNGKey(3), x.shape, x.dtype) * 0.05, like)
+    ids, mask = inputs(6, B=2, T=40, pads=(3, 0))
+
+    def loss(p):
+        (logits, values, _, _), sown = module.apply({"params": p}, ids, mask, mutable=["dsa_stats"])
+        return jax.nn.log_softmax(logits.astype(jnp.float32))[:, 20:].mean() + values.mean(), sown
+
+    step = jax.jit(jax.grad(loss, has_aux=True))
+    with mock.patch.object(transformer, "_saved_names", saved):  # the layers' remat is made as they are traced
+        calls = len(re.findall(r"\bcall @dsa_select\w*\(", step.lower(weights).as_text()))
+        return calls, step(weights)[0]
+
+
+@pytest.mark.parametrize("saves_selection", [True, False], ids=["saved", "recomputed"])
+def test_the_learner_selects_once_a_layer(saves_selection):
+    """The packed selection kept across the layer's recomputation: the backward
+    reads it and runs neither the indexer nor the selection kernel again, one
+    ``dsa_select`` call a layer in the lowered program where it was two."""
+    calls, _ = _recomputed_layer(saves_selection)
+    assert calls == 2 * (1 if saves_selection else 2), calls
+
+
+def test_the_kept_selection_gives_the_recomputed_gradient():
+    """The gradient through the kept selection is the one through a selection
+    made again, to float32 round-off: the same bits, the same attention."""
+    _, kept = _recomputed_layer(True)
+    _, again = _recomputed_layer(False)
+    for (path, a), (_, b) in zip(harness._paths(kept), harness._paths(again)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6, atol=0, err_msg="/".join(path))
+    assert any(float(np.abs(np.asarray(a)).max()) > 0 for _, a in harness._paths(kept))
+
+
+@pytest.mark.parametrize("name", ["full", "per_layer", "nothing_saveable", "dots_saveable"])
+def test_only_a_model_with_an_indexer_keeps_its_selection(name):
+    """Without an indexer a remat policy is what it was (``None`` for ``full`` and
+    ``per_layer``, jax's own policies else); with one, every policy but
+    ``nothing_saveable`` also keeps the name the packed selection carries."""
+    from trlx_tpu.models.transformer import _saved_names, remat_policy
+    from trlx_tpu.ops.sparse_attention import SELECTION_NAME
+
+    module, _ = tiny_program()
+    plain = module.config.replace(index_topk=0)
+    assert _saved_names(plain) == () and _saved_names(module.config) == (SELECTION_NAME,)
+    before = {"full": None, "per_layer": None, "nothing_saveable": jax.checkpoint_policies.nothing_saveable,
+              "dots_saveable": jax.checkpoint_policies.dots_saveable}[name]
+    assert remat_policy(name, _saved_names(plain)) is before
+    kept = remat_policy(name, _saved_names(module.config))
+    assert (kept is before) == (name == "nothing_saveable")
+
+
 def test_softmax_routing_is_the_direct_computation():
     """Qwen3-MoE's rule: a softmax over all experts in float32, the top ``k``,
     their probabilities over their sum; the sigmoid rule is untouched."""

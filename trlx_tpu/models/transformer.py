@@ -294,19 +294,38 @@ class TransformerConfig:
         return replace(self, **kw)
 
 
-def remat_policy(name: str):
+def remat_policy(name: str, saved: Tuple[str, ...] = ()):
     """Rematerialization policy by config name (shared by the listed-layer stack
     and the pipelined stage scan). ``per_layer`` = save only the block-boundary
     residuals (an ``nn.remat`` with no policy), the scale-appropriate middle
     ground between ``nothing_saveable`` (recompute everything, xl-class) and
     ``dots_saveable`` (keep matmul outputs, small models) — guidance per model
-    scale in docs/parallelism.md "Learner overlap & FSDP"."""
+    scale in docs/parallelism.md "Learner overlap & FSDP".
+
+    ``saved``: ``checkpoint_name``s that ``full``, ``per_layer`` and
+    ``dots_saveable`` keep besides; ``nothing_saveable`` keeps none.
+    ``TransformerLM`` passes the packed selection of a model with an indexer
+    (``ops/sparse_attention.py``: ``SELECTION_NAME``, 1 bit a (query, key) pair,
+    9.2 MB a layer and row at 8,448 x 8,704), so the backward does not select
+    again; for every other model the tuple is empty and the policy as it was."""
+    names = jax.checkpoint_policies.save_only_these_names(*saved) if saved else None
+    dots = jax.checkpoint_policies.dots_saveable
     return {
-        "full": None,
-        "per_layer": None,
+        "full": names,
+        "per_layer": names,
         "nothing_saveable": jax.checkpoint_policies.nothing_saveable,
-        "dots_saveable": jax.checkpoint_policies.dots_saveable,
+        "dots_saveable": jax.checkpoint_policies.save_from_both_policies(dots, names) if saved else dots,
     }[name]
+
+
+def _saved_names(config: TransformerConfig) -> Tuple[str, ...]:
+    """The ``checkpoint_name``s a layer's remat keeps for this model: the packed
+    selection where it has an indexer, else none."""
+    if not config.sparse_attention:
+        return ()
+    from trlx_tpu.ops.sparse_attention import SELECTION_NAME
+
+    return (SELECTION_NAME,)
 
 
 def _act(name: str):
@@ -1038,7 +1057,7 @@ class TransformerLM(nn.Module):
             )
         block = Block
         if c.remat != "none":
-            block = nn.remat(Block, policy=remat_policy(c.remat))
+            block = nn.remat(Block, policy=remat_policy(c.remat, _saved_names(c)))
         if c.stacked and c.num_experts > 0:
             raise ValueError(
                 "scan_layers / pipeline_stages > 1 run one scanned Block over stacked parameters, "

@@ -176,6 +176,14 @@ def table(program: str) -> Optional[Dict[str, Dict[str, Any]]]:
     return _tables[key]
 
 
+def kernels_named(rows: Dict[str, Dict[str, Any]], name: str) -> int:
+    """The kernel instructions of a table that the chip named after the function
+    jitted around a Pallas call (``%dsa_select.7``). A program that runs the
+    kernel interpreted (the CPU) holds none."""
+    named = re.compile(rf"^%(\w*_)?{re.escape(name)}[_.0-9]*$")
+    return sum(1 for instruction, row in rows.items() if row["kind"] == "kernel" and named.match(instruction))
+
+
 def write(path: str) -> Dict[str, int]:
     """Every noted program's table as one JSON object ``{program: table}``;
     returns the rows written by program."""

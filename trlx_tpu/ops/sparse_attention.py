@@ -25,6 +25,11 @@ the block's diagonal, and writes the mask as int8 ``[B, Tp, Sp]`` (``T`` padded 
 forms the whole ``[B, T, S]`` scores and takes ``lax.top_k`` of each row, as a
 decode step does.
 
+A forward through the kernels packs the mask to a bit a pair (:func:`pack_selection`)
+under the ``checkpoint_name`` :data:`SELECTION_NAME` and attends over it unpacked, so a
+layer's recomputation under ``remat`` can take the forward's selection and skip the
+indexer and the selection kernel (``models/transformer.py``: ``remat_policy``).
+
 The attention kernels (:func:`sparse_attention`, forward, ``dq`` and ``dkv``) are
 flash attention with that mask in place of the causal one: a program holds one kv
 head's ``rep`` query heads for 256 queries against 512 keys, and a (query block, key
@@ -49,6 +54,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -60,6 +66,10 @@ SELECT_ROWS = 128  # query rows of a selection program
 CHUNK = 512  # keys a selection pass takes at a time
 Q_BLOCK = 256  # queries of an attention program
 K_BLOCK = 512  # keys of an attention grid step
+PACK = 32  # query rows of the selection a word of the packed mask holds
+#: the ``checkpoint_name`` of a layer's packed selection: a remat policy that saves it keeps
+#: the learner's backward from running the indexer and the selection kernel again
+SELECTION_NAME = "dsa_selection"
 _VMEM_LIMIT = 64 * 2 ** 20
 
 MESH_REFUSAL = (
@@ -228,6 +238,26 @@ def select_keys(qi, ki, w, kv_valid, topk: int, interpret: bool):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"), vmem_limit_bytes=_VMEM_LIMIT),
     )(qi_t, w, ki, valid)
+
+
+def pack_selection(mask):
+    """int8 ``[B, Tp, Sp]`` -> uint32 ``[B, Tp / 32, Sp]``: bit ``r`` of word
+    ``(t // 32, s)`` is ``mask[t, s]``. A quarter of the int8 mask's bytes, and
+    the form a layer keeps of its selection across its recomputation."""
+    B, Tp, Sp = mask.shape
+    # the barrier holds the fusion to the 4-D view, whose tiles are the mask's own: left to
+    # itself the compiler moves the reshape and writes a uint32 [Tp, Sp] copy first (294 MB
+    # at 8,448 x 8,704), and so it does around the broadcast of the unpacking
+    rows = jax.lax.optimization_barrier(mask.reshape(B, Tp // PACK, PACK, Sp))
+    bits = (rows != 0).astype(jnp.uint32)
+    return jnp.left_shift(bits, jnp.arange(PACK, dtype=jnp.uint32)[:, None]).sum(axis=2, dtype=jnp.uint32)
+
+
+def unpack_selection(words):
+    """:func:`pack_selection`'s inverse: a broadcast over a major axis and a shift."""
+    B, n, Sp = words.shape
+    bits = jnp.right_shift(words[:, :, None, :], jnp.arange(PACK, dtype=jnp.uint32)[:, None]) & 1
+    return jax.lax.optimization_barrier(bits.astype(jnp.int8)).reshape(B, n * PACK, Sp)
 
 
 def block_table(mask):
@@ -503,7 +533,13 @@ def attend_selected(q, k, v, qi, ki, w, kv_valid, *, topk: int, scale: float, ke
     indexer's qi [B, T, Hi, Di], ki [B, T, Di], w [B, T, Hi]; ``kv_valid`` [B, T].
     ``kernels``: the Pallas kernels (interpret mode on the CPU), else XLA.
     Returns ([B, T, H * D] in q's dtype, the selection: int8 [B, Tp, Sp] from the
-    kernels, bool [B, T, S] from XLA)."""
+    kernels, bool [B, T, S] from XLA).
+
+    The kernels' selection is packed (:func:`pack_selection`) under the
+    ``checkpoint_name`` :data:`SELECTION_NAME`, and everything after it reads the
+    words unpacked, never the kernel's own output: a remat policy that saves the
+    name hands the layer's recomputation the forward's selection, and the
+    indexer and the selection kernel do not run again in the backward."""
     B, T, H, D = q.shape
     mesh = ambient_mesh()
     if kernels and mesh is not None and mesh.size > 1:
@@ -512,6 +548,7 @@ def attend_selected(q, k, v, qi, ki, w, kv_valid, *, topk: int, scale: float, ke
     mask = select(qi, ki, w, kv_valid, topk, kernels=kernels, interpret=interpret)
     if kernels:
         with jax.named_scope("dsa.select"):
+            mask = unpack_selection(checkpoint_name(pack_selection(mask), SELECTION_NAME))
             blocks = block_table(mask)
         with jax.named_scope("dsa.attend"):
             out = sparse_attention(q, k, v, mask, blocks, scale, interpret)

@@ -1682,14 +1682,16 @@ class PPOTrainer(MeshRLTrainer):
             dbatch = self._pop_staged_learn(batch)
             if dbatch is None:
                 dbatch = mesh_lib.put_batch(self.mesh, batch)
-        step = self._get_train_step(
-            batch.query_tensors.shape[0], batch.query_tensors.shape[1], batch.response_tensors.shape[1]
-        )
+        shape = (batch.query_tensors.shape[0], batch.query_tensors.shape[1], batch.response_tensors.shape[1])
+        first = shape not in self._train_steps
+        step = self._get_train_step(*shape)
         t_learn0 = time.monotonic()
         with span("learn.step"), self.mesh, compile_log.attributed(self.train_step_name):  # dispatch
             # (under the health guard ``step`` is a plain wrapper, which notes the jitted step it calls)
             op_scopes.note(self.train_step_name, step, (self.params, self.opt_state, dbatch), mesh=self.mesh)
             self.params, self.opt_state, stats = step(self.params, self.opt_state, dbatch)
+        if first and getattr(self.model_config, "sparse_attention", False):  # a T5Config has no indexer
+            self._count_selections()
         with span("learn.sync"):  # the host waiting for the device
             out = {k: float(v) for k, v in jax.device_get(stats).items()}
         for name, value in out.items():
@@ -1708,6 +1710,21 @@ class PPOTrainer(MeshRLTrainer):
             out.update(gauges.snapshot("serving/"))
             out.update(gauges.snapshot("fleet/"))
         return out
+
+    def _count_selections(self) -> None:
+        """Once a train step is compiled for a model with an indexer: the selection
+        kernel's calls in its program a layer, from the program's own table of
+        instructions (gauge ``dsa/train_step_selections_per_layer``): 1 where the
+        layer's recomputation reads the forward's packed selection, 2 where the
+        backward selects again. Where the kernel runs interpreted (the CPU) the
+        program holds no such instruction: no table is built and no gauge set."""
+        if self.mesh.devices.flat[0].platform == "cpu":
+            return
+        calls = op_scopes.kernels_named(op_scopes.table(self.train_step_name) or {}, "dsa_select")
+        if calls:
+            per_layer = calls / self.model_config.attention_layers
+            gauges.set("dsa/train_step_selections_per_layer", per_layer)
+            logger.info(f"{self.train_step_name}: {calls} selection kernel call(s), {per_layer:g} a layer")
 
     def post_backward_callback(self):
         """KL controller update per optimizer step (parity: :227-231); under the
